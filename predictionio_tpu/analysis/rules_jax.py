@@ -25,14 +25,12 @@ property checkable here:
 * ``PIO303`` unhashable static arg spec: ``static_argnums``/
   ``static_argnames`` given a list/set/dict literal — jit requires
   hashable statics; pass a tuple.
-* ``PIO304`` raw ``shard_map`` outside ``ops/compat.py``: the shim
-  there absorbs the API's home moves (``jax.experimental.shard_map`` ->
-  ``jax.shard_map``) AND its replication-check rename (``check_rep`` ->
-  ``check_vma``), so a direct import/attribute use in a kernel quietly
-  re-breaks jax<0.6 hosts the moment it needs either knob.
+* ``PIO304`` deprecated ``shard_map``: ``jax.experimental.shard_map`` is
+  deprecated on the installed JAX and spells the replication check
+  ``check_rep``; kernels call the top-level ``jax.shard_map`` (with
+  ``check_vma``) directly, so an experimental import is a finding.
 * ``PIO305`` raw int8 quantization outside ``ops/quant.py``: ONE
-  quantization rule lives in ONE module (the same containment contract
-  PIO304 enforces for shard_map) — the rounding mode, the zero-row
+  quantization rule lives in ONE module — the rounding mode, the zero-row
   guard, and the re-quantize-on-scatter rule must agree everywhere, or
   the fold-in path writes rows the serving kernels decode differently.
   ``.astype(jnp.int8)``, ``dtype=...int8`` and bare ``np.int8``/
@@ -298,24 +296,13 @@ def check_static_args(ctx: FileContext) -> Iterator[Finding]:
                 )
 
 
-#: the one module allowed to touch jax's shard_map API directly — the
-#: version shim every sharded kernel must import from
-_SHARD_MAP_SHIM = "predictionio_tpu/ops/compat.py"
-
-_SHARD_MAP_ATTRS = frozenset(
-    {"jax.shard_map", "jax.experimental.shard_map.shard_map"}
-)
-
-
 @rule(
     "PIO304",
-    "raw-shard-map",
-    "shard_map imported/used directly instead of the ops.compat shim",
+    "deprecated-shard-map",
+    "jax.experimental.shard_map used instead of jax.shard_map",
 )
-def check_raw_shard_map(ctx: FileContext) -> Iterator[Finding]:
+def check_deprecated_shard_map(ctx: FileContext) -> Iterator[Finding]:
     if not _in_scope(ctx):
-        return
-    if ctx.rel_path.replace("\\", "/") == _SHARD_MAP_SHIM:
         return
     seen: set[int] = set()
     for node in ast.walk(ctx.tree):
@@ -323,7 +310,7 @@ def check_raw_shard_map(ctx: FileContext) -> Iterator[Finding]:
         if isinstance(node, ast.ImportFrom):
             mod = node.module or ""
             if mod == "jax.experimental.shard_map" or (
-                mod in ("jax", "jax.experimental")
+                mod == "jax.experimental"
                 and any(a.name == "shard_map" for a in node.names)
             ):
                 hit = f"from {mod} import shard_map"
@@ -331,17 +318,15 @@ def check_raw_shard_map(ctx: FileContext) -> Iterator[Finding]:
             if any(a.name == "jax.experimental.shard_map" for a in node.names):
                 hit = "import jax.experimental.shard_map"
         elif isinstance(node, ast.Attribute):
-            if ctx.dotted_name(node) in _SHARD_MAP_ATTRS:
+            if ctx.dotted_name(node) == "jax.experimental.shard_map.shard_map":
                 hit = ctx.dotted_name(node)
         if hit is not None and node.lineno not in seen:
             seen.add(node.lineno)
             yield ctx.finding(
                 "PIO304",
                 node,
-                f"{hit}: sharded kernels must go through "
-                "predictionio_tpu.ops.compat.shard_map — the shim keeps "
-                "jax<0.6 hosts working (import home + check_rep/"
-                "check_vma rename both live there)",
+                f"{hit}: deprecated — call jax.shard_map (its replication "
+                "check is check_vma, not check_rep)",
             )
 
 

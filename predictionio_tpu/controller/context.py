@@ -21,9 +21,15 @@ import dataclasses
 from typing import Any, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
-__all__ = ["WorkflowContext", "local_context", "mesh_context"]
+__all__ = [
+    "WorkflowContext",
+    "device_info",
+    "device_memory",
+    "local_context",
+    "mesh_context",
+]
 
 #: Canonical mesh-axis names used across the framework. ``data`` shards the
 #: batch / entity dimension, ``model`` shards factor/feature dimensions.
@@ -56,6 +62,13 @@ class WorkflowContext:
     #: state from it (SURVEY.md section 8.3 "incremental re-index" —
     #: the reference gets cheap retrains from Spark RDD caching).
     warm_model: Any = None
+    #: facts the components record about THIS run — which kernel each
+    #: algorithm actually took (ALS solver and bucketing, two-tower CE
+    #: path). ``run_train`` copies it into the engine instance's ``env``
+    #: beside the device block, so a reader can tell a device run from a
+    #: quiet host run without importing jax. Shared (not copied) by
+    #: ``dataclasses.replace``.
+    run_info: dict = dataclasses.field(default_factory=dict, compare=False)
 
     # -- sharding helpers ---------------------------------------------------
     @property
@@ -79,6 +92,34 @@ class WorkflowContext:
         return self.mesh.size if self.mesh is not None else 1
 
 
+def device_info() -> dict:
+    """Where this process computes, as JAX reports it. Initialises the
+    backend: call it only from a process that uses (or may take) the
+    device — a chip belongs to one process at a time."""
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "deviceKind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
+def device_memory() -> dict:
+    """Device 0's allocator counters where the backend keeps them (TPU
+    does, XLA:CPU returns nothing): bytes in use, the peak since process
+    start, and the limit."""
+    stats = jax.devices()[0].memory_stats() or {}
+    return {
+        out: int(stats[key])
+        for out, key in (
+            ("bytesInUse", "bytes_in_use"),
+            ("peakBytesInUse", "peak_bytes_in_use"),
+            ("bytesLimit", "bytes_limit"),
+        )
+        if key in stats
+    }
+
+
 def local_context(batch: str = "", verbose: int = 0) -> WorkflowContext:
     """A mesh-less context for local algorithms and unit tests (the analog of
     the reference's ``local[*]`` SparkContext fixture)."""
@@ -96,16 +137,29 @@ def mesh_context(
 
     ``axis_sizes=None`` puts every device on the ``data`` axis with a
     ``model`` axis of 1 — pure data parallelism, the safe default for the
-    ALS/NB workloads this framework ships with.
+    ALS/NB workloads this framework ships with. Over ONE device that is
+    the mesh-less context: a 1x1 mesh shards nothing, yet ``mesh is not
+    None`` routes training onto the sharded path (Cholesky, host
+    bucketing, XLA cross-entropy) and away from the single-device
+    kernels. Explicit ``axis_sizes`` always build the mesh asked for.
     """
     devs = list(devices if devices is not None else jax.devices())
     if axis_sizes is None:
+        if len(devs) == 1:
+            return local_context(batch=batch, verbose=verbose)
         axis_sizes = [len(devs)] + [1] * (len(axis_names) - 1)
     if len(axis_sizes) != len(axis_names):
         raise ValueError(
             f"axis_sizes {axis_sizes} does not match axis_names {axis_names}"
         )
-    mesh = jax.make_mesh(tuple(axis_sizes), tuple(axis_names), devices=devs)
+    # Explicit axes, stated rather than inherited from make_mesh's default:
+    # the sharded kernels are written against sharding-in-types
+    # (``reshard``, ``out_sharding=``), and eager code on a sharded table
+    # must say where its result lives (``parallel.sharding.gather_rows``)
+    mesh = jax.make_mesh(
+        tuple(axis_sizes), tuple(axis_names), devices=devs,
+        axis_types=(AxisType.Explicit,) * len(axis_names),
+    )
     return WorkflowContext(
         mesh=mesh,
         host_index=jax.process_index(),

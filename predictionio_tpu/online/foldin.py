@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from predictionio_tpu.workflow import device_state
+
 __all__ = ["foldin_rows", "gram_yty"]
 
 #: floor for the padded per-row rating width buckets
@@ -129,7 +131,6 @@ def foldin_rows(
     re-trace nothing; padding rows solve a trivial identity system and
     are dropped before returning."""
     Y = opposite
-    on_host = isinstance(Y, np.ndarray)
     B = len(entries)
     K = int(Y.shape[1])
     if B == 0:
@@ -164,17 +165,13 @@ def foldin_rows(
         if prior_weights is not None:
             pw[:n] = np.asarray(prior_weights, np.float32)[lo : lo + B_CHUNK]
         # gather OUTSIDE the jit (host fancy-index, or an eager device
-        # gather for pinned tables): the kernel's trace must not depend
-        # on the catalog size, which cold-start injections keep growing
-        if on_host:
-            Yg = jnp.asarray(
-                np.asarray(Y, np.float32)[idx.reshape(-1)].reshape(
-                    B_CHUNK, L, K
-                )
-            )
-        else:
-            Yg = Y[jnp.asarray(idx.reshape(-1))].reshape(B_CHUNK, L, K)
-            Yg = Yg.astype(jnp.float32)
+        # gather for pinned/sharded tables): the kernel's trace must not
+        # depend on the catalog size, which cold-start injections keep
+        # growing
+        Yg = jnp.asarray(
+            device_state.take_rows(Y, idx.reshape(-1)).reshape(B_CHUNK, L, K),
+            jnp.float32,
+        )
         out = _foldin_kernel(
             Yg,
             jnp.asarray(val),

@@ -51,8 +51,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_inbatch_ce", "fused_ce_supported"]
 
-#: rows of the logits computed per grid step. 128 keeps the live tile set
-#: (L, E, dL at [TI, B] fp32) a few MB — VMEM-safe at B up to ~16k on v5e.
+#: rows of the logits computed per grid step. The live tile set (L, E, dL
+#: at [TI, B] fp32) is 12.6 MB at B=8192 beside the two [B, D] operands.
+#: Compiled by Mosaic on a v5e under the default scoped VMEM at B=8192
+#: and B=16384, D=64 (PERF.md, PR 21): forward+backward 1.65 ms / 2.60 ms
+#: against 4.66 ms / 13.3 ms for the XLA path. 256 rows measured 1.38 ms
+#: / 2.72 ms and 64 rows 1.85 ms / 3.16 ms, so 128 stays. Larger batches
+#: are not compiled yet.
 _TI = 128
 
 

@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.ops.topk import bucket_k, top_k_permuted
+from predictionio_tpu.ops.topk import SCORE_PRECISION, bucket_k, top_k_permuted
 
 __all__ = [
     "IVFIndex",
@@ -683,16 +683,18 @@ def _ivf_topk(
         # table, the strongest statement a lossy layout admits)
         flat = index.slabs.reshape(nlist * width, -1)
         if lane_scales is not None:
-            scores = (qvecs @ flat.T.astype(jnp.float32)) * (
-                lane_scales.reshape(1, nlist * width)
-            )
+            scores = jnp.matmul(
+                qvecs, flat.T.astype(jnp.float32), precision=SCORE_PRECISION
+            ) * lane_scales.reshape(1, nlist * width)
         else:
-            scores = qvecs @ flat.T
+            scores = jnp.matmul(qvecs, flat.T, precision=SCORE_PRECISION)
         ids = jnp.broadcast_to(
             index.slab_ids.reshape(1, nlist * width), scores.shape
         )
     else:
-        cent_scores = qvecs @ index.centroids.T  # [B, nlist]
+        cent_scores = jnp.matmul(  # [B, nlist]
+            qvecs, index.centroids.T, precision=SCORE_PRECISION
+        )
         _, probe = jax.lax.top_k(cent_scores, nprobe)  # [B, nprobe]
         # one gather+einsum per probe SLOT (static nprobe unroll): the
         # [B, W, K] intermediates stay cache-sized, measured ~25% faster
@@ -707,10 +709,13 @@ def _ivf_topk(
                 # instead of per element (measured faster on CPU, exact
                 # same value up to f32 rounding)
                 s_j = jnp.einsum(
-                    "bwk,bk->bw", cand.astype(jnp.float32), qvecs
+                    "bwk,bk->bw", cand.astype(jnp.float32), qvecs,
+                    precision=SCORE_PRECISION,
                 ) * lane_scales[sel]
             else:
-                s_j = jnp.einsum("bwk,bk->bw", cand, qvecs)
+                s_j = jnp.einsum(
+                    "bwk,bk->bw", cand, qvecs, precision=SCORE_PRECISION
+                )
             score_l.append(s_j)
             id_l.append(index.slab_ids[sel])
         scores = jnp.concatenate(score_l, axis=1)  # [B, nprobe*W]

@@ -30,8 +30,7 @@ Quality is kept by a **recall-guarded two-stage top-K**:
 
 This is ONE quantization rule in ONE module: piolint PIO305 bans raw
 ``int8`` construction anywhere else under ``ops/``, ``parallel/`` and
-``workflow/`` (the same containment contract PIO304 enforces for
-``shard_map``), so every code/scale pair in the repo agrees on the
+``workflow/``, so every code/scale pair in the repo agrees on the
 rounding, the zero-row guard, and the re-quantize-on-scatter rule the
 online fold-in relies on. Strictly opt-in: nothing imports this module
 until a deploy passes ``--quantize int8`` (CI-guarded like ``--ann`` /
@@ -47,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.ops.topk import bucket_k, sort_merge_topk
+from predictionio_tpu.ops.topk import SCORE_PRECISION, bucket_k, sort_merge_topk
 
 __all__ = [
     "QuantizedTable",
@@ -264,7 +263,7 @@ def _two_stage_topk(qvecs, codes, scales, k: int, kp: int, num_items):
     # TopkDecomposer cliff ops/topk.py documents
     cand = jax.lax.optimization_barrier(cand)
     deq = dequantize(codes[cand], scales[cand])  # [B, kp, K] f32 rows
-    exact = jnp.einsum("bpk,bk->bp", deq, qvecs)
+    exact = jnp.einsum("bpk,bk->bp", deq, qvecs, precision=SCORE_PRECISION)
     valid = cand < num_items
     exact = jnp.where(valid, exact, -jnp.inf)
     ids = jnp.where(valid, cand, num_items)

@@ -3,13 +3,14 @@
 XLA's ``lax.linalg.cholesky`` lowers a batched [B,K,K] factorization to a
 K-step sequential loop whose every step round-trips the whole batch
 through HBM; at the flagship bench shape ([138k,64,64]) that measures
-~1.1 s/solve on a v5e chip — ~60% of a whole ALS sweep. The kernel here
+~1.26 s/solve on a v5e chip — more than a whole ALS sweep with the
+kernel. The kernel here
 keeps each block of rows **resident in VMEM** and runs *blocked*
 Gauss-Jordan elimination vectorized across the batch: pivot blocks of
 P=8 columns are inverted with a tiny unrolled in-VMEM GJ, and the rank-P
 updates run as batched MXU ``dot_general``s at full f32 precision.
-Measured 369 ms vs 1133 ms for the XLA Cholesky at the bench shape
-(~3x), with max rel err ~2e-5 vs LAPACK f64.
+Measured on a v5e (PERF.md, PR 21): 322 ms vs 1260 ms for the XLA
+Cholesky at the bench shape (~3.9x), max rel err 1.5e-6 between them.
 
 Gauss-Jordan without pivoting is numerically safe here: every ALS normal
 matrix is SPD with an ALS-WR ridge (λ·max(n,1)·I), so diagonal pivots
@@ -23,47 +24,54 @@ TPU-native replacement for that hot path.
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["spd_solve", "gj_solve_pallas", "cholesky_solve"]
+__all__ = ["spd_solve", "gj_solve_pallas", "cholesky_solve", "pallas_rank_ok"]
 
-#: max rows per kernel block (see _auto_block_rows). 48 is ~15% faster
-#: for the STANDALONE kernel at K=64 on v5e, but inside the ALS sweep
-#: its ~13 MB VMEM footprint starves the surrounding gather/einsum
-#: pipeline and costs ~40% of the whole sweep — 32 is the fused optimum.
+logger = logging.getLogger(__name__)
+
+#: max rows per kernel block (see _auto_block_rows). Measured standalone
+#: at [138000, 64, 64] on a v5e (jax 0.9.0 / libtpu 0.0.34; PERF.md,
+#: PR 21): 8 rows 446 ms, 16 rows 323 ms, 32 rows 322 ms, 48 rows 272 ms,
+#: while the Mosaic compile grows with the block (6 s, 13 s, 33 s, 65 s —
+#: the body unrolls over rows). 32 is kept: 48 buys 15% for twice the
+#: compile, and an earlier toolchain measured its ~13 MB footprint
+#: slowing the surrounding gather/einsum pipeline inside the sweep.
 _BLOCK_ROWS = 32
 
-#: usable scoped-VMEM budget for the kernel's whole working set.
+#: budget for the kernel's whole working set, under the 16 MiB scoped
+#: VMEM this libtpu gives a kernel by default (no pallas_call here
+#: raises it with compiler_params).
 _VMEM_BUDGET = 14 << 20
 
-#: MEASURED total-VMEM multiplier over the [TB, K, K] A-block bytes: on
-#: v5e the compiler reports ~17.1 MB of scoped vmem for TB=64, K=64
-#: (A block 1 MB) — the loop-carried copy, rank-P operand copies, b/x,
-#: and pipeline double-buffers multiply the block ~17x. The previous
-#: heuristic budgeted the A block alone and OOM'd at K>=128 on real
-#: hardware (only interpret-mode CI kept it alive).
+#: MEASURED total-VMEM multiplier over the [TB, K, K] A-block bytes: at
+#: TB=64, K=64 (A block 1 MiB) the compiler refuses with "scoped
+#: allocation 17.14M, limit 16.00M" — the loop-carried copy, rank-P
+#: operand copies, b/x and pipeline double-buffers multiply the block
+#: ~17x, much of it lane padding (a 64-wide last dim occupies 128
+#: lanes). At K=128 the factor is smaller: TB=16 (also 1 MiB) compiles.
 _KERNEL_VMEM_MULTIPLIER = 17
 
-#: deliberately conservative Mosaic ceiling: K<=128 is validated against
-#: real v5e compilation; the VMEM model says blocks up to K~448 would
-#: still fit, but those shapes are unvalidated (and tiny 1-3-row blocks
-#: give the kernel no batching advantage anyway) — fall back to Cholesky.
-_MAX_PALLAS_K = 256
+#: the kernel's ceiling. Block rows must be a multiple of 8 (the [TB, K]
+#: right-hand-side block is tiled (8, 128); TB=2 and 4 are refused at
+#: lowering), and 8 rows fit the budget only up to K~164 by the model
+#: above. Compiled and checked against Cholesky on the chip at K=64 and
+#: K=128; beyond that spd_solve says so and solves with Cholesky.
+_MAX_PALLAS_K = 128
 
 
 def _auto_block_rows(K: int) -> int:
-    """Largest block_rows whose TOTAL kernel working set
+    """Largest multiple-of-8 block_rows whose TOTAL kernel working set
     (~_KERNEL_VMEM_MULTIPLIER x the [TB,K,K] A block) fits the VMEM
-    budget: 32 at K=64 (capped), 8 at K=128, 3 at K=256 — validated
-    against real Mosaic compilation, not just the interpreter."""
+    budget: 32 at K=64 (capped), 8 at K=128 — both compiled by Mosaic on
+    the chip, not just run in the interpreter."""
     tb = _VMEM_BUDGET // (_KERNEL_VMEM_MULTIPLIER * K * K * 4)
-    if tb >= 8:
-        tb = tb // 8 * 8
-    return max(1, min(_BLOCK_ROWS, tb))
+    return max(8, min(_BLOCK_ROWS, tb // 8 * 8))
 
 #: pivot-block width: rank-P updates run on the MXU; P=8 keeps the
 #: in-VMEM pivot-block inversion tiny while giving the MXU real work.
@@ -189,23 +197,41 @@ def gj_solve_pallas(
     return out[:B]
 
 
+def pallas_rank_ok(K: int) -> bool:
+    """Whether the kernel takes a [.., K, K] system (after padding K up
+    to the pivot block): beyond ``_MAX_PALLAS_K`` no block size fits the
+    scoped VMEM and callers get Cholesky."""
+    return -(-K // _PIVOT_BLOCK) * _PIVOT_BLOCK <= _MAX_PALLAS_K
+
+
 def spd_solve(A: jax.Array, b: jax.Array, method: str = "cholesky") -> jax.Array:
     """Dispatch: ``method`` in {"cholesky", "pallas", "pallas_interpret"}.
 
     Callers pick "pallas" on a real TPU backend (Mosaic-lowered);
     "pallas_interpret" runs the same kernel logic on CPU for tests;
-    "cholesky" is the portable XLA path. K not divisible by the pivot
-    block falls back to Cholesky (rank is usually a multiple of 8 —
-    ``ALSConfig.rank_pad_multiple`` exists to make it one), as does
-    K > 256, the validated Mosaic ceiling (see _MAX_PALLAS_K).
+    "cholesky" is the portable XLA path. A K that is not a multiple of
+    the pivot block is embedded in the next multiple — ``[[A, 0], [0,
+    I]] x = [b, 0]`` has the same solution in its first K entries — so
+    any rank (the templates' default 10 included) runs the kernel. K
+    beyond ``_MAX_PALLAS_K`` cannot: that is logged, once per traced
+    shape, and solved by Cholesky.
     """
     if method in ("pallas", "pallas_interpret"):
         K = A.shape[-1]
-        if K % _PIVOT_BLOCK == 0 and K <= _MAX_PALLAS_K:
+        if pallas_rank_ok(K):
             A2 = A.reshape((-1, K, K))
             b2 = b.reshape((-1, K))
+            pad = -K % _PIVOT_BLOCK
+            if pad:
+                A2 = jnp.pad(A2, ((0, 0), (0, pad), (0, pad)))
+                A2 = A2.at[:, K:, K:].set(jnp.eye(pad, dtype=A.dtype))
+                b2 = jnp.pad(b2, ((0, 0), (0, pad)))
             x = gj_solve_pallas(A2, b2, interpret=(method == "pallas_interpret"))
-            return x.reshape(b.shape)
+            return x[:, :K].reshape(b.shape)
+        logger.warning(
+            "spd_solve: K=%d exceeds the Pallas kernel's ceiling (%d); "
+            "solving with XLA Cholesky", K, _MAX_PALLAS_K,
+        )
         method = "cholesky"
     if method == "cholesky":
         return cholesky_solve(A, b)

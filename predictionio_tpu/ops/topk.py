@@ -37,12 +37,23 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
+    "SCORE_PRECISION",
     "bucket_k",
     "top_k_scores",
     "top_k_permuted",
     "sort_merge_topk",
     "top_k_host",
 ]
+
+#: matmul precision of every float32 serving score. XLA:CPU multiplies
+#: float32 exactly whatever this says, which is where the "identical to
+#: the host path" contracts of docs/serving.md were established; a TPU's
+#: default is one bf16 pass. Measured on a v5e (PERF.md, PR 21): the batch
+#: GEMM [2048,64]@[64,27027] is off by 1.2e-2 against numpy float32 at
+#: the default and by 1.9e-6 at HIGHEST — the first reorders near-ties,
+#: so every scoring program states the second, as training already does
+#: (``ALSConfig.precision = "highest"``).
+SCORE_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def bucket_k(k: int, n_items: int, floor: int = 16) -> int:

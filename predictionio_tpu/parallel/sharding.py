@@ -35,9 +35,6 @@ Three pieces:
   it OWNS, and the same two-level tie-stable merge gathers ``S·k``
   candidates per query.
 
-Every collective goes through the :mod:`predictionio_tpu.ops.compat`
-shims (piolint PIO304 enforces that no module outside ``ops/compat.py``
-touches ``jax.shard_map`` directly), so jax<0.6 hosts keep working.
 Strictly opt-in: nothing imports this module until a deploy passes
 ``--shard-factors`` (CI-guarded like ``--ann``/``--online``).
 """
@@ -50,10 +47,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
-from predictionio_tpu.ops.compat import shard_map
-from predictionio_tpu.ops.topk import bucket_k, sort_merge_topk
+from predictionio_tpu.ops.topk import SCORE_PRECISION, bucket_k, sort_merge_topk
 
 __all__ = [
     "MODEL_AXIS",
@@ -111,7 +107,12 @@ def serving_mesh(shards: int = 0) -> Mesh | None:
     n = len(devs) if shards <= 0 else max(1, min(int(shards), len(devs)))
     if n < 2:
         return None
-    return jax.make_mesh((n,), (MODEL_AXIS,), devices=devs[:n])
+    # Explicit, like the training mesh (controller/context.py): eager reads
+    # of a sharded table go through gather_rows, never ``tbl[idx]``
+    return jax.make_mesh(
+        (n,), (MODEL_AXIS,), devices=devs[:n],
+        axis_types=(AxisType.Explicit,),
+    )
 
 
 def table_spec(mesh: Mesh) -> NamedSharding:
@@ -222,7 +223,10 @@ def _resolve_rows(tbl, idx):
     me = jax.lax.axis_index(MODEL_AXIS)
     lidx = idx - me * rps
     inr = (lidx >= 0) & (lidx < rps)
-    rows = jnp.where(inr[:, None], tbl[jnp.where(inr, lidx, 0)], 0.0)
+    rows = tbl[jnp.where(inr, lidx, 0)]
+    # any rank/dtype: int8 codes and 1-D scale vectors resolve here too
+    inr = inr.reshape(inr.shape + (1,) * (rows.ndim - 1))
+    rows = jnp.where(inr, rows, jnp.zeros((), rows.dtype))
     return jax.lax.psum(rows, MODEL_AXIS)
 
 
@@ -234,12 +238,15 @@ def gather_rows(idx: jax.Array, tbl: jax.Array, mesh: Mesh) -> jax.Array:
     def local(i, t):
         return _resolve_rows(t, i)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
-        in_specs=(PartitionSpec(), PartitionSpec(MODEL_AXIS, None)),
+        in_specs=(
+            PartitionSpec(),
+            PartitionSpec(MODEL_AXIS, *([None] * (tbl.ndim - 1))),
+        ),
         out_specs=PartitionSpec(),
-        check_rep=False,
+        check_vma=False,
     )(idx, tbl)
 
 
@@ -277,7 +284,7 @@ def sharded_topk_users(
     def local(idx, u_l, i_l, n_items):
         q = _resolve_rows(u_l, idx)  # [B, K] true user rows
         me = jax.lax.axis_index(MODEL_AXIS)
-        scores = q @ i_l.T  # [B, I/S]
+        scores = jnp.matmul(q, i_l.T, precision=SCORE_PRECISION)  # [B, I/S]
         base = (me * i_rps).astype(jnp.int32)
         gid = base + jnp.arange(i_rps, dtype=jnp.int32)
         # zero padding rows must never outrank real negative scores
@@ -292,12 +299,12 @@ def sharded_topk_users(
         return sort_merge_topk(gv, gids, min(int(k), S * kk))
 
     P = PartitionSpec
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(MODEL_AXIS, None), P(MODEL_AXIS, None), P()),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(user_idx, user_tbl, item_tbl, jnp.asarray(num_items, jnp.int32))
 
 
@@ -357,7 +364,7 @@ def sharded_quantized_topk_users(
         # rescore: gather + dequantize only the local finalists, score
         # against the UNQUANTIZED f32 query
         deq = quant.dequantize(ic[p], isc[p])  # [B, kpp, K]
-        exact = jnp.einsum("bpk,bk->bp", deq, q)
+        exact = jnp.einsum("bpk,bk->bp", deq, q, precision=SCORE_PRECISION)
         gi = base + p.astype(jnp.int32)
         valid = gi < n_items
         exact = jnp.where(valid, exact, -jnp.inf)
@@ -368,7 +375,7 @@ def sharded_quantized_topk_users(
         return sort_merge_topk(gv, gids, min(int(k), S * kk))
 
     P = PartitionSpec
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -380,7 +387,7 @@ def sharded_quantized_topk_users(
             P(),
         ),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(
         user_idx, u_codes, u_scales, i_codes, i_scales,
         jnp.asarray(num_items, jnp.int32),
@@ -431,18 +438,21 @@ def sharded_ivf_topk(
             # slabs keep determinism over the dequantized table)
             flat = slabs_l.reshape(-1, slabs_l.shape[-1])
             if quantized:
-                scores = (q @ flat.T.astype(jnp.float32)) * (
-                    scales_l.reshape(1, -1)
-                )
+                scores = jnp.matmul(
+                    q, flat.T.astype(jnp.float32), precision=SCORE_PRECISION
+                ) * scales_l.reshape(1, -1)
             else:
-                scores = q @ flat.T  # [B, lists_per*W]
+                scores = jnp.matmul(  # [B, lists_per*W]
+                    q, flat.T, precision=SCORE_PRECISION
+                )
             ids = jnp.broadcast_to(
                 ids_l.reshape(1, -1), scores.shape
             )
             scores = jnp.where(ids < num_items, scores, -jnp.inf)
             ids = jnp.where(ids < num_items, ids, num_items)
         else:
-            cs = q @ cent.T  # [B, nlist_pad], replicated compute
+            # [B, nlist_pad], replicated compute
+            cs = jnp.matmul(q, cent.T, precision=SCORE_PRECISION)
             col = jnp.arange(cs.shape[-1], dtype=jnp.int32)
             cs = jnp.where(col[None, :] < nlist_true, cs, -jnp.inf)
             _, probe = jax.lax.top_k(cs, nprobe)  # global cluster ids
@@ -459,10 +469,13 @@ def sharded_ivf_topk(
                 ids_j = ids_l[sel]  # [B, W]
                 if quantized:
                     s_j = jnp.einsum(
-                        "bwk,bk->bw", cand.astype(jnp.float32), q
+                        "bwk,bk->bw", cand.astype(jnp.float32), q,
+                        precision=SCORE_PRECISION,
                     ) * scales_l[sel]
                 else:
-                    s_j = jnp.einsum("bwk,bk->bw", cand, q)
+                    s_j = jnp.einsum(
+                        "bwk,bk->bw", cand, q, precision=SCORE_PRECISION
+                    )
                 valid = own[:, j, None] & (ids_j < num_items)
                 sc_parts.append(jnp.where(valid, s_j, -jnp.inf))
                 id_parts.append(jnp.where(valid, ids_j, num_items))
@@ -483,7 +496,7 @@ def sharded_ivf_topk(
         if quantized
         else jnp.zeros((S, 0), jnp.float32)
     )
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -494,7 +507,7 @@ def sharded_ivf_topk(
             P(MODEL_AXIS, None),
         ),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(qvecs, index.centroids, index.slabs, index.slab_ids, scales_arg)
 
 

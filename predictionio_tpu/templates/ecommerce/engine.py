@@ -227,6 +227,7 @@ class ECommAlgorithm(JaxAlgorithm):
                 implicit=True, alpha=p.alpha, seed=0 if p.seed is None else p.seed,
             ),
             mesh=ctx.mesh,
+            info=ctx.run_info.setdefault("als", {}),
         )
         return ECommModel(
             user_factors=np.asarray(factors.user),

@@ -612,16 +612,14 @@ class ALSAlgorithmParams(Params):
     implicit_prefs: bool = False
     alpha: float = 1.0
     #: serve top-N from the accelerator instead of host numpy. Host serving
-    #: wins below ~10^6 items (one small GEMV); device serving wins for
-    #: huge catalogs or when queries are batched — and avoids it when the
-    #: TPU sits behind a network tunnel where each dispatch pays an RTT.
+    #: wins for small catalogs (one small GEMV); device serving is for
+    #: huge catalogs or batched queries.
     serve_on_device: bool = False
     #: guardrail for serve_on_device: a deploy-time probe measures real
     #: per-query device latency and falls back to host serving (with a
-    #: warning) when the median exceeds this budget — a remote/tunneled
-    #: accelerator pays an RTT per dispatch that silently blows the
-    #: reference's <10 ms serving target otherwise. <= 0 disables the
-    #: probe (always trust serve_on_device).
+    #: warning, and visibly on ``GET /``) when the median exceeds this
+    #: budget — the reference's serving target is <10 ms. <= 0 disables
+    #: the probe (always trust serve_on_device).
     device_latency_budget_ms: float = 10.0
     json_aliases = {
         "numIterations": "num_iterations",
@@ -691,6 +689,7 @@ class ALSAlgorithm(JaxAlgorithm):
             mesh=ctx.mesh,
             init_user=init_user,
             init_item=init_item,
+            info=ctx.run_info.setdefault("als", {}),
         )
         return ALSModel(
             user_factors=np.asarray(factors.user),
@@ -703,16 +702,19 @@ class ALSAlgorithm(JaxAlgorithm):
         if self.params.serve_on_device:
             import jax
 
-            from predictionio_tpu.templates.serving_util import device_latency_ok
+            from predictionio_tpu.templates.serving_util import (
+                device_latency_probe,
+            )
 
             model.user_factors = jax.device_put(np.asarray(model.user_factors))
             model.item_factors = jax.device_put(np.asarray(model.item_factors))
             if len(model.user_index):
                 probe = Query(user=model.user_index.keys()[0], num=4)
-                if not device_latency_ok(
+                model._pio_latency_probe = device_latency_probe(
                     lambda: self.predict(model, probe),
                     self.params.device_latency_budget_ms,
-                ):
+                )
+                if not model._pio_latency_probe["ok"]:
                     model.user_factors = np.asarray(model.user_factors)
                     model.item_factors = np.asarray(model.item_factors)
             return model
@@ -1021,6 +1023,7 @@ class ALSAlgorithm(JaxAlgorithm):
         serving lock; ``apply_online_update`` swaps the rows in."""
         from predictionio_tpu.online.foldin import foldin_rows, gram_yty
         from predictionio_tpu.online.types import OnlineUpdate, latest_wins
+        from predictionio_tpu.workflow import device_state
 
         p = self.params
         rate_event = ds_params.get("rate_event", ds_params.get("rateEvent", "rate"))
@@ -1092,7 +1095,10 @@ class ALSAlgorithm(JaxAlgorithm):
             known = prior_rows >= 0
             if n_own:
                 gathered = np.asarray(
-                    own_factors[np.where(known, prior_rows, 0)], np.float32
+                    device_state.take_rows(
+                        own_factors, np.where(known, prior_rows, 0)
+                    ),
+                    np.float32,
                 )
             else:
                 gathered = np.zeros(
@@ -1184,20 +1190,13 @@ class ALSAlgorithm(JaxAlgorithm):
         if ann is not None:
             from predictionio_tpu.ops import ivf
 
-            if quantrt is not None:
-                # quantized user table: __getitem__ dequantizes only the
-                # requested row (sharded or not)
-                qvec = np.asarray(
-                    model.user_factors[np.asarray([uidx], np.int64)]
-                )[0]
-            elif shards is not None:
-                from predictionio_tpu.parallel import sharding
+            if quantrt is not None or shards is not None:
+                # quantized and/or sharded user table: only the
+                # requested row is dequantized / leaves its shard
+                from predictionio_tpu.workflow import device_state
 
                 qvec = np.asarray(
-                    sharding.gather_rows(
-                        np.asarray([uidx], np.int32),
-                        model.user_factors, shards.mesh,
-                    )
+                    device_state.take_rows(model.user_factors, [uidx])
                 )[0]
             else:
                 qvec = np.asarray(model.user_factors[uidx])

@@ -195,6 +195,7 @@ class ALSAlgorithm(JaxAlgorithm):
                 implicit=True, alpha=p.alpha, seed=0 if p.seed is None else p.seed,
             ),
             mesh=ctx.mesh,
+            info=ctx.run_info.setdefault("als", {}),
         )
         item = np.asarray(factors.item)
         norms = np.linalg.norm(item, axis=1, keepdims=True)

@@ -329,6 +329,7 @@ class TwoTowerAlgorithm(JaxAlgorithm):
             mesh=ctx.mesh,
             init_user=init_user,
             init_item=init_item,
+            info=ctx.run_info.setdefault("twotower", {}),
         )
         return TwoTowerServingModel(
             user_vecs=model.user_vecs,
@@ -345,16 +346,19 @@ class TwoTowerAlgorithm(JaxAlgorithm):
         if self.params.serve_on_device:
             import jax
 
-            from predictionio_tpu.templates.serving_util import device_latency_ok
+            from predictionio_tpu.templates.serving_util import (
+                device_latency_probe,
+            )
 
             model.user_vecs = jax.device_put(np.asarray(model.user_vecs))
             model.item_vecs = jax.device_put(np.asarray(model.item_vecs))
             if len(model.user_index):
                 probe = Query(user=model.user_index.keys()[0], num=4)
-                if not device_latency_ok(
+                model._pio_latency_probe = device_latency_probe(
                     lambda: self.predict(model, probe),
                     self.params.device_latency_budget_ms,
-                ):
+                )
+                if not model._pio_latency_probe["ok"]:
                     model.user_vecs = np.asarray(model.user_vecs)
                     model.item_vecs = np.asarray(model.item_vecs)
             return model
@@ -708,18 +712,11 @@ class TwoTowerAlgorithm(JaxAlgorithm):
         if ann is not None:
             from predictionio_tpu.ops import ivf
 
-            if quantrt is not None:
-                qvec = np.asarray(
-                    model.user_vecs[np.asarray([uidx], np.int64)]
-                )[0]
-            elif shards is not None:
-                from predictionio_tpu.parallel import sharding
+            if quantrt is not None or shards is not None:
+                from predictionio_tpu.workflow import device_state
 
                 qvec = np.asarray(
-                    sharding.gather_rows(
-                        np.asarray([uidx], np.int32),
-                        model.user_vecs, shards.mesh,
-                    )
+                    device_state.take_rows(model.user_vecs, [uidx])
                 )[0]
             else:
                 qvec = np.asarray(model.user_vecs[uidx])

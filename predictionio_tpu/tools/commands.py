@@ -431,7 +431,10 @@ def status_check(out: Out = _print) -> dict:
             ok = False
     try:
         devices = jax.devices()
-        results["devices"] = f"{len(devices)} x {devices[0].platform}"
+        results["devices"] = (
+            f"{len(devices)} x {devices[0].platform} "
+            f"({devices[0].device_kind})"
+        )
     except Exception as e:
         results["devices"] = f"FAILED: {e}"
         ok = False
@@ -853,6 +856,11 @@ def undeploy(
         raise RuntimeError(
             f"Could not reach a deployment at {url}: {e.reason}"
         ) from e
+    except ConnectionResetError:
+        # the server shuts down on a helper thread while it answers; when
+        # the shutdown wins that race the response is cut short (seen as
+        # RemoteDisconnected). The request was accepted: the stop took.
+        pass
     out(f"Undeployed engine server at {ip}:{port}.")
 
 

@@ -20,6 +20,7 @@ import os
 import sys
 
 from predictionio_tpu.tools import commands
+from predictionio_tpu.utils import compile_cache
 from predictionio_tpu.version import __version__
 
 __all__ = ["main", "build_parser"]
@@ -123,8 +124,9 @@ def build_parser() -> argparse.ArgumentParser:
     train.add_argument(
         "--mesh",
         default="auto",
-        help="'auto' (all devices on data axis), 'none' (local), or "
-        "'data=N,model=M' axis sizes",
+        help="'auto' (all devices on the data axis; one device trains "
+        "mesh-less, like 'none'), 'none' (local), or 'data=N,model=M' "
+        "axis sizes",
     )
     # ---- deploy-time AOT serving (predictionio_tpu.workflow.aot;
     # docs/operations.md AOT runbook). Strictly opt-in: without --aot no
@@ -138,14 +140,6 @@ def build_parser() -> argparse.ArgumentParser:
         "the fleet model registry — `pio deploy --aot` replicas then boot "
         "by deserializing instead of compiling (zero serve-time "
         "compiles; docs/operations.md)",
-    )
-    train.add_argument(
-        "--compilation-cache-dir", default=None, metavar="DIR",
-        help="persistent XLA compilation cache directory shared across "
-        "replicas/hosts — the tier-2 fallback when AOT artifacts are "
-        "missing or fingerprint-stale (default: "
-        "$PIO_COMPILATION_CACHE_DIR or <basedir>/jax_cache; '0' "
-        "disables)",
     )
 
     def add_ssl_flags(sp):
@@ -411,18 +405,10 @@ def build_parser() -> argparse.ArgumentParser:
         "(jaxlib/backend/shape-bucket), warmed before the first query, "
         "ZERO serve-time compiles. A missing/stale/corrupt artifact set "
         "falls back LOUDLY to the persistent compilation cache (tier 2, "
-        "--compilation-cache-dir) and then plain JIT (tier 3) — results "
+        "$JAX_COMPILATION_CACHE_DIR) and then plain JIT (tier 3) — results "
         "stay bit-identical on every tier; implies --pin-model; "
         "/stats.json grows an 'aot' section with serveTimeCompiles "
         "(docs/operations.md)",
-    )
-    deploy.add_argument(
-        "--compilation-cache-dir", default=None, metavar="DIR",
-        help="persistent XLA compilation cache directory shared across "
-        "replicas/hosts — the tier-2 fallback when AOT artifacts are "
-        "missing or fingerprint-stale (default: "
-        "$PIO_COMPILATION_CACHE_DIR or <basedir>/jax_cache; '0' "
-        "disables)",
     )
     # ---- approximate retrieval (predictionio_tpu.ops.ivf; docs/serving.md).
     # Strictly opt-in: without --ann every query scores the exact path.
@@ -988,7 +974,7 @@ def _parse_mesh(spec: str):
     if spec == "none":
         return local_context()
     if spec == "auto":
-        return mesh_context()
+        return mesh_context()  # one device: the mesh-less context
     sizes = {}
     for part in spec.split(","):
         axis, _, n = part.partition("=")
@@ -996,45 +982,6 @@ def _parse_mesh(spec: str):
     return mesh_context(
         axis_sizes=list(sizes.values()), axis_names=list(sizes.keys())
     )
-
-
-def _setup_compilation_cache(explicit: str | None = None) -> None:
-    """Persist compiled XLA programs across runs: a repeat ``pio train``
-    on the same shapes skips the (tens-of-seconds, possibly remote)
-    compile entirely. Precedence: the ``--compilation-cache-dir`` flag
-    (``explicit``), then ``PIO_COMPILATION_CACHE_DIR``, then the
-    ``<PIO_FS_BASEDIR>/jax_cache`` default; ``0`` disables. Under
-    ``--aot`` this same directory doubles as the tier-2 fallback shared
-    across replicas (docs/operations.md AOT runbook). Costs no jax
-    import of its own: env vars configure a not-yet-imported jax lazily,
-    and only an already-imported jax (preloaded interpreters) gets
-    config.update."""
-    if explicit is None:
-        explicit = os.environ.get("PIO_COMPILATION_CACHE_DIR")
-    if explicit == "0":
-        return
-    if explicit:
-        cache_dir = os.path.expanduser(explicit)
-    else:
-        from predictionio_tpu.data.storage import Storage
-
-        cache_dir = os.path.join(Storage.base_dir(), "jax_cache")
-    if "jax" in sys.modules:
-        jax = sys.modules["jax"]
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        except Exception as e:
-            if explicit:
-                print(
-                    f"WARNING: could not enable the compilation cache at "
-                    f"{cache_dir}: {e}",
-                    file=sys.stderr,
-                )
-    else:
-        # jax reads these at import; operator-set JAX_* values win
-        os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir)
-        os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1.0")
 
 
 def _train_aot_export(variant, ctx, instance) -> None:
@@ -1141,6 +1088,68 @@ def _replica_argv(args, replica_id: str, announce_dir: str) -> list[str]:
     return argv
 
 
+#: deploy flags that make a replica open the JAX backend (pinned or
+#: sharded tables, exported programs, an IVF index, fold-in solves, the
+#: exploration rerank) — a flag-less replica serves from host numpy
+_DEVICE_SERVING_FLAGS = (
+    "pin_model", "shard_factors", "quantize", "aot", "ann", "online",
+    "explore",
+)
+
+
+def _check_fleet_fits_device(args) -> None:
+    """One process per chip, enforced at launch. Every replica is a
+    whole ``pio deploy`` process that opens ALL of the host's chips, and
+    a chip belongs to one process at a time: on an accelerator host the
+    second device-serving replica cannot open the backend (and before
+    this check its pin failure was caught, logged, and served from host
+    arrays beside a device-served sibling). Fail here, before anything
+    is spawned, naming the cause. The platform is asked of a short-lived
+    child — the supervisor itself must never hold a chip. Giving each
+    replica its own chip is future work (ROADMAP R6b)."""
+    import subprocess
+
+    flags = [f for f in _DEVICE_SERVING_FLAGS if getattr(args, f, None)]
+    wanted = max(args.replicas or 0, _autoscale_max(args.autoscale))
+    if wanted <= 1 or not flags:
+        return
+    probe = subprocess.run(
+        [
+            sys.executable, "-c",
+            "import jax; d = jax.devices(); print(d[0].platform, len(d))",
+        ],
+        capture_output=True, text=True, stdin=subprocess.DEVNULL,
+    )
+    if probe.returncode != 0:
+        raise SystemExit(
+            "pio deploy --replicas: could not open the JAX backend to "
+            "check that a device-serving fleet fits this host:\n"
+            + probe.stderr[-2000:]
+        )
+    platform, count = probe.stdout.split()[-2:]
+    if platform != "cpu":
+        raise SystemExit(
+            f"pio deploy --replicas {wanted} with "
+            f"{', '.join('--' + f.replace('_', '-') for f in flags)}: each "
+            f"replica is a process that opens all {count} {platform} "
+            "chip(s) of this host, and a chip belongs to one process at a "
+            "time — replicas after the first would fail to start or "
+            "serve from host arrays. Run one device-serving replica per "
+            "host, or drop the device flags to serve the fleet from host "
+            "arrays."
+        )
+
+
+def _autoscale_max(spec: str | None) -> int:
+    if not spec:
+        return 0
+    lo, _, hi = spec.partition(":")
+    try:
+        return int(hi or lo)
+    except ValueError:
+        return 0  # the autoscaler config reports the malformed spec
+
+
 def _deploy_fleet(args) -> int:
     """``pio deploy --replicas N`` (and ``--router-only``): spawn the
     replica subprocesses under the self-healing supervisor and serve the
@@ -1174,6 +1183,8 @@ def _deploy_fleet(args) -> int:
             "--router-only serves no supervisor to scale; run --autoscale "
             "on the fleet that owns the replicas"
         )
+    if not args.router_only:
+        _check_fleet_fits_device(args)
     base_dir = Storage.base_dir()
     endpoints_dir = args.endpoint_registry or os.path.join(
         base_dir, "fleet", "endpoints"
@@ -1487,9 +1498,10 @@ def main(argv: list[str] | None = None) -> int:
 
         jax.config.update("jax_platforms", platform_override)
     args = build_parser().parse_args(argv)
-    _setup_compilation_cache(
-        explicit=getattr(args, "compilation_cache_dir", None)
-    )
+    # one rule (utils/compile_cache.py): $JAX_COMPILATION_CACHE_DIR if
+    # set, else <checkout>/.jax_cache — a repeat `pio train` on the same
+    # shapes skips the compile, and a deploy reuses what train compiled
+    compile_cache.configure()
     cmd = args.command
     try:
         if cmd == "version":

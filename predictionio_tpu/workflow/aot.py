@@ -24,7 +24,7 @@ plane; this module applies the recipe to serving (ROADMAP item 5):
   consults before its jitted fallbacks.
 * Failure is **loud, tiered, and never fatal**: a fingerprint mismatch
   or corrupt blob logs the exact reason and falls back to tier 2 (the
-  persistent JAX compilation cache, ``--compilation-cache-dir``) and
+  persistent JAX compilation cache, ``$JAX_COMPILATION_CACHE_DIR``) and
   then tier 3 (today's JIT path) — results stay bit-identical by
   construction, because the exported programs are the SAME jaxprs the
   JIT path traces (CI-guarded parity test).
@@ -109,25 +109,17 @@ class AotConfig:
 def current_fingerprint() -> dict:
     """The environment identity serialized programs are valid within."""
     import jax
+    import jaxlib
 
-    try:
-        import jaxlib
-
-        jaxlib_version = getattr(jaxlib, "__version__", "")
-    except Exception:  # pragma: no cover - jaxlib rides with jax
-        jaxlib_version = ""
-    try:
-        devices = jax.devices()
-        device_kind = devices[0].device_kind
-        device_count = len(devices)
-    except Exception:  # pragma: no cover - backend init failure
-        device_kind, device_count = "unknown", 0
+    # a backend that cannot initialise raises here: an artifact set
+    # stamped "unknown x 0" would only fail later, at every deploy
+    devices = jax.devices()
     return {
         "jaxVersion": jax.__version__,
-        "jaxlibVersion": jaxlib_version,
+        "jaxlibVersion": jaxlib.__version__,
         "backend": jax.default_backend(),
-        "deviceKind": device_kind,
-        "deviceCount": device_count,
+        "deviceKind": devices[0].device_kind,
+        "deviceCount": len(devices),
     }
 
 
@@ -325,16 +317,14 @@ def _catalog_items(model) -> int:
 def fallback_tier() -> int:
     """Which tier a failed tier-1 load lands on: tier 2 when the
     persistent JAX compilation cache is configured (the backend compile
-    the JIT fallback pays is answered from the shared cache dir), else
+    the JIT fallback pays is answered from the shared cache dir —
+    ``utils/compile_cache.py`` has the rule that places it), else
     tier 3 (full JIT)."""
-    try:
-        import jax
+    import jax
 
-        if getattr(jax.config, "jax_compilation_cache_dir", None):
-            return 2
-    except Exception:
-        pass
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    if jax.config.jax_compilation_cache_dir or os.environ.get(
+        "JAX_COMPILATION_CACHE_DIR"
+    ):
         return 2
     return 3
 

@@ -13,7 +13,11 @@ import json
 import logging
 import uuid
 
-from predictionio_tpu.controller.context import WorkflowContext
+from predictionio_tpu.controller.context import (
+    WorkflowContext,
+    device_info,
+    device_memory,
+)
 from predictionio_tpu.controller.engine import EngineParams
 from predictionio_tpu.controller.evaluation import (
     EngineParamsGenerator,
@@ -197,7 +201,15 @@ def run_train(
             blob = engine.models_to_bytes(instance.id, engine_params, models)
             Storage.get_model_data_models().insert(Model(id=instance.id, models=blob))
             logger.info("Saved model blob for instance %s (%d bytes)", instance.id, len(blob))
-        env = {**instance.env, "phase_timings": json.dumps(timings)}
+        # where it ran and which kernels it took, beside the timings: a
+        # reader (chip_smoke.py, a benchmark) tells a device run from a
+        # quiet host run from the instance alone, without importing jax
+        env = {
+            **instance.env,
+            "phase_timings": json.dumps(timings),
+            "device": json.dumps({**device_info(), **device_memory()}),
+            "kernels": json.dumps(ctx.run_info),
+        }
         if warm_from is not None:
             env["warm_start_from"] = warm_from
         instance = dataclasses.replace(

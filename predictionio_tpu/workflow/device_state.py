@@ -45,7 +45,9 @@ __all__ = [
     "build_ann_pairs",
     "bytes_by_dtype",
     "aot_stats",
+    "serving_device",
     "set_rows",
+    "take_rows",
     "append_rows",
     "swap_side_rows",
     "update_ann_items",
@@ -195,6 +197,43 @@ def aot_stats(pairs: Sequence) -> dict | None:
     return out
 
 
+def serving_device(pairs: Sequence) -> dict:
+    """The ``device`` block of ``GET /``: whether predict reads device
+    buffers or host arrays — judged from the arrays the served models
+    hold NOW, so a ``serveOnDevice`` probe that fell back reads "host" —
+    and, once this process has opened the backend, the platform,
+    ``deviceKind`` and device count JAX reports. A host-serving process
+    never opens it (a chip belongs to one process), so those stay null.
+    The probe's outcome rides along when it ran."""
+    on_device = any(
+        hasattr(v, "addressable_shards") or getattr(v, "is_quantized", False)
+        for _, model in pairs
+        for v in getattr(model, "__dict__", {}).values()
+    )
+    probes = [
+        p
+        for _, model in pairs
+        if (p := getattr(model, "_pio_latency_probe", None)) is not None
+    ]
+    out = {
+        "servedFrom": "device" if on_device else "host",
+        "platform": None,
+        "deviceKind": None,
+        "count": None,
+    }
+    if on_device or probes:
+        from predictionio_tpu.controller.context import (
+            device_info,
+            device_memory,
+        )
+
+        out.update(device_info())
+        out.update(device_memory())
+    if probes:
+        out["latencyProbe"] = probes[0]
+    return out
+
+
 def bytes_by_dtype(pairs: Sequence) -> dict:
     """Aggregate per-dtype pinned-byte ledger across the served models —
     the ``cache.bytesByDtype`` block of ``/stats.json``. Each pin hook
@@ -313,21 +352,47 @@ def set_rows(mat, idx, rows):
     )
 
 
+def take_rows(mat, idx):
+    """Rows ``idx`` of a factor table wherever it lives — the read twin
+    of :func:`set_rows`. Host arrays fancy-index and a pinned table
+    gathers on device; a ``--shard-factors`` table resolves the rows from
+    their owner shards (``parallel.sharding.gather_rows``): the serving
+    mesh has Explicit axes, where an eager ``tbl[idx]`` on a sharded
+    table is a type error rather than a silent all-gather. A quantized
+    table dequantizes only the rows asked for."""
+    import numpy as np
+
+    if getattr(mat, "is_quantized", False):
+        from predictionio_tpu.ops import quant
+
+        return quant.dequantize(
+            take_rows(mat.codes, idx), take_rows(mat.scales, idx)
+        )
+    if isinstance(mat, np.ndarray):
+        return mat[np.asarray(idx, np.int64)]
+    import jax.numpy as jnp
+
+    idx = jnp.asarray(np.asarray(idx, np.int32))
+    sharded = _named_sharding_of(mat)
+    if sharded is not None:
+        from predictionio_tpu.parallel import sharding
+
+        return sharding.gather_rows(idx, mat, sharded.mesh)
+    return mat[idx]
+
+
 def _named_sharding_of(mat):
     """The table's NamedSharding when its rows are partitioned over a
     mesh axis (the --shard-factors layout), else None."""
-    try:
-        from jax.sharding import NamedSharding
+    from jax.sharding import NamedSharding
 
-        s = getattr(mat, "sharding", None)
-        if (
-            isinstance(s, NamedSharding)
-            and len(s.spec) >= 1
-            and s.spec[0] is not None
-        ):
-            return s
-    except Exception:  # pragma: no cover - very old jax
-        pass
+    s = getattr(mat, "sharding", None)
+    if (
+        isinstance(s, NamedSharding)
+        and len(s.spec) >= 1
+        and s.spec[0] is not None
+    ):
+        return s
     return None
 
 
@@ -341,10 +406,8 @@ def _sharded_set_rows(sharding):
     if fn is None:
         import jax
 
-        from predictionio_tpu.ops.compat import sharded_scatter_set
-
         fn = jax.jit(
-            lambda m, i, r: sharded_scatter_set(m, i, r, sharding),
+            lambda m, i, r: m.at[i].set(r, out_sharding=sharding),
             out_shardings=sharding,
         )
         _SHARDED_SET_CACHE[sharding] = fn

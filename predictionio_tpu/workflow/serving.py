@@ -945,9 +945,16 @@ class QueryService:
 
     # -------------------------------------------------------------- status
     def status_json(self) -> dict:
+        from predictionio_tpu.workflow import device_state
+
         inst = self.instance
+        with self._lock:
+            pairs = list(self._algo_model_pairs)
         return {
             "status": "alive",
+            # where predict computes: device buffers or host arrays, and
+            # on which platform/deviceKind (docs/serving.md)
+            "device": device_state.serving_device(pairs),
             "replicaId": self.replica_id,
             "generation": self.model_generation,
             "engineId": self.variant.id,

@@ -519,55 +519,82 @@ class TestPallasSolver:
             train_als(rows, cols, vals, 60, 40, ALSConfig(solver="qr"))
 
     def test_auto_block_rows_shrinks_with_rank(self):
-        # large K must scale the VMEM block down (round-2 advisor: K>=180
-        # blew the budget at the fixed 32-row block) and the interpret
-        # path still agrees with cholesky at a shrunken block
-        from predictionio_tpu.ops.solve import _auto_block_rows, spd_solve, cholesky_solve
+        # large K must scale the VMEM block down, in multiples of 8 (the
+        # [TB, K] block is tiled (8, 128): Mosaic refused TB=2 and 4 on
+        # the chip), and the interpret path still agrees with cholesky at
+        # a shrunken block
+        from predictionio_tpu.ops.solve import (
+            _MAX_PALLAS_K,
+            _auto_block_rows,
+            cholesky_solve,
+            gj_solve_pallas,
+        )
 
         # thresholds from MEASURED Mosaic VMEM use on v5e (the kernel's
-        # working set is ~17x the A block; K=128 at 32 rows OOM'd real
-        # hardware under the old A-block-only heuristic)
+        # working set is ~17x the A block: 17.14 MB at TB=64, K=64 against
+        # the 16 MiB scoped limit — PERF.md, PR 21)
         assert _auto_block_rows(64) == 32
+        assert _auto_block_rows(96) == 16
         assert _auto_block_rows(128) == 8
-        assert _auto_block_rows(256) == 3
-        assert _auto_block_rows(1024) == 1
+        assert all(
+            _auto_block_rows(k) % 8 == 0 for k in range(8, _MAX_PALLAS_K + 1, 8)
+        )
         rng = np.random.default_rng(7)
-        B, K = 5, 192
+        B, K = 5, 128
         M = rng.normal(size=(B, K, K)).astype(np.float32)
         A = jnp.asarray(M @ M.transpose(0, 2, 1) + 20 * np.eye(K, dtype=np.float32))
         b = jnp.asarray(rng.normal(size=(B, K)).astype(np.float32))
         np.testing.assert_allclose(
-            np.asarray(spd_solve(A, b, method="pallas_interpret")),
+            np.asarray(gj_solve_pallas(A, b, interpret=True)),
             np.asarray(cholesky_solve(A, b)),
             rtol=5e-3, atol=5e-4,
         )
 
-    def test_rank_above_vmem_ceiling_falls_back(self):
+    def test_rank_above_vmem_ceiling_falls_back_loudly(self, caplog):
         from predictionio_tpu.ops.solve import spd_solve, cholesky_solve
 
         rng = np.random.default_rng(8)
-        B, K = 2, 520  # multiple of 8 but above _MAX_PALLAS_K
+        B, K = 2, 136  # multiple of 8 but above _MAX_PALLAS_K
         M = rng.normal(size=(B, K, K)).astype(np.float32)
         A = jnp.asarray(M @ M.transpose(0, 2, 1) + 50 * np.eye(K, dtype=np.float32))
         b = jnp.asarray(rng.normal(size=(B, K)).astype(np.float32))
-        np.testing.assert_allclose(
-            np.asarray(spd_solve(A, b, method="pallas_interpret")),
-            np.asarray(cholesky_solve(A, b)),
-            rtol=1e-5, atol=1e-6,
-        )
+        with caplog.at_level("WARNING", logger="predictionio_tpu.ops.solve"):
+            x = np.asarray(spd_solve(A, b, method="pallas_interpret"))
+        # bit-identical to Cholesky because it IS Cholesky — and it says so
+        np.testing.assert_array_equal(x, np.asarray(cholesky_solve(A, b)))
+        assert any(
+            "K=136" in r.getMessage() and "Cholesky" in r.getMessage()
+            for r in caplog.records
+        ), "the downgrade to Cholesky must be logged"
 
-    def test_non_multiple_rank_falls_back(self):
-        # rank 10 is not a multiple of the pivot block; spd_solve must
-        # quietly use cholesky instead of crashing
-        from predictionio_tpu.ops.solve import spd_solve, cholesky_solve
+    def test_non_multiple_rank_runs_the_kernel_padded(self, caplog):
+        # rank 10 (the templates' default) is not a multiple of the pivot
+        # block: spd_solve embeds it in a 16x16 system with an identity
+        # block, so the kernel runs — no downgrade, nothing to log
+        from predictionio_tpu.ops import solve
 
         rng = np.random.default_rng(1)
         B, K = 8, 10
         M = rng.normal(size=(B, K, K)).astype(np.float32)
-        A = jnp.asarray(M @ M.transpose(0, 2, 1) + 5 * np.eye(K, dtype=np.float32))
-        b = jnp.asarray(rng.normal(size=(B, K)).astype(np.float32))
-        np.testing.assert_allclose(
-            np.asarray(spd_solve(A, b, method="pallas_interpret")),
-            np.asarray(cholesky_solve(A, b)),
-            rtol=1e-5, atol=1e-6,
-        )
+        A = M @ M.transpose(0, 2, 1) + 5 * np.eye(K, dtype=np.float32)
+        b = rng.normal(size=(B, K)).astype(np.float32)
+        seen = []
+        real = solve.gj_solve_pallas
+        try:
+            solve.gj_solve_pallas = lambda A2, b2, **kw: (
+                seen.append(A2.shape) or real(A2, b2, **kw)
+            )
+            with caplog.at_level("WARNING", logger="predictionio_tpu.ops.solve"):
+                x = np.asarray(
+                    solve.spd_solve(
+                        jnp.asarray(A), jnp.asarray(b), method="pallas_interpret"
+                    )
+                )
+        finally:
+            solve.gj_solve_pallas = real
+        assert seen == [(B, 16, 16)], seen
+        assert not caplog.records
+        want = np.linalg.solve(
+            A.astype(np.float64), b.astype(np.float64)[..., None]
+        )[..., 0]
+        np.testing.assert_allclose(x, want, rtol=1e-4, atol=1e-5)

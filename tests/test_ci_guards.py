@@ -379,8 +379,8 @@ def test_shard_factors_defaults_are_opt_in():
     default deploy path stays byte-identical to a build without the
     module. The piolint manifest must keep the parallel/ layering entry
     (jax allowed; templates/tools/serving/api forbidden) and the PIO304
-    rule must stay registered so sharded helpers keep going through the
-    ops/compat.py shims."""
+    rule must stay registered so sharded helpers stay on ``jax.shard_map``
+    rather than the deprecated experimental import."""
     import inspect
 
     from predictionio_tpu.serving import CacheConfig
@@ -426,7 +426,7 @@ def test_shard_factors_defaults_are_opt_in():
     ), "manifest no longer forbids parallel/ -> templates/tools imports"
     assert (
         "PIO304" in all_rules()
-    ), "PIO304 (raw shard_map outside ops/compat.py) fell out of piolint"
+    ), "PIO304 (deprecated jax.experimental.shard_map) fell out of piolint"
 
 
 def test_fleet_defaults_are_opt_in():
@@ -556,10 +556,165 @@ def test_elastic_fleet_defaults_are_opt_in():
     assert proc.returncode == 0, proc.stderr.decode()[-500:]
 
 
+def test_compile_cache_is_placed_from_outside(tmp_path):
+    """ISSUE 21 guard — one rule for the XLA compile cache, everywhere:
+    ``JAX_COMPILATION_CACHE_DIR`` set -> used, and nothing in code sets
+    another; unset -> ``<checkout>/.jax_cache``, never a path built from
+    the storage base dir or a temp name (a directory that moves never
+    hits). The rule lives in utils/compile_cache.py and nowhere else."""
+    probe = (
+        "import os, sys, json\n"
+        "from predictionio_tpu.tools.console import main\n"
+        "main(['version'])\n"
+        "import jax\n"
+        "print(json.dumps({'env': os.environ.get('JAX_COMPILATION_CACHE_DIR'),"
+        " 'config': jax.config.jax_compilation_cache_dir}))\n"
+    )
+
+    def run(extra_env):
+        env = {
+            k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"
+        }
+        # the storage base dir is a throwaway, as the docs tell everyone
+        # to make it: the cache must not follow it
+        env["PIO_FS_BASEDIR"] = str(tmp_path / "store")
+        env.update(extra_env)
+        proc = subprocess.run(
+            [sys.executable, "-c", probe], cwd=REPO, env=env,
+            capture_output=True, text=True,
+        )
+        assert proc.returncode == 0, proc.stderr[-800:]
+        return json.loads(proc.stdout.strip().splitlines()[-1])
+
+    unset = run({})
+    want = os.path.join(REPO, ".jax_cache")
+    assert unset == {"env": want, "config": want}, unset
+    outside = str(tmp_path / "elsewhere")
+    given = run({"JAX_COMPILATION_CACHE_DIR": outside})
+    assert given == {"env": outside, "config": outside}, given
+    # nothing else in the tree places the cache
+    setters = []
+    for rel in _tracked_py_files():
+        if rel.startswith("tests/") or rel.endswith("utils/compile_cache.py"):
+            continue
+        with open(os.path.join(REPO, rel), encoding="utf-8") as f:
+            src = f.read()
+        if '"jax_compilation_cache_dir"' in src or "PIO_COMPILATION_CACHE_DIR" in src:
+            setters.append(rel)
+    assert not setters, f"compile cache placed outside the rule: {setters}"
+
+
+def test_chip_smoke_refuses_to_pass_without_a_tpu():
+    """ISSUE 21 guard: `python chip_smoke.py` is the proof that the
+    system starts ON THE CHIP. With no TPU (this suite's platform is
+    cpu) it must exit non-zero, say why, and print no result line; its
+    parent process must never import jax."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=REPO, env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0
+    assert "need 'tpu'" in proc.stderr, proc.stderr[-500:]
+    assert '"ok"' not in proc.stdout, proc.stdout[-500:]
+    with open(os.path.join(REPO, "chip_smoke.py"), encoding="utf-8") as f:
+        tree = ast.parse(f.read())
+    top_level_imports = {
+        alias.name.split(".")[0]
+        for node in tree.body
+        if isinstance(node, (ast.Import, ast.ImportFrom))
+        for alias in (
+            node.names if isinstance(node, ast.Import)
+            else [ast.alias(name=node.module or "")]
+        )
+    }
+    assert "jax" not in top_level_imports
+    assert "numpy" not in top_level_imports  # legs import it, children jax
+
+
+def test_chip_smoke_cpu_rehearsal_runs_every_leg():
+    """The same plumbing at toy sizes on XLA:CPU: `pio train` (no --mesh
+    flag) -> `pio deploy --pin-model --batching` -> agreement with the
+    numpy reference -> two-tower train -> kernel checks in interpret
+    mode. Every field says cpu and the result can never be mistaken for
+    a chip pass (`"ok": false`)."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)  # one device, like one chip
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py", "--rehearse-cpu"], cwd=REPO,
+        env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    rec = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert rec["ok"] is False and rec["rehearsal"] is True
+    assert rec["legsPassed"] is True and rec["claim"] is None
+    assert rec["device"]["platform"] == "cpu"
+    assert set(rec["legs"]) == {
+        "probe", "load", "train", "serve", "agree", "twotower", "kernels"
+    }
+    assert rec["facts"]["als"]["solver"] == "cholesky"
+    assert rec["facts"]["twotower"]["fusedCe"] == "xla"
+    assert rec["legs"]["serve"]["device"]["servedFrom"] == "device"
+    assert rec["legs"]["serve"]["bucketMisses"] == 0
+    assert rec["legs"]["agree"]["worstErrorOverTolerance"] <= 1.0
+    assert rec["legs"]["agree"]["bf16PassEmulationOverTolerance"] > 1.0
+
+
+def test_device_serving_fleet_must_fit_the_host(monkeypatch):
+    """ISSUE 21 guard — one process per chip, at launch: every replica
+    is a `pio deploy` process that opens all of the host's chips, so on
+    an accelerator host `--replicas 2 --pin-model` is refused before
+    anything is spawned, naming the cause. (Measured on a v5e before
+    the check: the second replica's pin failed on the libtpu lockfile,
+    was caught, and it served from host arrays beside a device-served
+    sibling.) A CPU platform, a single replica, or a fleet with no
+    device flag passes, and only a device-serving fleet pays the probe
+    child."""
+    from predictionio_tpu.tools import console
+
+    calls = []
+
+    def fake_run(argv, **kw):
+        calls.append(argv)
+        return subprocess.CompletedProcess(argv, 0, stdout=answer, stderr="")
+
+    monkeypatch.setattr(subprocess, "run", fake_run)
+    parser = console.build_parser()
+    answer = "tpu 1\n"
+    args = parser.parse_args(["deploy", "--replicas", "2", "--pin-model"])
+    with pytest.raises(SystemExit) as exc:
+        console._check_fleet_fits_device(args)
+    msg = str(exc.value)
+    assert "--pin-model" in msg and "one process" in msg and "1 tpu" in msg
+    # an autoscaler that may grow past one replica is the same fleet
+    args = parser.parse_args(
+        ["deploy", "--replicas", "1", "--autoscale", "1:3", "--aot"]
+    )
+    with pytest.raises(SystemExit):
+        console._check_fleet_fits_device(args)
+    answer = "cpu 8\n"
+    args = parser.parse_args(["deploy", "--replicas", "2", "--pin-model"])
+    console._check_fleet_fits_device(args)  # CPU processes share freely
+    assert len(calls) == 3
+    # no device flag, or one replica: nothing to check, no probe child
+    console._check_fleet_fits_device(
+        parser.parse_args(["deploy", "--replicas", "4", "--result-cache"])
+    )
+    console._check_fleet_fits_device(
+        parser.parse_args(["deploy", "--replicas", "1", "--pin-model"])
+    )
+    assert len(calls) == 3
+
+
 def test_aot_defaults_are_opt_in():
     """ISSUE 19 guard: deploy-time AOT serving is strictly opt-in.
     Default ``pio train``/``pio deploy``/``pio chaos-serve`` parse with
-    ``--aot`` off and no compilation-cache override, loading the console
+    ``--aot`` off and no compilation-cache flag at all (the cache is
+    placed from outside, utils/compile_cache.py), loading the console
     never imports ``workflow.aot`` (the default serve path stays
     byte-identical — no export machinery in the process), and the
     module keeps its own manifest pin so a storage/console import from
@@ -572,8 +727,9 @@ def test_aot_defaults_are_opt_in():
         assert args.aot is False, f"--aot defaults on for {cmd}"
     for cmd in ("train", "deploy"):
         args = parser.parse_args([cmd])
-        assert args.compilation_cache_dir is None, (
-            f"--compilation-cache-dir defaults set for {cmd}"
+        assert not hasattr(args, "compilation_cache_dir"), (
+            f"--compilation-cache-dir is back on {cmd}: the cache "
+            "directory comes from $JAX_COMPILATION_CACHE_DIR only"
         )
     # default console path never pulls in the AOT module (parity with
     # the batching/caching/ann/online/fleet opt-in guards)
@@ -757,7 +913,7 @@ def test_quantize_defaults_are_opt_in(memory_storage_env):
         [sys.executable, "-c", probe], cwd=REPO, capture_output=True
     )
     assert proc.returncode == 0, proc.stderr.decode()[-500:]
-    # PIO305 registered (same containment contract as PIO304)
+    # PIO305 registered
     from predictionio_tpu.analysis import all_rules
 
     assert "PIO305" in all_rules(), (

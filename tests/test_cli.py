@@ -144,6 +144,10 @@ class TestConsoleEntryPoint:
         assert main(["status"]) == 0
         out = capsys.readouterr().out
         assert "All systems go!" in out
+        # N x platform (device_kind), as JAX reports them
+        import jax
+
+        assert f"x cpu ({jax.devices()[0].device_kind})" in out
 
     def test_app_new_via_argv(self, memory_storage_env, capsys):
         assert main(["app", "new", "cliapp"]) == 0

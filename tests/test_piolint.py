@@ -1145,7 +1145,7 @@ def test_pio301_static_args_are_not_traced():
     assert _codes("predictionio_tpu/parallel/x.py", traced) == ["PIO301"]
 
 
-def test_pio304_raw_shard_map():
+def test_pio304_deprecated_shard_map():
     import_from = """\
     from jax.experimental.shard_map import shard_map
 
@@ -1153,26 +1153,21 @@ def test_pio304_raw_shard_map():
         return shard_map(lambda y: y, mesh=None, in_specs=(), out_specs=())(x)
     """
     assert _codes("predictionio_tpu/ops/x.py", import_from) == ["PIO304"]
-    assert _codes("predictionio_tpu/parallel/x.py", import_from) == ["PIO304"]
-    attr = """\
+    found = _find("predictionio_tpu/parallel/x.py", import_from)
+    assert [f.code for f in found] == ["PIO304"]
+    assert "jax.shard_map" in found[0].message
+    plain_import = "import jax.experimental.shard_map\n"
+    assert _codes("predictionio_tpu/ops/x.py", plain_import) == ["PIO304"]
+    # host-side packages are out of the jax-hygiene scope
+    assert _codes("predictionio_tpu/workflow/x.py", import_from) == []
+    # the top-level API is the sanctioned spelling
+    ok = """\
     import jax
 
     def f(x):
-        return jax.shard_map(lambda y: y, mesh=None, in_specs=(), out_specs=())(x)
-    """
-    found = _find("predictionio_tpu/parallel/x.py", attr)
-    assert [f.code for f in found] == ["PIO304"]
-    assert "ops.compat" in found[0].message
-    # the shim itself is the one legal home
-    assert _codes("predictionio_tpu/ops/compat.py", import_from) == []
-    # host-side packages are out of the jax-hygiene scope
-    assert _codes("predictionio_tpu/workflow/x.py", import_from) == []
-    # the compat-shim import is the sanctioned spelling
-    ok = """\
-    from predictionio_tpu.ops.compat import shard_map
-
-    def f(x):
-        return shard_map(lambda y: y, mesh=None, in_specs=(), out_specs=())(x)
+        return jax.shard_map(
+            lambda y: y, mesh=None, in_specs=(), out_specs=(), check_vma=False
+        )(x)
     """
     assert _codes("predictionio_tpu/parallel/x.py", ok) == []
     # inline suppression works like every other rule

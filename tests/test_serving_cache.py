@@ -526,8 +526,25 @@ class TestPinnedServing:
         assert not isinstance(model.user_factors, np.ndarray)
         stats = qs.stats_json()["cache"]
         assert stats["bytesPinned"] > 0
-        # pinned predictions match the host path's results
+        # GET / says where predict computes: device buffers on the
+        # platform JAX reports, vs host arrays in a process that never
+        # opened the backend (platform stays null there)
+        import jax
+
         qs_host = QueryService(variant)
+        assert qs.status_json()["device"] == {
+            "servedFrom": "device",
+            "platform": "cpu",
+            "deviceKind": jax.devices()[0].device_kind,
+            "count": len(jax.devices()),
+        }
+        assert qs_host.status_json()["device"] == {
+            "servedFrom": "host",
+            "platform": None,
+            "deviceKind": None,
+            "count": None,
+        }
+        # pinned predictions match the host path's results
         r_pin = _query(qs, user="3", num=5)
         r_host = _query(qs_host, user="3", num=5)
         assert r_pin.status == r_host.status == 200
@@ -546,6 +563,8 @@ class TestPinnedServing:
         _, model = pairs[0]
         assert isinstance(model.user_factors, np.ndarray)
         assert not getattr(model, "_pio_pinned", True)
+        # judged from the arrays held NOW, not from the flag it booted with
+        assert qs.status_json()["device"]["servedFrom"] == "host"
 
     def test_pin_survives_algorithms_without_the_hook(self):
         from predictionio_tpu.workflow import device_state
