@@ -23,7 +23,9 @@ every leg that needs it runs as a child, one at a time.
   5 twotower child   `pio train` of the Two-Tower template (dim 64, batch 8192,
                      1M interactions, 20k x 10k, bf16 GEMMs); fusedCe must be
                      "pallas"; loss finite and decreasing
-  6 kernels  child   both Pallas kernels against their references on device
+  6 kernels  child   both Pallas kernels against their references on device;
+                     the solver also at the small padded ranks spd_solve sends
+                     it (the templates' default rank 10 -> K=16, 5 -> 8, 20 -> 24)
 
 It adds no fallback of its own: no CPU default, no interpret mode, no leg
 whose failure becomes a field. Any failed leg raises, the script exits
@@ -33,8 +35,9 @@ These are smoke facts on a named device, not benchmark results.
 
 `--rehearse-cpu` runs the same plumbing at toy sizes on XLA:CPU (kernel
 decisions cholesky/host/xla, Pallas in interpret mode). Every field says
-platform cpu, the result line says "ok": false, and it can never satisfy
-the chip check; it exists so the plumbing is debugged off the chip.
+platform cpu, the result line says "ok": false, it exits 4 when every leg
+passed (never 0), and it can never satisfy the chip check; it exists so the
+plumbing is debugged off the chip.
 """
 
 from __future__ import annotations
@@ -66,15 +69,25 @@ FULL = {
     "twotower": {"interactions": 1_000_000, "users": 20_000, "items": 10_000,
                  "dim": 64, "batch": 8192, "epochs": 3},
     "serve": {"sequential": 40, "concurrent": 200, "clients": 16, "num": 10},
-    "kernels": {"gj": [[138_000, 64], [8_000, 128]], "ce": [8192, 64]},
+    # "spd": [batch, rank] through spd_solve, the call the ALS sweep makes:
+    # a rank that is not a multiple of 8 is padded into the next one, so
+    # the templates' default rank 10 runs the kernel at K=16, rank 5 at
+    # K=8 and rank 20 at K=24 — each its own Mosaic compile
+    "kernels": {"gj": [[138_000, 64], [8_000, 128]],
+                "spd": [[138_000, 10], [27_027, 5], [27_027, 20]],
+                "ce": [8192, 64]},
 }
+#: engine defaults the smoke cuts in DEPTH (never width): reported in the
+#: result line's "reduced"
+ENGINE_DEFAULT_DEPTH = {"als": ("iterations", "numIterations", 20),
+                        "twotower": ("epochs", "epochs", 5)}
 TOY = {
     "als": {"ratings": 20_000, "users": 400, "items": 150,
             "rank": 8, "iterations": 2},
     "twotower": {"interactions": 4_000, "users": 300, "items": 120,
                  "dim": 16, "batch": 256, "epochs": 2},
     "serve": {"sequential": 8, "concurrent": 24, "clients": 8, "num": 10},
-    "kernels": {"gj": [[64, 16]], "ce": [256, 16]},
+    "kernels": {"gj": [[64, 16]], "spd": [[64, 10]], "ce": [256, 16]},
 }
 
 # Agreement tolerance of leg 4, per item i: |served_i - ref_i| <= AGREE_REL *
@@ -97,6 +110,11 @@ AGREE_ABS = 1e-6
 GJ_TOL = 1e-4
 CE_LOSS_TOL = 5e-3
 CE_GRAD_TOL = 2e-2
+
+
+#: exit status of a `--rehearse-cpu` run whose legs all passed (0 is a
+#: pass on a TPU, 1 a failed leg, 2 no checkout beside the script)
+REHEARSAL_EXIT = 4
 
 
 class LegFailed(RuntimeError):
@@ -145,7 +163,12 @@ def child_kernels(spec: dict, interpret: bool) -> int:
             steady.append(time.perf_counter() - t0)
         return result, first, sorted(steady)[1]
 
-    for batch, k in spec["gj"]:
+    method = "pallas_interpret" if interpret else "pallas"
+    # "gj": the kernel called directly at a multiple-of-8 K; "spd": a rank
+    # through spd_solve(method), which pads it into the next multiple
+    for via, batch, k in (
+        [("gj", *bk) for bk in spec["gj"]] + [("spd", *bk) for bk in spec["spd"]]
+    ):
         @jax.jit
         def make(key, batch=batch, k=k):
             kb, kr = jax.random.split(key)
@@ -154,9 +177,14 @@ def child_kernels(spec: dict, interpret: bool) -> int:
             return a + 0.1 * jnp.eye(k), jax.random.normal(kr, (batch, k))
 
         a, b = make(jax.random.PRNGKey(k))
-        x, first, steady = first_and_steady(
-            lambda: solve.gj_solve_pallas(a, b, interpret=interpret)
-        )
+        if via == "spd":
+            _require(solve.pallas_rank_ok(k), f"rank {k} would not run the kernel")
+            solver = jax.jit(lambda a, b: solve.spd_solve(a, b, method))
+            x, first, steady = first_and_steady(lambda: solver(a, b))
+        else:
+            x, first, steady = first_and_steady(
+                lambda: solve.gj_solve_pallas(a, b, interpret=interpret)
+            )
         x_ref = solve.cholesky_solve(a, b)
 
         @jax.jit
@@ -170,9 +198,12 @@ def child_kernels(spec: dict, interpret: bool) -> int:
             return jnp.max(num / den), jnp.max(resid)
 
         rel, resid = (float(v) for v in errors(a, b, x, x_ref))
+        k_kernel = -(-k // 8) * 8
         rec = {
+            "via": "gj_solve_pallas" if via == "gj" else f"spd_solve({method})",
             "shape": [batch, k, k],
-            "blockRows": solve._auto_block_rows(k),
+            "kernelK": k_kernel,
+            "blockRows": solve._auto_block_rows(k_kernel),
             "relErrVsCholesky": rel,
             "residual": resid,
             "firstCallSeconds": round(first, 2),
@@ -720,7 +751,12 @@ class Smoke:
             "device": self.device,
             "seed": self.seed,
             "sizes": {"als": self.sizes["als"], "twotower": self.sizes["twotower"]},
-            "reduced": [],
+            # depth only: every width, catalog and data size is the full one
+            "reduced": [
+                f"{leg} {param} {self.sizes[leg][key]} of the engine default {default}"
+                for leg, (key, param, default) in ENGINE_DEFAULT_DEPTH.items()
+                if self.sizes[leg][key] < default
+            ],
             "totalSeconds": round(time.monotonic() - self.t0, 1),
             "compileCacheDir": self.env["JAX_COMPILATION_CACHE_DIR"],
             "facts": self.facts,
@@ -756,7 +792,9 @@ def main() -> int:
     finally:
         smoke.close()
     print(json.dumps(result))
-    return 0
+    # a rehearsal is never a pass: its own code, for callers that read
+    # only the exit status
+    return REHEARSAL_EXIT if args.rehearse_cpu else 0
 
 
 if __name__ == "__main__":

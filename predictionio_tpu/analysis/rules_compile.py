@@ -144,6 +144,10 @@ _TRANSFER_ALLOWED: dict = {
     "predictionio_tpu/parallel/sharding.py": {
         "topk_users": "host-facing wrapper: bounded [B, k] finalist "
         "materialization — the single documented crossing per batch",
+        "take_rows": "the numpy conversions stage the caller's HOST "
+        "index list for the gather; the gathered rows stay where the "
+        "table lives (moved here from device_state, whose whole file "
+        "is allowed)",
     },
 }
 

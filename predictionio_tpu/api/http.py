@@ -278,6 +278,12 @@ def _make_handler(
                 getattr(resp, "content_type", "application/json; charset=UTF-8"),
                 getattr(resp, "headers", None),
             )
+            after_send = getattr(resp, "after_send", None)
+            if after_send is not None:
+                # the reply is on the wire before the hook runs: /stop
+                # shuts the listener (and the process) down from here
+                self.wfile.flush()
+                after_send()
 
         def _dispatch_stream(self, parsed, params):
             te = (self.headers.get("Transfer-Encoding") or "").lower()

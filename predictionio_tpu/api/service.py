@@ -28,7 +28,7 @@ import threading
 import time
 import weakref
 from collections import OrderedDict
-from typing import Any, Iterable, Mapping
+from typing import Any, Callable, Iterable, Mapping
 
 from predictionio_tpu.api.stats import Stats
 from predictionio_tpu.api.webhooks import (
@@ -87,6 +87,10 @@ class Response:
     #: extra HTTP headers (e.g. ``Retry-After`` on a 429 from the serving
     #: runtime's admission control); the transport layer emits them
     headers: Mapping[str, str] | None = None
+    #: run by the transport once the response bytes are flushed to the
+    #: socket (``GET /stop``: the listener goes down only after its own
+    #: answer has left, so the caller never sees a cut reply)
+    after_send: Callable[[], Any] | None = None
 
     def json_bytes(self) -> bytes:
         return json.dumps(self.body, default=str).encode()

@@ -329,7 +329,9 @@ class _Wire:
     module). ``raw`` carries an already-encoded replica body through
     unchanged; ``body`` is JSON-encoded at send time."""
 
-    __slots__ = ("status", "body", "raw", "headers", "content_type")
+    __slots__ = (
+        "status", "body", "raw", "headers", "content_type", "after_send",
+    )
 
     def __init__(
         self,
@@ -338,12 +340,15 @@ class _Wire:
         raw: bytes | None = None,
         headers: Mapping[str, str] | None = None,
         content_type: str = "application/json; charset=UTF-8",
+        after_send: Callable[[], Any] | None = None,
     ):
         self.status = status
         self.body = body
         self.raw = raw
         self.headers = dict(headers) if headers else None
         self.content_type = content_type
+        #: run by the transport after the reply is flushed (GET /stop)
+        self.after_send = after_send
 
     def json_bytes(self) -> bytes:
         if self.raw is not None:
@@ -1477,6 +1482,8 @@ class RouterService:
                 return _Wire(403, {"message": "Missing or invalid stop token."})
             if self.stop_server is None:
                 return _Wire(501, {"message": "This router has no stop hook."})
-            self.stop_server()
-            return _Wire(200, {"message": "Shutting down fleet."})
+            return _Wire(
+                200, {"message": "Shutting down fleet."},
+                after_send=self.stop_server,
+            )
         return self._passthrough(method, path, params, body)

@@ -79,6 +79,13 @@ class FleetSupervisor:
     #: flags) — stop respawning it and mark it failed in the state file
     MAX_RESPAWNS = 5
     RESPAWN_WINDOW_S = 60.0
+    #: exit code of a ``pio deploy`` that was asked to serve from the
+    #: device and could not open the JAX backend
+    #: (``workflow.device_state.DeviceUnavailableError.exit_code``). A
+    #: chip belongs to one process at a time and every replica opens all
+    #: of the host's chips, so a respawn cannot succeed while a sibling
+    #: holds them: the replica is marked failed at once, naming the cause
+    DEVICE_UNAVAILABLE_RC = 69
 
     def __init__(
         self,
@@ -140,6 +147,21 @@ class FleetSupervisor:
                 if failed or proc is None or proc.poll() is None:
                     continue
                 rc = proc.returncode
+                if rc == self.DEVICE_UNAVAILABLE_RC:
+                    logger.error(
+                        "replica %s (port %d) could not open the "
+                        "accelerator (rc=%d): a chip belongs to one "
+                        "process at a time and another process — a "
+                        "sibling replica, on a device-serving fleet with "
+                        "more replicas than this host can hold — has it. "
+                        "Not respawning; run one device-serving replica "
+                        "per host or drop the device flags.",
+                        spec.replica_id, spec.port, rc,
+                    )
+                    with self._lock:
+                        self._failed.add(spec.replica_id)
+                    changed = True
+                    continue
                 now = time.monotonic()
                 times = self._respawn_times.setdefault(spec.replica_id, [])
                 times[:] = [

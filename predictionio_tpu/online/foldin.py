@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from predictionio_tpu.workflow import device_state
+from predictionio_tpu.parallel.sharding import take_rows
 
 __all__ = ["foldin_rows", "gram_yty"]
 
@@ -169,7 +169,7 @@ def foldin_rows(
         # depend on the catalog size, which cold-start injections keep
         # growing
         Yg = jnp.asarray(
-            device_state.take_rows(Y, idx.reshape(-1)).reshape(B_CHUNK, L, K),
+            take_rows(Y, idx.reshape(-1)).reshape(B_CHUNK, L, K),
             jnp.float32,
         )
         out = _foldin_kernel(

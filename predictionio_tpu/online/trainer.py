@@ -30,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from predictionio_tpu.online.types import OnlineUpdate
-from predictionio_tpu.workflow import device_state
+from predictionio_tpu.parallel.sharding import take_rows
 
 __all__ = ["StreamingTrainer", "sgd_step"]
 
@@ -99,8 +99,8 @@ def sgd_step(
     ip[:B] = i_idx
     mask = np.zeros(Bp, np.float32)
     mask[:B] = 1.0
-    ue = np.asarray(device_state.take_rows(user_vecs, up), np.float32)
-    ie = np.asarray(device_state.take_rows(item_vecs, ip), np.float32)
+    ue = np.asarray(take_rows(user_vecs, up), np.float32)
+    ie = np.asarray(take_rows(item_vecs, ip), np.float32)
     loss, gu, gi = _grad_kernel(
         jnp.asarray(ue), jnp.asarray(ie), jnp.asarray(mask),
         jnp.float32(1.0 / max(temperature, 1e-6)),

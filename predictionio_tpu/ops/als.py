@@ -1245,6 +1245,9 @@ def train_als(
         "mesh": None if mesh is None else dict(mesh.shape),
     }
     logger.info("ALS kernel decisions: %s", decisions)
+    # the timing fields cost a host sync after bucketing and after every
+    # sweep: taken only for a caller that asked for them
+    timed = info is not None
     info = {} if info is None else info
     info.update(decisions)
     if mesh is not None and model_axis not in mesh.shape:
@@ -1328,10 +1331,13 @@ def train_als(
             user_bucketed = _device_buckets(user_b, mesh, data_axis)
             item_bucketed = _device_buckets(item_b, mesh, data_axis)
 
-    # bucketing wall (transfer, sort, fill — and their compiles when
-    # cold), closed by a sync so the first sweep is not charged for it
-    jax.block_until_ready((user_bucketed, item_bucketed))
-    info["bucketingSeconds"] = round(time.perf_counter() - t_bucketing, 3)
+    if timed:
+        # bucketing wall (transfer, sort, fill — and their compiles when
+        # cold), closed by a sync so the first sweep is not charged for it
+        jax.block_until_ready((user_bucketed, item_bucketed))
+        info["bucketingSeconds"] = round(
+            time.perf_counter() - t_bucketing, 3
+        )
 
     key_u, key_i = jax.random.split(jax.random.PRNGKey(config.seed))
     # Table length: num_rows + 1 sentinel row, padded up so the row axis
@@ -1487,11 +1493,11 @@ def train_als(
             data_axis=data_axis if mesh is not None else None,
             model_axis=model_axis if mesh is not None else None,
         )
-        # one sync per sweep (sweeps depend on each other anyway): the
-        # first entry carries the sweep's compile, the rest are steady
-        # state
-        jax.block_until_ready(vf)
-        sweep_seconds.append(round(time.perf_counter() - t_sweep, 3))
+        if timed:
+            # one sync per sweep: the first entry carries the sweep's
+            # compile, the rest are steady state
+            jax.block_until_ready(vf)
+            sweep_seconds.append(round(time.perf_counter() - t_sweep, 3))
         if manager is not None and (
             (step + 1) % config.checkpoint_interval == 0
             or step + 1 == config.iterations
@@ -1499,7 +1505,8 @@ def train_als(
             # _to_canonical hands the save fresh buffers, so the async
             # write overlaps the next sweep instead of serializing it
             manager.save(step + 1, _to_canonical(uf, vf))
-    info["sweepSeconds"] = sweep_seconds
+    if timed:
+        info["sweepSeconds"] = sweep_seconds
     if manager is not None:
         manager.wait()
         manager.close()

@@ -60,16 +60,22 @@ _KERNEL_VMEM_MULTIPLIER = 17
 #: the kernel's ceiling. Block rows must be a multiple of 8 (the [TB, K]
 #: right-hand-side block is tiled (8, 128); TB=2 and 4 are refused at
 #: lowering), and 8 rows fit the budget only up to K~164 by the model
-#: above. Compiled and checked against Cholesky on the chip at K=64 and
-#: K=128; beyond that spd_solve says so and solves with Cholesky.
+#: above. Mosaic refuses shapes the interpreter accepts, so the ceiling
+#: is what COMPILED: every multiple of 8 from 8 to 128 — each K that
+#: spd_solve's padding can produce — compiled on the chip at the block
+#: rows _auto_block_rows picks and agreed with Cholesky to 2.1e-6
+#: (PERF.md, PR 21; at K=88 the XLA reference itself ran out of scoped
+#: VMEM, so that one was checked by residual). Beyond it spd_solve says
+#: so and solves with Cholesky.
 _MAX_PALLAS_K = 128
 
 
 def _auto_block_rows(K: int) -> int:
     """Largest multiple-of-8 block_rows whose TOTAL kernel working set
     (~_KERNEL_VMEM_MULTIPLIER x the [TB,K,K] A block) fits the VMEM
-    budget: 32 at K=64 (capped), 8 at K=128 — both compiled by Mosaic on
-    the chip, not just run in the interpreter."""
+    budget: 32 up to K=80, 24 at 88, 16 up to 112, 8 at 120 and 128 —
+    each compiled by Mosaic on the chip, not just run in the
+    interpreter."""
     tb = _VMEM_BUDGET // (_KERNEL_VMEM_MULTIPLIER * K * K * 4)
     return max(8, min(_BLOCK_ROWS, tb // 8 * 8))
 

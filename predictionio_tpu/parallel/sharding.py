@@ -58,6 +58,8 @@ __all__ = [
     "shard_table",
     "shard_quantized_table",
     "gather_rows",
+    "row_sharding",
+    "take_rows",
     "sharded_topk_users",
     "sharded_quantized_topk_users",
     "sharded_ivf_topk",
@@ -248,6 +250,42 @@ def gather_rows(idx: jax.Array, tbl: jax.Array, mesh: Mesh) -> jax.Array:
         out_specs=PartitionSpec(),
         check_vma=False,
     )(idx, tbl)
+
+
+def row_sharding(mat) -> NamedSharding | None:
+    """The table's NamedSharding when its rows are partitioned over a
+    mesh axis (the --shard-factors layout), else None."""
+    s = getattr(mat, "sharding", None)
+    if (
+        isinstance(s, NamedSharding)
+        and len(s.spec) >= 1
+        and s.spec[0] is not None
+    ):
+        return s
+    return None
+
+
+def take_rows(mat, idx):
+    """Rows ``idx`` of a factor table wherever it lives — the read twin
+    of ``workflow.device_state.set_rows``. Host arrays fancy-index and a
+    pinned table gathers on device; a ``--shard-factors`` table resolves
+    the rows from their owner shards (:func:`gather_rows`): the serving
+    mesh has Explicit axes, where an eager ``tbl[idx]`` on a sharded
+    table is a type error rather than a silent all-gather. A quantized
+    table dequantizes only the rows asked for."""
+    if getattr(mat, "is_quantized", False):
+        from predictionio_tpu.ops import quant
+
+        return quant.dequantize(
+            take_rows(mat.codes, idx), take_rows(mat.scales, idx)
+        )
+    if isinstance(mat, np.ndarray):
+        return mat[np.asarray(idx, np.int64)]
+    idx = jnp.asarray(np.asarray(idx, np.int32))
+    sharded = row_sharding(mat)
+    if sharded is not None:
+        return gather_rows(idx, mat, sharded.mesh)
+    return mat[idx]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "mesh"))

@@ -1023,7 +1023,7 @@ class ALSAlgorithm(JaxAlgorithm):
         serving lock; ``apply_online_update`` swaps the rows in."""
         from predictionio_tpu.online.foldin import foldin_rows, gram_yty
         from predictionio_tpu.online.types import OnlineUpdate, latest_wins
-        from predictionio_tpu.workflow import device_state
+        from predictionio_tpu.parallel import sharding
 
         p = self.params
         rate_event = ds_params.get("rate_event", ds_params.get("rateEvent", "rate"))
@@ -1095,7 +1095,7 @@ class ALSAlgorithm(JaxAlgorithm):
             known = prior_rows >= 0
             if n_own:
                 gathered = np.asarray(
-                    device_state.take_rows(
+                    sharding.take_rows(
                         own_factors, np.where(known, prior_rows, 0)
                     ),
                     np.float32,
@@ -1193,10 +1193,10 @@ class ALSAlgorithm(JaxAlgorithm):
             if quantrt is not None or shards is not None:
                 # quantized and/or sharded user table: only the
                 # requested row is dequantized / leaves its shard
-                from predictionio_tpu.workflow import device_state
+                from predictionio_tpu.parallel import sharding
 
                 qvec = np.asarray(
-                    device_state.take_rows(model.user_factors, [uidx])
+                    sharding.take_rows(model.user_factors, [uidx])
                 )[0]
             else:
                 qvec = np.asarray(model.user_factors[uidx])

@@ -713,10 +713,10 @@ class TwoTowerAlgorithm(JaxAlgorithm):
             from predictionio_tpu.ops import ivf
 
             if quantrt is not None or shards is not None:
-                from predictionio_tpu.workflow import device_state
+                from predictionio_tpu.parallel import sharding
 
                 qvec = np.asarray(
-                    device_state.take_rows(model.user_vecs, [uidx])
+                    sharding.take_rows(model.user_vecs, [uidx])
                 )[0]
             else:
                 qvec = np.asarray(model.user_vecs[uidx])

@@ -856,11 +856,14 @@ def undeploy(
         raise RuntimeError(
             f"Could not reach a deployment at {url}: {e.reason}"
         ) from e
-    except ConnectionResetError:
-        # the server shuts down on a helper thread while it answers; when
-        # the shutdown wins that race the response is cut short (seen as
-        # RemoteDisconnected). The request was accepted: the stop took.
-        pass
+    except ConnectionResetError as e:
+        # the server flushes its answer before it shuts down, so a cut
+        # reply is not a stop: whatever held the port died or is not a
+        # deployment
+        raise RuntimeError(
+            f"Connection to {ip}:{port} was cut before /stop answered; "
+            "the deployment's state is unknown."
+        ) from e
     out(f"Undeployed engine server at {ip}:{port}.")
 
 

@@ -1088,68 +1088,6 @@ def _replica_argv(args, replica_id: str, announce_dir: str) -> list[str]:
     return argv
 
 
-#: deploy flags that make a replica open the JAX backend (pinned or
-#: sharded tables, exported programs, an IVF index, fold-in solves, the
-#: exploration rerank) — a flag-less replica serves from host numpy
-_DEVICE_SERVING_FLAGS = (
-    "pin_model", "shard_factors", "quantize", "aot", "ann", "online",
-    "explore",
-)
-
-
-def _check_fleet_fits_device(args) -> None:
-    """One process per chip, enforced at launch. Every replica is a
-    whole ``pio deploy`` process that opens ALL of the host's chips, and
-    a chip belongs to one process at a time: on an accelerator host the
-    second device-serving replica cannot open the backend (and before
-    this check its pin failure was caught, logged, and served from host
-    arrays beside a device-served sibling). Fail here, before anything
-    is spawned, naming the cause. The platform is asked of a short-lived
-    child — the supervisor itself must never hold a chip. Giving each
-    replica its own chip is future work (ROADMAP R6b)."""
-    import subprocess
-
-    flags = [f for f in _DEVICE_SERVING_FLAGS if getattr(args, f, None)]
-    wanted = max(args.replicas or 0, _autoscale_max(args.autoscale))
-    if wanted <= 1 or not flags:
-        return
-    probe = subprocess.run(
-        [
-            sys.executable, "-c",
-            "import jax; d = jax.devices(); print(d[0].platform, len(d))",
-        ],
-        capture_output=True, text=True, stdin=subprocess.DEVNULL,
-    )
-    if probe.returncode != 0:
-        raise SystemExit(
-            "pio deploy --replicas: could not open the JAX backend to "
-            "check that a device-serving fleet fits this host:\n"
-            + probe.stderr[-2000:]
-        )
-    platform, count = probe.stdout.split()[-2:]
-    if platform != "cpu":
-        raise SystemExit(
-            f"pio deploy --replicas {wanted} with "
-            f"{', '.join('--' + f.replace('_', '-') for f in flags)}: each "
-            f"replica is a process that opens all {count} {platform} "
-            "chip(s) of this host, and a chip belongs to one process at a "
-            "time — replicas after the first would fail to start or "
-            "serve from host arrays. Run one device-serving replica per "
-            "host, or drop the device flags to serve the fleet from host "
-            "arrays."
-        )
-
-
-def _autoscale_max(spec: str | None) -> int:
-    if not spec:
-        return 0
-    lo, _, hi = spec.partition(":")
-    try:
-        return int(hi or lo)
-    except ValueError:
-        return 0  # the autoscaler config reports the malformed spec
-
-
 def _deploy_fleet(args) -> int:
     """``pio deploy --replicas N`` (and ``--router-only``): spawn the
     replica subprocesses under the self-healing supervisor and serve the
@@ -1183,8 +1121,6 @@ def _deploy_fleet(args) -> int:
             "--router-only serves no supervisor to scale; run --autoscale "
             "on the fleet that owns the replicas"
         )
-    if not args.router_only:
-        _check_fleet_fits_device(args)
     base_dir = Storage.base_dir()
     endpoints_dir = args.endpoint_registry or os.path.join(
         base_dir, "fleet", "endpoints"
@@ -2176,7 +2112,9 @@ def main(argv: list[str] | None = None) -> int:
         return 0
     except Exception as e:
         print(f"ERROR: {e}", file=sys.stderr)
-        return 1
+        # DeviceUnavailableError carries its own code (69): the fleet
+        # supervisor reads it as "no chip for this replica", not a crash
+        return getattr(e, "exit_code", 1)
 
 
 if __name__ == "__main__":

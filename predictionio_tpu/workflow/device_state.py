@@ -40,6 +40,7 @@ import logging
 from typing import Sequence
 
 __all__ = [
+    "DeviceUnavailableError",
     "pin_pairs",
     "release_pairs",
     "build_ann_pairs",
@@ -47,7 +48,6 @@ __all__ = [
     "aot_stats",
     "serving_device",
     "set_rows",
-    "take_rows",
     "append_rows",
     "swap_side_rows",
     "update_ann_items",
@@ -55,6 +55,16 @@ __all__ = [
 ]
 
 logger = logging.getLogger(__name__)
+
+
+class DeviceUnavailableError(RuntimeError):
+    """A device flag cannot be honoured: this process cannot open the JAX
+    backend (on a TPU host, another process holds the chip)."""
+
+    #: ``pio deploy`` exits with this code (sysexits EX_UNAVAILABLE), so
+    #: the fleet supervisor can tell "no chip for this replica" from a
+    #: crash and does not respawn it (``fleet/supervisor.py``)
+    exit_code = 69
 
 
 def pin_pairs(
@@ -65,8 +75,13 @@ def pin_pairs(
 
     Returns ``(pairs, bytes_pinned)`` — the possibly-replaced pair list
     and the total device bytes now held by pinned state (0 when nothing
-    opted in or jax is unavailable). Pinning is best-effort: a pair
-    whose pin raises is served unpinned rather than failing the load.
+    opted in or jax is unavailable). An algorithm's pin hook raising is
+    best-effort — that pair is served unpinned rather than failing the
+    load. A BACKEND that cannot be opened is not: the operator asked
+    for device-resident state, and this process has no device (on a TPU
+    host, another process holds the chip), so the load fails with
+    :class:`DeviceUnavailableError` instead of serving from host arrays
+    under a device flag.
 
     ``shard=True`` (``pio deploy --shard-factors``) prefers each
     algorithm's ``shard_model_for_serving`` hook — pin factor SHARDS
@@ -99,6 +114,23 @@ def pin_pairs(
         logger.warning("--pin-model requested but jax is unavailable; "
                        "serving from host state")
         return list(pairs), 0
+    if any(
+        hasattr(algo, "pin_model_for_serving")
+        or hasattr(algo, "shard_model_for_serving")
+        or hasattr(algo, "quantize_model_for_serving")
+        for algo, _ in pairs
+    ):
+        try:
+            jax.devices()
+        except RuntimeError as e:
+            raise DeviceUnavailableError(
+                "device-resident serving state was requested "
+                "(--pin-model / --shard-factors / --quantize / --aot) but "
+                f"this process cannot open the JAX backend: {e} — a chip "
+                "belongs to one process at a time; another process on "
+                "this host (a trainer, a deployment, a sibling replica) "
+                "holds it."
+            ) from e
     out = []
     total = 0
     for algo, model in pairs:
@@ -336,7 +368,9 @@ def set_rows(mat, idx, rows):
         return out
     import jax.numpy as jnp
 
-    sharded = _named_sharding_of(mat)
+    from predictionio_tpu.parallel.sharding import row_sharding
+
+    sharded = row_sharding(mat)
     if sharded is not None:
         # --shard-factors: route each touched row to the device OWNING
         # its shard — a jitted scatter whose output sharding is pinned
@@ -350,50 +384,6 @@ def set_rows(mat, idx, rows):
     return mat.at[jnp.asarray(np.asarray(idx, np.int32))].set(
         jnp.asarray(np.asarray(rows), dtype=mat.dtype)
     )
-
-
-def take_rows(mat, idx):
-    """Rows ``idx`` of a factor table wherever it lives — the read twin
-    of :func:`set_rows`. Host arrays fancy-index and a pinned table
-    gathers on device; a ``--shard-factors`` table resolves the rows from
-    their owner shards (``parallel.sharding.gather_rows``): the serving
-    mesh has Explicit axes, where an eager ``tbl[idx]`` on a sharded
-    table is a type error rather than a silent all-gather. A quantized
-    table dequantizes only the rows asked for."""
-    import numpy as np
-
-    if getattr(mat, "is_quantized", False):
-        from predictionio_tpu.ops import quant
-
-        return quant.dequantize(
-            take_rows(mat.codes, idx), take_rows(mat.scales, idx)
-        )
-    if isinstance(mat, np.ndarray):
-        return mat[np.asarray(idx, np.int64)]
-    import jax.numpy as jnp
-
-    idx = jnp.asarray(np.asarray(idx, np.int32))
-    sharded = _named_sharding_of(mat)
-    if sharded is not None:
-        from predictionio_tpu.parallel import sharding
-
-        return sharding.gather_rows(idx, mat, sharded.mesh)
-    return mat[idx]
-
-
-def _named_sharding_of(mat):
-    """The table's NamedSharding when its rows are partitioned over a
-    mesh axis (the --shard-factors layout), else None."""
-    from jax.sharding import NamedSharding
-
-    s = getattr(mat, "sharding", None)
-    if (
-        isinstance(s, NamedSharding)
-        and len(s.spec) >= 1
-        and s.spec[0] is not None
-    ):
-        return s
-    return None
 
 
 #: one compiled scatter per distinct table sharding (NamedSharding is

@@ -1318,8 +1318,9 @@ class QueryService:
                     )
                 return Response(500, {"message": str(e)})
         if path == "/stop" and method == "GET":
-            # parity: CreateServer's stop route; the transport sets
-            # stop_server so the response is written before shutdown.
+            # parity: CreateServer's stop route. stop_server rides the
+            # response as after_send: the transport flushes the answer
+            # first and only then shuts the listener down.
             # When stop_token is set (pio deploy always sets one), the
             # caller must present it — otherwise anyone who can reach the
             # port could shut down a production deployment (advisor r3).
@@ -1345,8 +1346,10 @@ class QueryService:
                 return Response(
                     501, {"message": "This deployment has no stop hook."}
                 )
-            self.stop_server()
-            return Response(200, {"message": "Shutting down."})
+            return Response(
+                200, {"message": "Shutting down."},
+                after_send=self.stop_server,
+            )
         if path == "/profiler/start" and method == "POST":
             # jax.profiler trace capture (SURVEY.md section 6.1 rebuild
             # surface); view the dump with TensorBoard/XProf

@@ -640,7 +640,10 @@ def test_chip_smoke_cpu_rehearsal_runs_every_leg():
     flag) -> `pio deploy --pin-model --batching` -> agreement with the
     numpy reference -> two-tower train -> kernel checks in interpret
     mode. Every field says cpu and the result can never be mistaken for
-    a chip pass (`"ok": false`)."""
+    a chip pass: `"ok": false`, and an exit status of its own (4, never
+    0) for callers that read only that. The depth cuts are listed in
+    `reduced`; the kernel leg sends the templates' default rank 10
+    through `spd_solve` (padded to K=16)."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)  # one device, like one chip
     env["JAX_PLATFORMS"] = "cpu"
@@ -648,9 +651,15 @@ def test_chip_smoke_cpu_rehearsal_runs_every_leg():
         [sys.executable, "chip_smoke.py", "--rehearse-cpu"], cwd=REPO,
         env=env, capture_output=True, text=True, timeout=600,
     )
-    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.returncode == 4, proc.stderr[-3000:]
     rec = json.loads(proc.stdout.strip().splitlines()[-1])
     assert rec["ok"] is False and rec["rehearsal"] is True
+    assert rec["reduced"] == [
+        "als numIterations 2 of the engine default 20",
+        "twotower epochs 2 of the engine default 5",
+    ]
+    spd = [g for g in rec["legs"]["kernels"]["gj"] if g["via"].startswith("spd")]
+    assert [(g["shape"][1], g["kernelK"]) for g in spd] == [(10, 16)]
     assert rec["legsPassed"] is True and rec["claim"] is None
     assert rec["device"]["platform"] == "cpu"
     assert set(rec["legs"]) == {
@@ -664,50 +673,74 @@ def test_chip_smoke_cpu_rehearsal_runs_every_leg():
     assert rec["legs"]["agree"]["bf16PassEmulationOverTolerance"] > 1.0
 
 
-def test_device_serving_fleet_must_fit_the_host(monkeypatch):
-    """ISSUE 21 guard — one process per chip, at launch: every replica
-    is a `pio deploy` process that opens all of the host's chips, so on
-    an accelerator host `--replicas 2 --pin-model` is refused before
-    anything is spawned, naming the cause. (Measured on a v5e before
-    the check: the second replica's pin failed on the libtpu lockfile,
-    was caught, and it served from host arrays beside a device-served
-    sibling.) A CPU platform, a single replica, or a fleet with no
-    device flag passes, and only a device-serving fleet pays the probe
-    child."""
-    from predictionio_tpu.tools import console
-
-    calls = []
-
-    def fake_run(argv, **kw):
-        calls.append(argv)
-        return subprocess.CompletedProcess(argv, 0, stdout=answer, stderr="")
-
-    monkeypatch.setattr(subprocess, "run", fake_run)
-    parser = console.build_parser()
-    answer = "tpu 1\n"
-    args = parser.parse_args(["deploy", "--replicas", "2", "--pin-model"])
-    with pytest.raises(SystemExit) as exc:
-        console._check_fleet_fits_device(args)
-    msg = str(exc.value)
-    assert "--pin-model" in msg and "one process" in msg and "1 tpu" in msg
-    # an autoscaler that may grow past one replica is the same fleet
-    args = parser.parse_args(
-        ["deploy", "--replicas", "1", "--autoscale", "1:3", "--aot"]
+def test_online_math_does_not_import_the_serving_state_layer():
+    """Layering: `online/foldin.py` and `online/trainer.py` are jax/numpy
+    math. They read table rows through `parallel.sharding.take_rows`
+    (beside `gather_rows`), not through `workflow/device_state` — a lower
+    layer importing the serving-state layer would execute the whole
+    workflow package (core, storage, controller) and open an import cycle
+    the day workflow imports online eagerly."""
+    probe = (
+        "import sys; "
+        "import predictionio_tpu.online.foldin; "
+        "import predictionio_tpu.online.trainer; "
+        "sys.exit(1 if any(m.startswith('predictionio_tpu.workflow') "
+        "for m in sys.modules) else 0)"
     )
-    with pytest.raises(SystemExit):
-        console._check_fleet_fits_device(args)
-    answer = "cpu 8\n"
-    args = parser.parse_args(["deploy", "--replicas", "2", "--pin-model"])
-    console._check_fleet_fits_device(args)  # CPU processes share freely
-    assert len(calls) == 3
-    # no device flag, or one replica: nothing to check, no probe child
-    console._check_fleet_fits_device(
-        parser.parse_args(["deploy", "--replicas", "4", "--result-cache"])
+    proc = subprocess.run(
+        [sys.executable, "-c", probe], cwd=REPO, capture_output=True
     )
-    console._check_fleet_fits_device(
-        parser.parse_args(["deploy", "--replicas", "1", "--pin-model"])
+    assert proc.returncode == 0, proc.stderr.decode()[-500:]
+
+
+def test_device_flag_without_a_backend_fails_the_load(monkeypatch):
+    """ISSUE 21 guard — fail at the cause. Measured on a v5e: a second
+    process's `device_put` fails on the libtpu lockfile (a chip belongs
+    to one process), and `pin_pairs` used to catch that and serve from
+    host arrays under `--pin-model`, exit 0. Now a backend that cannot
+    be opened raises DeviceUnavailableError (deploy exits 69, which the
+    fleet supervisor reads as "no chip for this replica"), while an
+    algorithm's own pin hook raising stays best-effort."""
+    import jax
+
+    from predictionio_tpu.fleet import FleetSupervisor
+    from predictionio_tpu.tools import commands, console
+    from predictionio_tpu.workflow import device_state
+
+    class Pins:
+        def pin_model_for_serving(self, model):
+            raise ValueError("this model cannot pin")
+
+    model = object()
+    pairs, nbytes = device_state.pin_pairs([(Pins(), model)])
+    assert pairs[0][1] is model and nbytes == 0  # hook failure: unpinned
+
+    def no_backend():
+        raise RuntimeError(
+            "Unable to initialize backend 'tpu': ABORTED: Internal error "
+            "when accessing libtpu multi-process lockfile."
+        )
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    with pytest.raises(device_state.DeviceUnavailableError) as exc:
+        device_state.pin_pairs([(Pins(), model)])
+    assert "lockfile" in str(exc.value) and "one process" in str(exc.value)
+    # nothing to pin -> the backend is never opened, nothing raises
+    plain = object()
+    assert device_state.pin_pairs([(plain, model)]) == ([(plain, model)], 0)
+    # the code travels: console exit status == what the supervisor reads
+    assert (
+        device_state.DeviceUnavailableError.exit_code
+        == FleetSupervisor.DEVICE_UNAVAILABLE_RC
     )
-    assert len(calls) == 3
+
+    def refuse(*a, **kw):
+        raise device_state.DeviceUnavailableError("no chip")
+
+    monkeypatch.setattr(commands, "undeploy", refuse)
+    assert console.main(["undeploy"]) == FleetSupervisor.DEVICE_UNAVAILABLE_RC
+    # the launcher keeps no list of "device flags" to go stale
+    assert not hasattr(console, "_DEVICE_SERVING_FLAGS")
 
 
 def test_aot_defaults_are_opt_in():

@@ -517,3 +517,50 @@ class TestSupervisor:
         for p in (pid, new_pid):
             with pytest.raises(ProcessLookupError):
                 os.kill(p, 0)
+
+    def test_replica_without_a_chip_is_failed_not_respawned(
+        self, tmp_path, caplog
+    ):
+        """A replica that exits with the device-unavailable code (a
+        `pio deploy --pin-model` whose backend a sibling already holds)
+        is marked failed at once with the cause logged — respawning
+        cannot succeed while the sibling lives — and the sibling stays
+        up."""
+        import logging
+
+        from predictionio_tpu.fleet import FleetSupervisor
+
+        rc = FleetSupervisor.DEVICE_UNAVAILABLE_RC
+        state_path = str(tmp_path / "fleet-9998.json")
+        marker = tmp_path / "spawned"
+        specs = [
+            ReplicaSpec("r0", 1234, ("-c", "import time; time.sleep(600)")),
+            ReplicaSpec(
+                "r1", 1235,
+                ("-c", f"import sys; open({str(marker)!r}, 'a').write('x'); "
+                       f"sys.exit({rc})"),
+            ),
+        ]
+        sup = FleetSupervisor(
+            specs, state_path, router_port=9998, poll_interval_s=0.05
+        )
+        with caplog.at_level(logging.ERROR, "predictionio_tpu.fleet.supervisor"):
+            sup.start()
+            try:
+                deadline = time.monotonic() + 10
+                while time.monotonic() < deadline:
+                    r0, r1 = sup.state()["replicas"]
+                    if r1["failed"]:
+                        break
+                    time.sleep(0.05)
+                assert r1["failed"] and not r1["alive"]
+                assert r0["alive"] and not r0["failed"]
+                time.sleep(0.3)  # several polls: still no respawn
+                assert marker.read_text() == "x"
+            finally:
+                sup.stop()
+        assert any(
+            "could not open the accelerator" in r.getMessage()
+            and "one process" in r.getMessage()
+            for r in caplog.records
+        )

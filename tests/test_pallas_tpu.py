@@ -2,8 +2,9 @@
 
 Tier-1 runs the kernel only through ``interpret=True`` (CPU). These
 tests run the REAL Mosaic-compiled kernel on a TPU backend at the
-flagship bench shape ([138k, 64, 64]) and at K=128, comparing against
-XLA Cholesky. Everything — SPD generation, both solves, and the error
+flagship bench shape ([138k, 64, 64]), at K=128, and at the small padded
+ranks `spd_solve` sends it (rank 10 -> K=16, 5 -> 8, 20 -> 24), comparing
+against XLA Cholesky. Everything — SPD generation, both solves, and the error
 reduction — happens on device, so only scalars come back to the host.
 ``chip_smoke.py`` (leg 6) makes the same checks on every chip run.
 
@@ -69,6 +70,44 @@ def test_gj_solve_matches_cholesky_on_tpu(batch, k):
     err = float(rel_err(x_gj, x_ch))
     assert np.isfinite(err)
     assert err < 1e-4, f"pallas vs cholesky rel err {err} at [{batch},{k},{k}]"
+
+
+@pytest.mark.parametrize(
+    "batch,rank",
+    [
+        (138_000, 10),  # the templates' default rank: padded to K=16
+        (27_027, 5),  # K=8, a single pivot block
+        (27_027, 20),  # K=24
+    ],
+)
+def test_spd_solve_pads_small_ranks_into_the_kernel_on_tpu(batch, rank):
+    """`spd_solve(.., "pallas")` — the call the ALS sweep makes — embeds a
+    rank that is not a multiple of 8 in the next one. Each padded K is
+    its own Mosaic compile ([32, K, K] blocks, 8-wide lane slices), and
+    Mosaic refuses shapes the interpreter accepts: compile them here."""
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops.solve import (
+        cholesky_solve,
+        pallas_rank_ok,
+        spd_solve,
+    )
+
+    assert pallas_rank_ok(rank)
+    A, b = _device_spd_batch(batch, rank, seed=rank)
+    x_gj = jax.jit(lambda A, b: spd_solve(A, b, "pallas"))(A, b)
+    x_ch = cholesky_solve(A, b)
+    assert x_gj.shape == (batch, rank)
+
+    @jax.jit
+    def rel_err(xa, xb):
+        num = jnp.max(jnp.abs(xa - xb), axis=-1)
+        den = jnp.maximum(jnp.max(jnp.abs(xb), axis=-1), 1e-6)
+        return jnp.max(num / den)
+
+    err = float(rel_err(x_gj, x_ch))
+    assert np.isfinite(err)
+    assert err < 1e-4, f"pallas vs cholesky rel err {err} at rank {rank}"
 
 
 def test_gj_solve_residual_on_tpu():

@@ -147,9 +147,10 @@ class TestHTTPS:
             status, body = _get(
                 f"https://localhost:{port}/stop", ctx=_client_ctx()
             )
-            assert status == 200 and stopped
+            assert status == 200
+            # the hook runs after the reply is flushed, so wait for it
             thread.join(timeout=10)
-            assert not thread.is_alive()
+            assert not thread.is_alive() and stopped
         finally:
             server.server_close()
 
@@ -242,6 +243,67 @@ class TestLifecycle:
 
         with pytest.raises(RuntimeError, match="Could not reach"):
             commands.undeploy("127.0.0.1", 1, out=lambda _: None)
+
+    def test_undeploy_cut_connection_raises(self):
+        """A listener that drops the connection without answering is not
+        a stopped deployment: undeploy must not print success."""
+        import socket
+        import threading
+
+        from predictionio_tpu.tools import commands
+
+        lsock = socket.socket()
+        lsock.bind(("127.0.0.1", 0))
+        lsock.listen(1)
+
+        def accept_and_drop():
+            conn, _ = lsock.accept()
+            conn.recv(4096)
+            conn.close()
+
+        t = threading.Thread(target=accept_and_drop, daemon=True)
+        t.start()
+        out = []
+        try:
+            with pytest.raises(RuntimeError, match="was cut before /stop"):
+                commands.undeploy(
+                    "127.0.0.1", lsock.getsockname()[1], out=out.append
+                )
+            assert not out
+        finally:
+            t.join(timeout=5)
+            lsock.close()
+
+    def test_stop_reply_is_flushed_before_shutdown_hook(self, trained_variant):
+        """GET /stop: the transport writes and flushes the answer BEFORE
+        it runs the stop hook, so a process that exits the moment its
+        listener stops can never cut its own reply. The hook here refuses
+        to return until the client holds the full response."""
+        import threading
+
+        from predictionio_tpu.workflow.serving import QueryService
+
+        qs = QueryService(trained_variant)
+        server, thread = start_background(qs.dispatch)
+        client_has_reply = threading.Event()
+        order = []
+
+        def stop_hook():
+            order.append(("hook", client_has_reply.wait(timeout=10)))
+            server.shutdown()
+
+        qs.stop_server = stop_hook
+        port = server.server_address[1]
+        try:
+            status, body = _get(f"http://127.0.0.1:{port}/stop")
+            assert status == 200 and body["message"] == "Shutting down."
+            client_has_reply.set()
+            thread.join(timeout=10)
+            assert not thread.is_alive()
+            assert order == [("hook", True)]
+        finally:
+            client_has_reply.set()
+            server.server_close()
 
     def test_stop_token_gates_shutdown(self, trained_variant, tmp_path, monkeypatch):
         """With a stop token set (pio deploy always sets one), GET /stop
