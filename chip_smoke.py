@@ -29,13 +29,16 @@ every leg that needs it runs as a child, one at a time.
 
 It adds no fallback of its own: no CPU default, no interpret mode, no leg
 whose failure becomes a field. Any failed leg raises, the script exits
-non-zero and prints no result line. The last line of stdout of a passing
-run is one JSON object, {"ok": true, "device": {...}, ..., "claim": null}.
+non-zero and prints no result. A passing run ends its stdout with two JSON
+lines: the summary (sizes, `reduced`, facts, every leg's seconds, ...,
+"claim": null), then, LAST, the verdict the chip check reads, with exactly
+these keys and the device as JAX reports it:
+{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}.
 These are smoke facts on a named device, not benchmark results.
 
 `--rehearse-cpu` runs the same plumbing at toy sizes on XLA:CPU (kernel
 decisions cholesky/host/xla, Pallas in interpret mode). Every field says
-platform cpu, the result line says "ok": false, it exits 4 when every leg
+platform cpu, the verdict says "ok": false, it exits 4 when every leg
 passed (never 0), and it can never satisfy the chip check; it exists so the
 plumbing is debugged off the chip.
 """
@@ -78,7 +81,7 @@ FULL = {
                 "ce": [8192, 64]},
 }
 #: engine defaults the smoke cuts in DEPTH (never width): reported in the
-#: result line's "reduced"
+#: summary line's "reduced"
 ENGINE_DEFAULT_DEPTH = {"als": ("iterations", "numIterations", 20),
                         "twotower": ("epochs", "epochs", 5)}
 TOY = {
@@ -792,6 +795,11 @@ def main() -> int:
     finally:
         smoke.close()
     print(json.dumps(result))
+    # the last line is the verdict, and nothing but the verdict
+    device = result["device"]
+    print(json.dumps({"ok": result["ok"], "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}}))
     # a rehearsal is never a pass: its own code, for callers that read
     # only the exit status
     return REHEARSAL_EXIT if args.rehearse_cpu else 0

@@ -641,8 +641,9 @@ def test_chip_smoke_cpu_rehearsal_runs_every_leg():
     numpy reference -> two-tower train -> kernel checks in interpret
     mode. Every field says cpu and the result can never be mistaken for
     a chip pass: `"ok": false`, and an exit status of its own (4, never
-    0) for callers that read only that. The depth cuts are listed in
-    `reduced`; the kernel leg sends the templates' default rank 10
+    0) for callers that read only that. Stdout ends with the summary
+    line and then the verdict line. The depth cuts are listed in the
+    summary's `reduced`; the kernel leg sends the templates' default rank 10
     through `spd_solve` (padded to K=16)."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)  # one device, like one chip
@@ -652,7 +653,17 @@ def test_chip_smoke_cpu_rehearsal_runs_every_leg():
         env=env, capture_output=True, text=True, timeout=600,
     )
     assert proc.returncode == 4, proc.stderr[-3000:]
-    rec = json.loads(proc.stdout.strip().splitlines()[-1])
+    lines = proc.stdout.strip().splitlines()
+    # the LAST line is the verdict the chip check reads: exactly these
+    # keys, nothing else (the driver refuses any other shape)
+    verdict = json.loads(lines[-1])
+    assert verdict == {"ok": False, "device": verdict["device"]}
+    assert set(verdict["device"]) == {"platform", "kind", "count"}
+    assert isinstance(verdict["device"]["platform"], str)
+    assert isinstance(verdict["device"]["kind"], str)
+    assert type(verdict["device"]["count"]) is int
+    rec = json.loads(lines[-2])  # the summary, one line before it
+    assert rec["device"] == verdict["device"]
     assert rec["ok"] is False and rec["rehearsal"] is True
     assert rec["reduced"] == [
         "als numIterations 2 of the engine default 20",
