@@ -62,7 +62,6 @@ from typing import Any, Callable
 __all__ = [
     "JitWitness",
     "LEDGER_NAME",
-    "ServeCompileCounter",
     "active",
     "check_budget",
     "classify_findings",
@@ -340,60 +339,9 @@ def run_with_jit_witness(
 
 
 # ---------------------------------------------------------------------------
-# AOT serving: the zero-compile gate + the long-lived serve counter
+# AOT serving: the zero-compile gate (the long-lived count since boot is
+# the compile ledger's: utils/spans.py)
 # ---------------------------------------------------------------------------
-
-
-class ServeCompileCounter:
-    """Process-lifetime backend-compile counter for ``--aot`` serving
-    (workflow/serving.py): a ``jax.monitoring`` listener counts EVERY
-    XLA backend compile, the server marks the boot/serve boundary after
-    each successful reload, and ``/stats.json`` reports the difference
-    as ``aot.serveTimeCompiles`` — the number the AOT contract says
-    stays zero. Unlike :class:`JitWitness` this is not a patch set and
-    never uninstalls; it is one integer behind one listener, cheap
-    enough to leave armed for the life of a deployment."""
-
-    _instance: "ServeCompileCounter | None" = None
-
-    def __init__(self) -> None:
-        self._mu = threading.Lock()
-        self._total = 0
-        self._baseline = 0
-
-    @classmethod
-    def install(cls) -> "ServeCompileCounter":
-        """The process singleton, registering its listener on first use
-        (jax.monitoring has no unregister, so one listener serves every
-        QueryService in the process — the boot marks keep them honest)."""
-        if cls._instance is None:
-            inst = cls()
-
-            import jax.monitoring
-
-            def on_duration(name: str, seconds: float, **kw) -> None:
-                if name == _COMPILE_EVENT:
-                    with inst._mu:
-                        inst._total += 1
-
-            jax.monitoring.register_event_duration_secs_listener(on_duration)
-            cls._instance = inst
-        return cls._instance
-
-    def mark_boot_complete(self) -> None:
-        """Everything compiled so far was boot work (deserialize warm-ups
-        or fallback-tier compiles); compiles after this mark are
-        serve-time."""
-        with self._mu:
-            self._baseline = self._total
-
-    def total_compiles(self) -> int:
-        with self._mu:
-            return self._total
-
-    def serve_time_compiles(self) -> int:
-        with self._mu:
-            return self._total - self._baseline
 
 
 def zero_compile_gate(witness_report: dict, ledger: dict | None = None) -> dict:

@@ -18,6 +18,8 @@ import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import TYPE_CHECKING, Callable, Mapping
 
+from predictionio_tpu.utils import spans
+
 __all__ = [
     "serve",
     "start_background",
@@ -165,11 +167,22 @@ def _resolve_readiness(
     return hook if callable(hook) else None
 
 
+def _resolve_span_sink(dispatch: Dispatcher) -> Callable | None:
+    """A service object's ``record_http`` method, discovered like
+    ``readiness``: it is handed the spans each request closed on its
+    HTTP thread (``httpRead``, ``httpWrite``). Servers without one bind
+    no collector and record nothing."""
+    hook = getattr(getattr(dispatch, "__self__", None), "record_http", None)
+    return hook if callable(hook) else None
+
+
 def _make_handler(
     dispatch: Dispatcher,
     readiness: ReadinessHook | None = None,
     lifecycle: "DrainManager | None" = None,
 ):
+    span_sink = _resolve_span_sink(dispatch)
+
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
         #: per-connection socket timeout — bounds stalled clients (incl.
@@ -186,6 +199,14 @@ def _make_handler(
 
         def log_message(self, fmt, *args):  # route through logging, not stderr
             logger.debug("%s - %s", self.address_string(), fmt % args)
+
+        def setup(self):
+            super().setup()
+            # one handler per connection, on a thread of its own: the
+            # service's spans of this thread (never annotated into the
+            # profiler: utils/spans.py) collect here, taken per request
+            self._collector = spans.Collector() if span_sink else None
+            spans.bind(self._collector)
 
         def _respond(self):
             parsed = urllib.parse.urlparse(self.path)
@@ -238,27 +259,35 @@ def _make_handler(
             if stream_routes and (self.command, parsed.path) in stream_routes:
                 self._dispatch_stream(parsed, params)
                 return
+            if self._collector is not None:
+                self._collector.take()  # what an unanswered request left
             body = None
             form: Mapping[str, str] | None = None
-            length = int(self.headers.get("Content-Length") or 0)
-            raw = self.rfile.read(length) if length else b""
-            ctype = (self.headers.get("Content-Type") or "").split(";")[0].strip()
-            if raw:
-                # Tolerant parse: clients (e.g. bare `curl -d`) often send
-                # JSON under a form-encoded default content type. Try JSON
-                # first for any body; fall back to form fields only when
-                # the payload isn't JSON and the content type says form.
-                try:
-                    body = json.loads(raw)
-                except json.JSONDecodeError:
-                    if ctype == "application/x-www-form-urlencoded":
-                        form = {
-                            k: v[0]
-                            for k, v in urllib.parse.parse_qs(raw.decode()).items()
-                        }
-                    else:
-                        self._send(400, b'{"message": "Malformed JSON."}')
-                        return
+            with spans.span("httpRead"):
+                length = int(self.headers.get("Content-Length") or 0)
+                raw = self.rfile.read(length) if length else b""
+                ctype = (
+                    (self.headers.get("Content-Type") or "").split(";")[0].strip()
+                )
+                if raw:
+                    # Tolerant parse: clients (e.g. bare `curl -d`) often
+                    # send JSON under a form-encoded default content type.
+                    # Try JSON first for any body; fall back to form fields
+                    # only when the payload isn't JSON and the content type
+                    # says form.
+                    try:
+                        body = json.loads(raw)
+                    except json.JSONDecodeError:
+                        if ctype == "application/x-www-form-urlencoded":
+                            form = {
+                                k: v[0]
+                                for k, v in urllib.parse.parse_qs(
+                                    raw.decode()
+                                ).items()
+                            }
+                        else:
+                            self._send(400, b'{"message": "Malformed JSON."}')
+                            return
             try:
                 resp = dispatch(
                     method=self.command,
@@ -272,17 +301,22 @@ def _make_handler(
                 logger.exception("Unhandled error for %s %s", self.command, parsed.path)
                 self._send(500, b'{"message": "Internal Server Error"}')
                 return
-            self._send(
-                resp.status,
-                resp.json_bytes(),
-                getattr(resp, "content_type", "application/json; charset=UTF-8"),
-                getattr(resp, "headers", None),
-            )
+            with spans.span("httpWrite"):
+                self._send(
+                    resp.status,
+                    resp.json_bytes(),
+                    getattr(
+                        resp, "content_type", "application/json; charset=UTF-8"
+                    ),
+                    getattr(resp, "headers", None),
+                )
+                self.wfile.flush()  # the reply is on the wire
+            if span_sink is not None:
+                span_sink(self._collector.take())
             after_send = getattr(resp, "after_send", None)
             if after_send is not None:
-                # the reply is on the wire before the hook runs: /stop
-                # shuts the listener (and the process) down from here
-                self.wfile.flush()
+                # the reply is flushed before the hook runs: /stop shuts
+                # the listener (and the process) down from here
                 after_send()
 
         def _dispatch_stream(self, parsed, params):

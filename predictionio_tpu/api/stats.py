@@ -6,9 +6,12 @@
   buckets, served at ``/stats.json`` when the server runs with
   ``--stats``. Single-writer here (the service locks), no actor needed.
 * :class:`ServingStats` — query-server micro-batcher gauges, counters and
-  the per-request latency decomposition (queue wait / batch-form /
-  handle time), served at the query server's ``GET /stats.json``. No
-  reference counterpart (the reference has no cross-request batcher).
+  the latency decomposition per request and per batch (the dispatcher's
+  phases, the host gap between batches), served at the query server's
+  ``GET /stats.json``. No reference counterpart (the reference has no
+  cross-request batcher).
+* :class:`HttpStats` — what the query server's HTTP threads spend on a
+  request outside the service: reading it and writing the answer.
 """
 
 from __future__ import annotations
@@ -16,8 +19,9 @@ from __future__ import annotations
 import datetime as _dt
 import threading
 from collections import Counter, deque
+from typing import Mapping, Sequence
 
-__all__ = ["Stats", "ServingStats"]
+__all__ = ["Stats", "ServingStats", "HttpStats", "BATCH_PHASES"]
 
 
 def _bucket(dt: _dt.datetime) -> _dt.datetime:
@@ -88,17 +92,44 @@ def _percentiles(samples, points=(50, 95, 99)) -> dict[str, float]:
     return out
 
 
+#: the dispatcher thread's leaf spans (utils/spans.py), in the order it
+#: passes through them from one ``handle_batch`` return to the next; each
+#: is a window of ``latencyMs``
+BATCH_PHASES = (
+    "release", "take", "drain", "batchForm",
+    "bind", "lookup", "dispatch", "deviceWait", "format",
+)
+
+
 class ServingStats:
     """Micro-batcher serving statistics (thread-safe).
 
-    Latency decomposition per request, all in milliseconds:
+    Latency decomposition, all in milliseconds. Per request:
 
     * ``queueWait`` — enqueue until the dispatcher formed its batch;
-    * ``batchForm`` — per batch: drain-complete until ``handle_batch``
-      is entered (padding + bookkeeping);
-    * ``handle`` — per batch: the ``handle_batch`` call itself (bind +
-      device dispatch + serve tail);
-    * ``total`` — enqueue until the caller gets its result back.
+    * ``total`` — enqueue until the caller gets its result back;
+    * ``wake`` — the dispatcher's ``done.set()`` until the caller's
+      thread runs again.
+
+    Per batch, on the dispatcher thread, flat and in this order (one
+    cycle runs from a ``handle_batch`` return to the next):
+
+    * ``release`` — answers handed to the PREVIOUS batch's riders;
+    * ``take`` — blocked on the queue with nothing in it;
+    * ``drain`` — waiting up to the batch delay for batch mates;
+    * ``batchForm`` — drain-complete until ``handle_batch`` is entered
+      (padding + bookkeeping);
+    * ``handle`` — the ``handle_batch`` call itself, and inside it
+      ``bind`` (query objects from bodies), ``lookup`` (ids to the
+      padded index vector), ``dispatch`` (the call into the scoring
+      program until it returns), ``deviceWait`` (the readback that
+      blocks until the device is done), ``format`` (lists, result
+      objects, serve tail); a handler that has no such boundary records
+      none;
+    * ``hostGap`` — this batch's ``dispatch`` end less the previous
+      batch's ``deviceWait`` end, less this batch's ``take``: what the
+      host's own code kept the device waiting (absent for the first
+      batch and for handlers without a device).
 
     Windows keep the most recent :attr:`WINDOW` samples so percentiles
     track current behavior on a long-running server; counters are
@@ -129,9 +160,11 @@ class ServingStats:
         self.bucket_misses = 0
         self.warmup_ms: dict[int, float] = {}
         self._queue_wait_ms: deque = deque(maxlen=n)
-        self._form_ms: deque = deque(maxlen=n)
         self._handle_ms: deque = deque(maxlen=n)
         self._total_ms: deque = deque(maxlen=n)
+        self._wake_ms: deque = deque(maxlen=n)
+        self._host_gap_ms: deque = deque(maxlen=n)
+        self._phase_ms = {name: deque(maxlen=n) for name in BATCH_PHASES}
 
     # ------------------------------------------------------------ recording
     def record_submitted(self, queue_depth: int) -> None:
@@ -153,18 +186,23 @@ class ServingStats:
             # bounded by the batcher's finite bucket set, not request data
             self.warmup_ms[bucket] = round(ms, 3)  # piolint: disable=PIO205
 
-    def record_queue_wait(self, ms: float) -> None:
-        with self._lock:
-            self._queue_wait_ms.append(ms)
-
     def record_batch_start(self, queue_depth: int) -> None:
         with self._lock:
             self.inflight_batch = 1
             self.queue_depth = queue_depth
 
     def record_batch(
-        self, size: int, bucket: int, form_ms: float, handle_ms: float
+        self,
+        size: int,
+        bucket: int,
+        handle_ms: float,
+        queue_wait_ms: Sequence[float] = (),
+        phases: Mapping[str, float] | None = None,
+        host_gap_ms: float | None = None,
     ) -> None:
+        """One dispatched batch: its riders' queue waits, ``handle``, the
+        dispatcher's ``phases`` ({name: ms}, names of
+        :data:`BATCH_PHASES`) and the host gap before it."""
         with self._lock:
             self.inflight_batch = 0
             self.batches += 1
@@ -175,13 +213,23 @@ class ServingStats:
             if bucket not in self.warmed_buckets:
                 self.bucket_misses += 1
                 self.warmed_buckets.add(bucket)
-            self._form_ms.append(form_ms)
             self._handle_ms.append(handle_ms)
+            self._queue_wait_ms.extend(queue_wait_ms)
+            for name, ms in (phases or {}).items():
+                window = self._phase_ms.get(name)
+                if window is not None:
+                    window.append(ms)
+            if host_gap_ms is not None:
+                self._host_gap_ms.append(host_gap_ms)
 
-    def record_request(self, total_ms: float) -> None:
+    def record_request(
+        self, total_ms: float, wake_ms: float | None = None
+    ) -> None:
         with self._lock:
             self.completed += 1
             self._total_ms.append(total_ms)
+            if wake_ms is not None:
+                self._wake_ms.append(wake_ms)
 
     # ------------------------------------------------------------- reporting
     def handle_p50_ms(self) -> float:
@@ -219,8 +267,47 @@ class ServingStats:
                 "warmupMs": {str(k): v for k, v in sorted(self.warmup_ms.items())},
                 "latencyMs": {
                     "queueWait": _percentiles(self._queue_wait_ms),
-                    "batchForm": _percentiles(self._form_ms),
                     "handle": _percentiles(self._handle_ms),
                     "total": _percentiles(self._total_ms),
+                    "wake": _percentiles(self._wake_ms),
+                    "hostGap": _percentiles(self._host_gap_ms),
+                    **{
+                        name: _percentiles(window)
+                        for name, window in self._phase_ms.items()
+                    },
+                },
+            }
+
+
+class HttpStats:
+    """What an HTTP thread of the query server spends on a request around
+    the service's ``dispatch``, in milliseconds over the last
+    :attr:`ServingStats.WINDOW` requests: ``httpRead`` (the body read
+    and parsed), ``httpWrite`` (the answer to JSON bytes and onto the
+    socket) and ``inServer``, their sum per request."""
+
+    def __init__(self, window: int | None = None):
+        self._lock = threading.Lock()
+        n = window or ServingStats.WINDOW
+        self.requests = 0
+        self._read_ms: deque = deque(maxlen=n)
+        self._write_ms: deque = deque(maxlen=n)
+        self._in_server_ms: deque = deque(maxlen=n)
+
+    def record(self, read_ms: float, write_ms: float) -> None:
+        with self._lock:
+            self.requests += 1
+            self._read_ms.append(read_ms)
+            self._write_ms.append(write_ms)
+            self._in_server_ms.append(read_ms + write_ms)
+
+    def to_json(self) -> dict:
+        with self._lock:
+            return {
+                "requests": self.requests,
+                "latencyMs": {
+                    "httpRead": _percentiles(self._read_ms),
+                    "httpWrite": _percentiles(self._write_ms),
+                    "inServer": _percentiles(self._in_server_ms),
                 },
             }

@@ -34,6 +34,7 @@ from predictionio_tpu.controller.persistent import (
     load_persistent_model,
 )
 from predictionio_tpu.utils.serialization import dumps_model, loads_model
+from predictionio_tpu.utils.spans import span
 
 __all__ = [
     "EngineParams",
@@ -177,30 +178,35 @@ class Engine:
         (parity: ``object Engine.train``; the ``stop_after_*`` flags mirror
         ``WorkflowParams.stopAfterRead/Prepare``). When ``timings`` is a
         dict, per-phase wall-clock seconds are recorded into it
-        (read/prepare/train:<name>) — the EngineInstance timing surface of
+        (read/prepare/train:<name>, each a span of ``utils/spans.py``:
+        ``train.read``, ``train.prepare``, ``train.algorithm``) — the
+        EngineInstance timing surface of
         SURVEY.md section 6.1. ``warm_models`` (``models_from_bytes`` of a
         previous COMPLETED instance) hands each algorithm its predecessor
         via ``ctx.warm_model`` for warm-started retrains."""
         import dataclasses as _dc
-        import time as _time
 
-        def _timed(label: str, fn):
-            t0 = _time.perf_counter()
-            result = fn()
+        def _timed(label: str, fn, span_name: str, enclosing: bool = False):
+            with span(span_name, enclosing=enclosing) as phase:
+                result = fn()
             if timings is not None:
-                timings[label] = round(_time.perf_counter() - t0, 3)
+                timings[label] = round(phase.seconds, 3)
             return result
 
         # Instantiate algorithms first so a bad engine.json fails before the
         # (expensive) data read — mirrors the reference's early reflection.
         algorithms = self._make_algorithms(engine_params)
         datasource = create_doer(self.datasource_class, engine_params.datasource)
-        td = _timed("read", lambda: datasource.read_training_base(ctx))
+        td = _timed(
+            "read", lambda: datasource.read_training_base(ctx), "train.read"
+        )
         self._sanity(td, sanity_check, "training data")
         if stop_after_read:
             return []
         preparator = create_doer(self.preparator_class, engine_params.preparator)
-        pd = _timed("prepare", lambda: preparator.prepare_base(ctx, td))
+        pd = _timed(
+            "prepare", lambda: preparator.prepare_base(ctx, td), "train.prepare"
+        )
         self._sanity(pd, sanity_check, "prepared data")
         if stop_after_prepare:
             return []
@@ -231,7 +237,12 @@ class Engine:
             if timings is not None and key in timings:
                 key = f"train:{name}#{i}"  # same algorithm listed twice
             models.append(
-                _timed(key, lambda a=algo, c=a_ctx: a.train_base(c, pd))
+                # the algorithm's own leaves (transfer, bucketing, sweeps,
+                # readback) lie inside this one
+                _timed(
+                    key, lambda a=algo, c=a_ctx: a.train_base(c, pd),
+                    "train.algorithm", enclosing=True,
+                )
             )
         return models
 

@@ -52,7 +52,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
-import time
 import types
 from typing import Any, NamedTuple, Sequence
 
@@ -62,6 +61,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, reshard
 
 from predictionio_tpu.ops.topk import SCORE_PRECISION, top_k_scores
+from predictionio_tpu.utils.spans import span
 
 logger = logging.getLogger(__name__)
 
@@ -71,6 +71,7 @@ __all__ = [
     "BucketedRatings",
     "build_buckets",
     "build_buckets_device",
+    "factors_to_host",
     "train_als",
     "als_sweep",
     "predict_scores",
@@ -441,8 +442,9 @@ def _sort_coo(
     host runs in well under a second on the chip. ``n_max`` is padded to
     ``max(num_rows, num_cols)`` by the caller so the user- and item-side
     sorts share one compiled program."""
-    _, cols_s, vals_s = jax.lax.sort((rows, cols, vals), num_keys=1)
-    counts = jnp.zeros(n_max, jnp.int32).at[rows].add(1)
+    with jax.named_scope("pio_bucket_sort"):
+        _, cols_s, vals_s = jax.lax.sort((rows, cols, vals), num_keys=1)
+        counts = jnp.zeros(n_max, jnp.int32).at[rows].add(1)
     return cols_s, vals_s, counts
 
 
@@ -462,23 +464,24 @@ def _fill_buckets(cs: jax.Array, vs: jax.Array, meta: jax.Array, shapes: tuple):
     a per-call closure would recompile on every train."""
     out = []
     off = 0
-    for width, c, n_chunks in shapes:
-        n_pad = c * n_chunks
-        row_id = meta[off : off + n_pad]
-        st = meta[off + n_pad : off + 2 * n_pad]
-        ln = meta[off + 2 * n_pad : off + 3 * n_pad]
-        off += 3 * n_pad
-        lane = jnp.arange(width, dtype=jnp.int32)[None, :]
-        lm = lane < ln[:, None]
-        src = jnp.where(lm, st[:, None] + lane, 0)
-        out.append(
-            _Chunked(
-                row_id.reshape(n_chunks, c),
-                jnp.where(lm, cs[src], 0).reshape(n_chunks, c, width),
-                jnp.where(lm, vs[src], 0.0).reshape(n_chunks, c, width),
-                lm.astype(jnp.float32).reshape(n_chunks, c, width),
+    with jax.named_scope("pio_bucket_fill"):
+        for width, c, n_chunks in shapes:
+            n_pad = c * n_chunks
+            row_id = meta[off : off + n_pad]
+            st = meta[off + n_pad : off + 2 * n_pad]
+            ln = meta[off + 2 * n_pad : off + 3 * n_pad]
+            off += 3 * n_pad
+            lane = jnp.arange(width, dtype=jnp.int32)[None, :]
+            lm = lane < ln[:, None]
+            src = jnp.where(lm, st[:, None] + lane, 0)
+            out.append(
+                _Chunked(
+                    row_id.reshape(n_chunks, c),
+                    jnp.where(lm, cs[src], 0).reshape(n_chunks, c, width),
+                    jnp.where(lm, vs[src], 0.0).reshape(n_chunks, c, width),
+                    lm.astype(jnp.float32).reshape(n_chunks, c, width),
+                )
             )
-        )
     return tuple(out)
 
 
@@ -812,15 +815,18 @@ def _half_sweep(
 
         def step(fac, xs):
             row_id, idx, val, mask = xs
-            A, b, n = _gram_chunk(
-                other, idx, val, mask, implicit, alpha, hi,
-                mesh, data_axis, model_axis,
-            )
-            x = _finish_solve(A, b, n, reg, yty, solver)  # [C, K]
+            with jax.named_scope("pio_als_gram"):
+                A, b, n = _gram_chunk(
+                    other, idx, val, mask, implicit, alpha, hi,
+                    mesh, data_axis, model_axis,
+                )
+            with jax.named_scope("pio_als_solve"):
+                x = _finish_solve(A, b, n, reg, yty, solver)  # [C, K]
             # scatter data-sharded solved rows to their model shard —
             # GSPMD lowers to the ICI exchange replacing MLlib's
             # factor-block shuffle
-            fac = fac.at[row_id].set(x, out_sharding=model_sharding)
+            with jax.named_scope("pio_als_scatter"):
+                fac = fac.at[row_id].set(x, out_sharding=model_sharding)
             return fac, None
 
         factors, _ = jax.lax.scan(step, factors, tuple(ch))
@@ -841,24 +847,28 @@ def _half_sweep(
         def hot_step(carry, xs):
             A_acc, b_acc, n_acc = carry
             slot, idx, val, mask = xs
-            A, b, n = _gram_chunk(
-                other, idx, val, mask, implicit, alpha, hi,
-                mesh, data_axis, model_axis,
-            )
+            with jax.named_scope("pio_als_gram"):
+                A, b, n = _gram_chunk(
+                    other, idx, val, mask, implicit, alpha, hi,
+                    mesh, data_axis, model_axis,
+                )
             # scatter-add partial Gramians: segments of one row combine
             # here — the hot-row splitting that bounds memory by
             # nnz/max_width instead of the hottest row's count. The
             # accumulators are replicated (H_g is config-bounded), so
             # on a mesh the adds psum across the data axis.
-            A_acc = A_acc.at[slot].add(A, out_sharding=replicated)
-            b_acc = b_acc.at[slot].add(b, out_sharding=replicated)
-            n_acc = n_acc.at[slot].add(n, out_sharding=replicated)
+            with jax.named_scope("pio_als_scatter"):
+                A_acc = A_acc.at[slot].add(A, out_sharding=replicated)
+                b_acc = b_acc.at[slot].add(b, out_sharding=replicated)
+                n_acc = n_acc.at[slot].add(n, out_sharding=replicated)
             return (A_acc, b_acc, n_acc), None
 
         acc, _ = jax.lax.scan(hot_step, acc, tuple(ch))
-        x_hot = _finish_solve(*acc, reg, yty, solver)  # [num_slots, K]
+        with jax.named_scope("pio_als_solve"):
+            x_hot = _finish_solve(*acc, reg, yty, solver)  # [num_slots, K]
         hr = jnp.asarray(hot_rows_g)
-        factors = factors.at[hr].set(x_hot, out_sharding=model_sharding)
+        with jax.named_scope("pio_als_scatter"):
+            factors = factors.at[hr].set(x_hot, out_sharding=model_sharding)
 
     # padding rows scattered into the sentinel; re-zero it (array index:
     # the scalar-index path rejects/breaks on out_sharding). The sentinel
@@ -1174,8 +1184,12 @@ def train_als(
 
     ``info``, when given, receives the kernel decisions this train took
     (backend, solver, bucketing, precision, rank, mesh) and its timing
-    (``bucketingSeconds``, ``sweepSeconds`` — the first sweep carries the
-    compile) — what ``pio train`` records in the engine instance.
+    (``bucketingSeconds``: transfer, sort and fill, of which
+    ``transferSeconds`` is the host-to-device copy; ``initSeconds``: the
+    two tables seeded; ``sweepSeconds`` — the first sweep carries the
+    compile) — what ``pio train`` records in the engine instance. The
+    phases are spans (``utils/spans.py``: ``train.transfer``,
+    ``train.bucketing``, ``train.init``, one ``train.sweep`` a sweep).
 
     In a multi-process job, ``rows/cols/vals`` are this host's shard of
     the ratings (the sharded event-reader layout). With a mesh, shards are
@@ -1254,25 +1268,36 @@ def train_als(
         # a data-only mesh (e.g. `pio train --mesh data=8`): fall back to
         # replicated factor tables
         model_axis = None
-    t_bucketing = time.perf_counter()
+    # bucketing wall (transfer, sort, fill — and their compiles when cold),
+    # each span closed by a sync so the next phase is not charged for it
+    transfer = None
     if multihost and mesh is not None:
         # bounded-memory path: per-host shards stay sharded; only rows are
         # re-partitioned (VERDICT round-1 missing #3)
         from predictionio_tpu.parallel.exchange import allgather_objects
 
-        user_bucketed, u_rated = _multihost_bucketed(
-            rows, cols, vals, num_users, num_items, mesh, data_axis,
-            config.bucket_widths, config.chunk_entries, config.hot_group_slots,
-        )
-        item_bucketed, i_rated = _multihost_bucketed(
-            cols, rows, vals, num_items, num_users, mesh, data_axis,
-            config.bucket_widths, config.chunk_entries, config.hot_group_slots,
-        )
-        # the global rated mask is the OR of the per-host masks
-        u_rated = np.bitwise_or.reduce(allgather_objects(np.packbits(u_rated)))
-        i_rated = np.bitwise_or.reduce(allgather_objects(np.packbits(i_rated)))
-        u_rated = np.unpackbits(u_rated, count=num_users).astype(bool)
-        i_rated = np.unpackbits(i_rated, count=num_items).astype(bool)
+        with span("train.bucketing") as bucketing:
+            user_bucketed, u_rated = _multihost_bucketed(
+                rows, cols, vals, num_users, num_items, mesh, data_axis,
+                config.bucket_widths, config.chunk_entries,
+                config.hot_group_slots,
+            )
+            item_bucketed, i_rated = _multihost_bucketed(
+                cols, rows, vals, num_items, num_users, mesh, data_axis,
+                config.bucket_widths, config.chunk_entries,
+                config.hot_group_slots,
+            )
+            # the global rated mask is the OR of the per-host masks
+            u_rated = np.bitwise_or.reduce(
+                allgather_objects(np.packbits(u_rated))
+            )
+            i_rated = np.bitwise_or.reduce(
+                allgather_objects(np.packbits(i_rated))
+            )
+            u_rated = np.unpackbits(u_rated, count=num_users).astype(bool)
+            i_rated = np.unpackbits(i_rated, count=num_items).astype(bool)
+            if timed:
+                jax.block_until_ready((user_bucketed, item_bucketed))
     else:
         if multihost:
             # mesh-less multi-process training: legacy replicated path
@@ -1283,62 +1308,67 @@ def train_als(
         if mesh is not None:
             # chunk rows must divide evenly over the data axis
             row_multiple = int(np.lcm(8, mesh.shape.get(data_axis, 1)))
+        bucket_args = dict(
+            widths=config.bucket_widths, row_multiple=row_multiple,
+            chunk_entries=config.chunk_entries,
+            hot_group_slots=config.hot_group_slots,
+        )
         if use_device_bucketing:
-            # transfer the COO ONCE and hand device arrays to both sides
-            # (each side would otherwise re-upload the same ~12 bytes/nnz);
-            # validate on host BEFORE the int32 cast so out-of-range int64
-            # values cannot truncate into range
-            r_h, c_h = np.asarray(rows), np.asarray(cols)
-            v_h = np.asarray(vals, dtype=np.float32)
-            if r_h.size and (r_h.min() < 0 or r_h.max() >= num_users):
-                raise ValueError("row index out of range")
-            if c_h.size and (c_h.min() < 0 or c_h.max() >= num_items):
-                raise ValueError("column index out of range")
-            small = max(num_users, num_items) < 2**31 and r_h.size < 2**31
-            if small and r_h.size:
-                rows_x = jnp.asarray(r_h.astype(np.int32))
-                cols_x = jnp.asarray(c_h.astype(np.int32))
-                vals_x = jnp.asarray(v_h)
-            else:
-                rows_x, cols_x, vals_x = r_h, c_h, v_h
-            user_bucketed, u_rated = build_buckets_device(
-                rows_x, cols_x, vals_x, num_users, num_items,
-                widths=config.bucket_widths, row_multiple=row_multiple,
-                chunk_entries=config.chunk_entries,
-                hot_group_slots=config.hot_group_slots,
-            )
-            item_bucketed, i_rated = build_buckets_device(
-                cols_x, rows_x, vals_x, num_items, num_users,
-                widths=config.bucket_widths, row_multiple=row_multiple,
-                chunk_entries=config.chunk_entries,
-                hot_group_slots=config.hot_group_slots,
-            )
+            with span("train.transfer") as transfer:
+                # transfer the COO ONCE and hand device arrays to both
+                # sides (each side would otherwise re-upload the same ~12
+                # bytes/nnz); validate on host BEFORE the int32 cast so
+                # out-of-range int64 values cannot truncate into range
+                r_h, c_h = np.asarray(rows), np.asarray(cols)
+                v_h = np.asarray(vals, dtype=np.float32)
+                if r_h.size and (r_h.min() < 0 or r_h.max() >= num_users):
+                    raise ValueError("row index out of range")
+                if c_h.size and (c_h.min() < 0 or c_h.max() >= num_items):
+                    raise ValueError("column index out of range")
+                small = max(num_users, num_items) < 2**31 and r_h.size < 2**31
+                if small and r_h.size:
+                    rows_x = jnp.asarray(r_h.astype(np.int32))
+                    cols_x = jnp.asarray(c_h.astype(np.int32))
+                    vals_x = jnp.asarray(v_h)
+                    if timed:
+                        jax.block_until_ready((rows_x, cols_x, vals_x))
+                else:
+                    rows_x, cols_x, vals_x = r_h, c_h, v_h
+            with span("train.bucketing") as bucketing:
+                user_bucketed, u_rated = build_buckets_device(
+                    rows_x, cols_x, vals_x, num_users, num_items, **bucket_args
+                )
+                item_bucketed, i_rated = build_buckets_device(
+                    cols_x, rows_x, vals_x, num_items, num_users, **bucket_args
+                )
+                if timed:
+                    jax.block_until_ready((user_bucketed, item_bucketed))
         else:
-            user_b = build_buckets(
-                rows, cols, vals, num_users, num_items,
-                widths=config.bucket_widths, row_multiple=row_multiple,
-                chunk_entries=config.chunk_entries,
-                hot_group_slots=config.hot_group_slots,
-            )
-            item_b = build_buckets(
-                cols, rows, vals, num_items, num_users,
-                widths=config.bucket_widths, row_multiple=row_multiple,
-                chunk_entries=config.chunk_entries,
-                hot_group_slots=config.hot_group_slots,
-            )
-            u_rated = rated_row_mask(user_b)
-            i_rated = rated_row_mask(item_b)
-            user_bucketed = _device_buckets(user_b, mesh, data_axis)
-            item_bucketed = _device_buckets(item_b, mesh, data_axis)
+            with span("train.bucketing") as bucketing:
+                user_b = build_buckets(
+                    rows, cols, vals, num_users, num_items, **bucket_args
+                )
+                item_b = build_buckets(
+                    cols, rows, vals, num_items, num_users, **bucket_args
+                )
+                u_rated = rated_row_mask(user_b)
+                i_rated = rated_row_mask(item_b)
+            with span("train.transfer") as transfer:
+                user_bucketed = _device_buckets(user_b, mesh, data_axis)
+                item_bucketed = _device_buckets(item_b, mesh, data_axis)
+                if timed:
+                    jax.block_until_ready((user_bucketed, item_bucketed))
 
     if timed:
-        # bucketing wall (transfer, sort, fill — and their compiles when
-        # cold), closed by a sync so the first sweep is not charged for it
-        jax.block_until_ready((user_bucketed, item_bucketed))
         info["bucketingSeconds"] = round(
-            time.perf_counter() - t_bucketing, 3
+            bucketing.seconds + (transfer.seconds if transfer else 0.0), 3
         )
+        if transfer is not None:
+            info["transferSeconds"] = round(transfer.seconds, 3)
 
+    # seeding the two tables: a handful of eager programs (random draw,
+    # mask, pad), each traced and loaded on its first call
+    seeding = span("train.init").start()
     key_u, key_i = jax.random.split(jax.random.PRNGKey(config.seed))
     # Table length: num_rows + 1 sentinel row, padded up so the row axis
     # divides the model-axis size (extra rows stay zero, never written).
@@ -1401,6 +1431,11 @@ def train_als(
         else:
             uf = jax.device_put(uf, model_sharded)
             vf = jax.device_put(vf, model_sharded)
+    if timed:
+        jax.block_until_ready((uf, vf))
+    seeding.stop()
+    if timed:
+        info["initSeconds"] = round(seeding.seconds, 3)
 
     rep = None if mesh is None else NamedSharding(mesh, PartitionSpec())
     if mesh is not None:
@@ -1483,21 +1518,22 @@ def train_als(
 
     sweep_seconds = []
     for step in range(start_step, config.iterations):
-        t_sweep = time.perf_counter()
-        uf, vf = als_sweep(
-            uf, vf, user_bucketed, item_bucketed,
-            reg=config.reg, implicit=config.implicit, alpha=config.alpha,
-            precision=config.precision,
-            solver=solver,
-            mesh=mesh,
-            data_axis=data_axis if mesh is not None else None,
-            model_axis=model_axis if mesh is not None else None,
-        )
+        with span("train.sweep") as sweep:
+            uf, vf = als_sweep(
+                uf, vf, user_bucketed, item_bucketed,
+                reg=config.reg, implicit=config.implicit, alpha=config.alpha,
+                precision=config.precision,
+                solver=solver,
+                mesh=mesh,
+                data_axis=data_axis if mesh is not None else None,
+                model_axis=model_axis if mesh is not None else None,
+            )
+            if timed:
+                # one sync per sweep: the first entry carries the sweep's
+                # compile, the rest are steady state
+                jax.block_until_ready(vf)
         if timed:
-            # one sync per sweep: the first entry carries the sweep's
-            # compile, the rest are steady state
-            jax.block_until_ready(vf)
-            sweep_seconds.append(round(time.perf_counter() - t_sweep, 3))
+            sweep_seconds.append(round(sweep.seconds, 3))
         if manager is not None and (
             (step + 1) % config.checkpoint_interval == 0
             or step + 1 == config.iterations
@@ -1532,6 +1568,16 @@ def train_als(
                 item=np.asarray(vf)[:num_items],
             )
     return ALSFactors(user=uf[:num_users], item=vf[:num_items])
+
+
+def factors_to_host(info: dict, *tables: jax.Array) -> tuple[np.ndarray, ...]:
+    """The trained tables as host arrays (what a model blob holds), and
+    the seconds that took as ``info["readbackSeconds"]`` beside the other
+    timings of :func:`train_als`."""
+    with span("train.readback") as readback:
+        host = tuple(np.asarray(t) for t in tables)
+    info["readbackSeconds"] = round(readback.seconds, 3)
+    return host
 
 
 # ---------------------------------------------------------------------------
@@ -1577,8 +1623,11 @@ def top_k_items_batch(
     ``core/workflow/BatchPredict.scala`` ``batchPredictBase``): one
     dispatch per chunk amortizes the per-dispatch cost over the whole
     chunk, where per-query dispatch pays it per prediction."""
-    user_vecs = user_factors[user_idx]
-    scores = jnp.matmul(user_vecs, item_factors.T, precision=SCORE_PRECISION)
+    with jax.named_scope("pio_topk_score"):
+        user_vecs = user_factors[user_idx]
+        scores = jnp.matmul(
+            user_vecs, item_factors.T, precision=SCORE_PRECISION
+        )
     return top_k_scores(scores, k)
     # NB: donating the user_idx staging buffer was considered for the
     # pinned serving path and rejected: XLA input-output aliasing needs

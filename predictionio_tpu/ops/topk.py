@@ -89,7 +89,8 @@ def sort_merge_topk(
 def top_k_scores(scores: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     """Top-k of ``scores`` along the last axis: ``(indices, values)``.
     Ties break toward the lower index (``lax.top_k``'s contract)."""
-    values, indices = jax.lax.top_k(scores, k)
+    with jax.named_scope("pio_topk_select"):
+        values, indices = jax.lax.top_k(scores, k)
     return indices, values
 
 

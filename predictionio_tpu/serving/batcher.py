@@ -20,10 +20,13 @@ request with its own ``(status, payload)``.
 Admission control is explicit: when the queue is full the configured
 policy either rejects immediately (HTTP 429 + ``Retry-After``) or
 blocks the caller up to ``block_timeout_ms`` (503 on timeout). Queue
-depth, in-flight batch state, bucket hit/miss counts and a per-request
-latency decomposition (queue wait / batch-form / handle time) are
-recorded in :class:`predictionio_tpu.api.stats.ServingStats` and served
-from the query server's ``GET /stats.json``.
+depth, in-flight batch state, bucket hit/miss counts and the latency
+decomposition (per request: queue wait, total, wake; per batch: the
+dispatcher's phases as spans of ``utils/spans.py``, and the host gap
+between batches) are recorded in
+:class:`predictionio_tpu.api.stats.ServingStats` and served from the
+query server's ``GET /stats.json``. The dispatcher thread's leaf spans
+are also ``pio.*`` events in a running ``jax.profiler`` trace.
 
 No reference counterpart: the reference serves one query per spray
 route invocation. This is the TPU-native replacement for that hot path.
@@ -44,6 +47,8 @@ import time
 from typing import Any, Callable, Sequence
 
 from predictionio_tpu.api.stats import ServingStats
+from predictionio_tpu.utils import spans
+from predictionio_tpu.utils.spans import span
 
 __all__ = ["AdmissionPolicy", "BatcherConfig", "MicroBatcher"]
 
@@ -130,13 +135,18 @@ class BatcherConfig:
 
 
 class _Pending:
-    __slots__ = ("body", "enqueued_at", "done", "result", "drained")
+    __slots__ = ("body", "enqueued_at", "done", "result", "drained", "seq",
+                 "released_ns")
 
     def __init__(self, body: Any):
         self.body = body
         self.enqueued_at = time.monotonic()
         self.done = threading.Event()
         self.result: tuple[int, Any] | None = None
+        #: sequence number of the batch this request rode in
+        self.seq = 0
+        #: ``perf_counter_ns`` just before the dispatcher's ``done.set()``
+        self.released_ns = 0
         #: answered by a dead-queue drain (shutdown / dead dispatcher),
         #: not by a dispatched batch — kept out of the latency stats
         self.drained = False
@@ -184,6 +194,10 @@ class MicroBatcher:
         # idempotent _drain_dead_queue(), not by mutual exclusion
         self._lock = threading.Lock()
         self._closed = False
+        # the dispatcher thread feeds the device: its leaf spans also go
+        # into a running profiler trace (utils/spans.py). Bound by _loop;
+        # taken once a batch
+        self._spans = spans.Collector(annotate=True)
         if self.config.warmup_body is not None:
             self.warmup(self.config.warmup_body)
         self._thread = threading.Thread(
@@ -261,6 +275,7 @@ class MicroBatcher:
                 }
             if time.monotonic() >= give_up_at:
                 return 500, {"message": "Batch dispatcher did not respond."}
+        woke_ns = time.perf_counter_ns()
         assert pending.result is not None
         if pending.drained:
             # a shutdown/dead-dispatcher 503, not a served request: keep
@@ -269,8 +284,13 @@ class MicroBatcher:
             self.stats.record_rejected()
         else:
             self.stats.record_request(
-                total_ms=(time.monotonic() - pending.enqueued_at) * 1e3
+                total_ms=(time.monotonic() - pending.enqueued_at) * 1e3,
+                wake_ms=(woke_ns - pending.released_ns) / 1e6,
             )
+            collector = spans.current()
+            if collector is not None:
+                # this request's spans share its batch's identifier
+                collector.seq = pending.seq
         return pending.result
 
     def retry_after_seconds(self) -> int:
@@ -365,57 +385,95 @@ class MicroBatcher:
             batch.append(item)
         return batch
 
-    def _loop(self) -> None:
+    def _take(self) -> _Pending | None:
+        """Block until a request is queued; None once closed."""
         while True:
             try:
                 first = self._queue.get(timeout=0.05)
             except queue.Empty:
-                if self._closed:
-                    break
-                continue
+                first = None
+            if first is not None:
+                return first
+            if self._closed:
+                return None
+
+    def _loop(self) -> None:
+        spans.bind(self._spans)
+        #: when the previous batch's readback returned (perf_counter_ns)
+        device_done_ns = None
+        while True:
+            self._spans.seq += 1
+            with span("take"):
+                first = self._take()
             if first is None:
-                if self._closed:
-                    break
-                continue
-            batch = self._drain(first)
-            self._dispatch(batch)
+                break
+            with span("drain"):
+                batch = self._drain(first)
+            device_done_ns = self._dispatch(batch, device_done_ns)
         # drain leftovers so no client hangs on shutdown
         self._drain_dead_queue()
 
-    def _dispatch(self, batch: list[_Pending]) -> None:
-        formed_at = time.monotonic()
-        for p in batch:
-            self.stats.record_queue_wait((formed_at - p.enqueued_at) * 1e3)
-        bodies = [p.body for p in batch]
-        bucket = self._bucket_for(len(bodies))
-        # pad with a copy of the first body: identical query class and
-        # shape guarantees, results beyond len(bodies) are discarded
-        padded = bodies + [bodies[0]] * (bucket - len(bodies))
-        self.stats.record_batch_start(self._queue.qsize())
-        called_at = time.monotonic()
-        try:
-            results = self._call(padded, n_real=len(bodies))
-            if len(results) < len(bodies):  # defensive: misaligned handler
-                raise RuntimeError(
-                    f"handle_batch returned {len(results)} results "
-                    f"for {len(padded)} queries"
-                )
-        except Exception:
-            # handle_batch isolates per-item errors itself; reaching this
-            # means the batch MACHINERY failed — answer everyone rather
-            # than hanging the HTTP threads. Generic message: exception
-            # text can leak internals (details go to the log)
-            logger.exception("micro-batch dispatch failed")
-            results = [
-                (500, {"message": "Batch dispatch failed; see server log."})
-            ] * len(bodies)
-        finished_at = time.monotonic()
+    def _dispatch(
+        self, batch: list[_Pending], device_done_ns: int | None = None
+    ) -> int | None:
+        """Pad, run and answer one batch. ``device_done_ns`` is when the
+        previous batch's readback ended; returns this batch's, for the
+        next one's host gap."""
+        with span("batchForm"):
+            formed_at = time.monotonic()
+            waits = [(formed_at - p.enqueued_at) * 1e3 for p in batch]
+            bodies = [p.body for p in batch]
+            bucket = self._bucket_for(len(bodies))
+            # pad with a copy of the first body: identical query class and
+            # shape guarantees, results beyond len(bodies) are discarded
+            padded = bodies + [bodies[0]] * (bucket - len(bodies))
+            self.stats.record_batch_start(self._queue.qsize())
+        with span("handle", enclosing=True) as handle:
+            try:
+                results = self._call(padded, n_real=len(bodies))
+                if len(results) < len(bodies):  # defensive: misaligned handler
+                    raise RuntimeError(
+                        f"handle_batch returned {len(results)} results "
+                        f"for {len(padded)} queries"
+                    )
+            except Exception:
+                # handle_batch isolates per-item errors itself; reaching
+                # this means the batch MACHINERY failed — answer everyone
+                # rather than hanging the HTTP threads. Generic message:
+                # exception text can leak internals (details go to the log)
+                logger.exception("micro-batch dispatch failed")
+                results = [
+                    (500, {"message": "Batch dispatch failed; see server log."})
+                ] * len(bodies)
+        # one cycle of the dispatcher: the previous batch's release, then
+        # this batch's take ... handle
+        cycle = self._spans.take()
+        phases = spans.durations_ms(cycle)
+        dispatched_ns = next(
+            (r.end_ns for r in cycle if r.name == "dispatch"), None
+        )
+        host_gap_ms = None
+        if device_done_ns is not None and dispatched_ns is not None:
+            # an empty queue starves the device through no fault of the
+            # host code: take is not the host's gap
+            host_gap_ms = (
+                (dispatched_ns - device_done_ns) / 1e6
+                - phases.get("take", 0.0)
+            )
         self.stats.record_batch(
             size=len(bodies),
             bucket=bucket,
-            form_ms=(called_at - formed_at) * 1e3,
-            handle_ms=(finished_at - called_at) * 1e3,
+            handle_ms=handle.ms,
+            queue_wait_ms=waits,
+            phases=phases,
+            host_gap_ms=host_gap_ms,
         )
-        for p, result in zip(batch, results):
-            p.result = result
-            p.done.set()
+        with span("release"):
+            for p, result in zip(batch, results):
+                p.result = result
+                p.seq = self._spans.seq
+                p.released_ns = time.perf_counter_ns()
+                p.done.set()
+        return max(
+            (r.end_ns for r in cycle if r.name == "deviceWait"), default=None
+        )

@@ -34,7 +34,7 @@ from predictionio_tpu.controller import (
 )
 from predictionio_tpu.data.aggregator import BiMap
 from predictionio_tpu.data.store import LEventStore, PEventStore
-from predictionio_tpu.ops.als import ALSConfig, train_als
+from predictionio_tpu.ops.als import ALSConfig, factors_to_host, train_als
 from predictionio_tpu.templates.results import ItemScore, PredictedResult
 
 __all__ = [
@@ -229,9 +229,12 @@ class ECommAlgorithm(JaxAlgorithm):
             mesh=ctx.mesh,
             info=ctx.run_info.setdefault("als", {}),
         )
+        user, item = factors_to_host(
+            ctx.run_info["als"], factors.user, factors.item
+        )
         return ECommModel(
-            user_factors=np.asarray(factors.user),
-            item_factors=np.asarray(factors.item),
+            user_factors=user,
+            item_factors=item,
             user_index=pd.user_index,
             item_index=pd.item_index,
             categories=pd.categories,

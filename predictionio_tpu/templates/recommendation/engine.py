@@ -39,7 +39,8 @@ from predictionio_tpu.templates.serving_util import TOPK_CHUNK
 # re-exported (see __all__): the ranked-result wire types are shared by
 # the similarproduct and ecommerce templates via templates/results.py
 from predictionio_tpu.templates.results import ItemScore, PredictedResult
-from predictionio_tpu.ops.als import ALSConfig, train_als
+from predictionio_tpu.ops.als import ALSConfig, factors_to_host, train_als
+from predictionio_tpu.utils.spans import span
 
 __all__ = [
     "Query",
@@ -691,9 +692,12 @@ class ALSAlgorithm(JaxAlgorithm):
             init_item=init_item,
             info=ctx.run_info.setdefault("als", {}),
         )
+        user, item = factors_to_host(
+            ctx.run_info["als"], factors.user, factors.item
+        )
         return ALSModel(
-            user_factors=np.asarray(factors.user),
-            item_factors=np.asarray(factors.item),
+            user_factors=user,
+            item_factors=item,
             user_index=pd.user_index,
             item_index=pd.item_index,
         )
@@ -1297,25 +1301,27 @@ class ALSAlgorithm(JaxAlgorithm):
         n_items = len(model.item_index)
         results: list[tuple[int, PredictedResult]] = []
         valid: list[tuple[int, int, int]] = []  # (orig idx, uidx, k)
-        for idx, q in queries:
-            uidx = model.user_index.get(q.user)
-            k = min(int(q.num), n_items)
-            if uidx is None or k <= 0:
-                results.append((idx, PredictedResult(())))
-            else:
-                valid.append((idx, uidx, k))
+        with span("lookup"):
+            for idx, q in queries:
+                uidx = model.user_index.get(q.user)
+                k = min(int(q.num), n_items)
+                if uidx is None or k <= 0:
+                    results.append((idx, PredictedResult(())))
+                else:
+                    valid.append((idx, uidx, k))
         if not valid:
             return results
         inverse = model.item_index.inverse
         for part, idx_l, score_l in self._topk_staged(model, valid):
-            for (oi, _, k), ids, scs in zip(part, idx_l, score_l):
-                results.append((
-                    oi,
-                    PredictedResult(tuple(
-                        ItemScore(item=inverse(i), score=s)
-                        for i, s in zip(ids[:k], scs[:k])
-                    )),
-                ))
+            with span("format"):
+                for (oi, _, k), ids, scs in zip(part, idx_l, score_l):
+                    results.append((
+                        oi,
+                        PredictedResult(tuple(
+                            ItemScore(item=inverse(i), score=s)
+                            for i, s in zip(ids[:k], scs[:k])
+                        )),
+                    ))
         return results
 
     def _topk_staged(self, model: ALSModel, valid: list):

@@ -8,6 +8,8 @@ from typing import Callable, Iterator, Sequence
 
 import numpy as np
 
+from predictionio_tpu.utils.spans import span
+
 __all__ = ["device_latency_probe", "chunked_topk", "aligned_factor_init"]
 
 logger = logging.getLogger(__name__)
@@ -27,25 +29,38 @@ def _drain_staged(
     sees it — shared by the ANN and quantized staging paths."""
     import jax.numpy as jnp
 
-    if len(staged) > 1:
-        idx_all = np.asarray(
-            jnp.concatenate([i for _, i, _ in staged], axis=0)
-        )
-        score_all = np.asarray(
-            jnp.concatenate([s for _, _, s in staged], axis=0)
-        )
-    else:
-        idx_all = np.asarray(staged[0][1])
-        score_all = np.asarray(staged[0][2])
+    with span("deviceWait"):
+        if len(staged) > 1:
+            idx_all = np.asarray(
+                jnp.concatenate([i for _, i, _ in staged], axis=0)
+            )
+            score_all = np.asarray(
+                jnp.concatenate([s for _, _, s in staged], axis=0)
+            )
+        else:
+            idx_all = np.asarray(staged[0][1])
+            score_all = np.asarray(staged[0][2])
     off = 0
     for part, _, _ in staged:
-        ids_l, scores_l = [], []
-        for r in range(len(part)):
-            keep = idx_all[off + r] < n_items
-            ids_l.append(idx_all[off + r][keep].tolist())
-            scores_l.append(score_all[off + r][keep].tolist())
+        with span("format"):
+            ids_l, scores_l = [], []
+            for r in range(len(part)):
+                keep = idx_all[off + r] < n_items
+                ids_l.append(idx_all[off + r][keep].tolist())
+                scores_l.append(score_all[off + r][keep].tolist())
         yield part, ids_l, scores_l
         off += chunk
+
+
+def _padded_index(part: Sequence[tuple], chunk: int) -> np.ndarray:
+    """The chunk's user rows as the padded ``int32[chunk]`` every scoring
+    program takes (one compiled shape whatever the chunk holds)."""
+    with span("lookup"):
+        padded = np.zeros(chunk, np.int32)
+        padded[: len(part)] = np.fromiter(
+            (u for _, u, _ in part), np.int32, len(part)
+        )
+    return padded
 
 
 def chunked_topk(
@@ -88,6 +103,12 @@ def chunked_topk(
     jitted one — zero serve-time compiles; a call-time failure disables
     the program key and the very next chunk takes the jitted path.
 
+    Every branch is cut by the same four leaf spans (utils/spans.py),
+    never held across a ``yield``: ``lookup`` (the chunk's padded index
+    vector), ``dispatch`` (the call into the scoring program until it
+    returns), ``deviceWait`` (the readback that blocks until the device
+    is done; none on the host branch) and ``format`` (``tolist``).
+
     ``quant`` (a :class:`predictionio_tpu.ops.quant.QuantRuntime`, the
     ``--quantize int8`` tier) means both tables are int8 codes + per-row
     scales: the exact path runs the two-stage kernel (int8 coarse scan
@@ -116,50 +137,46 @@ def chunked_topk(
         ann_staged: list = []
         for lo in range(0, len(valid), chunk):
             part = list(valid[lo : lo + chunk])
-            uidx_arr = np.fromiter((u for _, u, _ in part), np.int32, len(part))
-            if user_quantized:
-                # --quantize: dequantize ONLY the chunk's user rows (the
-                # f32 queries the probe stage scores with); the probed
-                # slabs themselves stay int8 inside the index. The rows
-                # stay ON DEVICE — a host round trip here would
-                # serialize the chunk dispatches
-                padded = np.zeros(chunk, np.int32)
-                padded[: len(part)] = uidx_arr
-                qv = user_mat[jnp.asarray(padded)]
-                if shards is not None:
+            padded = _padded_index(part, chunk)
+            with span("dispatch"):
+                if user_quantized:
+                    # --quantize: dequantize ONLY the chunk's user rows
+                    # (the f32 queries the probe stage scores with); the
+                    # probed slabs themselves stay int8 inside the index.
+                    # The rows stay ON DEVICE — a host round trip here
+                    # would serialize the chunk dispatches
+                    qv = user_mat[jnp.asarray(padded)]
+                    if shards is not None:
+                        from predictionio_tpu.parallel import sharding
+
+                        idx_b, score_b = sharding.sharded_ivf_topk(
+                            qv, ann.index, k_max, ann.nprobe, shards.mesh
+                        )
+                    else:
+                        idx_b, score_b = ivf.ivf_topk_batch(
+                            qv, ann.index, k_max, ann.nprobe
+                        )
+                elif shards is not None:
                     from predictionio_tpu.parallel import sharding
 
+                    qv = sharding.gather_rows(padded, user_mat, shards.mesh)
                     idx_b, score_b = sharding.sharded_ivf_topk(
                         qv, ann.index, k_max, ann.nprobe, shards.mesh
                     )
-                else:
-                    idx_b, score_b = ivf.ivf_topk_batch(
-                        qv, ann.index, k_max, ann.nprobe
+                elif user_on_device:
+                    idx_b, score_b = ivf.ivf_topk_users(
+                        padded, user_mat, ann.index, k_max, ann.nprobe
                     )
-            elif shards is not None:
-                from predictionio_tpu.parallel import sharding
-
-                padded = np.zeros(chunk, np.int32)
-                padded[: len(part)] = uidx_arr
-                qv = sharding.gather_rows(padded, user_mat, shards.mesh)
-                idx_b, score_b = sharding.sharded_ivf_topk(
-                    qv, ann.index, k_max, ann.nprobe, shards.mesh
-                )
-            elif user_on_device:
-                padded = np.zeros(chunk, np.int32)
-                padded[: len(part)] = uidx_arr
-                idx_b, score_b = ivf.ivf_topk_users(
-                    padded, user_mat, ann.index, k_max, ann.nprobe
-                )
-            else:
-                # unpinned model: gather the chunk's user rows on host so
-                # each dispatch uploads [chunk, K] — NOT the whole user
-                # table, which would dwarf the nprobe savings per call
-                qv = np.zeros((chunk, user_mat.shape[1]), np.float32)
-                qv[: len(part)] = np.asarray(user_mat)[uidx_arr]
-                idx_b, score_b = ivf.ivf_topk_batch(
-                    jnp.asarray(qv), ann.index, k_max, ann.nprobe
-                )
+                else:
+                    # unpinned model: gather the chunk's user rows on host
+                    # so each dispatch uploads [chunk, K] — NOT the whole
+                    # user table, which would dwarf the nprobe savings
+                    # per call
+                    qv = np.zeros((chunk, user_mat.shape[1]), np.float32)
+                    qv[: len(part)] = np.asarray(user_mat)[padded[: len(part)]]
+                    idx_b, score_b = ivf.ivf_topk_batch(
+                        jnp.asarray(qv), ann.index, k_max, ann.nprobe
+                    )
             ann.note_queries(len(part))
             ann_staged.append((part, idx_b, score_b))
         # same staging discipline as the exact device path below: keep
@@ -172,13 +189,11 @@ def chunked_topk(
         q_staged: list = []
         for lo in range(0, len(valid), chunk):
             part = list(valid[lo : lo + chunk])
-            padded = np.zeros(chunk, np.int32)
-            padded[: len(part)] = np.fromiter(
-                (u for _, u, _ in part), np.int32, len(part)
-            )
-            idx_b, score_b = quant_ops.run_topk(
-                quant, user_mat, item_mat, padded, k_max, shards=shards
-            )
+            padded = _padded_index(part, chunk)
+            with span("dispatch"):
+                idx_b, score_b = quant_ops.run_topk(
+                    quant, user_mat, item_mat, padded, k_max, shards=shards
+                )
             q_staged.append((part, idx_b, score_b))
         yield from _drain_staged(q_staged, n_items, chunk)
         return
@@ -186,37 +201,36 @@ def chunked_topk(
     staged: list[tuple[list, object, object]] = []
     for lo in range(0, len(valid), chunk):
         part = list(valid[lo : lo + chunk])
-        uidx_arr = np.fromiter((u for _, u, _ in part), np.int32, len(part))
+        padded = _padded_index(part, chunk)
         if shards is not None:
             from predictionio_tpu.parallel import sharding
 
-            padded = np.zeros(chunk, np.int32)
-            padded[: len(part)] = uidx_arr
-            idx_b, score_b = sharding.sharded_topk_users(
-                padded, user_mat, item_mat, k_max, n_items, shards.mesh
-            )
+            with span("dispatch"):
+                idx_b, score_b = sharding.sharded_topk_users(
+                    padded, user_mat, item_mat, k_max, n_items, shards.mesh
+                )
         elif on_device:
             from predictionio_tpu.ops.als import top_k_items_batch
 
-            padded = np.zeros(chunk, np.int32)
-            padded[: len(part)] = uidx_arr
-            aot_key = f"top_k_items_batch_c{chunk}_b{k_max}"
-            fn = aot.get(aot_key) if aot is not None else None
-            if fn is not None:
-                try:
-                    idx_b, score_b = fn(padded, user_mat, item_mat)
-                except Exception as e:  # noqa: BLE001 - degrade, don't 500
-                    aot.disable(aot_key, str(e))
-                    fn = None
-            if fn is None:
-                idx_b, score_b = top_k_items_batch(
-                    padded, user_mat, item_mat, k_max
-                )
+            with span("dispatch"):
+                aot_key = f"top_k_items_batch_c{chunk}_b{k_max}"
+                fn = aot.get(aot_key) if aot is not None else None
+                if fn is not None:
+                    try:
+                        idx_b, score_b = fn(padded, user_mat, item_mat)
+                    except Exception as e:  # noqa: BLE001 - degrade, don't 500
+                        aot.disable(aot_key, str(e))
+                        fn = None
+                if fn is None:
+                    idx_b, score_b = top_k_items_batch(
+                        padded, user_mat, item_mat, k_max
+                    )
         else:
             from predictionio_tpu.ops.topk import top_k_host
 
             scores = (
-                np.asarray(user_mat)[uidx_arr] @ np.asarray(item_mat).T
+                np.asarray(user_mat)[padded[: len(part)]]
+                @ np.asarray(item_mat).T
             )  # [B, I]
             # descending score, ties broken by ascending item index —
             # the same rule lax.top_k uses, so host and device paths
@@ -224,28 +238,34 @@ def chunked_topk(
             # ops/topk.py)
             idx_b, score_b = top_k_host(scores, k_max)
         staged.append((part, idx_b, score_b))
-    if on_device and len(staged) > 1:
-        import jax.numpy as jnp
+    if on_device:
+        with span("deviceWait"):
+            if len(staged) > 1:
+                import jax.numpy as jnp
 
-        idx_all = np.asarray(jnp.concatenate([i for _, i, _ in staged], axis=0))
-        score_all = np.asarray(
-            jnp.concatenate([s for _, _, s in staged], axis=0)
-        )
+                # dispatches stayed async across chunks: ONE link crossing
+                idx_all = np.asarray(
+                    jnp.concatenate([i for _, i, _ in staged], axis=0)
+                )
+                score_all = np.asarray(
+                    jnp.concatenate([s for _, _, s in staged], axis=0)
+                )
+            else:
+                idx_all = np.asarray(staged[0][1])
+                score_all = np.asarray(staged[0][2])
         off = 0
         for part, _, _ in staged:
-            yield (
-                part,
-                idx_all[off : off + len(part)].tolist(),
-                score_all[off : off + len(part)].tolist(),
-            )
+            with span("format"):
+                ids = idx_all[off : off + len(part)].tolist()
+                scs = score_all[off : off + len(part)].tolist()
+            yield part, ids, scs
             off += chunk
         return
     for part, idx_b, score_b in staged:
-        yield (
-            part,
-            np.asarray(idx_b)[: len(part)].tolist(),
-            np.asarray(score_b)[: len(part)].tolist(),
-        )
+        with span("format"):
+            ids = np.asarray(idx_b)[: len(part)].tolist()
+            scs = np.asarray(score_b)[: len(part)].tolist()
+        yield part, ids, scs
 
 
 def device_latency_probe(

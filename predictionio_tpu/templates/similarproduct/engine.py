@@ -32,7 +32,7 @@ from predictionio_tpu.controller import (
 )
 from predictionio_tpu.data.aggregator import BiMap
 from predictionio_tpu.data.store import PEventStore
-from predictionio_tpu.ops.als import ALSConfig, train_als
+from predictionio_tpu.ops.als import ALSConfig, factors_to_host, train_als
 from predictionio_tpu.templates.results import ItemScore, PredictedResult
 
 __all__ = [
@@ -197,7 +197,7 @@ class ALSAlgorithm(JaxAlgorithm):
             mesh=ctx.mesh,
             info=ctx.run_info.setdefault("als", {}),
         )
-        item = np.asarray(factors.item)
+        (item,) = factors_to_host(ctx.run_info["als"], factors.item)
         norms = np.linalg.norm(item, axis=1, keepdims=True)
         norms[norms == 0] = 1.0
         return SimilarProductModel(

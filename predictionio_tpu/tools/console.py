@@ -20,7 +20,7 @@ import os
 import sys
 
 from predictionio_tpu.tools import commands
-from predictionio_tpu.utils import compile_cache
+from predictionio_tpu.utils import compile_cache, spans
 from predictionio_tpu.version import __version__
 
 __all__ = ["main", "build_parser"]
@@ -1424,6 +1424,9 @@ def _ssl_from_args(args):
 
 
 def main(argv: list[str] | None = None) -> int:
+    # process start (the kernel's record) to here: interpreter, imports
+    # and, under a wrapper that opens the device first, the device
+    startup_s = spans.process_age_s()
     # PIO_JAX_PLATFORMS=cpu forces the JAX platform even when the
     # interpreter preloaded jax with a different one (CPU CI runs,
     # multi-host rehearsals on hosts whose default platform is a single
@@ -1484,19 +1487,35 @@ def main(argv: list[str] | None = None) -> int:
             from predictionio_tpu.workflow.core import WorkflowParams
 
             initialize_from_env()  # multi-host when PIO_COORDINATOR_* set
-            variant = load_engine_variant(args.engine_json)
-            ctx = _parse_mesh(args.mesh)
-            instance = run_train(
-                variant,
-                ctx,
-                WorkflowParams(
-                    batch=args.batch,
-                    skip_sanity_check=args.skip_sanity_check,
-                    stop_after_read=args.stop_after_read,
-                    stop_after_prepare=args.stop_after_prepare,
-                    warm_start=args.warm_start,
-                ),
-            )
+            # the main thread feeds the device: its leaf spans also go
+            # into a profiler trace, if someone wraps this job in one
+            unbound = spans.bind(spans.Collector(annotate=True))
+            try:
+                with spans.span("train.backend_init") as backend_init:
+                    import jax
+
+                    jax.devices()
+                phase_timings = {
+                    "backend_init": round(backend_init.seconds, 3)
+                }
+                if startup_s is not None:
+                    phase_timings["startup"] = round(startup_s, 3)
+                variant = load_engine_variant(args.engine_json)
+                ctx = _parse_mesh(args.mesh)
+                instance = run_train(
+                    variant,
+                    ctx,
+                    WorkflowParams(
+                        batch=args.batch,
+                        skip_sanity_check=args.skip_sanity_check,
+                        stop_after_read=args.stop_after_read,
+                        stop_after_prepare=args.stop_after_prepare,
+                        warm_start=args.warm_start,
+                    ),
+                    phase_timings=phase_timings,
+                )
+            finally:
+                spans.bind(unbound)
             if args.aot:
                 # lazy: without --aot no AOT module is imported and the
                 # train output is byte-identical (CI-guarded)

@@ -28,6 +28,7 @@ from predictionio_tpu.controller.evaluation import (
 from predictionio_tpu.controller.params import params_to_json
 from predictionio_tpu.data.storage import Storage
 from predictionio_tpu.data.storage.base import EngineInstance, EvaluationInstance, Model
+from predictionio_tpu.utils.spans import CompileLedger, span
 from predictionio_tpu.workflow.engine_json import EngineVariant
 
 __all__ = ["WorkflowParams", "run_train", "run_evaluation"]
@@ -71,6 +72,7 @@ def run_train(
     workflow_params: WorkflowParams = WorkflowParams(),
     engine_id: str | None = None,
     engine_version: str = "",
+    phase_timings: dict | None = None,
 ) -> EngineInstance:
     """Train an engine variant end-to-end and record its lineage.
 
@@ -79,7 +81,16 @@ def run_train(
     ``Models`` repo -> update the instance to COMPLETED with timings and
     the resolved component params. On error the instance is marked FAILED
     and the exception re-raised.
+
+    The instance's ``env["phase_timings"]`` holds, in seconds, what the
+    caller measured before this call (``phase_timings``: `pio train`
+    passes ``startup`` and ``backend_init``), ``read``, ``prepare`` and
+    ``train:<algorithm>`` from ``Engine.train``, and ``publish`` with its
+    parts ``serialize`` and ``blob_write``; ``env["kernels"]["compile"]``
+    is the compile ledger's table for this job (``utils/spans.py``).
     """
+    ledger = CompileLedger.install()
+    compiled_before = ledger.snapshot()
     engine = variant.build_engine()
     engine_params = variant.engine_params(engine)
     instances = Storage.get_meta_data_engine_instances()
@@ -180,7 +191,7 @@ def run_train(
                 logger.info(
                     "Warm-starting from completed instance %s", warm_from
                 )
-        timings: dict = {}
+        timings: dict = dict(phase_timings or {})
         models = engine.train(
             ctx,
             engine_params,
@@ -198,9 +209,21 @@ def run_train(
                 instances.update(instance)
             return instance
         if workflow_params.save_model and is_writer:
-            blob = engine.models_to_bytes(instance.id, engine_params, models)
-            Storage.get_model_data_models().insert(Model(id=instance.id, models=blob))
+            with span("train.serialize") as serialize:
+                blob = engine.models_to_bytes(instance.id, engine_params, models)
+            with span("train.blob_write") as blob_write:
+                Storage.get_model_data_models().insert(
+                    Model(id=instance.id, models=blob)
+                )
+            timings["serialize"] = round(serialize.seconds, 3)
+            timings["blob_write"] = round(blob_write.seconds, 3)
+            timings["publish"] = round(
+                serialize.seconds + blob_write.seconds, 3
+            )
             logger.info("Saved model blob for instance %s (%d bytes)", instance.id, len(blob))
+        # what this job traced, lowered and compiled (or loaded from the
+        # persistent cache), per jitted function
+        ctx.run_info["compile"] = ledger.table(since=compiled_before)
         # where it ran and which kernels it took, beside the timings: a
         # reader (chip_smoke.py, a benchmark) tells a device run from a
         # quiet host run from the instance alone, without importing jax

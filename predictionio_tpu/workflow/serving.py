@@ -40,6 +40,8 @@ from predictionio_tpu.serving.cache import (
     canonical_key,
     extract_scope,
 )
+from predictionio_tpu.api.stats import HttpStats
+from predictionio_tpu.utils.spans import CompileLedger, durations_ms, span
 from predictionio_tpu.workflow.engine_json import EngineVariant
 
 __all__ = [
@@ -199,18 +201,17 @@ class QueryService:
         # workflow.aot and leaves every query on the exact prior code
         # path (CI-guarded like batching/caching/ann/online). When on,
         # reload() boots by DESERIALIZING the generation's exported
-        # serving programs, and the serve-time compile counter below
+        # serving programs, and the compile ledger's count since boot
         # proves the request path compiles nothing after boot.
         self.aot_config = (
             aot if aot is not None and getattr(aot, "active", False) else None
         )
-        self._serve_compiles = None
-        if self.aot_config is not None:
-            from predictionio_tpu.analysis.jit_witness import (
-                ServeCompileCounter,
-            )
-
-            self._serve_compiles = ServeCompileCounter.install()
+        #: every deploy counts its compiles (utils/spans.py): per jitted
+        #: function, and since the boot mark — the `compile` block of
+        #: /stats.json, and `aot.serveTimeCompiles` under --aot
+        self._compiles = CompileLedger.install()
+        #: what the HTTP threads spend around dispatch() (`http` block)
+        self._http_stats = HttpStats()
         self._cache_stats: CacheStats | None = None
         self._result_cache: ResultCache | None = None
         self._singleflight: Singleflight | None = None
@@ -297,6 +298,10 @@ class QueryService:
             if batching is not None
             else None
         )
+        # ready to serve: the batcher's bucket warm-up above compiled (or
+        # loaded) its programs as BOOT work, so the mark reload() left
+        # moves here; every later reload() marks at its own end
+        self._compiles.mark_boot_complete()
         for p in self.plugins:
             p.start(self)
 
@@ -521,12 +526,11 @@ class QueryService:
             from predictionio_tpu.workflow import device_state
 
             device_state.release_pairs(old_pairs)
-        if self._serve_compiles is not None:
-            # everything compiled so far this reload was BOOT work
-            # (deserialize warm-ups, or tier-2/3 fallback compiles);
-            # compiles counted from here on are serve-time — the number
-            # the --aot contract asserts stays ZERO
-            self._serve_compiles.mark_boot_complete()
+        # everything compiled so far this reload was BOOT work
+        # (deserialize warm-ups, or tier-2/3 fallback compiles);
+        # compiles counted from here on are serve-time — the number
+        # the --aot contract asserts stays ZERO
+        self._compiles.mark_boot_complete()
         logger.info(
             "Loaded engine instance %s (generation %d)", instance.id, generation
         )
@@ -790,21 +794,24 @@ class QueryService:
             return [(503, {"message": "No engine loaded"})] * len(bodies)
         out: list[tuple[int, Any] | None] = [None] * len(bodies)
         queries: list[tuple[int, Any]] = []
-        for i, body in enumerate(bodies):
-            if body is None:
-                out[i] = (400, {"message": "Query body is required (JSON)."})
-                continue
-            try:
-                query = self._bind_query(body, pairs)
-            except Exception as e:
-                out[i] = (400, {"message": f"Invalid query: {e}"})
-                continue
-            try:
-                query = serving.supplement_base(query)
-            except Exception as e:  # handle_query surfaces this as a 500 too
-                out[i] = (500, {"message": str(e)})
-                continue
-            queries.append((i, query))
+        with span("bind"):
+            for i, body in enumerate(bodies):
+                if body is None:
+                    out[i] = (
+                        400, {"message": "Query body is required (JSON)."}
+                    )
+                    continue
+                try:
+                    query = self._bind_query(body, pairs)
+                except Exception as e:
+                    out[i] = (400, {"message": f"Invalid query: {e}"})
+                    continue
+                try:
+                    query = serving.supplement_base(query)
+                except Exception as e:  # handle_query surfaces a 500 too
+                    out[i] = (500, {"message": str(e)})
+                    continue
+                queries.append((i, query))
         by_slot: dict[int, list[Any]] = {i: [] for i, _ in queries}
         if queries:
             try:
@@ -826,16 +833,19 @@ class QueryService:
                     except Exception as e:
                         out[i] = (500, {"message": str(e)})
         limit = len(bodies) if n_real is None else n_real
-        for i, query in queries:
-            if out[i] is not None:  # per-query fallback already failed it
-                continue
-            if i >= limit:  # padding slot: no serve tail, no side effects
-                out[i] = (200, None)
-                continue
-            try:
-                out[i] = self._finish_query(serving, bodies[i], query, by_slot[i])
-            except Exception as e:
-                out[i] = (500, {"message": str(e)})
+        with span("format"):
+            for i, query in queries:
+                if out[i] is not None:  # per-query fallback already failed it
+                    continue
+                if i >= limit:  # padding slot: no serve tail, no side effects
+                    out[i] = (200, None)
+                    continue
+                try:
+                    out[i] = self._finish_query(
+                        serving, bodies[i], query, by_slot[i]
+                    )
+                except Exception as e:
+                    out[i] = (500, {"message": str(e)})
         return [
             o if o is not None else (500, {"message": "unprocessed"}) for o in out
         ]
@@ -1031,6 +1041,12 @@ class QueryService:
             # breaker states + retry/abort counters from every registered
             # transport (storage RPC, feedback loop)
             "resilience": resilience.stats_snapshot(),
+            # compiles per jitted function and since the boot mark
+            # (sinceBoot: the request path should compile nothing)
+            "compile": self._compiles.to_json(),
+            # what the HTTP threads spend reading a request and writing
+            # its answer, around dispatch()
+            "http": self._http_stats.to_json(),
         }
         if self.feedback is not None:
             out["feedback"] = feedback_counts
@@ -1085,10 +1101,7 @@ class QueryService:
             aot_block = device_state.aot_stats(a_pairs) or {
                 "tier": None, "loaded": 0,
             }
-            if self._serve_compiles is not None:
-                aot_block["serveTimeCompiles"] = (
-                    self._serve_compiles.serve_time_compiles()
-                )
+            aot_block["serveTimeCompiles"] = self._compiles.since_boot()
             out["aot"] = aot_block
         if self.ann_config is not None:
             # approximate-retrieval decomposition (docs/serving.md):
@@ -1106,6 +1119,13 @@ class QueryService:
                 "models": [rt.stats_json() for rt in runtimes],
             }
         return out
+
+    def record_http(self, records: Sequence) -> None:
+        """The HTTP wrapper's hook (``api/http.py`` finds it by name):
+        the spans one request closed on its HTTP thread."""
+        ms = durations_ms(records)
+        if "httpRead" in ms and "httpWrite" in ms:
+            self._http_stats.record(ms["httpRead"], ms["httpWrite"])
 
     def readiness(self) -> dict:
         """``GET /readyz`` (served by the HTTP wrapper): storage
@@ -1355,10 +1375,18 @@ class QueryService:
             # surface); view the dump with TensorBoard/XProf
             import jax
 
-            log_dir = (body or {}).get("logDir") if isinstance(body, Mapping) else None
-            log_dir = log_dir or "/tmp/pio-profile"
+            options = body if isinstance(body, Mapping) else {}
+            log_dir = options.get("logDir") or "/tmp/pio-profile"
+            # JAX's own host events and the dispatcher's pio.* spans; the
+            # Python tracer (every frame of every HTTP thread) stalls a
+            # loaded server while it starts and is only on when asked for
+            profile = jax.profiler.ProfileOptions()
+            profile.host_tracer_level = 2
+            profile.python_tracer_level = (
+                1 if options.get("pythonTracer") is True else 0
+            )
             try:
-                jax.profiler.start_trace(log_dir)
+                jax.profiler.start_trace(log_dir, profiler_options=profile)
             except RuntimeError as e:
                 return Response(409, {"message": str(e)})
             return Response(200, {"message": "Profiler started", "logDir": log_dir})

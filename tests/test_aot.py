@@ -522,3 +522,49 @@ def test_aot_warm_serving_glue_hook(trained):
     _, pinned = pairs[0]
     assert getattr(pinned, "_pio_pinned", False)
     algo.aot_warm_serving(pinned)  # pinned: compiles the glue, once
+
+
+def test_compile_block_counts_from_after_the_warm_up_in_every_deploy(
+    trained, artifacts
+):
+    """ISSUE 25: the compile ledger's boot mark sits after the batcher's
+    bucket warm-up, in a plain deploy as under --aot, and
+    ``aot.serveTimeCompiles`` reads the same count."""
+    from predictionio_tpu.serving import BatcherConfig, CacheConfig
+
+    root, _ = artifacts
+    batching = BatcherConfig(
+        max_batch_size=4, max_batch_delay_ms=0.0,
+        warmup_body={"user": "1", "num": 5},
+    )
+    plain = QueryService(
+        trained.variant, trained.ctx, instance_id=trained.instance.id,
+        cache=CacheConfig(pin_model=True), batching=batching,
+    )
+    try:
+        stats = plain.stats_json()
+        assert "aot" not in stats  # opt-in block; the count is not
+        # the warm-up compiled the batch program: boot work, not counted
+        assert stats["compile"]["functions"]["top_k_items_batch"]["compiles"] >= 1
+        assert stats["compile"]["sinceBoot"] == 0
+        assert plain.batcher.submit({"user": "2", "num": 5})[0] == 200
+        assert plain.stats_json()["compile"]["sinceBoot"] == 0  # warmed shape
+        # num=40 takes the next k bucket (64): one fresh program
+        assert plain.batcher.submit({"user": "2", "num": 40})[0] == 200
+        assert plain.stats_json()["compile"]["sinceBoot"] == 1
+        plain.reload()  # every later reload marks at its own end
+        assert plain.stats_json()["compile"]["sinceBoot"] == 0
+    finally:
+        plain.close()
+    tier1 = QueryService(
+        trained.variant, trained.ctx, instance_id=trained.instance.id,
+        aot=aot.AotConfig(enabled=True, root=root), batching=batching,
+    )
+    try:
+        assert tier1.batcher.submit({"user": "3", "num": 5})[0] == 200
+        stats = tier1.stats_json()
+        assert stats["aot"]["tier"] == 1
+        assert stats["aot"]["serveTimeCompiles"] == 0
+        assert stats["compile"]["sinceBoot"] == stats["aot"]["serveTimeCompiles"]
+    finally:
+        tier1.close()

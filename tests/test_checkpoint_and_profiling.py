@@ -92,8 +92,72 @@ class TestPhaseTimings:
         })
         instance = run_train(variant, local_context())
         timings = json.loads(instance.env["phase_timings"])
-        assert set(timings) == {"read", "prepare", "train:a0"}
+        assert set(timings) == {
+            "read", "prepare", "train:a0", "serialize", "blob_write", "publish"}
         assert all(isinstance(v, float) for v in timings.values())
+        assert timings["publish"] == pytest.approx(
+            timings["serialize"] + timings["blob_write"], abs=0.002)
+        # what the caller measured before the call rides along
+        instance = run_train(
+            variant, local_context(), phase_timings={"startup": 1.5})
+        assert json.loads(instance.env["phase_timings"])["startup"] == 1.5
+
+    def test_a_toy_pio_train_instance_holds_the_job_by_phase(
+        self, memory_storage_env, tmp_path
+    ):
+        """`pio train` through the console on the recommendation template:
+        the new keys, and the old ones with their old meaning."""
+        from predictionio_tpu.tools import commands
+        from predictionio_tpu.tools.console import main
+        from predictionio_tpu.utils import spans
+
+        commands.app_new("spanapp", out=lambda *_: None)
+        rng = np.random.default_rng(0)
+        src = tmp_path / "events.jsonl"
+        with open(src, "w") as f:
+            for u in range(40):
+                for i in rng.choice(30, 8, replace=False):
+                    f.write(json.dumps({
+                        "event": "rate", "entityType": "user",
+                        "entityId": str(u), "targetEntityType": "item",
+                        "targetEntityId": str(i),
+                        "properties": {"rating": float(rng.integers(1, 6))},
+                    }) + "\n")
+        commands.import_events("spanapp", str(src), out=lambda *_: None)
+        ej = tmp_path / "engine.json"
+        ej.write_text(json.dumps({
+            "id": "span-engine", "version": "1",
+            "engineFactory":
+                "predictionio_tpu.templates.recommendation:engine_factory",
+            "datasource": {"params": {"appName": "spanapp"}},
+            "algorithms": [{"name": "als", "params": {
+                "rank": 8, "numIterations": 3, "lambda": 0.05, "seed": 3}}],
+        }))
+        assert main(["train", "--engine-json", str(ej), "--mesh", "none"]) == 0
+        assert spans.current() is None  # the job's collector is unbound again
+        inst = memory_storage_env.get_meta_data_engine_instances(
+        ).get_latest_completed("span-engine", "1", "span-engine")
+        timings = json.loads(inst.env["phase_timings"])
+        assert set(timings) == {
+            "startup", "backend_init", "read", "prepare", "train:als",
+            "serialize", "blob_write", "publish"}
+        assert timings["startup"] > 0  # this process is older than that
+        kernels = json.loads(inst.env["kernels"])
+        als = kernels["als"]
+        assert len(als["sweepSeconds"]) == 3  # one span a sweep, as before
+        # bucketingSeconds keeps its meaning: transfer, sort and fill
+        assert als["bucketingSeconds"] >= als["transferSeconds"] >= 0
+        assert als["readbackSeconds"] >= 0 and als["initSeconds"] >= 0
+        assert timings["train:als"] >= sum(als["sweepSeconds"])
+        sweep = kernels["compile"]["als_sweep"]
+        assert sweep["traces"] == sweep["lowers"] == sweep["compiles"] == 1
+        assert sweep["traceSeconds"] > 0 and sweep["lowerSeconds"] > 0
+        assert sweep["loadSeconds"] > 0
+        assert {"cacheRequests", "cacheHits", "cacheMisses",
+                "cacheRetrievalSeconds"} <= set(sweep)
+        # the first sweep carries the three of them
+        assert (sweep["traceSeconds"] + sweep["lowerSeconds"]
+                + sweep["loadSeconds"]) <= als["sweepSeconds"][0] + 0.01
 
 
 class TestProfilerEndpoint:
@@ -119,3 +183,32 @@ class TestProfilerEndpoint:
         import os
 
         assert os.path.isdir(log_dir), "trace dir written"
+
+    def test_the_python_tracer_is_off_unless_asked_for(
+        self, memory_storage_env, tmp_path, monkeypatch
+    ):
+        import jax
+
+        from predictionio_tpu.workflow.serving import QueryService
+
+        variant = load_engine_variant({
+            "id": "fake-engine", "version": "0.1",
+            "engineFactory": "fake_dase:engine0",
+            "datasource": {"params": {"base": 10}},
+            "algorithms": [{"name": "a0", "params": {"mult": 2}}],
+        })
+        run_train(variant, local_context())
+        qs = QueryService(variant)
+        started = []
+        monkeypatch.setattr(
+            jax.profiler, "start_trace",
+            lambda log_dir, profiler_options=None: started.append(
+                (log_dir, profiler_options.python_tracer_level,
+                 profiler_options.host_tracer_level)),
+        )
+        log_dir = str(tmp_path / "prof")
+        for body in ({"logDir": log_dir},
+                     {"logDir": log_dir, "pythonTracer": True},
+                     {"logDir": log_dir, "pythonTracer": "yes"}):
+            assert qs.dispatch("POST", "/profiler/start", {}, body).status == 200
+        assert started == [(log_dir, 0, 2), (log_dir, 1, 2), (log_dir, 0, 2)]

@@ -19,11 +19,13 @@ import urllib.request
 
 import pytest
 
-from predictionio_tpu.api.stats import ServingStats
+from predictionio_tpu.api.stats import BATCH_PHASES, ServingStats
 from predictionio_tpu.api.http import start_background
 from predictionio_tpu.controller import local_context
 from predictionio_tpu.serving import AdmissionPolicy, BatcherConfig, MicroBatcher
 from predictionio_tpu.workflow import load_engine_variant, run_train
+from predictionio_tpu.utils import spans
+from predictionio_tpu.utils.spans import span
 from predictionio_tpu.workflow.serving import QueryService
 
 VARIANT = {
@@ -49,6 +51,15 @@ def trained(memory_storage_env):
 def _echo_batch(bodies):
     """Stand-in handler: status 200, payload echoes the body."""
     return [(200, {"echo": b}) for b in bodies]
+
+
+def _phased_batch(bodies):
+    """Stand-in handler cut like a device-backed ``handle_batch``: the
+    five inner phases, each a few hundred microseconds."""
+    for name in ("bind", "lookup", "dispatch", "deviceWait", "format"):
+        with span(name):
+            time.sleep(0.0003)
+    return _echo_batch(bodies)
 
 
 class TestConfig:
@@ -396,6 +407,143 @@ class TestBatcherCore:
         assert all(s == 503 for s, _ in results)
 
 
+class TestDispatcherSpans:
+    """The dispatcher's phases as spans: windows of ``latencyMs``, the
+    host gap, the batch sequence number, and ``pio.*`` events in a
+    profiler trace (ISSUE 25)."""
+
+    def test_every_phase_is_a_window_and_the_inner_ones_fit_in_handle(self):
+        b = MicroBatcher(_phased_batch, BatcherConfig(max_batch_delay_ms=0.0))
+        try:
+            assert b.submit("q")[0] == 200  # exactly one batch
+            ms = b.stats.to_json()["latencyMs"]
+        finally:
+            b.close()
+        assert set(ms) == set(BATCH_PHASES) | {
+            "queueWait", "handle", "total", "wake", "hostGap"}
+        inner = ("bind", "lookup", "dispatch", "deviceWait", "format")
+        for name in ("take", "drain", "batchForm", "wake", *inner):
+            assert ms[name]["p50"] is not None, name
+        assert all(ms[name]["p50"] >= 0.3 for name in inner)
+        # one batch in the window, so each p50 is that batch's own value
+        assert sum(ms[n]["p50"] for n in inner) <= ms["handle"]["p50"] + 0.005
+        # release is the PREVIOUS batch's, recorded with the next cycle
+        assert ms["release"]["p50"] is None
+
+    def test_host_gap_is_absent_for_the_first_batch_and_excludes_take(self):
+        b = MicroBatcher(_phased_batch, BatcherConfig(max_batch_delay_ms=0.0))
+        try:
+            b.submit("first")
+            assert b.stats.to_json()["latencyMs"]["hostGap"]["p50"] is None
+            time.sleep(0.15)  # the queue is empty: the dispatcher sits in take
+            b.submit("second")
+            ms = b.stats.to_json()["latencyMs"]
+        finally:
+            b.close()
+        assert ms["take"]["p95"] >= 140.0
+        # previous deviceWait end -> this dispatch end spans the idle
+        # 150 ms; less take, what is left is the host's own code: release,
+        # drain, batchForm, bind, lookup, dispatch (and format before them)
+        assert ms["hostGap"]["p50"] is not None
+        assert 0.9 <= ms["hostGap"]["p50"] < 100.0
+        assert ms["release"]["p50"] is not None  # the first batch's
+
+    def test_a_handler_without_a_device_records_no_gap(self):
+        b = MicroBatcher(_echo_batch, BatcherConfig(max_batch_delay_ms=0.0))
+        try:
+            for q in range(3):
+                b.submit(q)
+            ms = b.stats.to_json()["latencyMs"]
+        finally:
+            b.close()
+        assert ms["hostGap"]["p50"] is None and ms["dispatch"]["p50"] is None
+        assert ms["handle"]["p50"] is not None
+
+    def test_a_batch_and_its_riders_share_a_sequence_number(self):
+        batch_seqs = []
+
+        def handler(bodies):
+            batch_seqs.append(spans.current().seq)
+            return _echo_batch(bodies)
+
+        b = MicroBatcher(
+            handler, BatcherConfig(max_batch_size=4, max_batch_delay_ms=50.0)
+        )
+        rider_seqs = []
+
+        def rider(q):
+            collector = spans.Collector()  # what an HTTP thread binds
+            spans.bind(collector)
+            b.submit(q)
+            rider_seqs.append(collector.seq)
+
+        try:
+            threads = [
+                threading.Thread(target=rider, args=(q,), daemon=True)
+                for q in range(4)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=10)
+            assert not any(t.is_alive() for t in threads)
+            rider(99)  # a later batch: a later number
+        finally:
+            b.close()
+            spans.bind(None)
+        assert len(rider_seqs) == 5 and set(rider_seqs) == set(batch_seqs)
+        assert rider_seqs[-1] == max(batch_seqs) > min(batch_seqs) >= 1
+
+    def test_dispatcher_leaves_are_in_the_profiler_trace_flat_on_one_line(
+        self, tmp_path
+    ):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        b = MicroBatcher(_phased_batch, BatcherConfig(max_batch_delay_ms=0.0))
+
+        def http_thread():
+            spans.bind(spans.Collector())  # never an annotating one
+            for q in range(3):
+                with span("httpRead"):
+                    pass
+                b.submit(q)
+                with span("httpWrite"):
+                    pass
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            t = threading.Thread(target=http_thread, daemon=True)
+            t.start()
+            t.join(timeout=20)
+            assert not t.is_alive()
+        finally:
+            jax.profiler.stop_trace()
+            b.close()
+        (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+        lines = {}  # (plane, line) -> [(start, end, name)]
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("pio."):
+                        lines.setdefault((plane.name, line.name), []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                        )
+        assert len(lines) == 1, sorted(lines)  # the dispatcher thread's
+        (events,) = lines.values()
+        names = {name for _, _, name in events}
+        assert names == {"pio." + n for n in BATCH_PHASES}
+        # no enclosing span, no HTTP thread's span
+        assert not names & {"pio.handle", "pio.httpRead", "pio.httpWrite"}
+        events.sort()
+        for (_, end, name), (start, _, after) in zip(events, events[1:]):
+            assert end <= start, f"{name} overlaps {after}"  # flat
+
+
 class TestQueryServiceIntegration:
     CFG = dict(max_batch_size=8, max_batch_delay_ms=5.0)
 
@@ -479,10 +627,51 @@ class TestQueryServiceIntegration:
             assert body["batching"] is True
             b = body["batcher"]
             assert b["submitted"] == b["completed"] == 5
-            for phase in ("queueWait", "batchForm", "handle", "total"):
+            for phase in ("queueWait", "batchForm", "handle", "total",
+                          "wake", "take", "drain", "bind", "format"):
                 assert b["latencyMs"][phase]["p50"] is not None
+            # fake_dase predicts on the host: no device phases, no gap
+            for phase in ("dispatch", "deviceWait", "hostGap"):
+                assert b["latencyMs"][phase]["p50"] is None
             assert b["queueDepth"] == 0 and b["inflightBatch"] == 0
+            # every deploy counts its compiles; nothing compiled since boot
+            assert body["compile"]["sinceBoot"] == 0
+            assert body["compile"]["missesSinceBoot"] == 0
         finally:
+            qs.close()
+
+    def test_http_threads_record_read_and_write_per_request(self, trained):
+        qs = QueryService(
+            trained,
+            batching=BatcherConfig(max_batch_size=4, max_batch_delay_ms=0.0),
+        )
+        server, _ = start_background(qs.dispatch)
+        port = server.server_address[1]
+        try:
+            for q in range(6):
+                req = urllib.request.Request(
+                    f"http://127.0.0.1:{port}/queries.json",
+                    data=json.dumps(q).encode(),
+                    headers={"Content-Type": "application/json"},
+                    method="POST",
+                )
+                with urllib.request.urlopen(req, timeout=10) as r:
+                    assert json.loads(r.read()) == 2 * q + 55
+            # the sixth answer is on the wire before its thread records it
+            for _ in range(200):
+                http = qs.stats_json()["http"]
+                if http["requests"] == 6:
+                    break
+                time.sleep(0.01)
+            assert http["requests"] == 6
+            ms = http["latencyMs"]
+            assert set(ms) == {"httpRead", "httpWrite", "inServer"}
+            assert all(ms[k]["p50"] is not None and ms[k]["p50"] >= 0 for k in ms)
+            assert ms["inServer"]["p99"] >= max(
+                ms["httpRead"]["p50"], ms["httpWrite"]["p50"])
+        finally:
+            server.shutdown()
+            server.server_close()
             qs.close()
 
     def test_http_429_carries_retry_after_header(self, trained):
@@ -582,3 +771,6 @@ def test_serving_stats_percentiles_empty_and_filled():
     assert j["completed"] == 4
     assert j["latencyMs"]["total"]["p50"] == 2.0
     assert j["latencyMs"]["total"]["p99"] == 100.0
+    assert j["latencyMs"]["wake"]["p50"] is None  # none was passed
+    s.record_request(5.0, wake_ms=0.25)
+    assert s.to_json()["latencyMs"]["wake"]["p50"] == 0.25
