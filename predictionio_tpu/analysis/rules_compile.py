@@ -327,8 +327,8 @@ def _local_flow(
                         e.value, ast.Name
                     ):
                         e = e.value  # x[i] = tainted -> x carries taint
-                    if not isinstance(e, ast.Name):
-                        continue
+                    if not isinstance(e, ast.Name) or e.id == "_":
+                        continue  # `_` is thrown away: it carries nothing
                     for dest in dests:
                         if e.id not in dest:
                             dest.add(e.id)

@@ -131,6 +131,13 @@ class ServingStats:
       host's own code kept the device waiting (absent for the first
       batch and for handlers without a device).
 
+    ``rowsScored`` and ``rowsReal`` count, over the live batches, the rows
+    the scoring programs were dispatched with and the rows of them that
+    held a query (the batcher's own filler slots are queries to the
+    program: ``paddingOverhead`` has those). Their ratio is what the
+    program's row bucket costs: 1.0 at a full batch, up to 8 for a lone
+    query under the floor of 8 rows (``ops/topk.py`` ``bucket_rows``).
+
     Windows keep the most recent :attr:`WINDOW` samples so percentiles
     track current behavior on a long-running server; counters are
     monotonic over the process lifetime.
@@ -149,6 +156,9 @@ class ServingStats:
         self.batches = 0
         self.batched_queries = 0
         self.padded_queries = 0  # filler slots added for bucket padding
+        #: the handler's own counts (templates/serving_util.py)
+        self.rows_scored = 0
+        self.rows_real = 0
         self.queue_depth = 0  # last observed; gauge
         self.inflight_batch = 0  # 0|1 — one dispatcher thread
         self.batch_size_hist: Counter = Counter()
@@ -199,15 +209,20 @@ class ServingStats:
         queue_wait_ms: Sequence[float] = (),
         phases: Mapping[str, float] | None = None,
         host_gap_ms: float | None = None,
+        rows_scored: int = 0,
+        rows_real: int = 0,
     ) -> None:
         """One dispatched batch: its riders' queue waits, ``handle``, the
         dispatcher's ``phases`` ({name: ms}, names of
-        :data:`BATCH_PHASES`) and the host gap before it."""
+        :data:`BATCH_PHASES`), the host gap before it, and the rows its
+        scoring dispatches took and really held."""
         with self._lock:
             self.inflight_batch = 0
             self.batches += 1
             self.batched_queries += size
             self.padded_queries += bucket - size
+            self.rows_scored += rows_scored
+            self.rows_real += rows_real
             self.batch_size_hist[size] += 1
             self.bucket_hist[bucket] += 1
             if bucket not in self.warmed_buckets:
@@ -256,6 +271,8 @@ class ServingStats:
                 if self.batches
                 else 0.0,
                 "paddingOverhead": round(self.padded_queries / real, 4),
+                "rowsScored": self.rows_scored,
+                "rowsReal": self.rows_real,
                 "batchSizeHist": {
                     str(k): v for k, v in sorted(self.batch_size_hist.items())
                 },

@@ -39,6 +39,7 @@ import numpy as np
 __all__ = [
     "SCORE_PRECISION",
     "bucket_k",
+    "bucket_rows",
     "top_k_scores",
     "top_k_permuted",
     "sort_merge_topk",
@@ -66,6 +67,20 @@ def bucket_k(k: int, n_items: int, floor: int = 16) -> int:
     math; changing the floor or rounding here moves every tier's bucket
     set at once instead of drifting per copy."""
     return min(int(n_items), max(floor, 1 << (max(1, int(k)) - 1).bit_length()))
+
+
+def bucket_rows(n: int, cap: int, floor: int = 8) -> int:
+    """The ONE row bucket of every batch scoring program: ``n`` query
+    rows round up to a power of two, not under ``floor`` and not over
+    ``cap``. A float32 tile has 8 sublanes, so fewer than 8 rows save
+    nothing on the chip and only add shapes to compile at boot; ``cap``
+    (``serving_util.TOPK_CHUNK``) is the most rows one dispatch may
+    score, which bounds the ``[rows, items]`` score matrix. A program's
+    row count is an array extent, so it keys the jit cache like
+    ``bucket_k``'s static ``k`` does: at most ``log2(cap / floor) + 1``
+    shapes ever exist (piolint PIO306 knows this helper by the "bucket"
+    in its name)."""
+    return min(int(cap), max(floor, 1 << (max(1, int(n)) - 1).bit_length()))
 
 
 def sort_merge_topk(

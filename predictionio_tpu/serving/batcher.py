@@ -308,8 +308,19 @@ class MicroBatcher:
         """Pre-compile every bucket shape with ``body`` replicated, largest
         first (jit caches often make smaller related shapes cheaper after
         the big one). Warm-up traffic flows through the REAL batch path so
-        the exact programs live traffic will hit are the ones compiled."""
-        for size in sorted(self._buckets, reverse=True):
+        the exact programs live traffic will hit are the ones compiled.
+
+        Every power of two under the largest bucket is sent as well: a
+        live batch can reach the device with fewer rows than its bucket
+        (a query for an unknown user is answered without one), and a
+        scoring program's rows are a power of two (``ops/topk.py``
+        ``bucket_rows``), so under ``--batch-buckets 32`` a batch of 32
+        with 20 unknown users must not meet a shape first."""
+        sizes = set(self._buckets)
+        sizes.update(
+            1 << s for s in range((self._buckets[-1] - 1).bit_length())
+        )
+        for size in sorted(sizes, reverse=True):
             t0 = time.monotonic()
             try:
                 # n_real=0: every slot is padding — full predict compile,
@@ -320,7 +331,8 @@ class MicroBatcher:
                 # simply compiles on first live traffic instead
                 logger.exception("micro-batcher warm-up failed at size %d", size)
                 continue
-            self.stats.record_warmup(size, (time.monotonic() - t0) * 1e3)
+            if size in self._buckets:
+                self.stats.record_warmup(size, (time.monotonic() - t0) * 1e3)
 
     def dispatcher_alive(self) -> bool:
         """Is the dispatcher thread able to answer submissions? Feeds the
@@ -448,6 +460,7 @@ class MicroBatcher:
         # one cycle of the dispatcher: the previous batch's release, then
         # this batch's take ... handle
         cycle = self._spans.take()
+        counts = self._spans.take_counts()
         phases = spans.durations_ms(cycle)
         dispatched_ns = next(
             (r.end_ns for r in cycle if r.name == "dispatch"), None
@@ -467,6 +480,8 @@ class MicroBatcher:
             queue_wait_ms=waits,
             phases=phases,
             host_gap_ms=host_gap_ms,
+            rows_scored=counts.get("rowsScored", 0),
+            rows_real=counts.get("rowsReal", 0),
         )
         with span("release"):
             for p, result in zip(batch, results):

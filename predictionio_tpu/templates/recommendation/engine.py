@@ -957,6 +957,7 @@ class ALSAlgorithm(JaxAlgorithm):
 
         from predictionio_tpu.ops.als import predict_scores, top_k_items_batch
         from predictionio_tpu.ops.topk import top_k_scores
+        from predictionio_tpu.templates.serving_util import serving_row_buckets
 
         n_users, rank = (int(d) for d in model.user_factors.shape)
         n_items = int(model.item_factors.shape[0])
@@ -964,8 +965,6 @@ class ALSAlgorithm(JaxAlgorithm):
         vec = jax.ShapeDtypeStruct((rank,), f32)
         users = jax.ShapeDtypeStruct((n_users, rank), f32)
         items = jax.ShapeDtypeStruct((n_items, rank), f32)
-        chunk = self.BATCH_PREDICT_CHUNK
-        idx_chunk = jax.ShapeDtypeStruct((chunk,), np.dtype(np.int32))
         out = {"predict_scores": jax_export.export(predict_scores)(vec, items)}
         for kb in buckets:
             # bind the static k through a jitted closure — jax.export
@@ -973,11 +972,16 @@ class ALSAlgorithm(JaxAlgorithm):
             out[f"top_k_scores_b{kb}"] = jax_export.export(
                 jax.jit(lambda s, _k=kb: top_k_scores(s, _k))
             )(jax.ShapeDtypeStruct((n_items,), f32))
-            out[f"top_k_items_batch_c{chunk}_b{kb}"] = jax_export.export(
-                jax.jit(
-                    lambda u, um, im, _k=kb: top_k_items_batch(u, um, im, _k)
-                )
-            )(idx_chunk, users, items)
+            batch = jax.jit(
+                lambda u, um, im, _k=kb: top_k_items_batch(u, um, im, _k)
+            )
+            # one program per row bucket a deploy can dispatch: those of
+            # a default batcher's batches, and a full batchpredict chunk
+            for rows in serving_row_buckets(self.BATCH_PREDICT_CHUNK):
+                out[f"top_k_items_batch_c{rows}_b{kb}"] = jax_export.export(
+                    batch
+                )(jax.ShapeDtypeStruct((rows,), np.dtype(np.int32)),
+                  users, items)
         return out
 
     def aot_warm_serving(self, model: ALSModel) -> None:
@@ -1284,9 +1288,9 @@ class ALSAlgorithm(JaxAlgorithm):
             )
         )
 
-    #: queries per device dispatch / host GEMM (shared tuning constant —
-    #: see serving_util.TOPK_CHUNK; kept as a class attribute so tests
-    #: can shrink it to force multi-chunk coverage)
+    #: the most queries one device dispatch / host GEMM scores (a cap,
+    #: not a shape — see serving_util.TOPK_CHUNK; kept as a class
+    #: attribute so tests can shrink it to force multi-chunk coverage)
     BATCH_PREDICT_CHUNK = TOPK_CHUNK
 
     def batch_predict(

@@ -8,20 +8,41 @@ from typing import Callable, Iterator, Sequence
 
 import numpy as np
 
-from predictionio_tpu.utils.spans import span
+from predictionio_tpu.utils.spans import count, span
 
-__all__ = ["device_latency_probe", "chunked_topk", "aligned_factor_init"]
+__all__ = [
+    "device_latency_probe", "chunked_topk", "serving_row_buckets",
+    "aligned_factor_init",
+]
 
 logger = logging.getLogger(__name__)
 
-#: queries per device dispatch / host GEMM in :func:`chunked_topk` — one
-#: compiled shape, so every chunk (the last padded up) reuses the same
-#: XLA program
+#: the MOST queries one device dispatch / host GEMM of
+#: :func:`chunked_topk` may score — a cap, not a shape: it bounds the
+#: ``[rows, items]`` float32 score matrix (2,048 x 624,961 x 4 B = 5.1 GB
+#: at the benchmark's catalog). A chunk's program is shaped by the rows
+#: the chunk really holds (``ops.topk.bucket_rows``: pow2, floor 8), so
+#: only a full chunk of ``pio batchpredict`` scores this many
 TOPK_CHUNK = 2048
 
 
+def serving_row_buckets(chunk: int = TOPK_CHUNK) -> list[int]:
+    """Every row bucket :func:`chunked_topk` can dispatch for a batch of
+    a default micro-batcher (at most ``BatcherConfig.max_batch_size``
+    queries), and the cap (a full ``pio batchpredict`` chunk): the
+    program shapes an ``--aot`` export must hold for a deploy to serve
+    with zero serve-time compiles."""
+    from predictionio_tpu.ops.topk import bucket_rows
+    from predictionio_tpu.serving.batcher import BatcherConfig
+
+    return sorted(
+        {bucket_rows(n, chunk)
+         for n in range(1, BatcherConfig.max_batch_size + 1)} | {chunk}
+    )
+
+
 def _drain_staged(
-    staged: list, n_items: int, chunk: int
+    staged: list, n_items: int
 ) -> Iterator[tuple[list, list, list]]:
     """Drain chunk-staged device results with ONE link crossing: concat
     all chunks' ids/scores on device, transfer once, then trim each
@@ -41,7 +62,7 @@ def _drain_staged(
             idx_all = np.asarray(staged[0][1])
             score_all = np.asarray(staged[0][2])
     off = 0
-    for part, _, _ in staged:
+    for part, idx_b, _ in staged:
         with span("format"):
             ids_l, scores_l = [], []
             for r in range(len(part)):
@@ -49,17 +70,25 @@ def _drain_staged(
                 ids_l.append(idx_all[off + r][keep].tolist())
                 scores_l.append(score_all[off + r][keep].tolist())
         yield part, ids_l, scores_l
-        off += chunk
+        # chunks hold unequal rows (the tail's bucket is its own)
+        off += int(idx_b.shape[0])
 
 
-def _padded_index(part: Sequence[tuple], chunk: int) -> np.ndarray:
-    """The chunk's user rows as the padded ``int32[chunk]`` every scoring
-    program takes (one compiled shape whatever the chunk holds)."""
+def _row_bucket_index(part: Sequence[tuple], chunk: int) -> np.ndarray:
+    """The chunk's user rows as the zero-padded
+    ``int32[bucket_rows(len(part), chunk)]`` every scoring program takes:
+    the program's rows follow the rows the chunk holds, never over
+    ``chunk``. Counts both (``rowsScored``, ``rowsReal``) on the
+    thread's span collector, once a chunk."""
+    from predictionio_tpu.ops.topk import bucket_rows
+
     with span("lookup"):
-        padded = np.zeros(chunk, np.int32)
+        padded = np.zeros(bucket_rows(len(part), chunk), np.int32)
         padded[: len(part)] = np.fromiter(
             (u for _, u, _ in part), np.int32, len(part)
         )
+    count("rowsScored", padded.size)
+    count("rowsReal", len(part))
     return padded
 
 
@@ -75,7 +104,12 @@ def chunked_topk(
     k buckets to the next power of two (floor 16): the jitted kernel's k
     is static, so raw ``max(num)`` would recompile per distinct value — a
     bounded bucket set keeps one XLA program per bucket; each query trims
-    its own k from the padded result. On device, dispatches stay async
+    its own k from the padded result. A chunk's ROWS bucket the same way
+    (``ops.topk.bucket_rows``: pow2, floor 8, capped at ``chunk``): an
+    online batch of 32 scores 32 rows and only a full ``pio
+    batchpredict`` chunk scores ``chunk``, so at most nine row shapes
+    (8 ... 2048) exist per k bucket and chunks of one call may hold
+    unequal rows. On device, dispatches stay async
     across chunks and ALL results concatenate on device to cross to the
     host in ONE transfer instead of one per chunk. ``tolist()`` converts
     whole chunks to Python scalars at C speed.
@@ -137,7 +171,7 @@ def chunked_topk(
         ann_staged: list = []
         for lo in range(0, len(valid), chunk):
             part = list(valid[lo : lo + chunk])
-            padded = _padded_index(part, chunk)
+            padded = _row_bucket_index(part, chunk)
             with span("dispatch"):
                 if user_quantized:
                     # --quantize: dequantize ONLY the chunk's user rows
@@ -169,10 +203,12 @@ def chunked_topk(
                     )
                 else:
                     # unpinned model: gather the chunk's user rows on host
-                    # so each dispatch uploads [chunk, K] — NOT the whole
+                    # so each dispatch uploads [rows, K] — NOT the whole
                     # user table, which would dwarf the nprobe savings
                     # per call
-                    qv = np.zeros((chunk, user_mat.shape[1]), np.float32)
+                    qv = np.zeros(
+                        (padded.size, user_mat.shape[1]), np.float32
+                    )
                     qv[: len(part)] = np.asarray(user_mat)[padded[: len(part)]]
                     idx_b, score_b = ivf.ivf_topk_batch(
                         jnp.asarray(qv), ann.index, k_max, ann.nprobe
@@ -181,7 +217,7 @@ def chunked_topk(
             ann_staged.append((part, idx_b, score_b))
         # same staging discipline as the exact device path below: keep
         # dispatches async across chunks, cross the link ONCE
-        yield from _drain_staged(ann_staged, n_items, chunk)
+        yield from _drain_staged(ann_staged, n_items)
         return
     if quant is not None:
         from predictionio_tpu.ops import quant as quant_ops
@@ -189,19 +225,21 @@ def chunked_topk(
         q_staged: list = []
         for lo in range(0, len(valid), chunk):
             part = list(valid[lo : lo + chunk])
-            padded = _padded_index(part, chunk)
+            padded = _row_bucket_index(part, chunk)
             with span("dispatch"):
                 idx_b, score_b = quant_ops.run_topk(
                     quant, user_mat, item_mat, padded, k_max, shards=shards
                 )
             q_staged.append((part, idx_b, score_b))
-        yield from _drain_staged(q_staged, n_items, chunk)
+        yield from _drain_staged(q_staged, n_items)
         return
     on_device = not isinstance(item_mat, np.ndarray)
     staged: list[tuple[list, object, object]] = []
     for lo in range(0, len(valid), chunk):
         part = list(valid[lo : lo + chunk])
-        padded = _padded_index(part, chunk)
+        # the host GEMM has no compiled shape to share: its cap is the
+        # rows it holds, so nothing is padded (rows scored = rows real)
+        padded = _row_bucket_index(part, chunk if on_device else len(part))
         if shards is not None:
             from predictionio_tpu.parallel import sharding
 
@@ -213,7 +251,7 @@ def chunked_topk(
             from predictionio_tpu.ops.als import top_k_items_batch
 
             with span("dispatch"):
-                aot_key = f"top_k_items_batch_c{chunk}_b{k_max}"
+                aot_key = f"top_k_items_batch_c{padded.size}_b{k_max}"
                 fn = aot.get(aot_key) if aot is not None else None
                 if fn is not None:
                     try:
@@ -228,10 +266,7 @@ def chunked_topk(
         else:
             from predictionio_tpu.ops.topk import top_k_host
 
-            scores = (
-                np.asarray(user_mat)[padded[: len(part)]]
-                @ np.asarray(item_mat).T
-            )  # [B, I]
+            scores = np.asarray(user_mat)[padded] @ np.asarray(item_mat).T
             # descending score, ties broken by ascending item index —
             # the same rule lax.top_k uses, so host and device paths
             # agree wherever the float scores do (shared helper:
@@ -254,12 +289,12 @@ def chunked_topk(
                 idx_all = np.asarray(staged[0][1])
                 score_all = np.asarray(staged[0][2])
         off = 0
-        for part, _, _ in staged:
+        for part, idx_b, _ in staged:
             with span("format"):
                 ids = idx_all[off : off + len(part)].tolist()
                 scs = score_all[off : off + len(part)].tolist()
             yield part, ids, scs
-            off += chunk
+            off += int(idx_b.shape[0])
         return
     for part, idx_b, score_b in staged:
         with span("format"):

@@ -536,7 +536,7 @@ class TwoTowerAlgorithm(JaxAlgorithm):
 
         from predictionio_tpu.ops.als import predict_scores, top_k_items_batch
         from predictionio_tpu.ops.topk import top_k_scores
-        from predictionio_tpu.templates.serving_util import TOPK_CHUNK
+        from predictionio_tpu.templates.serving_util import serving_row_buckets
 
         n_users, rank = (int(d) for d in model.user_vecs.shape)
         n_items = int(model.item_vecs.shape[0])
@@ -544,17 +544,19 @@ class TwoTowerAlgorithm(JaxAlgorithm):
         vec = jax.ShapeDtypeStruct((rank,), f32)
         users = jax.ShapeDtypeStruct((n_users, rank), f32)
         items = jax.ShapeDtypeStruct((n_items, rank), f32)
-        idx_chunk = jax.ShapeDtypeStruct((TOPK_CHUNK,), np.dtype(np.int32))
         out = {"predict_scores": jax_export.export(predict_scores)(vec, items)}
         for kb in buckets:
             out[f"top_k_scores_b{kb}"] = jax_export.export(
                 jax.jit(lambda s, _k=kb: top_k_scores(s, _k))
             )(jax.ShapeDtypeStruct((n_items,), f32))
-            out[f"top_k_items_batch_c{TOPK_CHUNK}_b{kb}"] = jax_export.export(
-                jax.jit(
-                    lambda u, um, im, _k=kb: top_k_items_batch(u, um, im, _k)
-                )
-            )(idx_chunk, users, items)
+            batch = jax.jit(
+                lambda u, um, im, _k=kb: top_k_items_batch(u, um, im, _k)
+            )
+            for rows in serving_row_buckets():
+                out[f"top_k_items_batch_c{rows}_b{kb}"] = jax_export.export(
+                    batch
+                )(jax.ShapeDtypeStruct((rows,), np.dtype(np.int32)),
+                  users, items)
         return out
 
     def aot_warm_serving(self, model: TwoTowerServingModel) -> None:

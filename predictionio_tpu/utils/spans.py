@@ -41,6 +41,7 @@ __all__ = [
     "CompileLedger",
     "SpanRecord",
     "bind",
+    "count",
     "current",
     "durations_ms",
     "process_age_s",
@@ -68,7 +69,7 @@ class Collector:
     #: a thread whose owner never takes keeps the newest spans only
     MAX_SPANS = 1024
 
-    __slots__ = ("annotate", "seq", "_closed", "_open")
+    __slots__ = ("annotate", "seq", "_closed", "_open", "_counts")
 
     def __init__(self, annotate: bool = False):
         #: leaf spans of this thread also go into the profiler's trace
@@ -76,10 +77,17 @@ class Collector:
         self.seq = 0
         self._closed: deque = deque(maxlen=self.MAX_SPANS)
         self._open: list[str] = []
+        #: :func:`count` totals since the last :meth:`take_counts`; the
+        #: names are the program's own, a handful
+        self._counts: dict[str, int] = {}
 
     def take(self) -> list[SpanRecord]:
         out = list(self._closed)
         self._closed.clear()
+        return out
+
+    def take_counts(self) -> dict[str, int]:
+        out, self._counts = self._counts, {}
         return out
 
 
@@ -93,6 +101,15 @@ def bind(collector: Collector | None) -> Collector | None:
 
 def current() -> Collector | None:
     return getattr(_bound, "collector", None)
+
+
+def count(name: str, n: int) -> None:
+    """Add ``n`` to the count ``name`` of the collector bound to the
+    current thread, if one is: work done at a span's boundary (rows
+    scored by a dispatch), read where the spans are."""
+    collector = getattr(_bound, "collector", None)
+    if collector is not None:
+        collector._counts[name] = collector._counts.get(name, 0) + n
 
 
 class span:
