@@ -97,8 +97,13 @@ def _percentiles(samples, points=(50, 95, 99)) -> dict[str, float]:
 #: is a window of ``latencyMs``
 BATCH_PHASES = (
     "release", "take", "drain", "batchForm",
-    "bind", "lookup", "dispatch", "deviceWait", "format",
+    "bind", "lookup", "filterLookup", "filterBuild",
+    "dispatch", "deviceWait", "format",
 )
+
+#: what a handler that filters its answers counts on the dispatcher's
+#: collector as ``filter.<name>`` (templates/ecommerce/engine.py)
+FILTER_COUNTS = ("excludedIds", "categoryRows", "hostPath", "shortAnswers")
 
 
 class ServingStats:
@@ -121,7 +126,9 @@ class ServingStats:
       (padding + bookkeeping);
     * ``handle`` — the ``handle_batch`` call itself, and inside it
       ``bind`` (query objects from bodies), ``lookup`` (ids to the
-      padded index vector), ``dispatch`` (the call into the scoring
+      padded index vector), ``filterLookup`` (a filtering engine's
+      store reads for the batch), ``filterBuild`` (its rules into
+      device inputs), ``dispatch`` (the call into the scoring
       program until it returns), ``deviceWait`` (the readback that
       blocks until the device is done), ``format`` (lists, result
       objects, serve tail); a handler that has no such boundary records
@@ -137,6 +144,13 @@ class ServingStats:
     program: ``paddingOverhead`` has those). Their ratio is what the
     program's row bucket costs: 1.0 at a full batch, up to 8 for a lone
     query under the floor of 8 rows (``ops/topk.py`` ``bucket_rows``).
+
+    ``filter`` counts what a filtering engine (the e-commerce template)
+    did over the live batches: ``excludedIds`` (item ids its rows left
+    out: seen, unavailable, black-listed), ``categoryRows`` (rows that
+    named a category), ``hostPath`` (queries answered by the host
+    ``predict``: a white list or an unknown user), ``shortAnswers``
+    (rows the rules left fewer than ``num`` items).
 
     Windows keep the most recent :attr:`WINDOW` samples so percentiles
     track current behavior on a long-running server; counters are
@@ -159,6 +173,7 @@ class ServingStats:
         #: the handler's own counts (templates/serving_util.py)
         self.rows_scored = 0
         self.rows_real = 0
+        self.filter_counts = dict.fromkeys(FILTER_COUNTS, 0)
         self.queue_depth = 0  # last observed; gauge
         self.inflight_batch = 0  # 0|1 — one dispatcher thread
         self.batch_size_hist: Counter = Counter()
@@ -211,11 +226,13 @@ class ServingStats:
         host_gap_ms: float | None = None,
         rows_scored: int = 0,
         rows_real: int = 0,
+        counts: Mapping[str, int] | None = None,
     ) -> None:
         """One dispatched batch: its riders' queue waits, ``handle``, the
         dispatcher's ``phases`` ({name: ms}, names of
-        :data:`BATCH_PHASES`), the host gap before it, and the rows its
-        scoring dispatches took and really held."""
+        :data:`BATCH_PHASES`), the host gap before it, the rows its
+        scoring dispatches took and really held, and the handler's other
+        ``counts`` (those named ``filter.<one of FILTER_COUNTS>``)."""
         with self._lock:
             self.inflight_batch = 0
             self.batches += 1
@@ -223,6 +240,10 @@ class ServingStats:
             self.padded_queries += bucket - size
             self.rows_scored += rows_scored
             self.rows_real += rows_real
+            for name in FILTER_COUNTS:
+                self.filter_counts[name] += (counts or {}).get(
+                    "filter." + name, 0
+                )
             self.batch_size_hist[size] += 1
             self.bucket_hist[bucket] += 1
             if bucket not in self.warmed_buckets:
@@ -273,6 +294,7 @@ class ServingStats:
                 "paddingOverhead": round(self.padded_queries / real, 4),
                 "rowsScored": self.rows_scored,
                 "rowsReal": self.rows_real,
+                "filter": dict(self.filter_counts),
                 "batchSizeHist": {
                     str(k): v for k, v in sorted(self.batch_size_hist.items())
                 },

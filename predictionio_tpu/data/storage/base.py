@@ -393,6 +393,24 @@ class LEvents(abc.ABC):
         ``reversed=True`` returns newest-first (requires an entity filter in
         the reference; here always supported)."""
 
+    def find_by_entities(
+        self, app_id: int, entities: Sequence[tuple[str, str]],
+        channel_id: int | None = None,
+        event_names: Sequence[str] | None = None,
+    ) -> dict[tuple[str, str], list[Event]]:
+        """The events of each ``(entity type, entity id)`` of ``entities``
+        (every pair a key, its events in ``find``'s order): what a served
+        batch asks of the store at once. The base makes one :meth:`find`
+        per entity; a driver that can answer all of them in one read
+        overrides it (``columnar``)."""
+        return {
+            (etype, eid): list(self.find(
+                app_id, channel_id, entity_type=etype, entity_id=eid,
+                event_names=event_names,
+            ))
+            for etype, eid in dict.fromkeys(entities)
+        }
+
     def close(self) -> None:  # optional resource hook
         pass
 

@@ -128,6 +128,21 @@ def _merge_vocabs(
     return np.concatenate(out) if out else np.zeros(0, np.int32), merged
 
 
+def _codes_of(vocab: np.ndarray, values: Iterable[str]) -> np.ndarray:
+    """The codes of those ``values`` a (sorted) segment vocabulary holds:
+    one binary search for all of them, in the vocabulary's own string
+    width (a wider needle would make numpy widen the whole vocabulary
+    first; a value longer than its widest entry is not in it)."""
+    widest = vocab.dtype.itemsize // 4
+    values = np.asarray(
+        [v for v in values if len(v) <= widest], dtype=vocab.dtype
+    )
+    if not (vocab.size and values.size):
+        return np.zeros(0, np.int64)
+    at = np.minimum(np.searchsorted(vocab, values), vocab.size - 1)
+    return at[vocab[at] == values]
+
+
 @dataclasses.dataclass
 class _Segment:
     """Loaded segment columns (decoded lazily from one ``seg-*.npz``)."""
@@ -157,9 +172,29 @@ class _Segment:
     #: (Explicit-id segments without the flag are compacted tails, which
     #: keeps pre-flag stores reading exactly as before.)
     bulk: bool = False
+    #: (argsort of ``eid_code``, ``eid_code`` in that order), made by the
+    #: first :meth:`entity_rows`
+    _by_entity: tuple | None = None
 
     def __len__(self) -> int:
         return int(self.ev_code.shape[0])
+
+    def entity_rows(self, entity_ids: Sequence[str]) -> np.ndarray:
+        """Rows whose entity id is one of ``entity_ids``, ascending: a
+        binary search in the entity column's sort order, which is worked
+        out on the first such read and kept with the (immutable, cached)
+        segment — what a serving-time read by entity costs after that is
+        the rows it returns, not the segment."""
+        if self._by_entity is None:
+            order = np.argsort(self.eid_code, kind="stable")
+            self._by_entity = (order, self.eid_code[order])
+        order, codes = self._by_entity
+        want = _codes_of(self.eid_vocab, entity_ids)
+        lo = np.searchsorted(codes, want, side="left")
+        hi = np.searchsorted(codes, want, side="right")
+        return np.sort(np.concatenate(
+            [order[a:b] for a, b in zip(lo, hi)] or [np.zeros(0, np.int64)]
+        ))
 
     def row_event(self, row: int) -> Event:
         props: dict[str, Any] = {}
@@ -295,6 +330,9 @@ class _ColumnarEvents(LEvents):
             self._CACHE_SEGMENTS if cache_segments is None else cache_segments
         )
         self._seg_seq = 0
+        #: (raw tail lines, the events they decode to) of the last
+        #: :meth:`find`; events are immutable, so readers share them
+        self._tail_parsed: tuple[list, list] | None = None
 
     # ---------------------------------------------------------- paths
     def _stream_dir(self, app_id: int, channel_id: int | None) -> str:
@@ -1562,26 +1600,33 @@ class _ColumnarEvents(LEvents):
         target_entity_id: str | None = None,
         limit: int | None = None,
         reversed: bool = False,
+        entities: Sequence[tuple[str, str]] | None = None,
     ) -> Iterator[Event]:
         """Compat scan: decodes matching rows into Events, globally sorted
         by (event_time, event_id). Materializes the matching set — bulk
-        training must use :meth:`find_columns` instead."""
+        training must use :meth:`find_columns` instead. ``entities``
+        (this driver's own, behind :meth:`find_by_entities`) keeps the
+        events of any of those ``(entity type, entity id)`` pairs: one
+        scan for a batch of them."""
         d = self._stream_dir(app_id, channel_id)
         seg_paths, tail_lines, tomb = self._snapshot(d)
         tail_tomb, seg_tomb = self._split_tombstones(tomb)
         out: list[Event] = []
 
+        wanted = None if entities is None else set(entities)
+
         def keep(e: Event) -> bool:
             return BaseStorageClient.match_filters(
                 e, start_time, until_time, entity_type, entity_id,
                 event_names, target_entity_type, target_entity_id,
-            )
+            ) and (wanted is None or (e.entity_type, e.entity_id) in wanted)
 
         for path in seg_paths:
             seg = self._segment(path)
             rows = self._matching_rows(
                 seg, start_time, until_time, entity_type, entity_id,
                 event_names, target_entity_type, target_entity_id,
+                entities,
             )
             if seg.ids is not None:
                 # explicit-id (compacted) segment: tombstones match by id
@@ -1593,7 +1638,14 @@ class _ColumnarEvents(LEvents):
             for row in rows:
                 if int(row) not in dead:
                     out.append(seg.row_event(int(row)))
-        for e in self._decode_tail_lines(tail_lines):
+        # a serving-time reader comes back every few milliseconds to a
+        # tail that has not moved: its lines are parsed once
+        parsed = self._tail_parsed
+        if parsed is None or parsed[0] != tail_lines:
+            parsed = (tail_lines, list(self._decode_tail_lines(tail_lines)))
+            with self._lock:
+                self._tail_parsed = parsed
+        for e in parsed[1]:
             if e.event_id not in tail_tomb and keep(e):
                 out.append(e)
         out.sort(key=BaseStorageClient.sorted_events_key, reverse=reversed)
@@ -1603,6 +1655,20 @@ class _ColumnarEvents(LEvents):
             if limit > 0:  # negative = unbounded (contract)
                 out = out[:limit]
         return iter(out)
+
+    def find_by_entities(
+        self, app_id: int, entities: Sequence[tuple[str, str]],
+        channel_id: int | None = None,
+        event_names: Sequence[str] | None = None,
+    ) -> dict[tuple[str, str], list[Event]]:
+        """One snapshot and one scan for all of ``entities`` (the base
+        class makes one read per entity)."""
+        out: dict[tuple[str, str], list[Event]] = {e: [] for e in entities}
+        for e in self.find(
+            app_id, channel_id, event_names=event_names, entities=list(out),
+        ):
+            out[e.entity_type, e.entity_id].append(e)
+        return out
 
     @staticmethod
     def _matching_rows(
@@ -1614,14 +1680,32 @@ class _ColumnarEvents(LEvents):
         event_names,
         target_entity_type,
         target_entity_id,
+        entities=None,
     ) -> np.ndarray:
-        """Vectorized filter over one segment's columns -> row indices."""
-        return np.flatnonzero(
-            _ColumnarEvents._matching_mask(
-                seg, start_time, until_time, entity_type, entity_id,
+        """Vectorized filter over one segment's columns -> row indices.
+        An entity filter goes first, through the segment's entity order
+        (:meth:`_Segment.entity_rows`); what it keeps is a handful of
+        rows, and the other filters read only those."""
+        match = _ColumnarEvents._matching_mask
+        if entity_id is None and entities is None:
+            return np.flatnonzero(match(
+                seg, start_time, until_time, entity_type, None,
                 event_names, target_entity_type, target_entity_id,
-            )
-        )
+            ))
+        by_type: dict[str, list[str]] = {}
+        for etype, eid in entities or [(entity_type, entity_id)]:
+            by_type.setdefault(etype, []).append(eid)
+        found = []
+        for etype, ids in by_type.items():
+            rows = seg.entity_rows(ids)
+            found.append(rows[match(
+                seg, None, None, etype, None, None, None, None, rows=rows,
+            )])
+        rows = np.sort(np.concatenate(found))
+        return rows[match(
+            seg, start_time, until_time, entity_type, None,
+            event_names, target_entity_type, target_entity_id, rows=rows,
+        )]
 
     @staticmethod
     def _matching_mask(
@@ -1633,8 +1717,15 @@ class _ColumnarEvents(LEvents):
         event_names,
         target_entity_type,
         target_entity_id,
+        rows: np.ndarray | None = None,
     ) -> np.ndarray:
-        mask = np.ones(len(seg), dtype=bool)
+        """The filters as a mask over the segment's rows, or over
+        ``rows`` of them."""
+
+        def col(a: np.ndarray) -> np.ndarray:
+            return a if rows is None else a[rows]
+
+        mask = np.ones(len(seg) if rows is None else rows.size, dtype=bool)
 
         def code_of(vocab: np.ndarray, value: str) -> int:
             i = np.searchsorted(vocab, value)
@@ -1642,21 +1733,30 @@ class _ColumnarEvents(LEvents):
                 return int(i)
             return -2  # matches nothing (tid/ttype use -1 for "none")
 
+        def among(vocab: np.ndarray, values) -> np.ndarray:
+            """A table over ``vocab``: which codes are one of ``values``
+            (one gather through it, however many are asked for)."""
+            table = np.zeros(vocab.size, dtype=bool)
+            table[_codes_of(vocab, values)] = True
+            return table
+
         if start_time is not None:
-            mask &= seg.t_us >= _to_us(start_time)
+            mask &= col(seg.t_us) >= _to_us(start_time)
         if until_time is not None:
-            mask &= seg.t_us < _to_us(until_time)
-        if entity_type is not None:
-            mask &= seg.etype_code == code_of(seg.etype_vocab, entity_type)
-        if entity_id is not None:
-            mask &= seg.eid_code == code_of(seg.eid_vocab, entity_id)
+            mask &= col(seg.t_us) < _to_us(until_time)
+        for codes, vocab, value in (
+            (seg.etype_code, seg.etype_vocab, entity_type),
+            (seg.eid_code, seg.eid_vocab, entity_id),
+            (seg.ttype_code, seg.ttype_vocab, target_entity_type),
+            (seg.tid_code, seg.tid_vocab, target_entity_id),
+        ):
+            if value is not None:
+                code = code_of(vocab, value)
+                if code < 0:  # the segment holds no such value: no scan
+                    return np.zeros(mask.size, dtype=bool)
+                mask &= col(codes) == code
         if event_names is not None:
-            codes = [code_of(seg.ev_vocab, n) for n in event_names]
-            mask &= np.isin(seg.ev_code, [c for c in codes if c >= 0])
-        if target_entity_type is not None:
-            mask &= seg.ttype_code == code_of(seg.ttype_vocab, target_entity_type)
-        if target_entity_id is not None:
-            mask &= seg.tid_code == code_of(seg.tid_vocab, target_entity_id)
+            mask &= among(seg.ev_vocab, event_names)[col(seg.ev_code)]
         return mask
 
     # ------------------------------------------------- bulk (PEvents side)

@@ -172,6 +172,26 @@ class _LEventStore:
             ),
         )
 
+    def find_by_entities(
+        self,
+        app_name: str,
+        entities: Sequence[tuple[str, str]],
+        channel_name: str | None = None,
+        event_names: Sequence[str] | None = None,
+        timeout: float | None = None,
+    ) -> dict[tuple[str, str], list[Event]]:
+        """:meth:`find_by_entity` for a batch of ``(entity type, entity
+        id)`` pairs: every pair a key, its events oldest first. One read
+        where the driver can (``LEvents.find_by_entities``), else one per
+        entity."""
+        app_id, channel_id = resolve_app(app_name, channel_name)
+        return dict(self._scan(
+            timeout,
+            lambda: Storage.get_l_events().find_by_entities(
+                app_id, entities, channel_id, event_names=event_names,
+            ).items(),
+        ))
+
     def find(
         self,
         app_name: str,

@@ -60,7 +60,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, reshard
 
-from predictionio_tpu.ops.topk import SCORE_PRECISION, top_k_scores
+from predictionio_tpu.ops.topk import (
+    NO_ITEM,
+    SCORE_PRECISION,
+    sort_merge_topk,
+    top_k_scores,
+)
 from predictionio_tpu.utils.spans import span
 
 logger = logging.getLogger(__name__)
@@ -1634,3 +1639,127 @@ def top_k_items_batch(
     # byte-compatible shapes, and the (chunk,) int32 index buffer can
     # never alias the (chunk, k>=16) outputs — the donation would only
     # produce "donated buffers were not usable" warnings.
+
+
+#: items one tile of :func:`top_k_items_filtered` holds: the ``[rows,
+#: tile]`` float32 scores of 32 rows are 64 MB, whatever the catalog
+FILTER_TILE = 1 << 19
+
+#: the most bytes those scores may take: what bounds the rows of one
+#: dispatch (64 at a full tile; the ``[tiles, rows, tile]`` mask of the
+#: excluded ids is a quarter of the catalog-wide scores)
+FILTER_SCORE_BYTES = 1 << 27
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _set_tile(tiles: jax.Array, rows: jax.Array, t: jax.Array) -> jax.Array:
+    return tiles.at[t].set(rows.T)
+
+
+def tile_items(table: np.ndarray, fill, tile: int | None = None) -> jax.Array:
+    """A per-item host table ``[I, C]`` as the device array ``[tiles, C,
+    width]`` that :func:`top_k_items_filtered` scans: cut along the items
+    into tiles of ``width`` (``tile``, by default ``FILTER_TILE``, or the
+    whole catalog rounded up to 128 lanes when that is less), each
+    transposed so the items lie along the lanes, the last padded with
+    ``fill``. Tiles cross the link one at a time into a donated buffer:
+    the device never holds the table twice and the host never a
+    transposed copy."""
+    table = np.asarray(table)
+    n, c = table.shape
+    width = min(int(tile or FILTER_TILE), -(-max(n, 1) // 128) * 128)
+    n_tiles = -(-max(n, 1) // width)
+    tiles = jnp.full((n_tiles, c, width), fill, table.dtype)
+    for t in range(n_tiles):
+        rows = table[t * width : (t + 1) * width]
+        if rows.shape[0] < width:
+            rows = np.concatenate(
+                [rows, np.full((width - rows.shape[0], c), fill, table.dtype)]
+            )
+        # one tile in flight: the loop would else run ahead of the link and
+        # park every tile on the device beside the buffer it is bound for
+        tiles = _set_tile(tiles, rows, t).block_until_ready()
+    return tiles
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def top_k_items_filtered(
+    user_vecs: jax.Array,
+    item_tiles: jax.Array,
+    code_tiles: jax.Array,
+    blocked: jax.Array,
+    wanted: jax.Array,
+    excluded: jax.Array,
+    k: int,
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`top_k_items_batch` under per-row business rules: the exact
+    top ``k`` of the items each row is ALLOWED, by the one tie rule
+    (descending score, ascending id), in one dispatch that never holds a
+    catalog-wide score matrix.
+
+    ``user_vecs`` ``f32[rows, rank]`` are the batch's user rows, gathered
+    by the caller: a row gather from a ``[users, rank]`` table in here
+    makes XLA copy the whole table into a row-major layout first, on
+    every dispatch (1 GB at 2 M users; compiled for a v5e, PERF.md).
+    ``item_tiles`` ``f32[tiles, rank, width]`` and ``code_tiles``
+    ``i32[tiles, C, width]`` are :func:`tile_items` of the item factors
+    and of the items' category codes (``-1`` = none; an item may carry
+    ``C``). ``blocked`` ``bool[tiles, width]`` marks what no row may be
+    given: padding past the catalog, and items out of stock. Per row:
+    ``wanted`` ``i32[rows, W]`` the category codes asked for (padded
+    with ``-2``; a row whose first entry is negative names no category
+    and allows all), ``excluded`` ``i32[rows, E]`` item ids to leave out
+    (padded with ``NO_ITEM``, which points past the tiles and is
+    dropped).
+
+    The excluded ids are scattered once into a ``[tiles, rows, width]``
+    mask; then each tile is scored (``U_b @ V_tile`` at
+    ``SCORE_PRECISION``), masked to ``-inf`` where not allowed, cut to
+    its own top ``k`` and merged into the carried ``[rows, k]`` by
+    :func:`~predictionio_tpu.ops.topk.sort_merge_topk`'s two keys — the
+    ``lax.top_k`` of the masked full row. A slot no allowed item fills
+    comes back as ``(NO_ITEM, -inf)``."""
+    n_tiles, _, width = item_tiles.shape
+    rows = user_vecs.shape[0]
+    with jax.named_scope("pio_topk_mask"):
+        row_of = jnp.broadcast_to(
+            jnp.arange(rows, dtype=jnp.int32)[:, None], excluded.shape
+        )
+        left_out = jnp.zeros((n_tiles, rows, width), jnp.bool_).at[
+            excluded // width, row_of, excluded % width
+        ].set(True, mode="drop")
+        names_none = wanted[:, :1] < 0
+    kt = min(k, width)
+
+    def one_tile(best, tile):
+        t, v_t, codes_t, blocked_t, left_out_t = tile
+        with jax.named_scope("pio_topk_score"):
+            scores = jnp.matmul(user_vecs, v_t, precision=SCORE_PRECISION)
+        with jax.named_scope("pio_topk_mask"):
+            in_category = jnp.any(
+                codes_t[None, :, None, :] == wanted[:, None, :, None],
+                axis=(1, 2),
+            )
+            allowed = (in_category | names_none) & ~(blocked_t[None] | left_out_t)
+            scores = jnp.where(allowed, scores, -jnp.inf)
+        with jax.named_scope("pio_topk_select"):
+            vals, pos = jax.lax.top_k(scores, kt)
+        with jax.named_scope("pio_topk_merge"):
+            ids = jnp.where(vals > -jnp.inf, pos + t * width, NO_ITEM)
+            ids, vals = sort_merge_topk(
+                jnp.concatenate([best[1], vals], axis=1),
+                jnp.concatenate([best[0], ids], axis=1),
+                k,
+            )
+        return (ids, vals), None
+
+    best = (
+        jnp.full((rows, k), NO_ITEM, jnp.int32),
+        jnp.full((rows, k), -jnp.inf, jnp.float32),
+    )
+    (ids, vals), _ = jax.lax.scan(
+        one_tile, best,
+        (jnp.arange(n_tiles, dtype=jnp.int32), item_tiles, code_tiles,
+         blocked, left_out),
+    )
+    return ids, vals

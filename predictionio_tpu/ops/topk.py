@@ -40,6 +40,8 @@ __all__ = [
     "SCORE_PRECISION",
     "bucket_k",
     "bucket_rows",
+    "bucket_width",
+    "NO_ITEM",
     "top_k_scores",
     "top_k_permuted",
     "sort_merge_topk",
@@ -81,6 +83,21 @@ def bucket_rows(n: int, cap: int, floor: int = 8) -> int:
     shapes ever exist (piolint PIO306 knows this helper by the "bucket"
     in its name)."""
     return min(int(cap), max(floor, 1 << (max(1, int(n)) - 1).bit_length()))
+
+
+def bucket_width(n: int, floor: int) -> int:
+    """The pow2 bucket of a per-row list's padded WIDTH (the excluded ids
+    and the wanted categories of a filtered top-K): ``n`` rounds up to a
+    power of two, not under ``floor``. The width is an array extent of
+    the program, so it keys the jit cache as ``bucket_rows`` does (piolint
+    PIO306 knows a bucket step by the "bucket" in its name)."""
+    return max(int(floor), 1 << (max(1, int(n)) - 1).bit_length())
+
+
+#: the id of a result slot that holds no item (its score is ``-inf``): past
+#: any catalog, so ``serving_util._drain_staged`` trims it, and the padding
+#: of an excluded-id list, so the scatter that reads it drops it
+NO_ITEM = np.iinfo(np.int32).max
 
 
 def sort_merge_topk(

@@ -482,6 +482,7 @@ class MicroBatcher:
             host_gap_ms=host_gap_ms,
             rows_scored=counts.get("rowsScored", 0),
             rows_real=counts.get("rowsReal", 0),
+            counts=counts,
         )
         with span("release"):
             for p, result in zip(batch, results):

@@ -13,12 +13,21 @@ Parity map (reference scala-parallel-ecommercerecommendation template):
   ranking for unknown users -> :class:`ECommAlgorithm`.
 * Query ``{"user": "u1", "num": 4, "categories"?, "whiteList"?,
   "blackList"?}`` -> ``{"itemScores": [...]}``.
+
+The rules are one mask however a query is answered: per item its category
+codes and whether it is in stock, per query the categories asked for and
+the item ids left out (seen, black-listed). ``predict`` applies it on the
+host to one score row; ``batch_predict`` hands it to
+``serving_util.chunked_topk(filt=...)``, which under ``pio deploy
+--pin-model`` selects inside one tiled device program
+(``ops.als.top_k_items_filtered``) over the item table and the category
+codes that :meth:`ECommAlgorithm.pin_model_for_serving` laid out there.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import Any, Sequence
 
 import numpy as np
 
@@ -32,10 +41,18 @@ from predictionio_tpu.controller import (
     SanityCheck,
     WorkflowContext,
 )
-from predictionio_tpu.data.aggregator import BiMap
+from predictionio_tpu.data.aggregator import BiMap, aggregate_properties_single
 from predictionio_tpu.data.store import LEventStore, PEventStore
 from predictionio_tpu.ops.als import ALSConfig, factors_to_host, train_als
+from predictionio_tpu.ops.topk import NO_ITEM, bucket_width, top_k_host
 from predictionio_tpu.templates.results import ItemScore, PredictedResult
+from predictionio_tpu.templates.serving_util import (
+    TOPK_CHUNK,
+    TopkFilter,
+    allowed_items_host,
+    chunked_topk,
+)
+from predictionio_tpu.utils.spans import count, span
 
 __all__ = [
     "Query",
@@ -44,6 +61,7 @@ __all__ = [
     "ECommerceDataSource",
     "ECommAlgorithmParams",
     "ECommModel",
+    "category_arrays",
     "ECommAlgorithm",
     "engine_factory",
 ]
@@ -206,8 +224,38 @@ class ECommModel:
     item_factors: Any
     user_index: BiMap
     item_index: BiMap
-    categories: dict
+    categories: dict  # item id -> tuple of categories, as training read them
     popularity: Any  # [I]
+    #: the category rule as arrays (:func:`category_arrays`): per item row
+    #: its codes into ``category_index``, ``-1`` = none. ``None`` in a blob
+    #: written before they existed: built from ``categories`` on first use
+    category_codes: Any = None  # int32 [I, C]
+    category_index: BiMap | None = None
+
+
+def category_arrays(categories: dict, item_index: BiMap) -> tuple[np.ndarray, BiMap]:
+    """``{item id: categories}`` as ``(codes int32[I, C], name -> code)``:
+    any number of distinct categories, ``C`` the most one item carries."""
+    index = BiMap.string_index(
+        sorted({c for cats in categories.values() for c in cats})
+    )
+    width = max([1, *(len(cats) for cats in categories.values())])
+    codes = np.full((len(item_index), width), -1, np.int32)
+    for item, cats in categories.items():
+        row = item_index.get(item)
+        if row is not None:
+            codes[row, : len(cats)] = [index[c] for c in cats]
+    return codes, index
+
+
+#: floors of the two per-row list widths of a filtered top-K
+#: (``ops.topk.bucket_width``). The excluded ids scatter into a mask whose
+#: cost hardly moves with their number (measured on a v5e, PERF.md), so one
+#: wide bucket holds every history short of a thousand items and a deploy
+#: compiles a single width; wanted categories are compared item by item,
+#: so their floor is what a category page asks for
+EXCLUDED_FLOOR = 1024
+WANTED_FLOOR = 2
 
 
 class ECommAlgorithm(JaxAlgorithm):
@@ -232,6 +280,7 @@ class ECommAlgorithm(JaxAlgorithm):
         user, item = factors_to_host(
             ctx.run_info["als"], factors.user, factors.item
         )
+        codes, category_index = category_arrays(pd.categories, pd.item_index)
         return ECommModel(
             user_factors=user,
             item_factors=item,
@@ -239,78 +288,134 @@ class ECommAlgorithm(JaxAlgorithm):
             item_index=pd.item_index,
             categories=pd.categories,
             popularity=pd.popularity,
+            category_codes=codes,
+            category_index=category_index,
         )
 
     # ------------------------------------------------------------- serving
-    def _seen_items(self, user: str) -> set:
-        """Items of the user's recent view/buy events, via the serving-time
-        LEventStore path (parity: ECommAlgorithm's seen-events lookup)."""
-        if not self.params.unseen_only or not self.params.app_name:
-            return set()
+    def _store_rules(self, users: Sequence[str]) -> tuple[dict[str, set], set]:
+        """What the event store says at query time, in ONE read where the
+        store can (``LEventStore.find_by_entities``): per user the items of
+        their view/buy events (parity: ECommAlgorithm's seen-events lookup
+        through ``LEventStore``), and the current ``items`` of the
+        ``constraint`` entity ``unavailableItems`` (parity: the template's
+        availability constraint). A store error means no filter."""
+        p = self.params
+        if not p.app_name:
+            return {}, set()
+        constraint = ("constraint", "unavailableItems")
+        people = [("user", u) for u in users] if p.unseen_only else []
         try:
-            events = LEventStore.find_by_entity(
-                app_name=self.params.app_name,
-                entity_type="user",
-                entity_id=user,
-                event_names=list(self.params.seen_events),
-                limit=None,
+            events = LEventStore.find_by_entities(
+                app_name=p.app_name,
+                entities=[*people, constraint],
+                event_names=[*p.seen_events, "$set", "$unset", "$delete"],
             )
         except Exception:
-            return set()
-        return {e.target_entity_id for e in events if e.target_entity_id}
+            return {}, set()
+        pm = aggregate_properties_single(events.pop(constraint, ()))
+        seen = {
+            user: {
+                e.target_entity_id for e in found
+                if e.target_entity_id and e.event in p.seen_events
+            }
+            for (_, user), found in events.items()
+        }
+        return seen, set() if pm is None else set(pm.opt("items", list, []))
 
-    def _unavailable_items(self) -> set:
-        """Current ``$set`` properties of the ``constraint_unavailableItems``
-        entity (parity: the template's availability constraint)."""
-        if not self.params.app_name:
-            return set()
-        try:
-            pm = LEventStore.aggregate_properties_of_entity(
-                app_name=self.params.app_name,
-                entity_type="constraint",
-                entity_id="unavailableItems",
+    @staticmethod
+    def _categories(model: ECommModel) -> tuple[np.ndarray, BiMap]:
+        if getattr(model, "category_codes", None) is None:
+            model.category_codes, model.category_index = category_arrays(
+                model.categories, model.item_index
             )
-        except Exception:
-            return set()
-        if pm is None:
-            return set()
-        return set(pm.opt("items", list, []))
+        return model.category_codes, model.category_index
+
+    @staticmethod
+    def _blocked(model: ECommModel, unavailable: set):
+        """The out-of-stock mask over the item rows — the host's
+        ``bool[items]`` or, pinned, the device's ``bool[tiles, width]`` with
+        the padding past the catalog blocked too — made when the constraint
+        changes, not per batch."""
+        cached = getattr(model, "_pio_blocked", None)
+        if cached is not None and cached[0] == unavailable:
+            return cached[1]
+        n = len(model.item_index)
+        tiles = getattr(model, "_pio_item_tiles", None)
+        mask = np.zeros(n if tiles is None else tiles.shape[0] * tiles.shape[2], bool)
+        mask[n:] = True
+        rows = [model.item_index.get(i) for i in unavailable]
+        mask[[r for r in rows if r is not None]] = True
+        if tiles is not None:
+            import jax
+
+            mask = jax.device_put(mask.reshape(tiles.shape[0], tiles.shape[2]))
+        model._pio_blocked = (set(unavailable), mask)
+        return mask
+
+    def _rules(
+        self, model: ECommModel, queries: Sequence[Query], seen: dict,
+        unavailable: set,
+    ) -> TopkFilter:
+        """The rules of ``queries`` as the arrays the top-K takes."""
+        codes, category_index = self._categories(model)
+        item_row = model.item_index.get
+        left_out = []
+        for q in queries:
+            ids = set(seen.get(q.user, ())).union(q.black_list or ())
+            left_out.append([r for r in map(item_row, ids) if r is not None])
+        excluded = np.full(
+            (len(queries), bucket_width(max(map(len, left_out)), EXCLUDED_FLOOR)),
+            NO_ITEM, np.int32,
+        )
+        for row, rows in zip(excluded, left_out):
+            row[: len(rows)] = rows
+        asked = [q.categories or () for q in queries]
+        wanted = np.full(
+            (len(queries), bucket_width(max(map(len, asked)), WANTED_FLOOR)),
+            -2, np.int32,
+        )
+        for row, names in zip(wanted, asked):
+            # a name no item carries is a code no item carries
+            row[: len(names)] = [
+                category_index.get(str(c), len(category_index)) for c in names
+            ]
+        count("filter.excludedIds",
+              sum(map(len, left_out)) + len(unavailable) * len(queries))
+        count("filter.categoryRows", sum(1 for names in asked if names))
+        tiles = getattr(model, "_pio_item_tiles", None)
+        return TopkFilter(
+            codes=codes if tiles is None else model._pio_code_tiles,
+            blocked=self._blocked(model, unavailable),
+            wanted=wanted, excluded=excluded, item_tiles=tiles,
+        )
 
     def predict(self, model: ECommModel, query: Query) -> PredictedResult:
-        n = model.item_factors.shape[0]
+        """One query on the host: one score row, the rules as one mask."""
+        n = len(model.item_index)
         uidx = model.user_index.get(query.user)
         if uidx is not None:
-            scores = model.item_factors @ np.asarray(model.user_factors[uidx])
+            scores = np.asarray(model.item_factors) @ np.asarray(
+                model.user_factors[uidx]
+            )
         else:
             # cold start: popularity ranking (parity: the template's
             # fallback to recent/popular items)
             scores = np.asarray(model.popularity, dtype=np.float64).copy()
-        allowed = np.ones(n, dtype=bool)
-        for item in self._seen_items(query.user) | self._unavailable_items():
-            idx = model.item_index.get(item)
-            if idx is not None:
-                allowed[idx] = False
+        filt = self._rules(model, [query], *self._store_rules([query.user]))
+        codes, _ = self._categories(model)
+        allowed = allowed_items_host(
+            codes, np.asarray(filt.blocked).reshape(-1)[:n], filt.wanted,
+            filt.excluded,
+        )[0]
         if query.white_list:
             allowed &= np.isin(
                 np.arange(n), [model.item_index.get(i, -1) for i in query.white_list]
             )
-        if query.black_list:
-            for item in query.black_list:
-                idx = model.item_index.get(item)
-                if idx is not None:
-                    allowed[idx] = False
-        if query.categories:
-            wanted = set(query.categories)
-            for idx in np.nonzero(allowed)[0]:
-                cats = model.categories.get(model.item_index.inverse(int(idx)), ())
-                if not wanted.intersection(cats):
-                    allowed[idx] = False
         scores = np.where(allowed, scores, -np.inf)
         k = min(int(query.num), int(allowed.sum()))
         if k <= 0:
             return PredictedResult(())
-        from predictionio_tpu.ops.topk import top_k_host
-
         top, _ = top_k_host(scores, k)  # shared tie rule (ops/topk.py)
         return PredictedResult(
             tuple(
@@ -319,6 +424,80 @@ class ECommAlgorithm(JaxAlgorithm):
                 if np.isfinite(scores[i])
             )
         )
+
+    #: the most queries one dispatch scores (serving_util.TOPK_CHUNK; the
+    #: filtered branch lowers it to what its score tile allows)
+    BATCH_PREDICT_CHUNK = TOPK_CHUNK
+
+    def batch_predict(
+        self, model: ECommModel, queries: Sequence[tuple[int, Query]]
+    ) -> list[tuple[int, PredictedResult]]:
+        """A batch through one filtered top-K: the store read once for the
+        batch's users, the rules compiled into arrays, the selection inside
+        ``chunked_topk`` (on the device when the model is pinned). A white
+        list and an unknown user keep :meth:`predict` (counted as
+        ``filter.hostPath``)."""
+        n_items = len(model.item_index)
+        results: list[tuple[int, PredictedResult]] = []
+        valid: list[tuple[int, int, int]] = []  # (slot, user row, k)
+        rows: list[Query] = []
+        with span("lookup"):
+            for slot, q in queries:
+                uidx = model.user_index.get(q.user)
+                k = min(int(q.num), n_items)
+                if k <= 0:
+                    results.append((slot, PredictedResult(())))
+                elif uidx is None or q.white_list:
+                    count("filter.hostPath", 1)
+                    results.append((slot, self.predict(model, q)))
+                else:
+                    valid.append((slot, uidx, k))
+                    rows.append(q)
+        if not valid:
+            return results
+        with span("filterLookup"):
+            seen, unavailable = self._store_rules(list({q.user for q in rows}))
+        with span("filterBuild"):
+            filt = self._rules(model, rows, seen, unavailable)
+        inverse = model.item_index.inverse
+        for part, idx_l, score_l in chunked_topk(
+            model.user_factors, model.item_factors, valid,
+            chunk=self.BATCH_PREDICT_CHUNK, filt=filt,
+        ):
+            with span("format"):
+                for (slot, _, k), ids, scs in zip(part, idx_l, score_l):
+                    if len(ids) < k:
+                        count("filter.shortAnswers", 1)
+                    results.append((
+                        slot,
+                        PredictedResult(tuple(
+                            ItemScore(item=inverse(i), score=s)
+                            for i, s in zip(ids[:k], scs[:k])
+                        )),
+                    ))
+        return results
+
+    def pin_model_for_serving(self, model: ECommModel) -> tuple[ECommModel, int]:
+        """``--pin-model`` (workflow/device_state.py): the item table and the
+        category codes go to the device once per model generation, cut into
+        the tiles ``ops.als.top_k_items_filtered`` scans, and
+        ``batch_predict`` selects there. The user table stays on the host:
+        a batch's rows ride with its rules (``serving_util._filtered_topk``
+        says why). Returns the model and the device bytes it holds."""
+        from predictionio_tpu.ops.als import tile_items
+
+        codes, _ = self._categories(model)
+        model._pio_blocked = None
+        model._pio_item_tiles = tile_items(
+            np.asarray(model.item_factors, np.float32), 0.0
+        )
+        model._pio_code_tiles = tile_items(codes, -1)
+        model._pio_pinned = True
+        model._pio_bytes_by_dtype = {
+            "float32": int(model._pio_item_tiles.nbytes),
+            "int32": int(model._pio_code_tiles.nbytes),
+        }
+        return model, sum(model._pio_bytes_by_dtype.values())
 
 
 def engine_factory() -> Engine:

@@ -2,9 +2,10 @@
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import time
-from typing import Callable, Iterator, Sequence
+from typing import Any, Callable, Iterator, Sequence
 
 import numpy as np
 
@@ -12,7 +13,7 @@ from predictionio_tpu.utils.spans import count, span
 
 __all__ = [
     "device_latency_probe", "chunked_topk", "serving_row_buckets",
-    "aligned_factor_init",
+    "aligned_factor_init", "TopkFilter", "allowed_items_host",
 ]
 
 logger = logging.getLogger(__name__)
@@ -41,6 +42,108 @@ def serving_row_buckets(chunk: int = TOPK_CHUNK) -> list[int]:
     )
 
 
+@dataclasses.dataclass
+class TopkFilter:
+    """The rules of a filtered :func:`chunked_topk` call. Per item:
+    ``codes`` (the category codes it carries, ``-1`` = none) and
+    ``blocked`` (no row may be given it). Per entry of ``valid``, in its
+    order: ``wanted`` ``i32[n, W]`` (category codes asked for, padded
+    with ``-2``; a row whose first entry is negative names none and
+    allows all) and ``excluded`` ``i32[n, E]`` (item ids left out,
+    padded with ``ops.topk.NO_ITEM``); ``W`` and ``E`` are
+    ``ops.topk.bucket_width`` buckets.
+
+    Unpinned, ``codes`` is ``i32[items, C]`` and ``blocked``
+    ``bool[items]`` on the host. Pinned, ``item_tiles`` holds the item
+    factors on the device as ``ops.als.tile_items`` laid them out, and
+    ``codes`` / ``blocked`` are the device arrays of the same tiles
+    (``blocked`` also marks the padding past the catalog)."""
+
+    codes: Any
+    blocked: Any
+    wanted: np.ndarray
+    excluded: np.ndarray
+    item_tiles: Any = None
+
+
+def allowed_items_host(
+    codes: np.ndarray, blocked: np.ndarray, wanted: np.ndarray,
+    excluded: np.ndarray,
+) -> np.ndarray:
+    """``bool[rows, items]``: the items each row is allowed, by the one
+    rule the device program (``ops.als.top_k_items_filtered``) applies —
+    the item carries a wanted category (or the row names none), is not
+    blocked, and is not among the row's excluded ids. Arguments as the
+    host fields of :class:`TopkFilter`."""
+    n_items = codes.shape[0]
+    allowed = np.empty((wanted.shape[0], n_items), dtype=bool)
+    for r, (want, out) in enumerate(zip(wanted, excluded)):
+        if want[0] < 0:
+            allowed[r] = True
+        else:
+            allowed[r] = np.isin(codes, want[want >= 0]).any(axis=1)
+        allowed[r, out[out < n_items]] = False
+    allowed &= ~blocked
+    return allowed
+
+
+def _pad_rows(a: np.ndarray, rows: int, fill: int) -> np.ndarray:
+    out = np.full((rows, a.shape[1]), fill, a.dtype)
+    out[: a.shape[0]] = a
+    return out
+
+
+def _filtered_topk(
+    user_mat, item_mat, valid: Sequence[tuple], chunk: int, k_max: int,
+    filt: TopkFilter,
+) -> Iterator[tuple[list, list, list]]:
+    """The filtered branch of :func:`chunked_topk`: the same row and k
+    buckets, leaf spans and sentinel trim as the others. The chunk's user
+    rows are gathered where the user table lies and ride with the rules:
+    a row gather from a pinned ``[users, rank]`` table makes XLA copy the
+    whole table into a row-major layout on every dispatch."""
+    from predictionio_tpu.ops.topk import NO_ITEM, top_k_host
+
+    n_items = int(item_mat.shape[0])
+    on_device = filt.item_tiles is not None
+    if on_device:
+        from predictionio_tpu.ops.als import (
+            FILTER_SCORE_BYTES,
+            top_k_items_filtered,
+        )
+
+        # the [rows, width] float32 scores of one tile bound the rows
+        most = FILTER_SCORE_BYTES // (4 * int(filt.item_tiles.shape[2]))
+        chunk = min(chunk, max(8, 1 << (most.bit_length() - 1)))
+    staged: list = []
+    for lo in range(0, len(valid), chunk):
+        part = list(valid[lo : lo + chunk])
+        padded = _row_bucket_index(part, chunk if on_device else len(part))
+        with span("dispatch"):
+            user_vecs = user_mat[padded]
+            wanted = _pad_rows(filt.wanted[lo : lo + chunk], padded.size, -2)
+            excluded = _pad_rows(
+                filt.excluded[lo : lo + chunk], padded.size, NO_ITEM
+            )
+            if on_device:
+                idx_b, score_b = top_k_items_filtered(
+                    user_vecs, filt.item_tiles, filt.codes, filt.blocked,
+                    wanted, excluded, k_max,
+                )
+            else:
+                scores = np.where(
+                    allowed_items_host(
+                        filt.codes, filt.blocked, wanted, excluded
+                    ),
+                    np.asarray(user_vecs) @ np.asarray(item_mat).T,
+                    -np.inf,
+                )
+                idx_b, score_b = top_k_host(scores, k_max)
+                idx_b = np.where(score_b > -np.inf, idx_b, NO_ITEM)
+        staged.append((part, idx_b, score_b))
+    yield from _drain_staged(staged, n_items)
+
+
 def _drain_staged(
     staged: list, n_items: int
 ) -> Iterator[tuple[list, list, list]]:
@@ -48,10 +151,10 @@ def _drain_staged(
     all chunks' ids/scores on device, transfer once, then trim each
     row's sentinel padding (id >= n_items at -inf) before any consumer
     sees it — shared by the ANN and quantized staging paths."""
-    import jax.numpy as jnp
-
     with span("deviceWait"):
-        if len(staged) > 1:
+        if len(staged) > 1 and not isinstance(staged[0][1], np.ndarray):
+            import jax.numpy as jnp
+
             idx_all = np.asarray(
                 jnp.concatenate([i for _, i, _ in staged], axis=0)
             )
@@ -59,8 +162,8 @@ def _drain_staged(
                 jnp.concatenate([s for _, _, s in staged], axis=0)
             )
         else:
-            idx_all = np.asarray(staged[0][1])
-            score_all = np.asarray(staged[0][2])
+            idx_all = np.concatenate([np.asarray(i) for _, i, _ in staged])
+            score_all = np.concatenate([np.asarray(s) for _, _, s in staged])
     off = 0
     for part, idx_b, _ in staged:
         with span("format"):
@@ -94,7 +197,7 @@ def _row_bucket_index(part: Sequence[tuple], chunk: int) -> np.ndarray:
 
 def chunked_topk(
     user_mat, item_mat, valid: Sequence[tuple], chunk: int = TOPK_CHUNK,
-    ann=None, shards=None, quant=None, aot=None,
+    ann=None, shards=None, quant=None, aot=None, filt=None,
 ) -> Iterator[tuple[list, list, list]]:
     """Chunked batch top-k over ``valid = [(slot, uidx, k), ...]``;
     yields ``(part, ids, scores)`` with ids/scores as Python lists — the
@@ -149,7 +252,16 @@ def chunked_topk(
     over-fetching ``max(4k, k+64)``, f32 rescore of only the gathered
     candidates), composing with ``shards`` through the shard_map
     variant; the ANN path dequantizes only the chunk's query rows and
-    probes the (int8-slabbed) index as usual."""
+    probes the (int8-slabbed) index as usual.
+
+    ``filt`` (a :class:`TopkFilter`: per-item category codes and a
+    blocked mask, per-row wanted categories and excluded ids) returns the
+    exact top ``k`` of the items each row is ALLOWED: on the device
+    through ``ops.als.top_k_items_filtered`` (tiled over the items; the
+    exclusion and category widths are further buckets of its program),
+    on the host through the same rule in numpy. A row with fewer than
+    ``k`` allowed items comes back shorter. It composes with none of the
+    tiers above."""
     if not valid:
         return
     # under --shard-factors the physical table is padded to a multiple
@@ -161,6 +273,9 @@ def chunked_topk(
     from predictionio_tpu.ops.topk import bucket_k
 
     k_max = bucket_k(max(k for _, _, k in valid), n_items)
+    if filt is not None:
+        yield from _filtered_topk(user_mat, item_mat, valid, chunk, k_max, filt)
+        return
     if ann is not None:
         import jax.numpy as jnp
 

@@ -53,10 +53,15 @@ def _echo_batch(bodies):
     return [(200, {"echo": b}) for b in bodies]
 
 
+_INNER_PHASES = ("bind", "lookup", "filterLookup", "filterBuild", "dispatch",
+                 "deviceWait", "format")
+
+
 def _phased_batch(bodies):
-    """Stand-in handler cut like a device-backed ``handle_batch``: the
-    five inner phases, each a few hundred microseconds."""
-    for name in ("bind", "lookup", "dispatch", "deviceWait", "format"):
+    """Stand-in handler cut like a device-backed ``handle_batch`` of an
+    engine that filters: the seven inner phases, each a few hundred
+    microseconds."""
+    for name in _INNER_PHASES:
         with span(name):
             time.sleep(0.0003)
     return _echo_batch(bodies)
@@ -421,7 +426,7 @@ class TestDispatcherSpans:
             b.close()
         assert set(ms) == set(BATCH_PHASES) | {
             "queueWait", "handle", "total", "wake", "hostGap"}
-        inner = ("bind", "lookup", "dispatch", "deviceWait", "format")
+        inner = _INNER_PHASES
         for name in ("take", "drain", "batchForm", "wake", *inner):
             assert ms[name]["p50"] is not None, name
         assert all(ms[name]["p50"] >= 0.3 for name in inner)
