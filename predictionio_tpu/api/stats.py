@@ -105,6 +105,9 @@ BATCH_PHASES = (
 #: collector as ``filter.<name>`` (templates/ecommerce/engine.py)
 FILTER_COUNTS = ("excludedIds", "categoryRows", "hostPath", "shortAnswers")
 
+#: the plans of ``ops.topk.select_plan``: the ``batcher.select`` counts
+SELECT_PLANS = ("blocked", "plain")
+
 
 class ServingStats:
     """Micro-batcher serving statistics (thread-safe).
@@ -145,6 +148,13 @@ class ServingStats:
     program's row bucket costs: 1.0 at a full batch, up to 8 for a lone
     query under the floor of 8 rows (``ops/topk.py`` ``bucket_rows``).
 
+    ``select`` counts the live batches' device dispatches by the
+    selection their program was built with (``ops/topk.py``
+    ``select_plan``, from the dispatch's rows, columns and k bucket):
+    ``blocked`` (the top k from the maxima of contiguous blocks) or
+    ``plain`` (``lax.top_k`` over the whole row). A host GEMM and the
+    tiers with kernels of their own (IVF, int8, sharded) count neither.
+
     ``filter`` counts what a filtering engine (the e-commerce template)
     did over the live batches: ``excludedIds`` (item ids its rows left
     out: seen, unavailable, black-listed), ``categoryRows`` (rows that
@@ -174,6 +184,7 @@ class ServingStats:
         self.rows_scored = 0
         self.rows_real = 0
         self.filter_counts = dict.fromkeys(FILTER_COUNTS, 0)
+        self.select_counts = dict.fromkeys(SELECT_PLANS, 0)
         self.queue_depth = 0  # last observed; gauge
         self.inflight_batch = 0  # 0|1 — one dispatcher thread
         self.batch_size_hist: Counter = Counter()
@@ -232,7 +243,8 @@ class ServingStats:
         dispatcher's ``phases`` ({name: ms}, names of
         :data:`BATCH_PHASES`), the host gap before it, the rows its
         scoring dispatches took and really held, and the handler's other
-        ``counts`` (those named ``filter.<one of FILTER_COUNTS>``)."""
+        ``counts`` (those named ``filter.<one of FILTER_COUNTS>`` and
+        ``select.<one of SELECT_PLANS>``)."""
         with self._lock:
             self.inflight_batch = 0
             self.batches += 1
@@ -243,6 +255,10 @@ class ServingStats:
             for name in FILTER_COUNTS:
                 self.filter_counts[name] += (counts or {}).get(
                     "filter." + name, 0
+                )
+            for name in SELECT_PLANS:
+                self.select_counts[name] += (counts or {}).get(
+                    "select." + name, 0
                 )
             self.batch_size_hist[size] += 1
             self.bucket_hist[bucket] += 1
@@ -295,6 +311,7 @@ class ServingStats:
                 "rowsScored": self.rows_scored,
                 "rowsReal": self.rows_real,
                 "filter": dict(self.filter_counts),
+                "select": dict(self.select_counts),
                 "batchSizeHist": {
                     str(k): v for k, v in sorted(self.batch_size_hist.items())
                 },

@@ -63,6 +63,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, reshard
 from predictionio_tpu.ops.topk import (
     NO_ITEM,
     SCORE_PRECISION,
+    select_top_k,
     sort_merge_topk,
     top_k_scores,
 )
@@ -1621,7 +1622,9 @@ def top_k_items_batch(
 ) -> tuple[jax.Array, jax.Array]:
     """Top-k for a BATCH of users in one dispatch: gather the user rows on
     device, score every item with one ``[B, K] @ [K, I]`` GEMM (MXU work,
-    not B GEMVs), and ``lax.top_k`` each row. Returns ``([B, k] item ids,
+    not B GEMVs), and select each row's top ``k`` (``ops.topk``
+    ``select_top_k``: ``lax.top_k``'s result, over a wide catalog from
+    the maxima of 128-column blocks). Returns ``([B, k] item ids,
     [B, k] scores)`` — the only host transfer is the 2·B·k result.
 
     This is the batch-amortized device serving path (ref
@@ -1715,7 +1718,10 @@ def top_k_items_filtered(
     The excluded ids are scattered once into a ``[tiles, rows, width]``
     mask; then each tile is scored (``U_b @ V_tile`` at
     ``SCORE_PRECISION``), masked to ``-inf`` where not allowed, cut to
-    its own top ``k`` and merged into the carried ``[rows, k]`` by
+    its own top ``k`` by :func:`~predictionio_tpu.ops.topk.select_top_k`
+    (``lax.top_k``'s result; at a full tile's width it reads the scores
+    once for the maxima of 128-column blocks and selects among the ``k``
+    leading blocks' columns) and merged into the carried ``[rows, k]`` by
     :func:`~predictionio_tpu.ops.topk.sort_merge_topk`'s two keys — the
     ``lax.top_k`` of the masked full row. A slot no allowed item fills
     comes back as ``(NO_ITEM, -inf)``."""
@@ -1743,7 +1749,7 @@ def top_k_items_filtered(
             allowed = (in_category | names_none) & ~(blocked_t[None] | left_out_t)
             scores = jnp.where(allowed, scores, -jnp.inf)
         with jax.named_scope("pio_topk_select"):
-            vals, pos = jax.lax.top_k(scores, kt)
+            vals, pos = select_top_k(scores, kt)
         with jax.named_scope("pio_topk_merge"):
             ids = jnp.where(vals > -jnp.inf, pos + t * width, NO_ITEM)
             ids, vals = sort_merge_topk(

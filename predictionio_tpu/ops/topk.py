@@ -10,9 +10,13 @@ comparable. The rule is the one ``jax.lax.top_k`` implements natively:
 
 Three entry points share it:
 
-* :func:`top_k_scores` — jitted ``lax.top_k`` over naturally-ordered
-  scores (the exact device path; ties -> ascending position is the
-  operator's own guarantee).
+* :func:`top_k_scores` — jitted :func:`select_top_k` over
+  naturally-ordered scores (the exact device path). ``select_top_k``
+  returns what ``lax.top_k`` returns, bit for bit (ties -> ascending
+  position is the operator's own guarantee); over a wide row it reaches
+  it in two stages, from the maxima of contiguous blocks, because the
+  chip's ``lax.top_k`` reads a tenth as fast as a max-reduction does.
+  :func:`select_plan` names which of the two a shape gets.
 * :func:`top_k_permuted` — jitted tie-stable top-K when the score axis
   is NOT in item-id order (the IVF cluster-major merge): a two-key
   ``lax.sort`` on ``(-score, id)`` reproduces the exact rule in the
@@ -31,6 +35,7 @@ path. Hoisting the helper is what fixed that.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +47,9 @@ __all__ = [
     "bucket_rows",
     "bucket_width",
     "NO_ITEM",
+    "SELECT_BLOCK",
+    "select_plan",
+    "select_top_k",
     "top_k_scores",
     "top_k_permuted",
     "sort_merge_topk",
@@ -117,12 +125,104 @@ def sort_merge_topk(
     return sid[..., :k], -neg[..., :k]
 
 
+#: columns of one block of :func:`select_top_k`'s first stage: one lane
+#: row of a float32 tile, so a block's maximum is a reduction inside one
+#: vector register and a block is one row of the gather's table
+SELECT_BLOCK = 128
+
+#: :func:`select_plan`'s bounds, each from a timing or a reading on a v5e
+#: (PERF.md section 6, PR 29). The ``k`` candidate blocks must be this
+#: many times narrower than the row (at the least shape that leaves, 8
+#: rows of 32,768 columns at k 16, the stages' handful of small device
+#: operations cost what ``lax.top_k`` takes for the whole row). ``k`` is
+#: at most this, and the rows whole tiles of 8: the chip's ``lax.top_k``
+#: breaks ties by ascending position only at some shapes, and at 256 and
+#: over, or over one row of candidates, it does not. A width that is no
+#: multiple of the block is padded into a second copy of the scores,
+#: which may hold no more than this many (1 GiB: a full ``pio
+#: batchpredict`` chunk would else hold 5.1 GB twice)
+_SELECT_MIN_RATIO = 16
+_SELECT_MAX_K = 128
+_SELECT_MAX_PADDED = 1 << 28
+
+
+def select_plan(rows: int, width: int, k: int) -> str:
+    """Which selection :func:`select_top_k` builds for ``rows`` score
+    rows of ``width`` columns at a static ``k``: ``"blocked"`` (two
+    stages, from block maxima) or ``"plain"`` (``lax.top_k`` itself).
+    A pure function of the shape, so host code that knows a dispatch's
+    shape can count its plan (``/stats.json`` ``batcher.select``)."""
+    blocked = (
+        k <= _SELECT_MAX_K
+        and rows % 8 == 0
+        and width >= _SELECT_MIN_RATIO * k * SELECT_BLOCK
+        and (width % SELECT_BLOCK == 0 or rows * width <= _SELECT_MAX_PADDED)
+    )
+    return "blocked" if blocked else "plain"
+
+
+def select_top_k(scores: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+    """``jax.lax.top_k(scores, k)``, values and positions bit for bit,
+    ties included — by :func:`select_plan`'s ``"blocked"`` plan where
+    the row is wide:
+
+    1. cut the last axis into CONTIGUOUS blocks of ``SELECT_BLOCK``
+       columns (a ragged end padded with ``-inf``) and take each block's
+       maximum;
+    2. ``lax.top_k`` over the maxima picks ``k`` blocks; their indices
+       are sorted ascending;
+    3. those blocks' columns are gathered in that order, so a
+       candidate's place is monotone in its column, and ``lax.top_k``
+       over the ``k * SELECT_BLOCK`` candidates picks the answer.
+
+    Exact because a top-k element in a block not picked would have ``k``
+    blocks ranked before its own (maximum descending, index ascending),
+    each holding an element that is larger, or equal at a lower column
+    since blocks are contiguous: ``k`` elements outrank it. Selection
+    does no arithmetic, so values are the input's own bits.
+
+    The scores are read where they lie: a ``[rows, width]`` float32
+    array is stored on the chip in tiles of 8 rows by 128 columns, so
+    (``rows`` being a multiple of 8 under the plan) its own bytes are
+    ``[row group, block, row in group, lane]``. Both views below say so:
+    the maxima reduce the lanes of that order and the gather takes
+    ``rows * k`` rows of it as a table of blocks, where a plain
+    ``reshape(rows, blocks, 128)`` copies the whole array first and a
+    ``lax.reduce_window`` reads it several times slower (compiled for
+    and timed on a v5e: PERF.md section 6, PR 29). Not a jitted program
+    of its own: it only ever runs inside a caller's trace, as
+    :func:`sort_merge_topk`."""
+    *lead, width = scores.shape
+    rows = math.prod(lead)
+    if select_plan(rows, width, k) == "plain" or not jnp.issubdtype(
+        scores.dtype, jnp.floating
+    ):
+        return jax.lax.top_k(scores, k)
+    b = SELECT_BLOCK
+    nb = -(-width // b)
+    s = scores.reshape(rows, width)
+    if nb * b != width:
+        low = jnp.array(-jnp.inf, scores.dtype)
+        s = jax.lax.pad(s, low, ((0, 0, 0), (0, nb * b - width, 0)))
+    tiles = s.reshape(rows // 8, 8, nb, b)
+    maxima = tiles.max(axis=-1).reshape(rows, nb)
+    blocks = jnp.sort(jax.lax.top_k(maxima, k)[1], axis=-1)
+    row = jnp.arange(rows, dtype=jnp.int32)[:, None]
+    at = ((row // 8) * nb + blocks) * 8 + row % 8
+    table = tiles.transpose(0, 2, 1, 3).reshape(rows * nb, b)
+    candidates = table.at[at.reshape(-1)].get(mode="promise_in_bounds")
+    values, place = jax.lax.top_k(candidates.reshape(rows, k * b), k)
+    positions = jnp.take_along_axis(blocks, place // b, axis=-1) * b + place % b
+    return values.reshape(*lead, k), positions.reshape(*lead, k)
+
+
 @functools.partial(jax.jit, static_argnames=("k",))
 def top_k_scores(scores: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     """Top-k of ``scores`` along the last axis: ``(indices, values)``.
-    Ties break toward the lower index (``lax.top_k``'s contract)."""
+    Ties break toward the lower index (``lax.top_k``'s contract, which
+    :func:`select_top_k` keeps whichever plan the shape gets)."""
     with jax.named_scope("pio_topk_select"):
-        values, indices = jax.lax.top_k(scores, k)
+        values, indices = select_top_k(scores, k)
     return indices, values
 
 

@@ -102,7 +102,7 @@ def _filtered_topk(
     rows are gathered where the user table lies and ride with the rules:
     a row gather from a pinned ``[users, rank]`` table makes XLA copy the
     whole table into a row-major layout on every dispatch."""
-    from predictionio_tpu.ops.topk import NO_ITEM, top_k_host
+    from predictionio_tpu.ops.topk import NO_ITEM, select_plan, top_k_host
 
     n_items = int(item_mat.shape[0])
     on_device = filt.item_tiles is not None
@@ -113,12 +113,15 @@ def _filtered_topk(
         )
 
         # the [rows, width] float32 scores of one tile bound the rows
-        most = FILTER_SCORE_BYTES // (4 * int(filt.item_tiles.shape[2]))
+        width = int(filt.item_tiles.shape[2])
+        most = FILTER_SCORE_BYTES // (4 * width)
         chunk = min(chunk, max(8, 1 << (most.bit_length() - 1)))
     staged: list = []
     for lo in range(0, len(valid), chunk):
         part = list(valid[lo : lo + chunk])
         padded = _row_bucket_index(part, chunk if on_device else len(part))
+        if on_device:  # the program selects a tile at a time
+            count("select." + select_plan(padded.size, width, min(k_max, width)), 1)
         with span("dispatch"):
             user_vecs = user_mat[padded]
             wanted = _pad_rows(filt.wanted[lo : lo + chunk], padded.size, -2)
@@ -182,7 +185,9 @@ def _row_bucket_index(part: Sequence[tuple], chunk: int) -> np.ndarray:
     ``int32[bucket_rows(len(part), chunk)]`` every scoring program takes:
     the program's rows follow the rows the chunk holds, never over
     ``chunk``. Counts both (``rowsScored``, ``rowsReal``) on the
-    thread's span collector, once a chunk."""
+    thread's span collector, once a chunk; the branches whose program
+    selects through ``ops.topk.select_top_k`` count its plan beside them
+    (``select.blocked`` or ``select.plain``)."""
     from predictionio_tpu.ops.topk import bucket_rows
 
     with span("lookup"):
@@ -364,7 +369,9 @@ def chunked_topk(
                 )
         elif on_device:
             from predictionio_tpu.ops.als import top_k_items_batch
+            from predictionio_tpu.ops.topk import select_plan
 
+            count("select." + select_plan(padded.size, n_items, k_max), 1)
             with span("dispatch"):
                 aot_key = f"top_k_items_batch_c{padded.size}_b{k_max}"
                 fn = aot.get(aot_key) if aot is not None else None

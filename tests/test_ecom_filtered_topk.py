@@ -1,19 +1,29 @@
 """The filtered top-K program (``ops.als.top_k_items_filtered``) against
 the top K of the masked full score rows (the tie rule of ``top_k_host``): every row bucket, catalogs
-that do and do not fill their last tile, and the rows that test a rule's
+that do and do not fill their last tile, a tile narrow enough that each is
+selected by ``lax.top_k`` and one wide enough that it is selected from its
+block maxima (``ops.topk.select_plan``), and the rows that test a rule's
 edge. One parametrised test, so each case counts."""
 
 import numpy as np
 import pytest
 
 from predictionio_tpu.ops.als import tile_items, top_k_items_filtered
-from predictionio_tpu.ops.topk import NO_ITEM, bucket_width, top_k_host
+from predictionio_tpu.ops.topk import (
+    NO_ITEM,
+    bucket_width,
+    select_plan,
+    top_k_host,
+)
 from predictionio_tpu.templates.serving_util import allowed_items_host
 
-TILE, RANK, K = 256, 8, 16
+RANK, K = 8, 16
+#: tile widths: every tile of the first is selected by the plain plan, every
+#: tile of the second, at 32 rows, by the blocked one
+TILE, TILE_WIDE = 256, 1 << 16
 
 
-def _case(name: str, rows: int, n_items: int, rng) -> dict:
+def _case(name: str, rows: int, n_items: int, rng, tile: int = TILE) -> dict:
     """Tables and rules of one case; every row of the batch is under it."""
     item = rng.integers(-3, 4, (n_items, RANK)).astype(np.float32)  # exact sums
     user = rng.integers(-3, 4, (rows, RANK)).astype(np.float32)
@@ -34,10 +44,10 @@ def _case(name: str, rows: int, n_items: int, rng) -> dict:
         wanted[:, 0] = 1
     elif name == "ties_across_a_tile_edge":
         best = 3 * rng.choice([-1.0, 1.0], RANK).astype(np.float32)
-        item[TILE - 6:TILE + 6] = best  # twelve equal scores around id 256
+        item[tile - 6:tile + 6] = best  # twelve equal scores around the edge
         user[:] = best / 3  # ... that no other item can pass
-        blocked[TILE - 6:TILE + 6] = False
-        blocked[TILE - 2] = True
+        blocked[tile - 6:tile + 6] = False
+        blocked[tile - 2] = True
     elif name == "several_categories":
         codes = rng.integers(-1, 6, (n_items, 3)).astype(np.int32)
         wanted[:, 0] = rng.integers(0, 6, rows)
@@ -61,15 +71,23 @@ CASES = ["no_filter", "every_item_filtered", "fewer_than_k_allowed",
          "ties_across_a_tile_edge", "several_categories", "more_than_32_categories"]
 
 
+SHAPES = [
+    (rows, TILE, n_items)
+    for rows in (8, 16, 32) for n_items in (2 * TILE, 2 * TILE + 188)
+] + [(32, TILE_WIDE, 2 * TILE_WIDE), (32, TILE_WIDE, 2 * TILE_WIDE + 188)]
+
+
 @pytest.mark.parametrize("name", CASES)
-@pytest.mark.parametrize("n_items", [2 * TILE, 2 * TILE + 188])
-@pytest.mark.parametrize("rows", [8, 16, 32])
-def test_filtered_program_is_top_k_of_the_masked_row(rows, n_items, name):
-    c = _case(name, rows, n_items, np.random.default_rng(rows * 1000 + n_items))
-    item_tiles = tile_items(c["item"], 0.0, tile=TILE)
-    code_tiles = tile_items(c["codes"], -1, tile=TILE)
+@pytest.mark.parametrize("rows,tile,n_items", SHAPES)
+def test_filtered_program_is_top_k_of_the_masked_row(rows, tile, n_items, name):
+    c = _case(name, rows, n_items, np.random.default_rng(rows * 1000 + n_items),
+              tile)
+    item_tiles = tile_items(c["item"], 0.0, tile=tile)
+    code_tiles = tile_items(c["codes"], -1, tile=tile)
     n_tiles, _, width = item_tiles.shape
-    assert width == TILE and n_tiles * width >= n_items
+    assert width == tile and n_tiles * width >= n_items
+    assert select_plan(rows, width, K) == (
+        "blocked" if tile == TILE_WIDE else "plain")
     blocked = np.ones(n_tiles * width, bool)
     blocked[:n_items] = c["blocked"]
     ids, vals = top_k_items_filtered(
@@ -95,7 +113,7 @@ def test_filtered_program_is_top_k_of_the_masked_row(rows, n_items, name):
         assert vals[r, :n].tolist() == want_vals[:n].tolist()
         assert (ids[r, n:] == NO_ITEM).all() and np.isneginf(vals[r, n:]).all()
         if name == "ties_across_a_tile_edge":
-            tied = [i for i in range(TILE - 6, TILE + 6)
+            tied = [i for i in range(tile - 6, tile + 6)
                     if ok[i]][:K]
             assert ids[r, :len(tied)].tolist() == tied  # ascending id over the edge
     # and the host mirror of the rule says the same

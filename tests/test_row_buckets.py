@@ -142,6 +142,11 @@ def test_chunked_topk_rows_in_input_order_and_rows_dispatched(
     else:
         assert counts["rowsScored"] == sum(bucket_rows(p, CHUNK) for p in parts)
         assert counts["rowsScored"] < 2 * max(n, 8)
+    # the selection's plan is counted where the program selects through
+    # ops.topk.select_top_k: 40 items are ``lax.top_k``'s everywhere
+    assert counts.get("select.plain", 0) == (
+        len(parts) if branch == "device" else 0)
+    assert "select.blocked" not in counts
 
 
 # ---------------------------------------------------------------------------
@@ -265,6 +270,8 @@ def test_deploy_warmup_leaves_no_compile_for_any_live_batch(trained, buckets):
     # and the pow2 rounding, never the cap's 2048 a batch
     assert 0 < b["rowsReal"] <= b["rowsScored"] < 8 * b["rowsReal"]
     assert b["rowsScored"] <= 32 * b["batches"]
+    # a batch of unknown users alone reaches no program
+    assert b["select"]["blocked"] == 0 < b["select"]["plain"] <= b["batches"]
 
 
 def test_aot_export_holds_every_row_bucket_and_serves_with_no_compile(
