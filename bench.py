@@ -3371,6 +3371,7 @@ def _bench_scale_sharded() -> dict:
         ALSAlgorithmParams,
         ALSModel,
     )
+    from predictionio_tpu.templates.retrieval import serving_state
 
     devices = len(jax.devices())
     hbm_budget = 17 * 2**30  # the v5e-class budget BENCH_r01 died against
@@ -3425,7 +3426,7 @@ def _bench_scale_sharded() -> dict:
 
         model_s = ALSModel(uf.copy(), vf.copy(), empty, empty)
         model_s, bytes_sharded = algo.shard_model_for_serving(model_s)
-        info = model_s._pio_shards
+        info = serving_state(model_s).shards
         S = info.num_shards
         measured_per_dev = sharding.per_device_bytes(
             model_s.user_factors
@@ -3472,12 +3473,12 @@ def _bench_scale_sharded() -> dict:
         model_q, bytes_quant = algo.quantize_model_for_serving(
             model_q, shard=True
         )
-        q_info = model_q._pio_shards
+        q_info = serving_state(model_q).shards
         measured_q = sharding.per_device_bytes_quantized(
             model_q.user_factors
         ) + sharding.per_device_bytes_quantized(model_q.item_factors)
         quant_ok = measured_q <= repl / (S * 3.5)
-        qrt = model_q._pio_quant
+        qrt = serving_state(model_q).quant
         q_shard_stats, q_shard_ids = timed(
             lambda q: quant.run_topk(
                 qrt, model_q.user_factors, model_q.item_factors, q, k,

@@ -197,7 +197,7 @@ DEFAULT_MANIFEST: Manifest = (
     PackageRule(
         package="predictionio_tpu/templates",
         sibling_isolation=True,
-        allow=("serving_util", "columnar_util", "results"),
+        allow=("serving_util", "retrieval", "columnar_util", "results"),
         reason="a template must stay copy-out-able as a standalone engine "
         "(`pio template get`); shared code belongs in a helper module "
         "directly under templates/",

@@ -766,8 +766,8 @@ class AnnRuntime:
     """Per-model serving state: the index, the deploy-time ``nprobe``,
     build info, and thread-safe query counters for ``/stats.json``.
 
-    Attached to a model as ``model._pio_ann`` by the algorithm's
-    ``build_ann_for_serving`` hook (driven by
+    Held as ``ann`` of the model's ``templates.retrieval.ServingState``
+    by the algorithm's ``build_ann_for_serving`` hook (driven by
     :mod:`predictionio_tpu.workflow.device_state` at (re)load), detached
     by ``release_ann_state`` when the generation is superseded."""
 

@@ -310,9 +310,9 @@ def quantized_topk_users(
 
 
 class QuantRuntime:
-    """Per-model serving state of the quantized tier, attached as
-    ``model._pio_quant`` by the algorithms' ``quantize_model_for_serving``
-    hooks: the mode, the real byte ledger (codes/scales vs the f32
+    """Per-model serving state of the quantized tier, held as ``quant``
+    of the model's ``templates.retrieval.ServingState`` by the
+    ``quantize_model_for_serving`` hook: the mode, the real byte ledger (codes/scales vs the f32
     baseline), measured quantization error, and thread-safe counters
     for the ``/stats.json`` ``quant`` block — including the MEASURED
     rescore depth (the ``k'`` each bucket actually paid)."""

@@ -82,8 +82,9 @@ GROW_STEP = 1024
 
 @dataclasses.dataclass
 class ShardInfo:
-    """Per-model sharded-serving state, attached as ``model._pio_shards``
-    by the algorithms' ``shard_model_for_serving`` hooks.
+    """Per-model sharded-serving state, held as ``shards`` of the model's
+    ``templates.retrieval.ServingState`` by the ``shard_model_for_serving``
+    hook.
 
     ``rows`` maps side name (``"user"``/``"item"``) to the LOGICAL row
     count — the physical tables are padded up to a multiple of the mesh

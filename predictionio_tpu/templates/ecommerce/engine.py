@@ -46,6 +46,7 @@ from predictionio_tpu.data.store import LEventStore, PEventStore
 from predictionio_tpu.ops.als import ALSConfig, factors_to_host, train_als
 from predictionio_tpu.ops.topk import NO_ITEM, bucket_width, top_k_host
 from predictionio_tpu.templates.results import ItemScore, PredictedResult
+from predictionio_tpu.templates.retrieval import ServingState, serving_state
 from predictionio_tpu.templates.serving_util import (
     TOPK_CHUNK,
     TopkFilter,
@@ -233,6 +234,18 @@ class ECommModel:
     category_index: BiMap | None = None
 
 
+@dataclasses.dataclass
+class ECommServingState(ServingState):
+    """What this engine keeps beside a deployed model."""
+
+    #: pinned: the item factors and the category codes on the device, as
+    #: ``ops.als.tile_items`` cut them
+    item_tiles: Any = None
+    code_tiles: Any = None
+    #: ``(unavailable ids, mask)`` of the last :meth:`ECommAlgorithm._blocked`
+    blocked: tuple | None = None
+
+
 def category_arrays(categories: dict, item_index: BiMap) -> tuple[np.ndarray, BiMap]:
     """``{item id: categories}`` as ``(codes int32[I, C], name -> code)``:
     any number of distinct categories, ``C`` the most one item carries."""
@@ -337,11 +350,11 @@ class ECommAlgorithm(JaxAlgorithm):
         ``bool[items]`` or, pinned, the device's ``bool[tiles, width]`` with
         the padding past the catalog blocked too — made when the constraint
         changes, not per batch."""
-        cached = getattr(model, "_pio_blocked", None)
-        if cached is not None and cached[0] == unavailable:
-            return cached[1]
+        state = serving_state(model, ECommServingState)
+        if state.blocked is not None and state.blocked[0] == unavailable:
+            return state.blocked[1]
         n = len(model.item_index)
-        tiles = getattr(model, "_pio_item_tiles", None)
+        tiles = state.item_tiles
         mask = np.zeros(n if tiles is None else tiles.shape[0] * tiles.shape[2], bool)
         mask[n:] = True
         rows = [model.item_index.get(i) for i in unavailable]
@@ -350,7 +363,7 @@ class ECommAlgorithm(JaxAlgorithm):
             import jax
 
             mask = jax.device_put(mask.reshape(tiles.shape[0], tiles.shape[2]))
-        model._pio_blocked = (set(unavailable), mask)
+        state.blocked = (set(unavailable), mask)
         return mask
 
     def _rules(
@@ -383,11 +396,11 @@ class ECommAlgorithm(JaxAlgorithm):
         count("filter.excludedIds",
               sum(map(len, left_out)) + len(unavailable) * len(queries))
         count("filter.categoryRows", sum(1 for names in asked if names))
-        tiles = getattr(model, "_pio_item_tiles", None)
+        state = serving_state(model, ECommServingState)
         return TopkFilter(
-            codes=codes if tiles is None else model._pio_code_tiles,
+            codes=codes if state.item_tiles is None else state.code_tiles,
             blocked=self._blocked(model, unavailable),
-            wanted=wanted, excluded=excluded, item_tiles=tiles,
+            wanted=wanted, excluded=excluded, item_tiles=state.item_tiles,
         )
 
     def predict(self, model: ECommModel, query: Query) -> PredictedResult:
@@ -487,17 +500,18 @@ class ECommAlgorithm(JaxAlgorithm):
         from predictionio_tpu.ops.als import tile_items
 
         codes, _ = self._categories(model)
-        model._pio_blocked = None
-        model._pio_item_tiles = tile_items(
+        state = serving_state(model, ECommServingState)
+        state.blocked = None
+        state.item_tiles = tile_items(
             np.asarray(model.item_factors, np.float32), 0.0
         )
-        model._pio_code_tiles = tile_items(codes, -1)
-        model._pio_pinned = True
-        model._pio_bytes_by_dtype = {
-            "float32": int(model._pio_item_tiles.nbytes),
-            "int32": int(model._pio_code_tiles.nbytes),
+        state.code_tiles = tile_items(codes, -1)
+        state.pinned = True
+        state.bytes_by_dtype = {
+            "float32": int(state.item_tiles.nbytes),
+            "int32": int(state.code_tiles.nbytes),
         }
-        return model, sum(model._pio_bytes_by_dtype.values())
+        return model, sum(state.bytes_by_dtype.values())
 
 
 def engine_factory() -> Engine:

@@ -35,7 +35,7 @@ from predictionio_tpu.controller import (
 )
 from predictionio_tpu.data.aggregator import BiMap
 from predictionio_tpu.data.store import PEventStore
-from predictionio_tpu.templates.serving_util import TOPK_CHUNK
+from predictionio_tpu.templates.retrieval import TwoTableRetrieval, serving_state
 # re-exported (see __all__): the ranked-result wire types are shared by
 # the similarproduct and ecommerce templates via templates/results.py
 from predictionio_tpu.templates.results import ItemScore, PredictedResult
@@ -642,9 +642,11 @@ class ALSModel:
     item_index: BiMap
 
 
-class ALSAlgorithm(JaxAlgorithm):
+class ALSAlgorithm(TwoTableRetrieval, JaxAlgorithm):
     params_class = ALSAlgorithmParams
     query_class = Query
+    USER_TABLE = "user_factors"
+    ITEM_TABLE = "item_factors"
 
     def __init__(self, params: ALSAlgorithmParams):
         super().__init__(params)
@@ -702,296 +704,6 @@ class ALSAlgorithm(JaxAlgorithm):
             item_index=pd.item_index,
         )
 
-    def prepare_model_for_serving(self, model: ALSModel) -> ALSModel:
-        if self.params.serve_on_device:
-            import jax
-
-            from predictionio_tpu.templates.serving_util import (
-                device_latency_probe,
-            )
-
-            model.user_factors = jax.device_put(np.asarray(model.user_factors))
-            model.item_factors = jax.device_put(np.asarray(model.item_factors))
-            if len(model.user_index):
-                probe = Query(user=model.user_index.keys()[0], num=4)
-                model._pio_latency_probe = device_latency_probe(
-                    lambda: self.predict(model, probe),
-                    self.params.device_latency_budget_ms,
-                )
-                if not model._pio_latency_probe["ok"]:
-                    model.user_factors = np.asarray(model.user_factors)
-                    model.item_factors = np.asarray(model.item_factors)
-            return model
-        model.user_factors = np.ascontiguousarray(model.user_factors)
-        model.item_factors = np.ascontiguousarray(model.item_factors)
-        # warm-up so the first real query pays no compile / cache fill
-        # (parity: CreateServer's deploy-time warm-up)
-        if len(model.user_index):
-            self.predict(model, Query(user=model.user_index.keys()[0], num=4))
-        return model
-
-    # ------------------------------------------------------ pinned serving
-    def pin_model_for_serving(self, model: ALSModel) -> tuple[ALSModel, int]:
-        """``--pin-model`` cache tier (workflow/device_state.py):
-        ``device_put`` the factor matrices once per model generation so
-        every request scores against resident buffers — no per-request
-        host->device staging — and predict/batch_predict flip onto the
-        existing jitted device path (bucket-keyed static-``k`` score+
-        top-K programs). Returns the pinned model
-        and the device bytes it holds (``bytesPinned`` on /stats.json).
-        Idempotent: re-pinning an already-pinned model re-uses it."""
-        import jax
-
-        user = model.user_factors
-        item = model.item_factors
-        if isinstance(user, np.ndarray):
-            user = jax.device_put(user)
-        if isinstance(item, np.ndarray):
-            item = jax.device_put(item)
-        model.user_factors = user
-        model.item_factors = item
-        model._pio_pinned = True
-        nbytes = int(user.size) * user.dtype.itemsize
-        nbytes += int(item.size) * item.dtype.itemsize
-        model._pio_bytes_by_dtype = {"float32": nbytes}
-        return model, nbytes
-
-    # ------------------------------------------------------ sharded serving
-    def shard_model_for_serving(self, model: ALSModel) -> tuple[ALSModel, int]:
-        """``--shard-factors`` tier (workflow/device_state.py): pin
-        factor SHARDS per device — each of the ``S`` local devices holds
-        a ``[rows/S, K]`` slice of each table instead of a replica, so
-        per-device factor memory is ``O((U+I)·K / S)`` and the largest
-        servable catalog scales with the mesh (the ALX layout training
-        already uses, extended to the query path). Top-K routes through
-        the shard_map kernel in ``parallel/sharding.py``, which is
-        tie-stable-identical to the replicated exact path. Falls back to
-        plain pinning on a single-device host."""
-        from predictionio_tpu.parallel import sharding
-
-        mesh = sharding.serving_mesh()
-        if mesh is None:
-            logging.getLogger(__name__).warning(
-                "--shard-factors requested but only one device is "
-                "visible; falling back to --pin-model replication"
-            )
-            return self.pin_model_for_serving(model)
-        user = sharding.shard_table(np.asarray(model.user_factors), mesh)
-        item = sharding.shard_table(np.asarray(model.item_factors), mesh)
-        info = sharding.ShardInfo(
-            mesh=mesh,
-            rows={
-                "user": int(np.asarray(model.user_factors).shape[0]),
-                "item": int(np.asarray(model.item_factors).shape[0]),
-            },
-        )
-        model.user_factors = user
-        model.item_factors = item
-        model._pio_shards = info
-        model._pio_pinned = True
-        nbytes = int(user.size) * user.dtype.itemsize
-        nbytes += int(item.size) * item.dtype.itemsize
-        model._pio_bytes_by_dtype = {"float32": nbytes}
-        return model, nbytes
-
-    # ---------------------------------------------------- quantized serving
-    def quantize_model_for_serving(
-        self, model: ALSModel, mode: str = "int8", shard: bool = False
-    ) -> tuple[ALSModel, int]:
-        """``--quantize int8`` tier (workflow/device_state.py): pin the
-        factor tables as int8 codes + per-row f32 scales (ops/quant.py's
-        one rounding rule) so the served catalog costs ``rank + 4``
-        bytes per row instead of ``4·rank``. Serving routes through the
-        recall-guarded two-stage kernel (int8 coarse scan over-fetching
-        ``max(4k, k+64)``, f32 rescore of only the gathered candidates,
-        shared tie rule). ``shard=True`` composes with
-        ``--shard-factors``: codes and scales shard over the model mesh,
-        so per-device bytes are ``catalog·(rank+4)/S`` — the tiers
-        multiply. Returns ``(model, real pinned bytes)``; the per-dtype
-        ledger lands on ``model._pio_bytes_by_dtype``."""
-        from predictionio_tpu.ops import quant
-
-        user_f = np.asarray(model.user_factors, np.float32)
-        item_f = np.asarray(model.item_factors, np.float32)
-        mesh = None
-        if shard:
-            from predictionio_tpu.parallel import sharding
-
-            mesh = sharding.serving_mesh()
-            if mesh is None:
-                logging.getLogger(__name__).warning(
-                    "--shard-factors requested but only one device is "
-                    "visible; quantized tables pin replicated"
-                )
-        if mesh is not None:
-            from predictionio_tpu.parallel import sharding
-
-            user = sharding.shard_quantized_table(user_f, mesh)
-            item = sharding.shard_quantized_table(item_f, mesh)
-            model._pio_shards = sharding.ShardInfo(
-                mesh=mesh,
-                rows={
-                    "user": int(user_f.shape[0]),
-                    "item": int(item_f.shape[0]),
-                },
-            )
-        else:
-            user = quant.quantize_table(user_f)
-            item = quant.quantize_table(item_f)
-        model.user_factors = user
-        model.item_factors = item
-        model._pio_pinned = True
-        breakdown = {
-            "int8": user.nbytes_codes + item.nbytes_codes,
-            "scalesFloat32": user.nbytes_scales + item.nbytes_scales,
-        }
-        model._pio_bytes_by_dtype = breakdown
-        model._pio_quant = quant.QuantRuntime(
-            mode=mode,
-            bytes_by_dtype=breakdown,
-            bytes_f32=user_f.nbytes + item_f.nbytes,
-            # item-side error is what reorders results; one pass at
-            # load time, reported on /stats.json quant
-            error=quant.quantization_error(
-                item_f,
-                np.asarray(item.codes)[: item_f.shape[0]],
-                np.asarray(item.scales)[: item_f.shape[0]],
-            ),
-        )
-        return model, sum(breakdown.values())
-
-    def release_pinned_model(self, model: ALSModel) -> None:
-        """Drop a superseded generation's pinned buffers (hot reload must
-        not accumulate one catalog of device memory per swap). For a
-        SHARDED generation this must drop every device's shard handles —
-        not just device 0's — so the host-gather strips the even-shard
-        padding and the ShardInfo goes with the buffers. Quantized
-        tables dequantize back to host f32 (np.asarray reads through the
-        codes), and the QuantRuntime goes with them."""
-        shards = getattr(model, "_pio_shards", None)
-        quantized = getattr(model, "_pio_quant", None) is not None
-        # the AOT runtime is per-generation (its programs are lowered
-        # against this generation's table shapes) — it retires with the
-        # pinned buffers
-        if getattr(model, "_pio_aot", None) is not None:
-            model._pio_aot = None
-        if shards is not None:
-            model.user_factors = np.asarray(model.user_factors)[
-                : shards.rows["user"]
-            ]
-            model.item_factors = np.asarray(model.item_factors)[
-                : shards.rows["item"]
-            ]
-            model._pio_shards = None
-            model._pio_pinned = False
-            model._pio_quant = None
-            return
-        if getattr(model, "_pio_pinned", False) or quantized:
-            model.user_factors = np.asarray(model.user_factors)
-            model.item_factors = np.asarray(model.item_factors)
-            model._pio_pinned = False
-            model._pio_quant = None
-
-    # --------------------------------------------------- ANN retrieval
-    def build_ann_for_serving(self, model: ALSModel, ann) -> tuple[ALSModel, dict]:
-        """``--ann`` retrieval tier (workflow/device_state.py): cluster
-        the item factors into an on-device IVF index once per model
-        generation; predict/batch_predict then score only ``nprobe``
-        cluster slabs per query instead of the whole catalog. Returns
-        the model (with ``model._pio_ann`` attached) and the build info
-        for ``/stats.json``."""
-        from predictionio_tpu.ops import ivf
-
-        shards = getattr(model, "_pio_shards", None)
-        # np.asarray dequantizes a --quantize table; k-means runs on the
-        # f32 values either way, and the SERVED slabs re-quantize below
-        items = np.asarray(model.item_factors)
-        if shards is not None:
-            # sharded tables carry even-shard padding rows — the index
-            # must cluster only the LOGICAL catalog
-            items = items[: shards.rows["item"]]
-        index, info = ivf.build_ivf(
-            items,
-            nlist=ann.nlist, seed=ann.seed, iters=ann.kmeans_iters,
-            # --quantize composition: slabs stored int8 + per-lane
-            # scales, so per-probe gather bytes drop ~4x (the centroid
-            # stage stays f32)
-            quantize=getattr(model, "_pio_quant", None) is not None,
-        )
-        model._pio_ann = ivf.AnnRuntime(index, ann.nprobe, info)
-        if shards is not None:
-            # --shard-factors composition: the cluster-major slabs shard
-            # over the same model axis as the factor tables
-            info = dict(info, **ivf.shard_runtime(model._pio_ann, shards.mesh))
-        info = dict(info, algorithm=type(self).__name__,
-                    nprobe=model._pio_ann.nprobe)
-        return model, info
-
-    def release_ann_state(self, model: ALSModel) -> None:
-        """Drop a superseded generation's IVF index (same contract as
-        release_pinned_model: a hot-reloading server must not accumulate
-        one index of device memory per swap)."""
-        if getattr(model, "_pio_ann", None) is not None:
-            model._pio_ann = None
-
-    # --------------------------------------------------- AOT serving export
-    def aot_export_for_serving(self, model: ALSModel, buckets: list) -> dict:
-        """``--aot`` tier (workflow/aot.py): lower + serialize the pinned
-        exact serving programs per pow2 k-bucket, so replicas boot by
-        DESERIALIZING instead of tracing — zero serve-time compiles.
-
-        The export mirrors the JIT path's deliberate program split —
-        k-independent ``predict_scores`` plus per-bucket ``top_k_scores``
-        (and the batch GEMM+top-k per chunk/bucket) — rather than fusing
-        score+select into one program, so bit-identity with the jitted
-        path holds by construction: same jaxprs, same rounding, same tie
-        order. Sharded/quantized/ANN generations export nothing — their
-        kernels close over live runtime objects (mesh, codes, index) and
-        serve through their own budgeted paths."""
-        if getattr(model, "_pio_shards", None) is not None:
-            return {}
-        if getattr(model, "_pio_quant", None) is not None:
-            return {}
-        import jax
-        from jax import export as jax_export
-
-        from predictionio_tpu.ops.als import predict_scores, top_k_items_batch
-        from predictionio_tpu.ops.topk import top_k_scores
-        from predictionio_tpu.templates.serving_util import serving_row_buckets
-
-        n_users, rank = (int(d) for d in model.user_factors.shape)
-        n_items = int(model.item_factors.shape[0])
-        f32 = np.dtype(np.float32)
-        vec = jax.ShapeDtypeStruct((rank,), f32)
-        users = jax.ShapeDtypeStruct((n_users, rank), f32)
-        items = jax.ShapeDtypeStruct((n_items, rank), f32)
-        out = {"predict_scores": jax_export.export(predict_scores)(vec, items)}
-        for kb in buckets:
-            # bind the static k through a jitted closure — jax.export
-            # lowers concrete avals, static_argnames stay host-side
-            out[f"top_k_scores_b{kb}"] = jax_export.export(
-                jax.jit(lambda s, _k=kb: top_k_scores(s, _k))
-            )(jax.ShapeDtypeStruct((n_items,), f32))
-            batch = jax.jit(
-                lambda u, um, im, _k=kb: top_k_items_batch(u, um, im, _k)
-            )
-            # one program per row bucket a deploy can dispatch: those of
-            # a default batcher's batches, and a full batchpredict chunk
-            for rows in serving_row_buckets(self.BATCH_PREDICT_CHUNK):
-                out[f"top_k_items_batch_c{rows}_b{kb}"] = jax_export.export(
-                    batch
-                )(jax.ShapeDtypeStruct((rows,), np.dtype(np.int32)),
-                  users, items)
-        return out
-
-    def aot_warm_serving(self, model: ALSModel) -> None:
-        """Warm the pinned predict path's eager GLUE at boot: the
-        ``user_factors[uidx]`` row gather (dynamic_slice + squeeze) is
-        index-operand cached by jax, so one call here compiles the
-        executables every user's query will reuse — without it the
-        first query after an AOT boot still witnesses two compiles."""
-        if getattr(model, "_pio_pinned", False):
-            _ = model.user_factors[0]
     @staticmethod
     def _online_state(model: ALSModel, max_entities: int) -> dict:
         """Per-model online rating accumulator (LRU-bounded per side):
@@ -999,17 +711,16 @@ class ALSAlgorithm(JaxAlgorithm):
         entity's re-solve uses its accumulated ONLINE history anchored
         to its trained row (online/foldin.py). Dies with the model on a
         full /reload — by then a retrain owns the history."""
-        state = getattr(model, "_pio_online", None)
-        if state is None:
+        serving = serving_state(model)
+        if serving.online is None:
             from collections import OrderedDict
 
-            state = {
+            serving.online = {
                 "users": OrderedDict(),
                 "items": OrderedDict(),
                 "max": max_entities,
             }
-            model._pio_online = state
-        return state
+        return serving.online
 
     @staticmethod
     def _remember(side: "Any", key: str, other: str, t_us: int,
@@ -1192,106 +903,12 @@ class ALSAlgorithm(JaxAlgorithm):
         k = min(int(query.num), len(model.item_index))
         if k <= 0:
             return PredictedResult(())
-        ann = getattr(model, "_pio_ann", None)
-        shards = getattr(model, "_pio_shards", None)
-        quantrt = getattr(model, "_pio_quant", None)
-        if ann is not None:
-            from predictionio_tpu.ops import ivf
-
-            if quantrt is not None or shards is not None:
-                # quantized and/or sharded user table: only the
-                # requested row is dequantized / leaves its shard
-                from predictionio_tpu.parallel import sharding
-
-                qvec = np.asarray(
-                    sharding.take_rows(model.user_factors, [uidx])
-                )[0]
-            else:
-                qvec = np.asarray(model.user_factors[uidx])
-            ids, scores = ivf.query_topk(ann, qvec, k)
-            pairs = list(zip(ids, scores))
-        elif quantrt is not None:
-            # quantized exact: int8 coarse scan with over-fetch, f32
-            # rescore of the gathered candidates (ops/quant.py); routes
-            # through the shard_map kernel under --shard-factors
-            from predictionio_tpu.ops import quant
-
-            ids_b, scores_b = quant.topk_users(
-                quantrt, model.user_factors, model.item_factors,
-                [uidx], k, shards=shards,
-            )
-            pairs = [
-                (int(i), float(s)) for i, s in zip(ids_b[0], scores_b[0])
-            ]
-        elif shards is not None:
-            # sharded exact: one dispatch, each device scores its item
-            # shard, only the S*k finalists cross the interconnect
-            from predictionio_tpu.parallel import sharding
-
-            ids_b, scores_b = sharding.topk_users(
-                shards, model.user_factors, model.item_factors, [uidx], k
-            )
-            pairs = [
-                (int(i), float(s)) for i, s in zip(ids_b[0], scores_b[0])
-            ]
-        elif isinstance(model.item_factors, np.ndarray):
-            # host path: one GEMV + partial sort, microseconds at catalog
-            # sizes below ~10^6 items (shared tie rule: ops/topk.py)
-            from predictionio_tpu.ops.topk import top_k_host
-
-            scores = model.item_factors @ np.asarray(model.user_factors[uidx])
-            top, vals = top_k_host(scores, k)
-            pairs = [(int(i), float(s)) for i, s in zip(top, vals)]
-        else:
-            # pinned-device path: k buckets to a power of two (floor 16)
-            # so the jitted selection compiles once per bucket — raw
-            # query.num would key the jit cache at request cardinality
-            # (piolint PIO306; same idiom as ivf.query_topk). Scoring is
-            # a SEPARATE k-independent program (predict_scores) so the
-            # GEMV's float rounding — and therefore tie order vs the
-            # host path — cannot drift with the chosen bucket
-            from predictionio_tpu.ops.als import predict_scores
-            from predictionio_tpu.ops.topk import bucket_k, top_k_scores
-
-            kb = bucket_k(k, int(model.item_factors.shape[0]))
-            idx = scores = None
-            aot = getattr(model, "_pio_aot", None)
-            if aot is not None:
-                # --aot tier 1: the SAME two programs, deserialized at
-                # boot instead of traced here; any call-time failure
-                # (e.g. shape drift after an online catalog grow)
-                # disables the key and the jitted path takes over
-                score_fn = aot.get("predict_scores")
-                topk_fn = aot.get(f"top_k_scores_b{kb}")
-                if score_fn is not None and topk_fn is not None:
-                    try:
-                        dev_scores = score_fn(
-                            model.user_factors[uidx], model.item_factors
-                        )
-                        idx, scores = topk_fn(dev_scores)
-                    except Exception as e:  # noqa: BLE001 - degrade, don't 500
-                        aot.disable("predict_scores", str(e))
-                        aot.disable(f"top_k_scores_b{kb}", str(e))
-                        idx = scores = None
-            if idx is None:
-                dev_scores = predict_scores(
-                    model.user_factors[uidx], model.item_factors
-                )
-                idx, scores = top_k_scores(dev_scores, kb)
-            pairs = [
-                (int(i), float(s))
-                for i, s in zip(np.asarray(idx)[:k], np.asarray(scores)[:k])
-            ]
         return PredictedResult(
             tuple(
-                ItemScore(item=model.item_index.inverse(i), score=s) for i, s in pairs
+                ItemScore(item=model.item_index.inverse(i), score=s)
+                for i, s in self.top_k(model, uidx, k)
             )
         )
-
-    #: the most queries one device dispatch / host GEMM scores (a cap,
-    #: not a shape — see serving_util.TOPK_CHUNK; kept as a class
-    #: attribute so tests can shrink it to force multi-chunk coverage)
-    BATCH_PREDICT_CHUNK = TOPK_CHUNK
 
     def batch_predict(
         self, model: ALSModel, queries: Sequence[tuple[int, Query]]
@@ -1316,7 +933,7 @@ class ALSAlgorithm(JaxAlgorithm):
         if not valid:
             return results
         inverse = model.item_index.inverse
-        for part, idx_l, score_l in self._topk_staged(model, valid):
+        for part, idx_l, score_l in self.top_k_staged(model, valid):
             with span("format"):
                 for (oi, _, k), ids, scs in zip(part, idx_l, score_l):
                     results.append((
@@ -1327,22 +944,6 @@ class ALSAlgorithm(JaxAlgorithm):
                         )),
                     ))
         return results
-
-    def _topk_staged(self, model: ALSModel, valid: list):
-        """Chunked top-k over ``valid = [(slot, uidx, k), ...]`` — see
-        :func:`predictionio_tpu.templates.serving_util.chunked_topk`.
-        With ``--ann`` state attached the chunks route through the IVF
-        kernel (only ``nprobe`` cluster slabs scored per query)."""
-        from predictionio_tpu.templates.serving_util import chunked_topk
-
-        return chunked_topk(
-            model.user_factors, model.item_factors, valid,
-            chunk=self.BATCH_PREDICT_CHUNK,
-            ann=getattr(model, "_pio_ann", None),
-            shards=getattr(model, "_pio_shards", None),
-            quant=getattr(model, "_pio_quant", None),
-            aot=getattr(model, "_pio_aot", None),
-        )
 
     def batch_predict_json(
         self, model: ALSModel, bodies: Sequence[Any]
@@ -1389,7 +990,7 @@ class ALSAlgorithm(JaxAlgorithm):
                 for i in range(n_items)
             ]
             model._item_json_prefix = pre
-        for part, idx_l, score_l in self._topk_staged(model, valid):
+        for part, idx_l, score_l in self.top_k_staged(model, valid):
             for (j, _, k), ids, scs in zip(part, idx_l, score_l):
                 out[j] = (
                     '{"itemScores": ['

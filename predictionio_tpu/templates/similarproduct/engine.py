@@ -34,6 +34,7 @@ from predictionio_tpu.data.aggregator import BiMap
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.ops.als import ALSConfig, factors_to_host, train_als
 from predictionio_tpu.templates.results import ItemScore, PredictedResult
+from predictionio_tpu.templates.retrieval import ItemTableAnn, serving_state
 
 __all__ = [
     "Query",
@@ -178,9 +179,13 @@ class SimilarProductModel:
     categories: dict
 
 
-class ALSAlgorithm(JaxAlgorithm):
+class ALSAlgorithm(ItemTableAnn, JaxAlgorithm):
     params_class = ALSAlgorithmParams
     query_class = Query
+    #: ``--ann`` clusters the L2-normalized item factors: cosine scoring is
+    #: the inner product on unit rows, so the clustered layout is exactly
+    #: the metric the queries use
+    ITEM_TABLE = "item_factors"
 
     def __init__(self, params: ALSAlgorithmParams):
         super().__init__(params)
@@ -206,28 +211,6 @@ class ALSAlgorithm(JaxAlgorithm):
             categories=pd.categories,
         )
 
-    # --------------------------------------------------- ANN retrieval
-    def build_ann_for_serving(
-        self, model: SimilarProductModel, ann
-    ) -> tuple[SimilarProductModel, dict]:
-        """``--ann`` retrieval tier: IVF over the L2-normalized item
-        factors (cosine scoring == inner product on unit rows, so the
-        clustered layout is exactly the metric the queries use)."""
-        from predictionio_tpu.ops import ivf
-
-        index, info = ivf.build_ivf(
-            np.asarray(model.item_factors),
-            nlist=ann.nlist, seed=ann.seed, iters=ann.kmeans_iters,
-        )
-        model._pio_ann = ivf.AnnRuntime(index, ann.nprobe, info)
-        info = dict(info, algorithm=type(self).__name__,
-                    nprobe=model._pio_ann.nprobe)
-        return model, info
-
-    def release_ann_state(self, model: SimilarProductModel) -> None:
-        if getattr(model, "_pio_ann", None) is not None:
-            model._pio_ann = None
-
     def predict(self, model: SimilarProductModel, query: Query) -> PredictedResult:
         idxs = [model.item_index.get(i) for i in query.items]
         idxs = [i for i in idxs if i is not None]
@@ -237,7 +220,7 @@ class ALSAlgorithm(JaxAlgorithm):
         norm = np.linalg.norm(target)
         if norm == 0:
             return PredictedResult(())
-        ann = getattr(model, "_pio_ann", None)
+        ann = serving_state(model).ann
         if ann is not None and not query.white_list and not query.categories:
             # ANN path. Exclusions (query items + blacklist) are applied
             # by OVER-FETCHING num + |excluded| candidates before the

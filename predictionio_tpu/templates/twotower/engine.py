@@ -48,6 +48,7 @@ from predictionio_tpu.controller import (
 from predictionio_tpu.data.aggregator import BiMap
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.ops.twotower import TwoTowerConfig, train_two_tower
+from predictionio_tpu.templates.retrieval import TwoTableRetrieval
 
 __all__ = [
     "DataSourceParams",
@@ -277,9 +278,11 @@ class TwoTowerServingModel:
     loss_history: tuple = ()
 
 
-class TwoTowerAlgorithm(JaxAlgorithm):
+class TwoTowerAlgorithm(TwoTableRetrieval, JaxAlgorithm):
     params_class = TwoTowerParams
     query_class = Query
+    USER_TABLE = "user_vecs"
+    ITEM_TABLE = "item_vecs"
 
     def __init__(self, params: TwoTowerParams):
         super().__init__(params)
@@ -340,264 +343,6 @@ class TwoTowerAlgorithm(JaxAlgorithm):
             loss_history=model.loss_history,
         )
 
-    def prepare_model_for_serving(
-        self, model: TwoTowerServingModel
-    ) -> TwoTowerServingModel:
-        if self.params.serve_on_device:
-            import jax
-
-            from predictionio_tpu.templates.serving_util import (
-                device_latency_probe,
-            )
-
-            model.user_vecs = jax.device_put(np.asarray(model.user_vecs))
-            model.item_vecs = jax.device_put(np.asarray(model.item_vecs))
-            if len(model.user_index):
-                probe = Query(user=model.user_index.keys()[0], num=4)
-                model._pio_latency_probe = device_latency_probe(
-                    lambda: self.predict(model, probe),
-                    self.params.device_latency_budget_ms,
-                )
-                if not model._pio_latency_probe["ok"]:
-                    model.user_vecs = np.asarray(model.user_vecs)
-                    model.item_vecs = np.asarray(model.item_vecs)
-            return model
-        model.user_vecs = np.ascontiguousarray(model.user_vecs)
-        model.item_vecs = np.ascontiguousarray(model.item_vecs)
-        if len(model.user_index):
-            self.predict(model, Query(user=model.user_index.keys()[0], num=4))
-        return model
-
-    # ------------------------------------------------------ pinned serving
-    def pin_model_for_serving(
-        self, model: TwoTowerServingModel
-    ) -> tuple[TwoTowerServingModel, int]:
-        """``--pin-model`` cache tier (workflow/device_state.py): same
-        contract as the recommendation template — tower matrices are
-        ``device_put`` once per model generation, predictions flip onto
-        the jitted device path, and the pinned bytes surface on
-        ``/stats.json``."""
-        import jax
-
-        user = model.user_vecs
-        item = model.item_vecs
-        if isinstance(user, np.ndarray):
-            user = jax.device_put(user)
-        if isinstance(item, np.ndarray):
-            item = jax.device_put(item)
-        model.user_vecs = user
-        model.item_vecs = item
-        model._pio_pinned = True
-        nbytes = int(user.size) * user.dtype.itemsize
-        nbytes += int(item.size) * item.dtype.itemsize
-        model._pio_bytes_by_dtype = {"float32": nbytes}
-        return model, nbytes
-
-    # ------------------------------------------------------ sharded serving
-    def shard_model_for_serving(
-        self, model: TwoTowerServingModel
-    ) -> tuple[TwoTowerServingModel, int]:
-        """``--shard-factors`` tier: same contract as the recommendation
-        template — tower matrices shard row-wise over a one-axis model
-        mesh (each device holds ``rows/S``), retrieval routes through
-        the tie-stable shard_map kernel, single-device hosts fall back
-        to plain pinning."""
-        from predictionio_tpu.parallel import sharding
-
-        mesh = sharding.serving_mesh()
-        if mesh is None:
-            logging.getLogger(__name__).warning(
-                "--shard-factors requested but only one device is "
-                "visible; falling back to --pin-model replication"
-            )
-            return self.pin_model_for_serving(model)
-        user = sharding.shard_table(np.asarray(model.user_vecs), mesh)
-        item = sharding.shard_table(np.asarray(model.item_vecs), mesh)
-        info = sharding.ShardInfo(
-            mesh=mesh,
-            rows={
-                "user": int(np.asarray(model.user_vecs).shape[0]),
-                "item": int(np.asarray(model.item_vecs).shape[0]),
-            },
-        )
-        model.user_vecs = user
-        model.item_vecs = item
-        model._pio_shards = info
-        model._pio_pinned = True
-        nbytes = int(user.size) * user.dtype.itemsize
-        nbytes += int(item.size) * item.dtype.itemsize
-        model._pio_bytes_by_dtype = {"float32": nbytes}
-        return model, nbytes
-
-    # ---------------------------------------------------- quantized serving
-    def quantize_model_for_serving(
-        self, model: TwoTowerServingModel, mode: str = "int8",
-        shard: bool = False,
-    ) -> tuple[TwoTowerServingModel, int]:
-        """``--quantize int8`` tier: same contract as the recommendation
-        template — tower matrices pin as int8 codes + per-row scales,
-        retrieval runs the recall-guarded two-stage kernel, and
-        ``shard=True`` shards codes and scales over the model mesh so
-        the memory tiers compose multiplicatively."""
-        from predictionio_tpu.ops import quant
-
-        user_f = np.asarray(model.user_vecs, np.float32)
-        item_f = np.asarray(model.item_vecs, np.float32)
-        mesh = None
-        if shard:
-            from predictionio_tpu.parallel import sharding
-
-            mesh = sharding.serving_mesh()
-            if mesh is None:
-                logging.getLogger(__name__).warning(
-                    "--shard-factors requested but only one device is "
-                    "visible; quantized tables pin replicated"
-                )
-        if mesh is not None:
-            from predictionio_tpu.parallel import sharding
-
-            user = sharding.shard_quantized_table(user_f, mesh)
-            item = sharding.shard_quantized_table(item_f, mesh)
-            model._pio_shards = sharding.ShardInfo(
-                mesh=mesh,
-                rows={
-                    "user": int(user_f.shape[0]),
-                    "item": int(item_f.shape[0]),
-                },
-            )
-        else:
-            user = quant.quantize_table(user_f)
-            item = quant.quantize_table(item_f)
-        model.user_vecs = user
-        model.item_vecs = item
-        model._pio_pinned = True
-        breakdown = {
-            "int8": user.nbytes_codes + item.nbytes_codes,
-            "scalesFloat32": user.nbytes_scales + item.nbytes_scales,
-        }
-        model._pio_bytes_by_dtype = breakdown
-        model._pio_quant = quant.QuantRuntime(
-            mode=mode,
-            bytes_by_dtype=breakdown,
-            bytes_f32=user_f.nbytes + item_f.nbytes,
-            error=quant.quantization_error(
-                item_f,
-                np.asarray(item.codes)[: item_f.shape[0]],
-                np.asarray(item.scales)[: item_f.shape[0]],
-            ),
-        )
-        return model, sum(breakdown.values())
-
-    def release_pinned_model(self, model: TwoTowerServingModel) -> None:
-        shards = getattr(model, "_pio_shards", None)
-        quantized = getattr(model, "_pio_quant", None) is not None
-        # the AOT runtime is lowered against this generation's tower
-        # shapes — it retires with the pinned buffers
-        if getattr(model, "_pio_aot", None) is not None:
-            model._pio_aot = None
-        if shards is not None:
-            # every device's shard handles die here, and the host copy
-            # strips the even-shard padding rows (np.asarray dequantizes
-            # a --quantize table back to f32)
-            model.user_vecs = np.asarray(model.user_vecs)[
-                : shards.rows["user"]
-            ]
-            model.item_vecs = np.asarray(model.item_vecs)[
-                : shards.rows["item"]
-            ]
-            model._pio_shards = None
-            model._pio_pinned = False
-            model._pio_quant = None
-            return
-        if getattr(model, "_pio_pinned", False) or quantized:
-            model.user_vecs = np.asarray(model.user_vecs)
-            model.item_vecs = np.asarray(model.item_vecs)
-            model._pio_pinned = False
-            model._pio_quant = None
-
-    # --------------------------------------------------- AOT serving export
-    def aot_export_for_serving(
-        self, model: TwoTowerServingModel, buckets: list
-    ) -> dict:
-        """``--aot`` tier (workflow/aot.py): same contract as the
-        recommendation template — serialize the pinned exact serving
-        programs (k-independent ``predict_scores`` + per-bucket top-k,
-        plus the chunked batch GEMM) so replicas deserialize at boot
-        instead of tracing; the two-program split keeps results
-        bit-identical to the jitted path by construction. Sharded and
-        quantized generations export nothing (their kernels close over
-        live runtime objects)."""
-        if getattr(model, "_pio_shards", None) is not None:
-            return {}
-        if getattr(model, "_pio_quant", None) is not None:
-            return {}
-        import jax
-        from jax import export as jax_export
-
-        from predictionio_tpu.ops.als import predict_scores, top_k_items_batch
-        from predictionio_tpu.ops.topk import top_k_scores
-        from predictionio_tpu.templates.serving_util import serving_row_buckets
-
-        n_users, rank = (int(d) for d in model.user_vecs.shape)
-        n_items = int(model.item_vecs.shape[0])
-        f32 = np.dtype(np.float32)
-        vec = jax.ShapeDtypeStruct((rank,), f32)
-        users = jax.ShapeDtypeStruct((n_users, rank), f32)
-        items = jax.ShapeDtypeStruct((n_items, rank), f32)
-        out = {"predict_scores": jax_export.export(predict_scores)(vec, items)}
-        for kb in buckets:
-            out[f"top_k_scores_b{kb}"] = jax_export.export(
-                jax.jit(lambda s, _k=kb: top_k_scores(s, _k))
-            )(jax.ShapeDtypeStruct((n_items,), f32))
-            batch = jax.jit(
-                lambda u, um, im, _k=kb: top_k_items_batch(u, um, im, _k)
-            )
-            for rows in serving_row_buckets():
-                out[f"top_k_items_batch_c{rows}_b{kb}"] = jax_export.export(
-                    batch
-                )(jax.ShapeDtypeStruct((rows,), np.dtype(np.int32)),
-                  users, items)
-        return out
-
-    def aot_warm_serving(self, model: TwoTowerServingModel) -> None:
-        """Warm the pinned predict path's eager GLUE at boot: the
-        ``user_vecs[uidx]`` row gather (dynamic_slice + squeeze) is
-        index-operand cached by jax, so one call here compiles the
-        executables every user's query will reuse (see the
-        recommendation template's twin)."""
-        if getattr(model, "_pio_pinned", False):
-            _ = model.user_vecs[0]
-    def build_ann_for_serving(
-        self, model: TwoTowerServingModel, ann
-    ) -> tuple[TwoTowerServingModel, dict]:
-        """``--ann`` retrieval tier (workflow/device_state.py): IVF over
-        the L2-normalized item-tower embeddings; serving scores only
-        ``nprobe`` cluster slabs per query. The seen-item filter keeps
-        its over-fetch (num + |seen| candidates fetched BEFORE the
-        merge), so ANN answers still hold ``num`` unseen items whenever
-        the probed clusters do."""
-        from predictionio_tpu.ops import ivf
-
-        shards = getattr(model, "_pio_shards", None)
-        items = np.asarray(model.item_vecs)  # dequantizes under --quantize
-        if shards is not None:
-            items = items[: shards.rows["item"]]
-        index, info = ivf.build_ivf(
-            items,
-            nlist=ann.nlist, seed=ann.seed, iters=ann.kmeans_iters,
-            quantize=getattr(model, "_pio_quant", None) is not None,
-        )
-        model._pio_ann = ivf.AnnRuntime(index, ann.nprobe, info)
-        if shards is not None:
-            info = dict(info, **ivf.shard_runtime(model._pio_ann, shards.mesh))
-        info = dict(info, algorithm=type(self).__name__,
-                    nprobe=model._pio_ann.nprobe)
-        return model, info
-
-    def release_ann_state(self, model: TwoTowerServingModel) -> None:
-        if getattr(model, "_pio_ann", None) is not None:
-            model._pio_ann = None
-
     # ----------------------------------------------- online streaming SGD
     def online_trainer_spec(self, model: TwoTowerServingModel) -> dict:
         """Opt into the streaming mini-batch trainer (``pio deploy
@@ -654,8 +399,6 @@ class TwoTowerAlgorithm(JaxAlgorithm):
         instead of one GEMV/dispatch per query). Seen-item filtering
         matches :meth:`predict`: fetch ``num + len(seen)`` candidates,
         then drop seen ones host-side."""
-        from predictionio_tpu.templates.serving_util import chunked_topk
-
         n_items = len(model.item_index)
         results: list[tuple[int, PredictedResult]] = []
         valid: list[tuple[int, int, int]] = []
@@ -676,13 +419,7 @@ class TwoTowerAlgorithm(JaxAlgorithm):
             nums[idx] = num
             valid.append((idx, uidx, k))
         inverse = model.item_index.inverse
-        for part, idx_l, score_l in chunked_topk(
-            model.user_vecs, model.item_vecs, valid,
-            ann=getattr(model, "_pio_ann", None),
-            shards=getattr(model, "_pio_shards", None),
-            quant=getattr(model, "_pio_quant", None),
-            aot=getattr(model, "_pio_aot", None),
-        ):
+        for part, idx_l, score_l in self.top_k_staged(model, valid):
             for (oi, _, k), ids, scs in zip(part, idx_l, score_l):
                 seen = seen_by_slot[oi]
                 num = nums[oi]
@@ -708,85 +445,8 @@ class TwoTowerAlgorithm(JaxAlgorithm):
         k = min(int(query.num) + len(seen), len(model.item_index))
         if k <= 0:
             return PredictedResult(())
-        ann = getattr(model, "_pio_ann", None)
-        shards = getattr(model, "_pio_shards", None)
-        quantrt = getattr(model, "_pio_quant", None)
-        if ann is not None:
-            from predictionio_tpu.ops import ivf
-
-            if quantrt is not None or shards is not None:
-                from predictionio_tpu.parallel import sharding
-
-                qvec = np.asarray(
-                    sharding.take_rows(model.user_vecs, [uidx])
-                )[0]
-            else:
-                qvec = np.asarray(model.user_vecs[uidx])
-            ids, sc = ivf.query_topk(ann, qvec, k)
-            pairs = list(zip(ids, sc))
-        elif quantrt is not None:
-            from predictionio_tpu.ops import quant
-
-            ids_b, sc_b = quant.topk_users(
-                quantrt, model.user_vecs, model.item_vecs, [uidx], k,
-                shards=shards,
-            )
-            pairs = [(int(i), float(s)) for i, s in zip(ids_b[0], sc_b[0])]
-        elif shards is not None:
-            from predictionio_tpu.parallel import sharding
-
-            ids_b, sc_b = sharding.topk_users(
-                shards, model.user_vecs, model.item_vecs, [uidx], k
-            )
-            pairs = [(int(i), float(s)) for i, s in zip(ids_b[0], sc_b[0])]
-        elif isinstance(model.item_vecs, np.ndarray):
-            from predictionio_tpu.ops.topk import top_k_host
-
-            scores = model.item_vecs @ np.asarray(model.user_vecs[uidx])
-            # shared tie rule — descending score, ascending item index
-            # (ops/topk.py), so host and device paths agree
-            top, vals = top_k_host(scores, k)
-            pairs = [(int(i), float(s)) for i, s in zip(top, vals)]
-        else:
-            # k buckets to a power of two (floor 16) so the jitted
-            # selection compiles once per bucket — raw query.num would
-            # key the jit cache at request cardinality (piolint PIO306).
-            # Scoring runs in the k-independent predict_scores program so
-            # GEMV rounding (and tie order vs the host path) cannot
-            # drift with the chosen bucket
-            from predictionio_tpu.ops.als import predict_scores
-            from predictionio_tpu.ops.topk import bucket_k, top_k_scores
-
-            kb = bucket_k(k, int(model.item_vecs.shape[0]))
-            idx = sc = None
-            aot = getattr(model, "_pio_aot", None)
-            if aot is not None:
-                # --aot tier 1: same two programs, deserialized at boot;
-                # call-time failure disables the key and the jitted path
-                # takes over on the next dispatch
-                score_fn = aot.get("predict_scores")
-                topk_fn = aot.get(f"top_k_scores_b{kb}")
-                if score_fn is not None and topk_fn is not None:
-                    try:
-                        dev_scores = score_fn(
-                            model.user_vecs[uidx], model.item_vecs
-                        )
-                        idx, sc = topk_fn(dev_scores)
-                    except Exception as e:  # noqa: BLE001 - degrade, don't 500
-                        aot.disable("predict_scores", str(e))
-                        aot.disable(f"top_k_scores_b{kb}", str(e))
-                        idx = sc = None
-            if idx is None:
-                dev_scores = predict_scores(
-                    model.user_vecs[uidx], model.item_vecs
-                )
-                idx, sc = top_k_scores(dev_scores, kb)
-            pairs = [
-                (int(i), float(s))
-                for i, s in zip(np.asarray(idx)[:k], np.asarray(sc)[:k])
-            ]
         out = []
-        for i, score in pairs:
+        for i, score in self.top_k(model, uidx, k):
             item = model.item_index.inverse(i)
             if item in seen:
                 continue

@@ -210,7 +210,7 @@ def export_instance(
         hook = getattr(algo, "aot_export_for_serving", None)
         if hook is None:
             continue
-        n_items = _catalog_items(model)
+        n_items = _catalog_items(algo, model)
         buckets = serving_buckets(
             n_items,
             max_buckets=ledger_max_buckets(
@@ -307,8 +307,10 @@ def _fsync_dir(directory: str) -> None:
         os.close(fd)
 
 
-def _catalog_items(model) -> int:
-    items = getattr(model, "item_factors", None)
+def _catalog_items(algo, model) -> int:
+    """Rows of the item table the algorithm names (``ITEM_TABLE``,
+    ``templates/retrieval.py``): what caps the exported k buckets."""
+    items = getattr(model, getattr(algo, "ITEM_TABLE", "item_factors"), None)
     if items is not None and hasattr(items, "shape"):
         return int(items.shape[0])
     return 1

@@ -39,6 +39,8 @@ from __future__ import annotations
 import logging
 from typing import Sequence
 
+from predictionio_tpu.templates.retrieval import serving_state
+
 __all__ = [
     "DeviceUnavailableError",
     "pin_pairs",
@@ -95,7 +97,7 @@ def pin_pairs(
     factor tables pin as int8 codes + per-row f32 scales (``ops/quant``)
     so per-device factor bytes drop another ~4x ON TOP of the ``/S``
     from sharding — the two tiers compose multiplicatively. Hooks set
-    ``model._pio_bytes_by_dtype`` so :func:`bytes_by_dtype` can report
+    the state's ``bytes_by_dtype`` so :func:`bytes_by_dtype` can report
     the served per-dtype ledger, not recomputed shape math.
 
     ``aot`` (a :class:`predictionio_tpu.workflow.aot.AotConfig` with
@@ -103,11 +105,11 @@ def pin_pairs(
     BY DESERIALIZING: after pinning, the generation's exported serving
     programs are loaded from ``<aot.root>/<instance_id>/``, verified
     (fingerprint + per-blob SHA-256), warmed once, and attached as
-    ``model._pio_aot`` — so the serving path compiles NOTHING at request
+    the state's ``aot`` — so the serving path compiles NOTHING at request
     time. Any load failure logs loudly and serves through the jitted
     path (tier 2 with the persistent compilation cache, else tier 3),
-    bit-identical by construction; the tier report lands on
-    ``model._pio_aot_report`` for /stats.json."""
+    bit-identical by construction; the tier report lands on the state's
+    ``aot_report`` for /stats.json."""
     try:
         import jax  # noqa: F401  (availability probe only)
     except Exception:  # pragma: no cover - jax is a hard dep in practice
@@ -192,10 +194,11 @@ def _attach_aot(pairs: list, aot, instance_id: str | None) -> None:
         }
         logger.exception("AOT artifact load raised; serving via JIT")
     for algo, model in pairs:
-        if getattr(model, "_pio_pinned", False):
+        state = serving_state(model)
+        if state.pinned:
             if runtime is not None:
-                model._pio_aot = runtime
-            model._pio_aot_report = report
+                state.aot = runtime
+            state.aot_report = report
             # warm the engine's eager GLUE ops too (the row gather
             # feeding the exported programs): jax caches eager-op
             # executables by shape, so one warm call at boot is the
@@ -217,10 +220,11 @@ def aot_stats(pairs: Sequence) -> dict | None:
     report = None
     runtime = None
     for _, model in pairs:
+        state = serving_state(model)
         if report is None:
-            report = getattr(model, "_pio_aot_report", None)
+            report = state.aot_report
         if runtime is None:
-            runtime = getattr(model, "_pio_aot", None)
+            runtime = state.aot
     if report is None and runtime is None:
         return None
     out = dict(report or {})
@@ -231,8 +235,8 @@ def aot_stats(pairs: Sequence) -> dict | None:
 
 def serving_device(pairs: Sequence) -> dict:
     """The ``device`` block of ``GET /``: whether predict reads device
-    buffers or host arrays — judged from the arrays the served models
-    hold NOW, so a ``serveOnDevice`` probe that fell back reads "host" —
+    buffers or host arrays — judged from the arrays the models and their
+    states hold NOW, so a ``serveOnDevice`` probe that fell back reads "host" —
     and, once this process has opened the backend, the platform,
     ``deviceKind`` and device count JAX reports. A host-serving process
     never opens it (a chip belongs to one process), so those stay null.
@@ -240,12 +244,13 @@ def serving_device(pairs: Sequence) -> dict:
     on_device = any(
         hasattr(v, "addressable_shards") or getattr(v, "is_quantized", False)
         for _, model in pairs
-        for v in getattr(model, "__dict__", {}).values()
+        for holder in (model, serving_state(model))
+        for v in getattr(holder, "__dict__", {}).values()
     )
     probes = [
         p
         for _, model in pairs
-        if (p := getattr(model, "_pio_latency_probe", None)) is not None
+        if (p := serving_state(model).latency_probe) is not None
     ]
     out = {
         "servedFrom": "device" if on_device else "host",
@@ -269,14 +274,14 @@ def serving_device(pairs: Sequence) -> dict:
 def bytes_by_dtype(pairs: Sequence) -> dict:
     """Aggregate per-dtype pinned-byte ledger across the served models —
     the ``cache.bytesByDtype`` block of ``/stats.json``. Each pin hook
-    records its own breakdown on ``model._pio_bytes_by_dtype`` from the
+    records its own breakdown on the state's ``bytes_by_dtype`` from the
     ACTUAL arrays it placed (``{"float32": ...}`` for the classic tiers,
     ``{"int8": ..., "scalesFloat32": ...}`` quantized), so the stats
     report served truth instead of recomputed shape math."""
     agg: dict = {}
     for _, model in pairs:
         for dtype, nbytes in (
-            getattr(model, "_pio_bytes_by_dtype", None) or {}
+            serving_state(model).bytes_by_dtype or {}
         ).items():
             agg[dtype] = agg.get(dtype, 0) + int(nbytes)
     return agg
@@ -287,7 +292,7 @@ def shard_count(pairs: Sequence) -> int:
     sharded) — the ``factor_shards`` gauge on ``/stats.json``."""
     n = 0
     for _, model in pairs:
-        shards = getattr(model, "_pio_shards", None)
+        shards = serving_state(model).shards
         if shards is not None:
             n = max(n, shards.num_shards)
     return n
@@ -449,7 +454,7 @@ def swap_side_rows(
     factor table, so a new row must not become rankable before the index
     can translate it back to an item id.
 
-    Under ``--shard-factors`` (``model._pio_shards`` set) the table is
+    Under ``--shard-factors`` (the state's ``shards`` set) the table is
     padded to a multiple of the mesh axis, so cold-start rows first fill
     the existing padding slots via the shard-routed scatter; only when
     the physical capacity is exhausted does the table re-lay-out (host
@@ -481,7 +486,7 @@ def swap_side_rows(
         )
     if new:
         new_ids = [ids[j] for j in new]
-        shards = getattr(model, "_pio_shards", None)
+        shards = serving_state(model).shards
 
         def grow(mat):
             if shards is None:
@@ -530,7 +535,7 @@ def update_ann_items(model, item_ids, rows, index_attr: str = "item_index"):
     (when one is built); returns the update info dict or ``None``."""
     import numpy as np
 
-    ann = getattr(model, "_pio_ann", None)
+    ann = serving_state(model).ann
     if ann is None:
         return None
     index = getattr(model, index_attr)

@@ -41,6 +41,7 @@ from predictionio_tpu.serving.cache import (
     extract_scope,
 )
 from predictionio_tpu.api.stats import HttpStats
+from predictionio_tpu.templates.retrieval import serving_state
 from predictionio_tpu.utils.spans import CompileLedger, durations_ms, span
 from predictionio_tpu.workflow.engine_json import EngineVariant
 
@@ -486,7 +487,7 @@ class QueryService:
             self._ann_runtimes = [
                 rt
                 for _, model in pairs
-                if (rt := getattr(model, "_pio_ann", None)) is not None
+                if (rt := serving_state(model).ann) is not None
             ]
             self.instance = instance
             self.degraded = False
@@ -1085,7 +1086,7 @@ class QueryService:
                 "models": [
                     rt.stats_json()
                     for _, model in q_pairs
-                    if (rt := getattr(model, "_pio_quant", None)) is not None
+                    if (rt := serving_state(model).quant) is not None
                 ],
             }
         if self.aot_config is not None:

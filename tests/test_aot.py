@@ -26,6 +26,7 @@ from predictionio_tpu.controller import local_context
 from predictionio_tpu.data.event import DataMap, Event
 from predictionio_tpu.data.storage import Storage
 from predictionio_tpu.data.storage.base import App
+from predictionio_tpu.templates.retrieval import serving_state
 from predictionio_tpu.workflow import aot, load_engine_variant, run_train
 from predictionio_tpu.workflow.serving import QueryService
 
@@ -513,14 +514,14 @@ def test_aot_warm_serving_glue_hook(trained):
     eager-op executables every query reuses) and is a no-op on an
     unpinned model — and it is duck-typed exactly like the pin hooks."""
     algo, model = _fresh_pairs(trained)[0]
-    assert not getattr(model, "_pio_pinned", False)
+    assert not serving_state(model).pinned
     algo.aot_warm_serving(model)  # unpinned: must not raise, must not pin
-    assert not getattr(model, "_pio_pinned", False)
+    assert not serving_state(model).pinned
     from predictionio_tpu.workflow import device_state
 
     pairs, _ = device_state.pin_pairs([(algo, model)])
     _, pinned = pairs[0]
-    assert getattr(pinned, "_pio_pinned", False)
+    assert serving_state(pinned).pinned
     algo.aot_warm_serving(pinned)  # pinned: compiles the glue, once
 
 

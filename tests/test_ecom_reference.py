@@ -24,6 +24,7 @@ from predictionio_tpu.templates.ecommerce.engine import (  # noqa: E402
     Query,
     category_arrays,
 )
+from predictionio_tpu.templates.retrieval import serving_state  # noqa: E402
 from predictionio_tpu.utils import spans  # noqa: E402
 
 APP, N_ITEMS, N_USERS, RANK, NUM = "shop", 700, 40, 8, 10
@@ -99,7 +100,7 @@ def test_engine_agrees_with_the_plain_reference(shop, how):
     else:
         if how == "batch_pinned":
             model, nbytes = algo.pin_model_for_serving(model)
-            assert model._pio_item_tiles.shape == (3, RANK, 256) and nbytes > 0
+            assert serving_state(model).item_tiles.shape == (3, RANK, 256) and nbytes > 0
         got = dict(algo.batch_predict(model, list(enumerate(queries))))
         results = [got[i] for i in range(len(queries))]
     lines = []

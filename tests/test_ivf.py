@@ -17,6 +17,16 @@ from predictionio_tpu.ops import ivf
 from predictionio_tpu.ops.als import top_k_items_batch
 from predictionio_tpu.ops.topk import top_k_host, top_k_permuted
 from predictionio_tpu.serving import AnnConfig
+from predictionio_tpu.templates.retrieval import serving_state
+
+
+#: how far a full-probe IVF score may lie from the exact path's. Both are
+#: float32 GEMMs at ``SCORE_PRECISION`` (HIGHEST since PR 21), one over the
+#: item table in id order and one over the cluster-major slabs: the same
+#: products summed in another order differ in the last place (measured
+#: 0.52301013 against 0.5230101; float32 eps is 1.2e-7). Ids and order are
+#: held exactly, ties included.
+SCORE_RTOL = 1e-6
 
 
 def clustered_factors(
@@ -135,6 +145,8 @@ class TestMerge:
         assert np.array_equal(hi1, np.asarray(di)[0])
 
     def test_nprobe_eq_nlist_bit_identical_to_exact(self):
+        """At full probe the ids and their order are the exact path's;
+        the scores agree to ``SCORE_RTOL`` (see there)."""
         x = clustered_factors(1200, dim=16)
         q = clustered_factors(40, dim=16, seed=9)
         index, _ = ivf.build_ivf(x, nlist=12, seed=0, iters=4)
@@ -142,7 +154,9 @@ class TestMerge:
         ei, es = top_k_items_batch(uidx, jnp.asarray(q), jnp.asarray(x), 17)
         ai, a_s = ivf.ivf_topk_users(uidx, jnp.asarray(q), index, 17, 12)
         assert np.array_equal(np.asarray(ei), np.asarray(ai))
-        assert np.array_equal(np.asarray(es), np.asarray(a_s))
+        np.testing.assert_allclose(
+            np.asarray(a_s), np.asarray(es), rtol=SCORE_RTOL, atol=0
+        )
         # nprobe beyond nlist clamps to the same exact mode
         ai2, _ = ivf.ivf_topk_users(uidx, jnp.asarray(q), index, 17, 99)
         assert np.array_equal(np.asarray(ei), np.asarray(ai2))
@@ -251,9 +265,25 @@ def rec_variant(memory_storage_env):
 
 
 def _exact_equiv_ann() -> AnnConfig:
-    # nprobe >= nlist: ANN results must be bit-identical to exact, so
-    # integration equality asserts are deterministic
+    # nprobe >= nlist: ANN answers rank as the exact path does, so
+    # integration asserts are deterministic
     return AnnConfig(enabled=True, nlist=8, nprobe=8, kmeans_iters=3)
+
+
+def assert_same_ranking(got, want):
+    """Two ``handle_batch`` answer lists agree: status, item ids and
+    their order equal, scores within ``SCORE_RTOL``."""
+    assert len(got) == len(want)
+    for (g_status, g), (w_status, w) in zip(got, want):
+        assert g_status == w_status
+        assert [s["item"] for s in g["itemScores"]] == [
+            s["item"] for s in w["itemScores"]
+        ]
+        np.testing.assert_allclose(
+            [s["score"] for s in g["itemScores"]],
+            [s["score"] for s in w["itemScores"]],
+            rtol=SCORE_RTOL, atol=0,
+        )
 
 
 class TestServingIntegration:
@@ -270,7 +300,7 @@ class TestServingIntegration:
         assert qs.ann_config is None
         assert qs._cache_mode == "exact"
         model = qs._algo_model_pairs[0][1]
-        assert getattr(model, "_pio_ann", None) is None
+        assert serving_state(model).ann is None
         assert "ann" not in qs.stats_json()
         assert qs.status_json()["ann"] is False
         # a disabled config is treated exactly like none
@@ -284,8 +314,8 @@ class TestServingIntegration:
         bodies = [{"user": str(u), "num": 5} for u in range(25)]
         exact = QueryService(variant).handle_batch(bodies)
         qs = QueryService(variant, ann=_exact_equiv_ann())
-        assert qs._algo_model_pairs[0][1]._pio_ann is not None
-        assert qs.handle_batch(bodies) == exact
+        assert serving_state(qs._algo_model_pairs[0][1]).ann is not None
+        assert_same_ranking(qs.handle_batch(bodies), exact)
 
     def test_ann_single_predict_serves_k_items(self, rec_variant):
         from predictionio_tpu.workflow.serving import QueryService
@@ -308,16 +338,16 @@ class TestServingIntegration:
         _, variant = rec_variant
         qs = QueryService(variant, ann=_exact_equiv_ann())
         old_model = qs._algo_model_pairs[0][1]
-        old_runtime = old_model._pio_ann
+        old_runtime = serving_state(old_model).ann
         assert old_runtime is not None
         qs.reload()
         # the superseded generation's index is dropped (release hook)...
-        assert getattr(old_model, "_pio_ann", None) is None
+        assert serving_state(old_model).ann is None
         # ...and the new generation carries its own, rebuilt state
         new_model = qs._algo_model_pairs[0][1]
-        assert new_model._pio_ann is not None
-        assert new_model._pio_ann is not old_runtime
-        assert qs._ann_runtimes == [new_model._pio_ann]
+        assert serving_state(new_model).ann is not None
+        assert serving_state(new_model).ann is not old_runtime
+        assert qs._ann_runtimes == [serving_state(new_model).ann]
 
     def test_cache_keys_are_mode_tagged(self, rec_variant):
         from predictionio_tpu.serving import CacheConfig
@@ -349,10 +379,6 @@ class TestServingIntegration:
         from predictionio_tpu.workflow.serving import QueryService
 
         _, variant = rec_variant
-        # compare batch path to batch path: the single-query GEMV path
-        # legitimately differs from the batched GEMM in the last ulp
-        # (pre-existing host/device float caveat), while the batched
-        # exact and full-probe ANN paths are bit-identical
         exact = QueryService(variant).handle_batch([{"user": "2", "num": 4}])[0]
         qs = QueryService(
             variant,
@@ -360,8 +386,9 @@ class TestServingIntegration:
             ann=_exact_equiv_ann(),
         )
         try:
-            status, payload = qs.batcher.submit({"user": "2", "num": 4})
-            assert (status, payload) == exact
+            assert_same_ranking(
+                [qs.batcher.submit({"user": "2", "num": 4})], [exact]
+            )
         finally:
             qs.close()
 
@@ -405,7 +432,7 @@ class TestTemplateHooks:
         )
         assert {s.item for s in wl.item_scores} <= {"i5", "i9", "i17"}
         algo.release_ann_state(model)
-        assert model._pio_ann is None
+        assert serving_state(model).ann is None
 
     def test_twotower_seen_overfetch_with_ann(self):
         from predictionio_tpu.data.aggregator import BiMap
@@ -440,7 +467,7 @@ class TestTemplateHooks:
         assert len(got) == 10
         assert not set(got) & seen
         algo.release_ann_state(model)
-        assert model._pio_ann is None
+        assert serving_state(model).ann is None
 
 
 def test_default_import_path_never_touches_ivf():

@@ -20,6 +20,7 @@ import pytest
 
 from predictionio_tpu.data.event import DataMap, Event
 from predictionio_tpu.data.storage import Storage
+from predictionio_tpu.templates.retrieval import serving_state
 
 
 @pytest.fixture()
@@ -761,7 +762,7 @@ class TestQueryServiceOnline:
         )
         # redeliver the same event body (same id — the accumulator's
         # latest-wins makes it a no-op history change) and re-fold
-        deltas_state = model._pio_online["users"]["idem-u"].copy()
+        deltas_state = serving_state(model).online["users"]["idem-u"].copy()
         from predictionio_tpu.online.types import EventDelta
 
         upd = algo.online_foldin(
@@ -772,7 +773,7 @@ class TestQueryServiceOnline:
         )
         qs.apply_online_update([(0, upd)])
         row2 = np.asarray(model.user_factors[model.user_index["idem-u"]])
-        assert model._pio_online["users"]["idem-u"] == deltas_state
+        assert serving_state(model).online["users"]["idem-u"] == deltas_state
         np.testing.assert_allclose(row1, row2, rtol=1e-5, atol=1e-6)
 
     def test_reload_supersedes_online_generation(self, online_service):
@@ -900,7 +901,7 @@ class TestOnlineUnderShardFactors:
         Storage, app_id, qs = sharded_online_service
         pairs, _ = qs.snapshot_pairs()
         _algo, model = pairs[0]
-        shards = model._pio_shards
+        shards = serving_state(model).shards
         assert shards is not None and shards.num_shards == 8
         assert _query(qs, "fresh-su").body == {"itemScores": []}
         Storage.get_l_events().insert_batch(

@@ -1894,8 +1894,8 @@ def test_deleting_a_pow2_bucket_step_is_caught():
             "k_max = min(n_items, max(k for _, _, k in valid))",
         ),
         (
-            "predictionio_tpu/templates/recommendation/engine.py",
-            "kb = bucket_k(k, int(model.item_factors.shape[0]))",
+            "predictionio_tpu/templates/retrieval.py",
+            "kb = bucket_k(k, int(item_mat.shape[0]))",
             "kb = k",
         ),
     ]

@@ -16,6 +16,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 from predictionio_tpu.ops import quant  # noqa: E402
+from predictionio_tpu.templates.retrieval import serving_state  # noqa: E402
 
 
 def _table(rows: int, dim: int, seed: int = 0, ties: bool = False):
@@ -432,7 +433,7 @@ class TestQueryServiceQuantized:
         _, variant = quant_variant
         qs = self._service(variant, quantize="int8")
         _, model = qs._algo_model_pairs[0]
-        assert getattr(model, "_pio_quant", None) is not None
+        assert serving_state(model).quant is not None
         assert getattr(model.item_factors, "is_quantized", False)
         r = _query(qs)
         assert r.status == 200 and len(r.body["itemScores"]) == 5
@@ -480,7 +481,7 @@ class TestQueryServiceQuantized:
         qs_s = self._service(variant, quantize="int8", shard_factors=True)
         qs_r = self._service(variant, quantize="int8")
         _, model = qs_s._algo_model_pairs[0]
-        assert getattr(model, "_pio_shards", None) is not None
+        assert serving_state(model).shards is not None
         for user in ("1", "7"):
             rs = _query(qs_s, user=user, num=8)
             rr = _query(qs_r, user=user, num=8)
@@ -500,7 +501,7 @@ class TestQueryServiceQuantized:
             ann=AnnConfig(enabled=True, nlist=8, nprobe=8),
         )
         _, model = qs._algo_model_pairs[0]
-        assert model._pio_ann.index.slab_scales is not None  # int8 slabs
+        assert serving_state(model).ann.index.slab_scales is not None  # int8 slabs
         r = _query(qs)
         assert r.status == 200 and len(r.body["itemScores"]) == 5
         ann_stats = qs.stats_json()["ann"]["models"][0]
@@ -590,8 +591,8 @@ class TestQueryServiceQuantized:
             _, model = pairs[0]
             assert isinstance(model.user_factors, np.ndarray)
             assert model.user_factors.dtype == np.float32
-            assert getattr(model, "_pio_quant", None) is None
-            assert not getattr(model, "_pio_pinned", True)
+            assert serving_state(model).quant is None
+            assert not serving_state(model).pinned
 
     def test_reload_swaps_quantized_generations(self, quant_variant):
         _, variant = quant_variant
@@ -601,7 +602,7 @@ class TestQueryServiceQuantized:
         gen2_model = qs._algo_model_pairs[0][1]
         assert gen2_model is not gen1_model
         # the superseded generation's quant state was released
-        assert getattr(gen1_model, "_pio_quant", None) is None
+        assert serving_state(gen1_model).quant is None
         assert isinstance(gen1_model.user_factors, np.ndarray)
         assert _query(qs).status == 200
 

@@ -114,7 +114,7 @@ def test_chunked_topk_rows_in_input_order_and_rows_dispatched(
     collector = spans.Collector()
     previous = spans.bind(collector)
     try:
-        got = list(algo._topk_staged(model, valid))
+        got = list(algo.top_k_staged(model, valid))
     finally:
         spans.bind(previous)
     # the chunks, in order, hold the queries in input order

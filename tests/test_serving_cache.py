@@ -26,6 +26,7 @@ from predictionio_tpu.serving.cache import (
     extract_scope,
     scopes_from_events,
 )
+from predictionio_tpu.templates.retrieval import serving_state
 
 # ---------------------------------------------------------------------------
 # Unit: keys, config, stats
@@ -522,7 +523,7 @@ class TestPinnedServing:
         _, variant = trained_variant
         qs = QueryService(variant, cache=CacheConfig(pin_model=True))
         algo, model = qs._algo_model_pairs[0]
-        assert getattr(model, "_pio_pinned", False)
+        assert serving_state(model).pinned
         assert not isinstance(model.user_factors, np.ndarray)
         stats = qs.stats_json()["cache"]
         assert stats["bytesPinned"] > 0
@@ -562,7 +563,7 @@ class TestPinnedServing:
         device_state.release_pairs(pairs)
         _, model = pairs[0]
         assert isinstance(model.user_factors, np.ndarray)
-        assert not getattr(model, "_pio_pinned", True)
+        assert not serving_state(model).pinned
         # judged from the arrays held NOW, not from the flag it booted with
         assert qs.status_json()["device"]["servedFrom"] == "host"
 

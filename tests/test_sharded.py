@@ -27,6 +27,7 @@ from predictionio_tpu.templates.recommendation.engine import (
     ALSModel,
     Query,
 )
+from predictionio_tpu.templates.retrieval import serving_state
 
 
 def _factors(U=70, I=130, K=8, seed=3):
@@ -206,8 +207,8 @@ class TestServingHooks:
         algo = ALSAlgorithm(ALSAlgorithmParams())
         m_s, nbytes = algo.shard_model_for_serving(_model(uf, vf))
         m_p, _ = algo.pin_model_for_serving(_model(uf, vf))
-        assert m_s._pio_shards is not None
-        assert m_s._pio_shards.num_shards == 8
+        assert serving_state(m_s).shards is not None
+        assert serving_state(m_s).shards.num_shards == 8
         assert nbytes >= uf.nbytes + vf.nbytes  # padding only adds
         for u in ("u0", "u13", "u69"):
             got = algo.predict(m_s, Query(user=u, num=7))
@@ -248,7 +249,7 @@ class TestServingHooks:
         ref_u, ref_i = weakref.ref(old_user), weakref.ref(old_item)
         del old_user, old_item
         algo.release_pinned_model(m)
-        assert m._pio_shards is None
+        assert serving_state(m).shards is None
         assert isinstance(m.user_factors, np.ndarray)
         assert m.user_factors.shape == uf.shape  # padding stripped
         np.testing.assert_array_equal(m.user_factors, uf)
@@ -270,8 +271,8 @@ class TestServingHooks:
         m_p, _ = algo.pin_model_for_serving(_model(uf, vf))
         m_p, _info = algo.build_ann_for_serving(m_p, cfg)
         assert info_s["shards"] == 8
-        assert m_s._pio_ann.shard_mesh is not None
-        assert m_s._pio_ann.host_index is not None
+        assert serving_state(m_s).ann.shard_mesh is not None
+        assert serving_state(m_s).ann.host_index is not None
         for u in ("u0", "u7", "u39"):
             got = algo.predict(m_s, Query(user=u, num=9))
             want = algo.predict(m_p, Query(user=u, num=9))
@@ -332,7 +333,7 @@ class TestServingHooks:
         algo = TwoTowerAlgorithm(TwoTowerParams())
         m_s, _ = algo.shard_model_for_serving(mk())
         m_h = mk()  # host numpy path as the oracle
-        assert m_s._pio_shards is not None
+        assert serving_state(m_s).shards is not None
         for u in ("u0", "u7", "u29"):
             got = algo.predict(m_s, TTQuery(user=u, num=6))
             want = algo.predict(m_h, TTQuery(user=u, num=6))
@@ -431,7 +432,7 @@ class TestQueryServiceSharded:
         _, variant = trained_variant
         qs = QueryService(variant, cache=CacheConfig(shard_factors=True))
         (_algo, model0), = qs._algo_model_pairs
-        assert model0._pio_shards is not None
+        assert serving_state(model0).shards is not None
         refs = [
             weakref.ref(model0.user_factors),
             weakref.ref(model0.item_factors),
@@ -441,9 +442,9 @@ class TestQueryServiceSharded:
         assert r.status == 200
         (_algo1, model1), = qs._algo_model_pairs
         assert model1 is not model0
-        assert model1._pio_shards is not None  # new generation re-sharded
+        assert serving_state(model1).shards is not None  # new generation re-sharded
         # the released generation fell back to trimmed host arrays...
-        assert model0._pio_shards is None
+        assert serving_state(model0).shards is None
         assert isinstance(model0.user_factors, np.ndarray)
         assert model0.user_factors.shape[0] <= old_user_shape[0]
         # ...and its sharded tables are collectable on every device
