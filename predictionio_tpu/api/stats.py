@@ -6,10 +6,10 @@
   buckets, served at ``/stats.json`` when the server runs with
   ``--stats``. Single-writer here (the service locks), no actor needed.
 * :class:`ServingStats` — query-server micro-batcher gauges, counters and
-  the latency decomposition per request and per batch (the dispatcher's
-  phases, the host gap between batches), served at the query server's
-  ``GET /stats.json``. No reference counterpart (the reference has no
-  cross-request batcher).
+  the latency decomposition per request and per batch (its worker's
+  phases, the host gap before it, whether it overlapped the batch before
+  it), served at the query server's ``GET /stats.json``. No reference
+  counterpart (the reference has no cross-request batcher).
 * :class:`HttpStats` — what the query server's HTTP threads spend on a
   request outside the service: reading it and writing the answer.
 """
@@ -92,7 +92,7 @@ def _percentiles(samples, points=(50, 95, 99)) -> dict[str, float]:
     return out
 
 
-#: the dispatcher thread's leaf spans (utils/spans.py), in the order it
+#: a batcher worker's leaf spans (utils/spans.py), in the order it
 #: passes through them from one ``handle_batch`` return to the next; each
 #: is a window of ``latencyMs``
 BATCH_PHASES = (
@@ -116,13 +116,15 @@ class ServingStats:
 
     * ``queueWait`` — enqueue until the dispatcher formed its batch;
     * ``total`` — enqueue until the caller gets its result back;
-    * ``wake`` — the dispatcher's ``done.set()`` until the caller's
+    * ``wake`` — the worker's ``done.set()`` until the caller's
       thread runs again.
 
-    Per batch, on the dispatcher thread, flat and in this order (one
-    cycle runs from a ``handle_batch`` return to the next):
+    Per batch, on the worker thread that carried it (the batcher has
+    two), flat and in this order (one cycle runs from one of the
+    worker's ``handle_batch`` returns to its next):
 
-    * ``release`` — answers handed to the PREVIOUS batch's riders;
+    * ``release`` — answers handed to the riders of the worker's
+      PREVIOUS batch;
     * ``take`` — blocked on the queue with nothing in it;
     * ``drain`` — waiting up to the batch delay for batch mates;
     * ``batchForm`` — drain-complete until ``handle_batch`` is entered
@@ -136,10 +138,22 @@ class ServingStats:
       blocks until the device is done), ``format`` (lists, result
       objects, serve tail); a handler that has no such boundary records
       none;
-    * ``hostGap`` — this batch's ``dispatch`` end less the previous
-      batch's ``deviceWait`` end, less this batch's ``take``: what the
-      host's own code kept the device waiting (absent for the first
-      batch and for handlers without a device).
+    * ``hostGap`` — this batch's ``dispatch`` end less the latest
+      ``deviceWait`` end of the batches before it, less what of
+      this batch's ``take`` lies between the two, floored at 0: what the
+      host's own code kept the device waiting. A program enqueued while
+      the one before it still runs kept it waiting 0 ms (absent for the
+      first batch and for handlers without a device).
+
+    ``inflightBatch`` counts the batches inside ``handle_batch`` now: 0,
+    1 or 2. ``overlap`` counts the live batches by whether their
+    ``dispatch`` ended before the batch before them had left the device
+    (its ``deviceWait`` end): ``overlapped``, or ``alone`` (the first
+    batch, a handler without a device, and every batch of a server
+    whose queue never holds a full batch while another is handled: a
+    second batch goes only then, and not for a while after a run of them
+    met an idle device, ``serving/batcher.py``); ``overlapPct``
+    is the share of ``overlapped`` among them, 0.0 before any batch.
 
     ``rowsScored`` and ``rowsReal`` count, over the live batches, the rows
     the scoring programs were dispatched with and the rows of them that
@@ -186,7 +200,8 @@ class ServingStats:
         self.filter_counts = dict.fromkeys(FILTER_COUNTS, 0)
         self.select_counts = dict.fromkeys(SELECT_PLANS, 0)
         self.queue_depth = 0  # last observed; gauge
-        self.inflight_batch = 0  # 0|1 — one dispatcher thread
+        self.inflight_batch = 0  # 0|1|2 — the batcher's two workers
+        self.overlap = {"overlapped": 0, "alone": 0}
         self.batch_size_hist: Counter = Counter()
         self.bucket_hist: Counter = Counter()
         #: buckets whose jit programs are assumed compiled (warm-up or a
@@ -224,7 +239,7 @@ class ServingStats:
 
     def record_batch_start(self, queue_depth: int) -> None:
         with self._lock:
-            self.inflight_batch = 1
+            self.inflight_batch += 1
             self.queue_depth = queue_depth
 
     def record_batch(
@@ -235,18 +250,21 @@ class ServingStats:
         queue_wait_ms: Sequence[float] = (),
         phases: Mapping[str, float] | None = None,
         host_gap_ms: float | None = None,
+        overlapped: bool = False,
         rows_scored: int = 0,
         rows_real: int = 0,
         counts: Mapping[str, int] | None = None,
     ) -> None:
         """One dispatched batch: its riders' queue waits, ``handle``, the
-        dispatcher's ``phases`` ({name: ms}, names of
-        :data:`BATCH_PHASES`), the host gap before it, the rows its
+        worker's ``phases`` ({name: ms}, names of
+        :data:`BATCH_PHASES`), the host gap before it, whether it was
+        dispatched while the batch before it was on the device, the rows its
         scoring dispatches took and really held, and the handler's other
         ``counts`` (those named ``filter.<one of FILTER_COUNTS>`` and
         ``select.<one of SELECT_PLANS>``)."""
         with self._lock:
-            self.inflight_batch = 0
+            self.inflight_batch -= 1
+            self.overlap["overlapped" if overlapped else "alone"] += 1
             self.batches += 1
             self.batched_queries += size
             self.padded_queries += bucket - size
@@ -303,6 +321,10 @@ class ServingStats:
                 "queueDepth": self.queue_depth,
                 "inflightBatch": self.inflight_batch,
                 "batches": self.batches,
+                "overlap": dict(self.overlap),
+                "overlapPct": round(
+                    100.0 * self.overlap["overlapped"] / self.batches, 2
+                ) if self.batches else 0.0,
                 "batchedQueries": self.batched_queries,
                 "meanBatchSize": round(self.batched_queries / self.batches, 2)
                 if self.batches

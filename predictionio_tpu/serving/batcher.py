@@ -8,25 +8,66 @@ batch (see ``docs/performance.md``). This module closes that gap the way
 TPU serving stacks do (cf. ALX's batched matrix-factorization serving,
 arxiv 2112.02194): requests from independent HTTP handler threads
 enqueue into a bounded queue with a per-request completion event; a
-single dispatcher thread drains up to ``max_batch_size`` requests or
-waits ``max_batch_delay_ms`` past the oldest request (whichever comes
-first), pads the batch up to a small set of **bucket sizes** so the
-jitted predict programs compile once per bucket (warm-up at startup
+worker drains up to ``max_batch_size`` requests or waits
+``max_batch_delay_ms`` past the oldest request (whichever comes first),
+pads the batch up to a small set of **bucket sizes** so the jitted
+predict programs compile once per bucket (warm-up at startup
 pre-compiles all of them), routes the batch through the existing
 ``QueryService.handle_batch`` / ``batch_predict_base`` path — which
 already guarantees per-item error isolation — and resolves each waiting
 request with its own ``(status, payload)``.
 
+**Two batches in flight.** Two workers share the queue. Forming (take +
+drain) is serial under one forming lock, so a request rides in exactly
+one batch, batches leave the queue in arrival order and a backlog of 64
+is two batches of 32. ``handle_batch`` runs outside that lock: while one
+worker blocks in the readback of batch n (which releases the interpreter
+lock), the other forms batch n+1, binds it, reads the store for it and
+calls its scoring program. JAX dispatch is asynchronous and the device
+runs programs in the order they were enqueued, so the second program
+starts the instant the first ends. Two, because a cycle has two halves
+(the host's and the device's); a third batch would only queue behind the
+second on the device and add a cycle to every rider's wait.
+
+**The second batch is a full one.** With no batch in flight a worker
+forms as the single dispatcher did: the first request, then the drain.
+With one in flight the other worker forms only once the queue holds
+``max_batch_size`` requests: the batch the drain would end on with no
+waiting, so going now costs nobody a batchmate. Short of that it waits
+for the batch in flight to return, as the requests behind a single
+dispatcher did, and then forms by the first rule. So a second batch
+never spreads the callers over more and smaller batches than one
+dispatcher would have made of them (each batch has a batch's whole
+overhead, and where the cycle is the interpreter lock's, that is all a
+part-full second batch brings: PERF.md, PR 31), an idle or lightly
+loaded server behaves as with one worker, and a backlogged one keeps
+two batches out.
+
+**And only while it finds the device busy.** A second batch pays when
+part of its host half runs under the first one's device time. Where a
+cycle is the interpreter lock's and not the device's (a small model:
+the lock is shared with the HTTP threads), the second batch's program
+meets an idle device every time (measured: 0.5% of the batches were
+enqueued behind a running program there, 45% where it pays; PERF.md,
+PR 31) and all it does is empty the queue, so that now and then both
+workers return to a short one and a part-full batch goes. So after
+``_ALONE_RUN`` second batches in a row whose program met an idle device
+no second batch goes for ``_REST_S`` seconds (the single dispatcher's
+behaviour), then again. What the batcher observes is the queue's depth,
+its own count of batches in flight and its own batches' spans: no flag,
+no field.
+
 Admission control is explicit: when the queue is full the configured
 policy either rejects immediately (HTTP 429 + ``Retry-After``) or
 blocks the caller up to ``block_timeout_ms`` (503 on timeout). Queue
 depth, in-flight batch state, bucket hit/miss counts and the latency
-decomposition (per request: queue wait, total, wake; per batch: the
-dispatcher's phases as spans of ``utils/spans.py``, and the host gap
-between batches) are recorded in
+decomposition (per request: queue wait, total, wake; per batch: its
+worker's phases as spans of ``utils/spans.py``, the host gap before it
+and whether it overlapped the batch before it) are recorded in
 :class:`predictionio_tpu.api.stats.ServingStats` and served from the
-query server's ``GET /stats.json``. The dispatcher thread's leaf spans
-are also ``pio.*`` events in a running ``jax.profiler`` trace.
+query server's ``GET /stats.json``. Each worker's leaf spans are also
+``pio.*`` events in a running ``jax.profiler`` trace, one flat line a
+worker.
 
 No reference counterpart: the reference serves one query per spray
 route invocation. This is the TPU-native replacement for that hot path.
@@ -58,6 +99,19 @@ logger = logging.getLogger(__name__)
 #: must not hang the HTTP handler thread forever
 _RESULT_TIMEOUT_S = 300.0
 
+#: batches in flight at once: one on the device and one being prepared
+#: cover a cycle of two halves (module text)
+_WORKERS = 2
+#: second batches in a row whose program met an idle device, after which
+#: none goes for _REST_S seconds. Where a second batch pays about one in
+#: two is enqueued behind a running program, where it does not one in two
+#: hundred (PERF.md, PR 31): 64 in a row is 2**-55 by the first share and
+#: a second's worth of batches by the other (at a cost of some 3% of the
+#: rate while they last). A rest in seconds, not in batches, so that being
+#: wrong costs a slow model no more than a fast one
+_ALONE_RUN = 64
+_REST_S = 10.0
+
 
 class AdmissionPolicy(str, enum.Enum):
     """What a full queue does to a new request."""
@@ -86,10 +140,11 @@ class BatcherConfig:
     """
 
     max_batch_size: int = 32
-    #: how long the dispatcher waits past the OLDEST queued request for
+    #: how long a worker waits past the OLDEST queued request for
     #: batchmates; the p99 latency a request can gain over the
     #: per-request path is bounded by ~2x this (one wait while queued +
-    #: one batch in flight ahead of it)
+    #: the batch being formed ahead of it) and, short of a full batch
+    #: queued, the rest of the batch in flight ahead of it
     max_batch_delay_ms: float = 2.0
     #: bounded admission queue; full -> the admission policy applies
     max_queue: int = 256
@@ -136,7 +191,7 @@ class BatcherConfig:
 
 class _Pending:
     __slots__ = ("body", "enqueued_at", "done", "result", "drained", "seq",
-                 "released_ns")
+                 "released_ns", "worker")
 
     def __init__(self, body: Any):
         self.body = body
@@ -145,11 +200,38 @@ class _Pending:
         self.result: tuple[int, Any] | None = None
         #: sequence number of the batch this request rode in
         self.seq = 0
+        #: the worker thread that took this request off the queue
+        self.worker: threading.Thread | None = None
         #: ``perf_counter_ns`` just before the dispatcher's ``done.set()``
         self.released_ns = 0
         #: answered by a dead-queue drain (shutdown / dead dispatcher),
         #: not by a dispatched batch — kept out of the latency stats
         self.drained = False
+
+
+class _Flight:
+    """What one batch's worker measured, until the batch is accounted."""
+
+    __slots__ = ("record", "beside", "take_start_ns", "take_end_ns",
+                 "dispatched_ns", "device_done_ns")
+
+    def __init__(self, cycle: Sequence[spans.SpanRecord], record: dict,
+                 beside: bool):
+        #: the keywords of ``ServingStats.record_batch``
+        self.record = record
+        #: formed while another batch was in flight: a second batch
+        self.beside = beside
+        self.take_start_ns, self.take_end_ns = next(
+            ((r.start_ns, r.end_ns) for r in cycle if r.name == "take"), (0, 0)
+        )
+        #: when the batch's (first) scoring program was enqueued, and when
+        #: its last readback returned; None for a handler without a device
+        self.dispatched_ns = next(
+            (r.end_ns for r in cycle if r.name == "dispatch"), None
+        )
+        self.device_done_ns = max(
+            (r.end_ns for r in cycle if r.name == "deviceWait"), default=None
+        )
 
 
 class MicroBatcher:
@@ -189,21 +271,41 @@ class MicroBatcher:
         )
         # guards writes to _closed (shared with submit() on HTTP handler
         # threads; piolint PIO201 keeps every post-__init__ write under
-        # it). Readers stay lock-free on purpose: the submit/close race
+        # it) and the workers' shared bookkeeping below. Readers of
+        # _closed stay lock-free on purpose: the submit/close race
         # is resolved by submit()'s post-enqueue re-check plus the
         # idempotent _drain_dead_queue(), not by mutual exclusion
         self._lock = threading.Lock()
         self._closed = False
-        # the dispatcher thread feeds the device: its leaf spans also go
-        # into a running profiler trace (utils/spans.py). Bound by _loop;
-        # taken once a batch
-        self._spans = spans.Collector(annotate=True)
+        # held over take + drain: one worker gathers a batch at a time
+        self._form_lock = threading.Lock()
+        #: batches dispatched so far; the last one's sequence number
+        self._seq = 0
+        #: the last batch accounted (batches are, in the order of their
+        #: numbers) and those that returned ahead of an earlier one
+        self._accounted = 0
+        self._returned: dict[int, _Flight | None] = {}
+        #: the latest deviceWait end of the batches accounted so far
+        self._device_done_ns: int | None = None
+        #: batches inside handle_batch now; the event wakes a worker that
+        #: waits to form (_may_form): set when one returns, when the queue
+        #: reaches a full batch, and by close()
+        self._in_flight = 0
+        self._go = threading.Event()
+        #: second batches in a row that met an idle device, and until when
+        #: (``time.monotonic``) none goes because of it (_account)
+        self._alone_run = 0
+        self._shut_until = 0.0
         if self.config.warmup_body is not None:
             self.warmup(self.config.warmup_body)
-        self._thread = threading.Thread(
-            target=self._loop, name="pio-microbatcher", daemon=True
-        )
-        self._thread.start()
+        self._threads = [
+            threading.Thread(
+                target=self._loop, name=f"pio-batcher-{i}", daemon=True
+            )
+            for i in range(_WORKERS)
+        ]
+        for t in self._threads:
+            t.start()
 
     # ------------------------------------------------------------ client API
     def submit(self, body: Any) -> tuple[int, Any]:
@@ -213,8 +315,8 @@ class MicroBatcher:
         cfg = self.config
         if self._closed:
             return 503, {"message": "Serving runtime is shut down."}
-        if not self._thread.is_alive():
-            # a dead dispatcher (a bug — the loop is defensive) must fail
+        if not self._workers_alive():
+            # a dead worker (a bug — the loop is defensive) must fail
             # fast with a clean 503, not park the HTTP thread for the
             # full result timeout; /readyz turns unready via
             # dispatcher_alive() so orchestrators restart the pod
@@ -243,7 +345,10 @@ class MicroBatcher:
                 f"{cfg.block_timeout_ms:g} ms.",
                 "retryAfterSeconds": retry_after,
             }
-        self.stats.record_submitted(self._queue.qsize())
+        depth = self._queue.qsize()
+        self.stats.record_submitted(depth)
+        if depth >= cfg.max_batch_size:
+            self._go.set()  # a full batch: a second one may go (_may_form)
         if self._closed:
             # raced with close(): the dispatcher may already be past its
             # final drain, so this request could sit in a dead queue —
@@ -253,26 +358,31 @@ class MicroBatcher:
             self._drain_dead_queue()
         give_up_at = time.monotonic() + _RESULT_TIMEOUT_S
         while not pending.done.wait(timeout=1.0):
-            if not self._thread.is_alive():
-                # the dispatcher died while this request was queued:
-                # answer every stranded request (ours included) instead
-                # of letting them sit out the full result timeout
+            if not self._workers_alive():
+                # a worker died while this request was queued: answer
+                # every stranded request (ours included) instead of
+                # letting them sit out the full result timeout
                 self._drain_dead_queue(
                     "Serving runtime dispatcher died; request not processed."
                 )
                 if pending.done.is_set():
                     break
-                # in-flight when the dispatcher died (not in the queue):
-                # manufacture the same 503, and count it like every other
-                # rejected response so /stats.json stays truthful during
-                # the incident
-                self.stats.record_rejected()
-                return 503, {
-                    "message": (
-                        "Serving runtime dispatcher died; request not processed."
-                    ),
-                    "retryAfterSeconds": self.retry_after_seconds(),
-                }
+                worker = pending.worker
+                if worker is not None and not worker.is_alive():
+                    # in-flight when its worker died (not in the queue):
+                    # manufacture the same 503, and count it like every
+                    # other rejected response so /stats.json stays
+                    # truthful during the incident
+                    self.stats.record_rejected()
+                    return 503, {
+                        "message": (
+                            "Serving runtime dispatcher died; request not "
+                            "processed."
+                        ),
+                        "retryAfterSeconds": self.retry_after_seconds(),
+                    }
+                # else riding in the live worker's batch: it will answer,
+                # or the result timeout below binds as ever
             if time.monotonic() >= give_up_at:
                 return 500, {"message": "Batch dispatcher did not respond."}
         woke_ns = time.perf_counter_ns()
@@ -334,20 +444,29 @@ class MicroBatcher:
             if size in self._buckets:
                 self.stats.record_warmup(size, (time.monotonic() - t0) * 1e3)
 
+    def _workers_alive(self) -> bool:
+        return all(t.is_alive() for t in self._threads)
+
     def dispatcher_alive(self) -> bool:
-        """Is the dispatcher thread able to answer submissions? Feeds the
-        query server's ``/readyz`` readiness probe."""
-        return not self._closed and self._thread.is_alive()
+        """Are both workers able to answer submissions? False as soon as
+        either is dead. Feeds the query server's ``/readyz`` readiness
+        probe."""
+        return not self._closed and self._workers_alive()
 
     def close(self) -> None:
-        """Stop the dispatcher. Requests already being drained are
-        answered normally; anything still queued (or racing in) gets 503."""
+        """Stop the workers. Batches already formed are answered
+        normally; anything still queued (or racing in) gets 503."""
         with self._lock:
             self._closed = True
-        self._queue.put(None)  # wake the dispatcher even when idle
-        self._thread.join(timeout=5.0)
+        self._go.set()
+        try:
+            self._queue.put_nowait(None)  # wake the worker in take at once
+        except queue.Full:
+            pass  # it looks at _closed at least every 50 ms
+        for t in self._threads:
+            t.join(timeout=5.0)
         # a submit() that passed its _closed check concurrently with this
-        # close may have enqueued after the dispatcher's final drain
+        # close may have enqueued after the workers' final drain
         self._drain_dead_queue()
 
     def _drain_dead_queue(
@@ -394,43 +513,79 @@ class MicroBatcher:
                 break
             if item is None:  # close() sentinel
                 break
+            item.worker = threading.current_thread()
             batch.append(item)
         return batch
 
     def _take(self) -> _Pending | None:
-        """Block until a request is queued; None once closed."""
-        while True:
+        """Block until a request is queued; None once closed (what is
+        queued then is answered 503 by the dead-queue drain)."""
+        while not self._closed:
             try:
                 first = self._queue.get(timeout=0.05)
             except queue.Empty:
-                first = None
-            if first is not None:
+                continue
+            if first is not None:  # else close()'s sentinel
+                first.worker = threading.current_thread()
                 return first
-            if self._closed:
-                return None
+        return None
+
+    def _may_form(self) -> bool:
+        """May a worker gather a batch now? With none in flight, always
+        (the single dispatcher's rule); with one, only a full batch, and
+        not while second batches rest (module text); with two, no."""
+        in_flight = self._in_flight
+        return in_flight == 0 or (
+            in_flight < _WORKERS
+            and self._queue.qsize() >= self.config.max_batch_size
+            and time.monotonic() >= self._shut_until
+        )
 
     def _loop(self) -> None:
-        spans.bind(self._spans)
-        #: when the previous batch's readback returned (perf_counter_ns)
-        device_done_ns = None
+        # a worker feeds the device: its leaf spans also go into a
+        # running profiler trace (utils/spans.py); taken once a batch
+        collector = spans.Collector(annotate=True)
+        spans.bind(collector)
         while True:
-            self._spans.seq += 1
-            with span("take"):
-                first = self._take()
-            if first is None:
-                break
-            with span("drain"):
-                batch = self._drain(first)
-            device_done_ns = self._dispatch(batch, device_done_ns)
+            while True:
+                # cleared before the look, so that what is set after it
+                # (a batch returned, the queue filled up) ends the wait
+                self._go.clear()
+                if self._closed or self._may_form():
+                    break
+                self._go.wait(timeout=0.05)
+            with self._form_lock:
+                if not (self._closed or self._may_form()):
+                    continue  # the other worker's batch went out meanwhile
+                with span("take"):
+                    first = self._take()
+                if first is None:
+                    break
+                with span("drain"):
+                    batch = self._drain(first)
+                # before the forming lock goes: the next to form sees it
+                beside = self._number(collector)
+            self._dispatch(batch, collector, beside)
         # drain leftovers so no client hangs on shutdown
         self._drain_dead_queue()
 
+    def _number(self, collector: spans.Collector) -> bool:
+        """Give the batch just formed its number and count it in flight.
+        Was another in flight already?"""
+        with self._lock:
+            self._seq += 1
+            collector.seq = self._seq
+            self._in_flight += 1
+            return self._in_flight > 1
+
     def _dispatch(
-        self, batch: list[_Pending], device_done_ns: int | None = None
-    ) -> int | None:
-        """Pad, run and answer one batch. ``device_done_ns`` is when the
-        previous batch's readback ended; returns this batch's, for the
-        next one's host gap."""
+        self, batch: list[_Pending], collector: spans.Collector, beside: bool
+    ) -> None:
+        """Pad, run and answer one batch on the calling thread, a worker's:
+        ``collector`` is the one bound to it; ``beside``: formed while
+        another batch was in flight. What lies between two of a worker's
+        batches (release, take, drain) carries the earlier one's sequence
+        number."""
         with span("batchForm"):
             formed_at = time.monotonic()
             waits = [(formed_at - p.enqueued_at) * 1e3 for p in batch]
@@ -440,56 +595,101 @@ class MicroBatcher:
             # shape guarantees, results beyond len(bodies) are discarded
             padded = bodies + [bodies[0]] * (bucket - len(bodies))
             self.stats.record_batch_start(self._queue.qsize())
-        with span("handle", enclosing=True) as handle:
-            try:
-                results = self._call(padded, n_real=len(bodies))
-                if len(results) < len(bodies):  # defensive: misaligned handler
-                    raise RuntimeError(
-                        f"handle_batch returned {len(results)} results "
-                        f"for {len(padded)} queries"
-                    )
-            except Exception:
-                # handle_batch isolates per-item errors itself; reaching
-                # this means the batch MACHINERY failed — answer everyone
-                # rather than hanging the HTTP threads. Generic message:
-                # exception text can leak internals (details go to the log)
-                logger.exception("micro-batch dispatch failed")
-                results = [
-                    (500, {"message": "Batch dispatch failed; see server log."})
-                ] * len(bodies)
-        # one cycle of the dispatcher: the previous batch's release, then
-        # this batch's take ... handle
-        cycle = self._spans.take()
-        counts = self._spans.take_counts()
-        phases = spans.durations_ms(cycle)
-        dispatched_ns = next(
-            (r.end_ns for r in cycle if r.name == "dispatch"), None
-        )
-        host_gap_ms = None
-        if device_done_ns is not None and dispatched_ns is not None:
-            # an empty queue starves the device through no fault of the
-            # host code: take is not the host's gap
-            host_gap_ms = (
-                (dispatched_ns - device_done_ns) / 1e6
-                - phases.get("take", 0.0)
+        flight = None
+        try:
+            with span("handle", enclosing=True) as handle:
+                try:
+                    results = self._call(padded, n_real=len(bodies))
+                    if len(results) < len(bodies):  # defensive: misaligned handler
+                        raise RuntimeError(
+                            f"handle_batch returned {len(results)} results "
+                            f"for {len(padded)} queries"
+                        )
+                except Exception:
+                    # handle_batch isolates per-item errors itself; reaching
+                    # this means the batch MACHINERY failed — answer everyone
+                    # rather than hanging the HTTP threads. Generic message:
+                    # exception text can leak internals (details go to the log)
+                    logger.exception("micro-batch dispatch failed")
+                    results = [
+                        (500, {"message": "Batch dispatch failed; see server log."})
+                    ] * len(bodies)
+            # one cycle of this worker: its previous batch's release, then
+            # this batch's take ... handle
+            cycle = collector.take()
+            counts = collector.take_counts()
+            flight = _Flight(
+                cycle,
+                dict(
+                    size=len(bodies),
+                    bucket=bucket,
+                    handle_ms=handle.ms,
+                    queue_wait_ms=waits,
+                    phases=spans.durations_ms(cycle),
+                    rows_scored=counts.get("rowsScored", 0),
+                    rows_real=counts.get("rowsReal", 0),
+                    counts=counts,
+                ),
+                beside,
             )
-        self.stats.record_batch(
-            size=len(bodies),
-            bucket=bucket,
-            handle_ms=handle.ms,
-            queue_wait_ms=waits,
-            phases=phases,
-            host_gap_ms=host_gap_ms,
-            rows_scored=counts.get("rowsScored", 0),
-            rows_real=counts.get("rowsReal", 0),
-            counts=counts,
-        )
+        finally:
+            # also when the handler killed this worker: the batches formed
+            # after this one must not wait for it
+            self._account(collector.seq, flight)
         with span("release"):
             for p, result in zip(batch, results):
                 p.result = result
-                p.seq = self._spans.seq
+                p.seq = collector.seq
                 p.released_ns = time.perf_counter_ns()
                 p.done.set()
-        return max(
-            (r.end_ns for r in cycle if r.name == "deviceWait"), default=None
-        )
+
+    def _account(self, seq: int, flight: "_Flight | None") -> None:
+        """Hand batch ``seq``'s measurements to the stats. Batches are
+        accounted in the order of their numbers, so that the ``deviceWait``
+        end a batch's host gap is measured from is known: one that returns
+        ahead of an earlier one is kept until that one has returned (its
+        riders are not). An earlier one that stays out while more than
+        ``_WORKERS`` wait for it (a handler that hangs) is passed over, and
+        accounted with no gap whenever it does return. ``flight`` None:
+        the batch measured nothing."""
+        ready = []
+        with self._lock:
+            self._in_flight -= 1
+            if seq <= self._accounted:  # passed over: nothing to measure from
+                if flight is not None:
+                    ready.append(flight.record)
+            else:
+                self._returned[seq] = flight
+            while (self._accounted + 1 in self._returned
+                   or len(self._returned) > _WORKERS):
+                self._accounted += 1
+                flight = self._returned.pop(self._accounted, None)
+                if flight is None:
+                    continue
+                done = self._device_done_ns
+                if flight.dispatched_ns is not None and done is not None:
+                    # a program enqueued while the one before it still
+                    # runs kept the device waiting 0 ms; an empty queue
+                    # starves it through no fault of the host code: what
+                    # of take lies in the gap is not the host's
+                    gap_ns = (
+                        flight.dispatched_ns - done
+                        - max(0, flight.take_end_ns - max(flight.take_start_ns, done))
+                    )
+                    flight.record["host_gap_ms"] = max(0.0, gap_ns / 1e6)
+                    flight.record["overlapped"] = flight.dispatched_ns < done
+                if flight.device_done_ns is not None:
+                    self._device_done_ns = max(flight.device_done_ns, done or 0)
+                ready.append(flight.record)
+                if flight.beside and "overlapped" in flight.record:
+                    # a second batch that met an idle device hid nothing
+                    # of its host half; a run of them: none for a while
+                    self._alone_run = (
+                        0 if flight.record["overlapped"] else self._alone_run + 1
+                    )
+                    if self._alone_run >= _ALONE_RUN:
+                        self._alone_run = 0
+                        self._shut_until = time.monotonic() + _REST_S
+        self._go.set()  # a worker that waits to form may now (_may_form)
+        for record in ready:
+            self.stats.record_batch(**record)

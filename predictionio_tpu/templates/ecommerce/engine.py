@@ -338,21 +338,28 @@ class ECommAlgorithm(JaxAlgorithm):
 
     @staticmethod
     def _categories(model: ECommModel) -> tuple[np.ndarray, BiMap]:
-        if getattr(model, "category_codes", None) is None:
-            model.category_codes, model.category_index = category_arrays(
+        codes = getattr(model, "category_codes", None)
+        if codes is None:
+            # two batches may fill this at once (the batcher has two in
+            # flight): the index first, so whoever sees the codes sees both
+            codes, model.category_index = category_arrays(
                 model.categories, model.item_index
             )
-        return model.category_codes, model.category_index
+            model.category_codes = codes
+        return codes, model.category_index
 
     @staticmethod
     def _blocked(model: ECommModel, unavailable: set):
         """The out-of-stock mask over the item rows — the host's
         ``bool[items]`` or, pinned, the device's ``bool[tiles, width]`` with
         the padding past the catalog blocked too — made when the constraint
-        changes, not per batch."""
+        changes, not per batch. Two batches in flight may hold different
+        reads of the constraint: each is served the mask of its own (the
+        cache is one tuple, read once and assigned once)."""
         state = serving_state(model, ECommServingState)
-        if state.blocked is not None and state.blocked[0] == unavailable:
-            return state.blocked[1]
+        cached = state.blocked
+        if cached is not None and cached[0] == unavailable:
+            return cached[1]
         n = len(model.item_index)
         tiles = state.item_tiles
         mask = np.zeros(n if tiles is None else tiles.shape[0] * tiles.shape[2], bool)

@@ -49,6 +49,7 @@ import logging
 import os
 import shutil
 import tempfile
+import threading
 from typing import Any, Sequence
 
 # the artifact SCHEMA (manifest name, dir layout, stdlib verification)
@@ -349,25 +350,31 @@ class AotRuntime:
         self._programs = programs
         self.manifest = manifest
         self.tier = tier
+        # asked from many threads at once: HTTP threads on the per-request
+        # path, the batcher's two workers on the batch path
+        self._lock = threading.Lock()
         self.hits = 0
         self.misses = 0
         self._disabled: set[str] = set()
 
     def get(self, key: str):
         fn = self._programs.get(key)
-        if fn is None or key in self._disabled:
-            self.misses += 1
-            return None
-        self.hits += 1
+        with self._lock:
+            if fn is None or key in self._disabled:
+                self.misses += 1
+                return None
+            self.hits += 1
         return fn
 
     def disable(self, key: str, reason: str) -> None:
-        if key not in self._disabled:
+        with self._lock:
+            if key in self._disabled:
+                return
             self._disabled.add(key)
-            logger.warning(
-                "AOT program %s disabled at serve time (%s); the jitted "
-                "path serves this shape from now on", key, reason,
-            )
+        logger.warning(
+            "AOT program %s disabled at serve time (%s); the jitted "
+            "path serves this shape from now on", key, reason,
+        )
 
     def __len__(self) -> int:
         return len(self._programs) - len(self._disabled)

@@ -21,6 +21,7 @@ from predictionio_tpu.templates.ecommerce.engine import (  # noqa: E402
     ECommAlgorithm,
     ECommAlgorithmParams,
     ECommModel,
+    ECommServingState,
     Query,
     category_arrays,
 )
@@ -175,3 +176,72 @@ def test_a_store_error_means_no_filter(shop, monkeypatch):
     monkeypatch.setattr(store.LEventStore, "find_by_entities", boom)
     assert algo._store_rules(["1", "2"]) == ({}, set())
     assert len(algo.predict(model, Query(user="1", num=5)).item_scores) == 5
+
+
+def test_two_batches_at_once_are_each_served_the_mask_of_their_own_read(shop, monkeypatch):
+    """ISSUE 31: the batcher keeps two batches in flight, so ``batch_predict``
+    runs against itself, and the constraint may change between the two
+    store reads: each batch's answers obey the constraint *it* read."""
+    import threading
+
+    algo, model, queries, _, unavailable, *_ = shop
+    model, _ = algo.pin_model_for_serving(model)
+    read = algo._store_rules
+    by_thread = {}  # a thread's read of the constraint
+
+    def store_rules(users):
+        seen, _ = read(users)
+        return seen, set(by_thread[threading.current_thread().name])
+
+    monkeypatch.setattr(algo, "_store_rules", store_rules)
+    slots = list(enumerate(queries))
+    constraints = {"old": {str(int(i)) for i in unavailable},
+                   "new": {str(i) for i in range(0, N_ITEMS, 3)}}
+    want = {}
+    for name, ids in constraints.items():
+        by_thread[threading.current_thread().name] = ids
+        want[name] = dict(algo.batch_predict(model, slots))
+    assert want["old"] != want["new"]
+    wrong = []
+
+    def batches(name):
+        by_thread[threading.current_thread().name] = constraints[name]
+        for _ in range(25):
+            got = dict(algo.batch_predict(model, slots))
+            if got != want[name]:
+                wrong.append(name)
+
+    threads = [threading.Thread(target=batches, args=(name,), name=name, daemon=True)
+               for name in constraints]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    assert wrong == []
+
+
+def test_the_blocked_mask_is_read_from_its_cache_once(shop):
+    """What the other batch assigns between two reads of the cache must not
+    reach this one: the cache is one tuple, read once."""
+    algo, model, *_ = shop
+    mine, theirs = {"1", "2"}, {"3"}
+    mask = algo._blocked(model, mine)
+    other = algo._blocked(model, theirs)
+    assert not np.array_equal(mask, other)
+
+    class Swapped(ECommServingState):
+        """A state whose cache another batch re-assigns after every read."""
+        reads = 0
+
+        @property
+        def blocked(self):
+            Swapped.reads += 1
+            return (mine, mask) if Swapped.reads == 1 else (theirs, other)
+
+        @blocked.setter
+        def blocked(self, value):
+            pass
+
+    model._pio_serving = Swapped()
+    assert algo._blocked(model, mine) is mask and Swapped.reads == 1

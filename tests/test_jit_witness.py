@@ -494,6 +494,7 @@ class TestBucketCompileCounts:
         from predictionio_tpu.data.storage.base import App
         from predictionio_tpu.serving import BatcherConfig, CacheConfig
         from predictionio_tpu.serving.batcher import _Pending
+        from predictionio_tpu.utils import spans
         from predictionio_tpu.workflow import load_engine_variant, run_train
         from predictionio_tpu.workflow.serving import QueryService
 
@@ -564,12 +565,15 @@ class TestBucketCompileCounts:
             try:
 
                 def serve():
+                    collector = spans.Collector()
                     for n in range(1, 9):
                         qs.batcher._dispatch(
                             [
                                 _Pending({"user": str(u % 25), "num": 7})
                                 for u in range(n)
-                            ]
+                            ],
+                            collector,
+                            qs.batcher._number(collector),
                         )
 
                 _, serve_rep = jw.run_with_jit_witness(serve)

@@ -312,3 +312,68 @@ def test_aot_export_holds_every_row_bucket_and_serves_with_no_compile(
     assert block["serveTimeCompiles"] == 0
     # every batch found its row bucket's program: none fell to the jit
     assert block["misses"] == 0 and block["hits"] >= stats["batcher"]["batches"]
+
+
+def test_two_handle_batch_calls_at_once_give_each_caller_its_answers(trained):
+    """ISSUE 31: the batcher keeps two batches in flight, so
+    ``QueryService.handle_batch`` runs against itself: on a pinned deploy
+    two threads call it at once, over and over, with a reload under them,
+    and every slot holds what the same body is answered alone."""
+    from predictionio_tpu.serving import BatcherConfig, CacheConfig
+    from predictionio_tpu.workflow.serving import QueryService
+
+    service = QueryService(
+        trained.variant, trained.ctx, instance_id=trained.instance.id,
+        cache=CacheConfig(pin_model=True),
+        batching=BatcherConfig(
+            max_batch_delay_ms=1.0, warmup_body={"user": "0", "num": 10}),
+    )
+    try:
+        users = [str(u) for u in range(N_TRAINED_USERS)] + ["ghost"]
+        alone = {u: service.handle_batch([{"user": u, "num": 10}])[0] for u in users}
+        assert all(status == 200 for status, _ in alone.values())
+        assert len(alone["0"][1]["itemScores"]) == 10
+        failures: list = []
+        asked = [0, 0]
+        counted = service.query_count
+        start = threading.Barrier(2)
+
+        def caller(which: int) -> None:
+            rng = np.random.default_rng(which)
+            for round_ in range(30):
+                bodies = [{"user": u, "num": 10}
+                          for u in rng.choice(users, int(rng.integers(1, 33)))]
+                asked[which] += len(bodies)
+                if round_ % 10 == 0:
+                    start.wait(timeout=30)  # enter handle_batch together
+                for body, (status, payload) in zip(
+                        bodies, service.handle_batch(bodies)):
+                    want = alone[body["user"]][1]["itemScores"]
+                    got = payload["itemScores"] if status == 200 else None
+                    if got is None or [s["item"] for s in got] != [
+                            s["item"] for s in want]:
+                        failures.append((which, round_, body, status, payload))
+                if which == 0 and round_ == 15:
+                    service.reload()  # the other caller holds its snapshot
+
+        threads = [threading.Thread(target=caller, args=(w,), daemon=True)
+                   for w in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+        assert failures == []
+        # the serve tail ran once a query: no count lost between the two
+        assert service.query_count - counted == sum(asked)
+        # and through the batcher's two workers: every rider its own answer
+        bodies = [{"user": users[i % len(users)], "num": 10} for i in range(96)]
+        for body, (status, payload) in zip(
+                bodies, _submit_together(service.batcher, bodies)):
+            assert status == 200
+            assert [s["item"] for s in payload["itemScores"]] == [
+                s["item"] for s in alone[body["user"]][1]["itemScores"]]
+        b = service.stats_json()["batcher"]
+        assert sum(b["overlap"].values()) == b["batches"] and b["inflightBatch"] == 0
+    finally:
+        service.close()
