@@ -22,7 +22,8 @@ every leg that needs it runs as a child, one at a time.
                      computed here from the stored model blob
   5 twotower child   `pio train` of the Two-Tower template (dim 64, batch 8192,
                      1M interactions, 20k x 10k, bf16 GEMMs); fusedCe must be
-                     "pallas"; loss finite and decreasing
+                     "pallas" and optimizer "rows" (Adam on the rows a batch
+                     gathered); loss finite and decreasing
   6 kernels  child   both Pallas kernels against their references on device;
                      the solver also at the small padded ranks spd_solve sends
                      it (the templates' default rank 10 -> K=16, 5 -> 8, 20 -> 24)
@@ -680,6 +681,7 @@ class Smoke:
         self.pio("twotower", "train", "--engine-json", path)
         inst = self._instance("smoke-tt")
         want = {"fusedCe": "xla" if self.rehearse else "pallas",
+                "optimizer": "rows",
                 "gemmDtype": "bfloat16", "batch": t["batch"], "dim": t["dim"]}
         device, kernels = self._check_where_it_ran(inst, "twotower", want)
         model = self._load_models(inst.id)[0]
@@ -705,7 +707,8 @@ class Smoke:
             "peakBytesInUse": device.get("peakBytesInUse"),
         })
         self.facts["twotower"] = {k: kernels[k] for k in
-                                  ("backend", "fusedCe", "fusedCeWhy", "gemmDtype")}
+                                  ("backend", "fusedCe", "fusedCeWhy", "optimizer",
+                                   "gemmDtype")}
         self.say(f"twotower: {self.legs['twotower']}")
 
     # ---------------------------------------------------------- 6 kernels

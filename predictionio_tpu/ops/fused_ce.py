@@ -223,8 +223,14 @@ def fused_inbatch_ce(
     return loss
 
 
+#: the device scope of both kernel calls: a trace's reduction finds the
+#: forward and the backward sweep under it, whatever Mosaic names them
+SCOPE = "pio_tt_ce"
+
+
 def _fused_fwd(ue, ie, inv_temp, interpret):
-    rs, cs = _fwd_call(ue, ie, inv_temp, interpret)
+    with jax.named_scope(SCOPE):
+        rs, cs = _fwd_call(ue, ie, inv_temp, interpret)
     B = ue.shape[0]
     # positive-pair logits: the [B, B] diagonal is just the rowwise dot
     diag = jnp.sum(ue * ie, axis=1) * inv_temp
@@ -238,7 +244,8 @@ def _fused_fwd(ue, ie, inv_temp, interpret):
 
 def _fused_bwd(inv_temp, interpret, res, g):
     ue, ie, rs, cs = res
-    due, die = _bwd_call(ue, ie, rs, cs, inv_temp, interpret)
+    with jax.named_scope(SCOPE):
+        due, die = _bwd_call(ue, ie, rs, cs, inv_temp, interpret)
     # the positive pair's -delta correction, hoisted out of the kernel:
     # d/due_i of (-diag terms) = -(2 * 0.5/B) * inv_temp * ie_i
     c2 = inv_temp / ue.shape[0]

@@ -24,6 +24,15 @@ TPU-first design:
   batch size and each step ``dynamic_slice``s its batch from the
   device-resident permutation, so one compiled step serves the whole
   run.
+* **Adam on the rows a step gathered** — on one device the step reads
+  and writes ``p``, ``m`` and ``v`` of the distinct rows of its batch and
+  of no other (:func:`adam_rows`): O(batch x dim) bytes a step whatever
+  the tables hold. A row outside the batch keeps ``p``, ``m`` and ``v``
+  as they are, which is what TensorFlow's LazyAdam and PyTorch's
+  SparseAdam do; dense Adam (``optax.adam`` over the whole tables, what a
+  mesh still runs here) goes on moving such a row by its decaying ``m``.
+  The two agree where every row is in every batch. Which of the two a
+  train took is in ``info["optimizer"]`` with its why.
 """
 
 from __future__ import annotations
@@ -31,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import logging
+import zlib
 from types import MappingProxyType
 from typing import Any, NamedTuple
 
@@ -40,14 +50,24 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, reshard
 
+from predictionio_tpu.utils.spans import CompileLedger, count, span
+
 logger = logging.getLogger(__name__)
 
 __all__ = [
     "TwoTowerConfig",
     "TwoTowerModel",
+    "adam_rows",
+    "pack_rows",
+    "unpack_rows",
     "sharded_embedding_lookup",
     "train_two_tower",
 ]
+
+#: optax.adam's defaults, which the row update shares with the dense one
+ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
+#: the losses of the first steps kept in ``info`` (a reference replays them)
+FIRST_LOSSES = 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,18 +178,117 @@ def _ce_path(
     return "pallas", "single-device TPU"
 
 
+def _optimizer_path(mesh: Mesh | None) -> tuple[str, str]:
+    """Which Adam the step runs — ``("rows" | "dense", why)``, recorded
+    beside :func:`_ce_path`'s decision. One device always updates rows
+    (:func:`adam_rows`); a mesh keeps ``optax.adam`` over its sharded
+    tables until the row update is sharded too."""
+    if mesh is not None:
+        return "dense", "mesh training keeps optax.adam over the sharded tables"
+    return "rows", "single device: Adam on the rows the batch gathered"
+
+
+def state_width(dim: int) -> int:
+    """Columns of a packed row: ``p | m | v`` and zeros up to a whole
+    number of 128-lane tiles (256 at dim 64)."""
+    return _pad_rows(3 * dim, 128)
+
+
+def pack_rows(table: jax.Array) -> jax.Array:
+    """``[N, D]`` parameters as the row update's state ``[N, W]``: each
+    row ``p | m | v | 0...`` with ``m = v = 0``.
+
+    Why one wide array and not three ``[N, D]``: the chip keeps a
+    ``f32[N, 64]`` argument column-major (rows of 64 would be padded to
+    128 lanes), and a program that gathers rows of it first copies it
+    whole into the padded row-major form — at 7.68 M rows three such
+    arrays a table are 5.6 GB as arguments and 11.1 GB more as copies, over
+    the chip's 15.75 GB (compiled for a v5e: PERF.md, PR 32). A row of 256
+    floats is two whole lane tiles: stored row-major as it stands, no
+    copy, and one gather and one scatter move ``p``, ``m`` and ``v``."""
+    dim = table.shape[1]
+    return jnp.pad(table, ((0, 0), (0, state_width(dim) - dim)))
+
+
+_pack_rows = jax.jit(pack_rows)
+
+
+def unpack_rows(state: jax.Array, dim: int) -> tuple[jax.Array, ...]:
+    """``(p, m, v)`` of a packed state, each ``[N, D]``."""
+    return tuple(state[:, k * dim:(k + 1) * dim] for k in range(3))
+
+
+def _whole_rows(state: jax.Array, ids: jax.Array) -> jax.Array:
+    """``state[ids]`` as a gather of whole rows, kept apart from whatever
+    slices them: folded into the gather, a column slice turns it into the
+    chip's slow windowed form."""
+    return jax.lax.optimization_barrier(
+        state.at[ids].get(mode="promise_in_bounds"))
+
+
+def adam_rows(
+    state: jax.Array,  # [N, W] packed rows p | m | v (pack_rows)
+    ids: jax.Array,  # [B] int32, duplicates allowed
+    grads: jax.Array,  # [B, D]: dL/d(p[ids[j]]) for each slot j
+    step: jax.Array,  # the global step, from 1
+    learning_rate: float,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One Adam step on the distinct rows of ``ids`` and on no other.
+
+    For each distinct id ``r``: ``g_r`` is the sum of the slots' gradients
+    that gathered ``r`` (the row of the dense gradient, which is zero
+    elsewhere); ``m_r <- b1 m_r + (1 - b1) g_r``; ``v_r <- b2 v_r +
+    (1 - b2) g_r^2``; ``p_r <- p_r - lr (m_r / (1 - b1^t)) /
+    (sqrt(v_r / (1 - b2^t)) + eps)``, ``b1``, ``b2``, ``eps`` optax's. Every
+    other row keeps its ``p``, ``m`` and ``v`` (lazy Adam). Returns the
+    state, the number of distinct rows and ``sum_r |g_r|^2``.
+
+    Nothing of the table's size is read, written or filled: the ids are
+    sorted (B keys), duplicates summed into the first ``n`` of B slots, the
+    ``n`` rows gathered, updated and scattered back; slots past ``n`` point
+    beyond the table and are dropped by the scatter."""
+    B, D = grads.shape
+    n_rows = state.shape[0]
+    slot = jnp.arange(B, dtype=jnp.int32)
+    xs, order = jax.lax.sort_key_val(ids, slot)
+    first = jnp.concatenate([jnp.ones((1,), bool), xs[1:] != xs[:-1]])
+    seg = jnp.cumsum(first.astype(jnp.int32)) - 1
+    g = jax.ops.segment_sum(
+        grads[order], seg, num_segments=B, indices_are_sorted=True
+    )
+    # slot k < n holds the k-th distinct id (its duplicates write the same
+    # value); slot k >= n holds n_rows + k: sorted, distinct, out of range
+    rows = (n_rows + slot).at[seg].set(xs, indices_are_sorted=True)
+    old = state.at[rows].get(mode="fill", fill_value=0)
+    p_r, m_r, v_r = unpack_rows(old, D)
+    t = step.astype(jnp.float32)
+    m_r = ADAM_B1 * m_r + (1.0 - ADAM_B1) * g
+    v_r = ADAM_B2 * v_r + (1.0 - ADAM_B2) * (g * g)
+    m_hat = m_r / (1.0 - ADAM_B1 ** t)
+    v_hat = v_r / (1.0 - ADAM_B2 ** t)
+    p_r = p_r - learning_rate * m_hat / (jnp.sqrt(v_hat) + ADAM_EPS)
+    new = jnp.concatenate([p_r, m_r, v_r, old[:, 3 * D:]], axis=1)
+    # no `indices_are_sorted` / `unique_indices` on this scatter, true as
+    # both are: with them the chip takes 14.3 ms for 8,192 rows of 1 KB
+    # into 4.57 M, without them 0.73 ms (measured, PR 32)
+    state = state.at[rows].set(new, mode="drop")
+    return state, seg[-1] + 1, jnp.sum(g * g)
+
+
 @functools.lru_cache(maxsize=16)
 def _epoch_program(
     mesh: Mesh | None,
     data_axis: str | None,
     model_axis: str | None,
     B: int,
+    D: int,
     n_pad: int,
     steps_per_epoch: int,
     learning_rate: float,
     inv_temp: float,
     gemm_dtype_name: str,
     ce_path: str,
+    optimizer: str,
 ):
     """Build (and cache) the jitted per-epoch training program.
 
@@ -178,9 +297,19 @@ def _epoch_program(
     warm-up/timed pair — reuse the SAME jit object instead of re-tracing
     a fresh closure each call (re-tracing the full-epoch scan costs ~1 s
     even with the persistent compile cache hitting). ``ce_path`` is
-    :func:`_ce_path`'s decision."""
+    :func:`_ce_path`'s decision and ``optimizer`` :func:`_optimizer_path`'s.
+
+    Returns ``(train_epoch, init_state, tables)``. ``init_state(params)``
+    gives the carry ``(p, o)``: the tables and optax's state under
+    ``dense``, the packed rows (:func:`pack_rows`) and nothing under
+    ``rows``. ``train_epoch(p, o, epoch, r, c, perm_key)`` returns ``(p, o,
+    losses, touched, grad_sq)``, a row a step: ``touched`` the distinct rows
+    it updated, both tables (under ``dense`` every row, every step),
+    ``grad_sq`` the squared norm of each table's gradient (user, item),
+    duplicates summed. ``tables(p)`` are the ``[N, D]`` parameters of a
+    carry."""
     gemm_dtype = jnp.bfloat16 if gemm_dtype_name == "bfloat16" else jnp.float32
-    from predictionio_tpu.ops.fused_ce import fused_inbatch_ce
+    from predictionio_tpu.ops.fused_ce import SCOPE as ce_scope, fused_inbatch_ce
 
     rep_sharding = (
         None if mesh is None else NamedSharding(mesh, PartitionSpec())
@@ -199,12 +328,12 @@ def _epoch_program(
             * inv_temp
         )
 
-    def loss_fn(p, u_ids, i_ids):
-        ue = sharded_embedding_lookup(p["user"], u_ids, mesh, data_axis, model_axis)
-        ie = sharded_embedding_lookup(p["item"], i_ids, mesh, data_axis, model_axis)
+    def loss_of_rows(ue, ie):
+        """The loss from the batch's gathered rows ``[B, D]``."""
         ue = ue / (jnp.linalg.norm(ue, axis=-1, keepdims=True) + 1e-8)
         ie = ie / (jnp.linalg.norm(ie, axis=-1, keepdims=True) + 1e-8)
         if ce_path != "xla":
+            # the kernel's two calls carry the scope pio_tt_ce themselves
             return fused_inbatch_ce(ue, ie, inv_temp, ce_path == "interpret")
         labels = jnp.arange(B)
         if mesh is not None:
@@ -221,13 +350,50 @@ def _epoch_program(
         else:
             ue_r, ie_r = ue, ie
         # symmetric in-batch softmax: user->item and item->user
-        l1 = optax.softmax_cross_entropy_with_integer_labels(
-            _logits(ue, ie_r), labels
-        )
-        l2 = optax.softmax_cross_entropy_with_integer_labels(
-            _logits(ie, ue_r), labels
-        )
-        return 0.5 * (l1.mean() + l2.mean())
+        with jax.named_scope(ce_scope):
+            l1 = optax.softmax_cross_entropy_with_integer_labels(
+                _logits(ue, ie_r), labels
+            )
+            l2 = optax.softmax_cross_entropy_with_integer_labels(
+                _logits(ie, ue_r), labels
+            )
+            return 0.5 * (l1.mean() + l2.mean())
+
+    def loss_fn(p, u_ids, i_ids):
+        with jax.named_scope("pio_tt_gather"):
+            ue = sharded_embedding_lookup(
+                p["user"], u_ids, mesh, data_axis, model_axis)
+            ie = sharded_embedding_lookup(
+                p["item"], i_ids, mesh, data_axis, model_axis)
+        return loss_of_rows(ue, ie)
+
+    def dense_step(p, o, u_ids, i_ids, step):
+        loss, grads = jax.value_and_grad(loss_fn)(p, u_ids, i_ids)
+        with jax.named_scope("pio_tt_update"):
+            updates, o = tx.update(grads, o, p)
+            p = optax.apply_updates(p, updates)
+        grad_sq = jnp.stack([jnp.sum(grads[k] ** 2) for k in ("user", "item")])
+        rows = jnp.int32(p["user"].shape[0] + p["item"].shape[0])
+        return p, o, loss, rows, grad_sq
+
+    def rows_step(p, o, u_ids, i_ids, step):
+        with jax.named_scope("pio_tt_gather"):
+            # whole packed rows, then the slice: `table[ids, :D]` is one
+            # gather of [1, D] windows at two-part indices, which the
+            # chip runs as a loop of 8,192 dynamic slices (10 ms a table,
+            # measured, PR 32); whole rows are one vectorised gather
+            ue = unpack_rows(_whole_rows(p["user"], u_ids), D)[0]
+            ie = unpack_rows(_whole_rows(p["item"], i_ids), D)[0]
+        loss, grads = jax.value_and_grad(loss_of_rows, argnums=(0, 1))(ue, ie)
+        with jax.named_scope("pio_tt_update"):
+            user, n_u, sq_u = adam_rows(
+                p["user"], u_ids, grads[0], step + 1, learning_rate)
+            item, n_i, sq_i = adam_rows(
+                p["item"], i_ids, grads[1], step + 1, learning_rate)
+        return ({"user": user, "item": item}, o, loss, n_u + n_i,
+                jnp.stack([sq_u, sq_i]))
+
+    one_step = rows_step if optimizer == "rows" else dense_step
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def train_epoch(p, o, epoch, r, c, perm_key):
@@ -257,16 +423,27 @@ def _epoch_program(
                 bspec = NamedSharding(mesh, PartitionSpec(data_axis))
                 u_ids = reshard(u_ids, bspec)
                 i_ids = reshard(i_ids, bspec)
-            loss, grads = jax.value_and_grad(loss_fn)(p, u_ids, i_ids)
-            updates, o = tx.update(grads, o, p)
-            return (optax.apply_updates(p, updates), o), loss
+            p, o, loss, touched, grad_sq = one_step(
+                p, o, u_ids, i_ids, epoch * steps_per_epoch + step
+            )
+            return (p, o), (loss, touched, grad_sq)
 
-        (p, o), losses = jax.lax.scan(
-            body, (p, o), jnp.arange(steps_per_epoch)
+        (p, o), per_step = jax.lax.scan(
+            body, (p, o), jnp.arange(steps_per_epoch, dtype=jnp.int32)
         )
-        return p, o, losses
+        return (p, o, *per_step)
 
-    return train_epoch, tx
+    def init_state(params):
+        if optimizer == "dense":
+            return params, tx.init(params)
+        return {k: _pack_rows(t) for k, t in params.items()}, ()
+
+    def tables(p):
+        if optimizer == "dense":
+            return p
+        return {k: s[:, :D] for k, s in p.items()}
+
+    return train_epoch, init_state, tables
 
 
 def _pad_rows(n: int, mult: int) -> int:
@@ -289,7 +466,17 @@ def train_two_tower(
     """Train user/item towers from implicit interaction pairs.
 
     ``info``, when given, receives the kernel decisions this train took
-    (backend, cross-entropy path and why, GEMM dtype, batch, dim, mesh).
+    (backend, cross-entropy path and why, optimizer and why, GEMM dtype,
+    batch, dim, mesh) and what it measured: ``epochSeconds`` (one dispatch
+    and one sync an epoch; the first carries the compile), ``stepMs`` (the
+    median epoch but the first over its steps), ``firstLosses`` and
+    ``firstGradNorms`` (of the first steps: each table's gradient norm,
+    duplicates summed: user, item), ``lastLosses``,
+    ``rowsTouched`` / ``rowsTouchedPerStep`` (distinct rows updated, both
+    tables, counted on the device), ``pairsChecksum`` (CRC-32 of the id
+    arrays as uploaded: another order of pairs is told from other
+    mathematics by it), ``epochProgram`` (the compile ledger's entry) and
+    ``epochProgramSeconds`` (its trace + lower + load).
 
     ``rows[i]``/``cols[i]`` is one (user, item) interaction. Returns
     L2-normalized tower vectors as replicated host-readable arrays.
@@ -377,26 +564,30 @@ def train_two_tower(
     # device-side permutation gather (the previous per-epoch host
     # permutation + re-upload was a full-dataset transfer stall per epoch
     # — VERDICT r3 weak #6)
-    import time as _time
-
-    t_ingest = _time.perf_counter()
-    r_base = jnp.asarray(rows[reps].astype(np.int32))
-    c_base = jnp.asarray(cols[reps].astype(np.int32))
-    if rep_sharding is not None:
-        r_base = jax.device_put(r_base, rep_sharding)
-        c_base = jax.device_put(c_base, rep_sharding)
-    int(c_base[-1])  # hard sync: the upload is complete, not just enqueued
-    t_ingest = _time.perf_counter() - t_ingest
+    r_host = rows[reps].astype(np.int32)
+    c_host = cols[reps].astype(np.int32)
+    pairs_checksum = zlib.crc32(c_host, zlib.crc32(r_host))
+    with span("train.ingest") as ingest:
+        r_base = jnp.asarray(r_host)
+        c_base = jnp.asarray(c_host)
+        if rep_sharding is not None:
+            r_base = jax.device_put(r_base, rep_sharding)
+            c_base = jax.device_put(c_base, rep_sharding)
+        int(c_base[-1])  # hard sync: the upload is complete, not just enqueued
+    del r_host, c_host
 
     steps_per_epoch = n_pad // B
     inv_temp = 1.0 / config.temperature
     ce_path, ce_why = _ce_path(
         mesh, config.gemm_dtype, config.fused_ce, B, D, inv_temp
     )
+    optimizer, optimizer_why = _optimizer_path(mesh)
     decisions = {
         "backend": jax.default_backend(),
         "fusedCe": ce_path,
         "fusedCeWhy": ce_why,
+        "optimizer": optimizer,
+        "optimizerWhy": optimizer_why,
         "gemmDtype": config.gemm_dtype,
         "batch": B,
         "dim": D,
@@ -405,37 +596,63 @@ def train_two_tower(
     logger.info("two-tower kernel decisions: %s", decisions)
     info = {} if info is None else info
     info.update(decisions)
-    train_epoch, tx = _epoch_program(
-        mesh, data_axis, model_axis, B, n_pad, steps_per_epoch,
-        config.learning_rate, inv_temp, config.gemm_dtype, ce_path,
+    train_epoch, init_state, tables = _epoch_program(
+        mesh, data_axis, model_axis, B, D, n_pad, steps_per_epoch,
+        config.learning_rate, inv_temp, config.gemm_dtype, ce_path, optimizer,
     )
-    opt_state = tx.init(params)
+    params, opt_state = init_state(params)
+    ledger = CompileLedger.install()
+    compiled_before = ledger.snapshot()
 
     history = []
     total_steps = config.epochs * steps_per_epoch
-    t_train = _time.perf_counter()
     epoch_seconds = []  # the first carries the compile
+    rows_touched = 0
     for epoch in range(config.epochs):
-        t_epoch = _time.perf_counter()
-        params, opt_state, losses = train_epoch(
-            params, opt_state, jnp.int32(epoch), r_base, c_base, k_perm
-        )
-        losses_np = np.asarray(losses)  # one readback per epoch
-        epoch_seconds.append(round(_time.perf_counter() - t_epoch, 3))
+        with span("train.epoch") as one_epoch:
+            params, opt_state, losses, touched, grad_sq = train_epoch(
+                params, opt_state, jnp.int32(epoch), r_base, c_base, k_perm
+            )
+            losses_np = np.asarray(losses)  # one readback per epoch
+        epoch_seconds.append(round(one_epoch.seconds, 3))
+        rows_touched += int(np.asarray(touched, np.int64).sum())
+        if epoch == 0:
+            info["firstLosses"] = [float(x) for x in losses_np[:FIRST_LOSSES]]
+            info["firstGradNorms"] = np.sqrt(
+                np.asarray(grad_sq[:FIRST_LOSSES], np.float64)).tolist()
         for i, loss in enumerate(losses_np):
             step = epoch * steps_per_epoch + i
             if step % config.log_every == 0 or step == total_steps - 1:
                 history.append((step, float(loss)))
-    t_train = _time.perf_counter() - t_train
     info["epochSeconds"] = epoch_seconds
     info["stepsPerEpoch"] = steps_per_epoch
+    info["stepMs"] = round(
+        1e3 * float(np.median(epoch_seconds[1:] or epoch_seconds))
+        / steps_per_epoch, 4)
+    info["lastLosses"] = [float(x) for x in losses_np[-FIRST_LOSSES:]]
+    info["rowsTouched"] = rows_touched
+    count("rowsTouched", rows_touched)
+    info["rowsTouchedPerStep"] = round(rows_touched / total_steps, 2)
+    info["pairsChecksum"] = pairs_checksum
+    entry = ledger.table(since=compiled_before).get("train_epoch", {})
+    info["epochProgram"] = entry
+    info["epochProgramSeconds"] = round(sum(
+        entry.get(k, 0.0) for k in ("traceSeconds", "lowerSeconds", "loadSeconds")
+    ), 3)
+    # dense Adam's state is the tables' size twice over: gone before the
+    # normalised copies are made
+    del opt_state
 
     def _finalize(p):
-        u = p["user"] / (jnp.linalg.norm(p["user"], axis=-1, keepdims=True) + 1e-8)
-        v = p["item"] / (jnp.linalg.norm(p["item"], axis=-1, keepdims=True) + 1e-8)
+        with jax.named_scope("pio_tt_finalize"):
+            p = tables(p)
+            u = p["user"] / (
+                jnp.linalg.norm(p["user"], axis=-1, keepdims=True) + 1e-8)
+            v = p["item"] / (
+                jnp.linalg.norm(p["item"], axis=-1, keepdims=True) + 1e-8)
         return u, v
 
-    t_final = _time.perf_counter()
+    finalize = span("train.finalize").start()
     if mesh is not None and jax.process_count() > 1:
         # multi-host: replicate before the host reads the (possibly
         # model-sharded) tables; slicing off padding happens host-side
@@ -451,14 +668,17 @@ def train_two_tower(
         u, v = jax.jit(_finalize)(params)
     user_vecs = np.asarray(u)[:num_users]
     item_vecs = np.asarray(v)[:num_items]
-    t_final = _time.perf_counter() - t_final
+    finalize.stop()
+    timings = {
+        "ingest_seconds": round(ingest.seconds, 4),
+        "train_seconds": round(sum(epoch_seconds), 4),
+        "finalize_seconds": round(finalize.seconds, 4),
+    }
+    info["ingestSeconds"] = timings["ingest_seconds"]
+    info["finalizeSeconds"] = timings["finalize_seconds"]
     return TwoTowerModel(
         user_vecs=user_vecs,
         item_vecs=item_vecs,
         loss_history=tuple(history),
-        timings={
-            "ingest_seconds": round(t_ingest, 4),
-            "train_seconds": round(t_train, 4),
-            "finalize_seconds": round(t_final, 4),
-        },
+        timings=timings,
     )

@@ -49,6 +49,7 @@ from predictionio_tpu.data.aggregator import BiMap
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.ops.twotower import TwoTowerConfig, train_two_tower
 from predictionio_tpu.templates.retrieval import TwoTableRetrieval
+from predictionio_tpu.utils.spans import span
 
 __all__ = [
     "DataSourceParams",
@@ -56,6 +57,7 @@ __all__ = [
     "TwoTowerDataSource",
     "TwoTowerParams",
     "TwoTowerAlgorithm",
+    "SeenItems",
     "Query",
     "PredictedResult",
     "ItemScore",
@@ -100,13 +102,62 @@ class DataSourceParams(Params):
     }
 
 
+class SeenItems:
+    """The serving-time filter, user code -> the item codes the user has
+    interacted with, as two arrays: ``items[offsets[u]:offsets[u + 1]]``
+    (ascending) are user ``u``'s. Built from the training pairs with no
+    per-pair Python and pickled as two buffers: at 11.45 M pairs the dict
+    of 4.57 M sets this replaces took 33 s to build and 7 s to pickle
+    (measured on a host, PR 32). What `--online` folds in afterwards lies
+    in a small overlay, user code -> all of that user's codes, replaced
+    whole on every addition so that a reader never sees a set change."""
+
+    def __init__(self, offsets: np.ndarray, items: np.ndarray):
+        self.offsets = offsets  # [users + 1] int64
+        self.items = items  # [pairs] int32
+        self._added: dict[int, np.ndarray] = {}
+
+    @classmethod
+    def from_pairs(cls, rows: np.ndarray, cols: np.ndarray, n_users: int) -> "SeenItems":
+        """From distinct (user code, item code) pairs in any order (the
+        columnar read hands them sorted already: no sort then)."""
+        rows, cols = np.asarray(rows, np.int64), np.asarray(cols, np.int64)
+        key = rows * (int(cols.max()) + 1 if cols.size else 1) + cols
+        items = cols.astype(np.int32)
+        if not np.all(key[1:] > key[:-1]):
+            items = items[np.argsort(key, kind="stable")]
+        offsets = np.zeros(n_users + 1, np.int64)
+        np.cumsum(np.bincount(rows, minlength=n_users), out=offsets[1:])
+        return cls(offsets, items)
+
+    def codes(self, user: int) -> np.ndarray:
+        """The item codes of user code ``user``, ascending."""
+        added = self._added.get(user)
+        if added is not None:
+            return added
+        if 0 <= user < self.offsets.size - 1:
+            return self.items[self.offsets[user]:self.offsets[user + 1]]
+        return self.items[:0]
+
+    def add(self, user: int, item: int) -> None:
+        self._added[user] = np.union1d(self.codes(user), np.int32(item))
+
+    def as_dict(self, user_index: BiMap, item_index: BiMap) -> dict:
+        """``{user id: {item ids}}`` of every user with an item."""
+        users = set(np.flatnonzero(np.diff(self.offsets)).tolist()) | set(self._added)
+        return {
+            user_index.inverse(u): {item_index.inverse(int(i)) for i in self.codes(u)}
+            for u in users
+        }
+
+
 @dataclasses.dataclass
 class TrainingData(SanityCheck):
     rows: np.ndarray  # user idx, one entry per (user, item) pair
     cols: np.ndarray  # item idx
     user_index: BiMap
     item_index: BiMap
-    seen: dict  # user id -> set of item ids (serving-time filter)
+    seen: SeenItems  # the serving-time filter
 
     def sanity_check(self) -> None:
         if self.rows.size == 0:
@@ -167,34 +218,41 @@ class TwoTowerDataSource(DataSource):
         n = len(pairs)
         rows = np.fromiter((user_index[u] for u, _ in pairs), np.int64, n)
         cols = np.fromiter((item_index[i] for _, i in pairs), np.int64, n)
-        seen: dict[str, set] = {}
-        for u, i in pairs:
-            seen.setdefault(u, set()).add(i)
+        seen = SeenItems.from_pairs(rows, cols, len(user_index))
         return TrainingData(rows, cols, user_index, item_index, seen)
 
     def _read_training_columnar(self, ctx: WorkflowContext) -> TrainingData:
         """Vectorized single-host read: columnar bulk scan + grouped pair
         dedup (in-batch softmax has no per-pair weight, so a distinct-
-        pair set is the right shape) — no per-event Python. The seen-
-        filter dict is built from the (much smaller) deduped pair set."""
+        pair set is the right shape) — no per-event Python. Its three
+        parts are spans (``train.pairs``, ``train.id_maps``,
+        ``train.seen``) and land in ``kernels.twotower.readSeconds``."""
         from predictionio_tpu.templates.columnar_util import (
             aggregate_pairs,
             densify_pairs,
         )
 
         p = self.params
-        cols = PEventStore.find_columns(
-            app_name=p.app_name, event_names=list(p.event_names)
-        )
-        u_sel, i_sel, _counts = aggregate_pairs(cols)
-        rows, cols_idx, user_vocab, item_vocab = densify_pairs(
-            cols, u_sel, i_sel
-        )
-        user_index = BiMap.string_index(user_vocab)
-        item_index = BiMap.string_index(item_vocab)
-        seen: dict[str, set] = {}
-        for r, c in zip(rows.tolist(), cols_idx.tolist()):
-            seen.setdefault(user_vocab[r], set()).add(item_vocab[c])
+        with span("train.scan") as scan:
+            cols = PEventStore.find_columns(
+                app_name=p.app_name, event_names=list(p.event_names)
+            )
+        with span("train.pairs") as pairs:
+            u_sel, i_sel, _counts = aggregate_pairs(cols)
+        with span("train.id_maps") as id_maps:
+            rows, cols_idx, user_vocab, item_vocab = densify_pairs(
+                cols, u_sel, i_sel
+            )
+            user_index = BiMap.string_index(user_vocab)
+            item_index = BiMap.string_index(item_vocab)
+        with span("train.seen") as seen_span:
+            seen = SeenItems.from_pairs(rows, cols_idx, len(user_index))
+        ctx.run_info.setdefault("twotower", {})["readSeconds"] = {
+            "scan": round(scan.seconds, 3),
+            "pairs": round(pairs.seconds, 3),
+            "idMaps": round(id_maps.seconds, 3),
+            "seen": round(seen_span.seconds, 3),
+        }
         return TrainingData(rows, cols_idx, user_index, item_index, seen)
 
     def read_training(self, ctx: WorkflowContext) -> TrainingData:
@@ -274,8 +332,20 @@ class TwoTowerServingModel:
     item_vecs: Any  # [I, D] L2-normalized
     user_index: BiMap
     item_index: BiMap
-    seen: dict
+    #: :class:`SeenItems`; a ``{user id: item ids}`` dict (a blob stored
+    #: before PR 32, a test's model) is read the same
+    seen: Any
     loss_history: tuple = ()
+
+    def seen_items(self, user: str):
+        """The ids of the items ``user`` has interacted with."""
+        if isinstance(self.seen, dict):
+            return self.seen.get(user, ())
+        uidx = self.user_index.get(user)
+        if uidx is None:
+            return ()
+        inverse = self.item_index.inverse
+        return {inverse(int(i)) for i in self.seen.codes(uidx)}
 
 
 class TwoTowerAlgorithm(TwoTableRetrieval, JaxAlgorithm):
@@ -388,7 +458,12 @@ class TwoTowerAlgorithm(TwoTableRetrieval, JaxAlgorithm):
         for u, i in upd.seen_pairs:
             # copy-on-write per user: a reader iterating the old set must
             # never observe a concurrent mutation
-            model.seen[u] = set(model.seen.get(u, ())) | {i}
+            if isinstance(model.seen, dict):
+                model.seen[u] = set(model.seen.get(u, ())) | {i}
+                continue
+            u_code, i_code = model.user_index.get(u), model.item_index.get(i)
+            if u_code is not None and i_code is not None:
+                model.seen.add(u_code, i_code)
         return info
 
     def batch_predict(
@@ -410,7 +485,7 @@ class TwoTowerAlgorithm(TwoTableRetrieval, JaxAlgorithm):
             if uidx is None or num <= 0:
                 results.append((idx, PredictedResult(())))
                 continue
-            seen = model.seen.get(q.user, ())
+            seen = model.seen_items(q.user)
             k = min(num + len(seen), n_items)
             if k <= 0:
                 results.append((idx, PredictedResult(())))
@@ -438,7 +513,7 @@ class TwoTowerAlgorithm(TwoTableRetrieval, JaxAlgorithm):
         uidx = model.user_index.get(query.user)
         if uidx is None or int(query.num) <= 0:
             return PredictedResult(())
-        seen = model.seen.get(query.user, ())
+        seen = model.seen_items(query.user)
         # over-fetch num + |seen| BEFORE the top-K so the post-hoc seen
         # filter still leaves num items (applies to the exact and ANN
         # paths alike)

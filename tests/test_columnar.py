@@ -640,7 +640,11 @@ class TestTwoTowerColumnarRead:
                 for r, c in zip(td_slow.rows, td_slow.cols)
             }
             assert fast == slow and len(td_fast.rows) == len(td_slow.rows)
-            assert td_fast.seen == td_slow.seen
+            assert td_fast.seen.as_dict(
+                td_fast.user_index, td_fast.item_index
+            ) == td_slow.seen.as_dict(td_slow.user_index, td_slow.item_index)
+            assert sum(len(v) for v in td_fast.seen.as_dict(
+                td_fast.user_index, td_fast.item_index).values()) == len(td_fast.rows)
         finally:
             Storage.configure(None)
 

@@ -441,14 +441,20 @@ print("MULTIHOST-TWOTOWER-OK", me)
 
 
 @pytest.mark.parametrize("nproc", [2, 4])
-def test_twotower_multiprocess_matches_single(tmp_path, nproc):
+def test_twotower_multiprocess_matches_single(tmp_path, nproc, monkeypatch):
     """Two-tower training over a REAL multi-process jax.distributed mesh
     (embedding tables sharded over `model`, batches over `data`) must
     reproduce the single-device run — the same guarantee the ALS sweep
     has at P in {2,4,8}; single-process virtual meshes already cover the
-    sharding math, this covers the cross-process collectives."""
+    sharding math, this covers the cross-process collectives. One device
+    updates only the rows a batch gathered (PR 32) while a mesh still runs
+    dense Adam: another optimizer, not another sharding, so the single
+    device is steered to the mesh's optimizer here, in the test."""
+    import predictionio_tpu.ops.twotower as tt
     from predictionio_tpu.ops.twotower import TwoTowerConfig, train_two_tower
 
+    monkeypatch.setattr(
+        tt, "_optimizer_path", lambda mesh: ("dense", "steered by the test"))
     rng = np.random.default_rng(5)
     num_users, num_items = 60, 30
     rows = rng.integers(0, num_users, 800)

@@ -1,0 +1,92 @@
+"""Programs of the main paths compiled for a TPU v5e that is described, not
+attached (the chip's own compiler is installed here): what interpret mode
+and the CPU backend cannot show — the layouts the chip picks, the copies it
+adds and whether a program fits its memory. Nothing runs; no time is read.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process may load the TPU's library, and every xdist
+worker imports every test file."""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+#: Google Local Reviews (2018): benchmark/configs/twotower_glocal2018.json
+USERS, ITEMS, PAIRS, DIM, BATCH = 4_567_431, 3_116_785, 11_453_845, 64, 8192
+HBM = 15.75e9  # what the compiler lets a v5e program use of the chip's 16 GiB
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without a chip: keep it out."""
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+
+
+def test_the_two_tower_epoch_touches_rows_only_and_fits(one_chip, no_compile_cache):
+    """The epoch program of `twotower_glocal2018.retrain_pairs` at its real
+    size, fused kernel and row update: the packed tables are arguments and
+    aliased results, the scan holds no copy of one, and the program's own
+    memory is a few batches'."""
+    from predictionio_tpu.ops import twotower as tt
+
+    n_pad = -(-PAIRS // BATCH) * BATCH
+    steps = n_pad // BATCH
+    epoch, init_state, _ = tt._epoch_program(
+        None, "data", "model", BATCH, DIM, n_pad, steps, 0.05, 10.0, "bfloat16",
+        "pallas", "rows")
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    tables = {"user": jax.ShapeDtypeStruct((USERS, DIM), jnp.float32),
+              "item": jax.ShapeDtypeStruct((ITEMS, DIM), jnp.float32)}
+    p, o = on_chip(jax.eval_shape(init_state, tables))
+    width = tt.state_width(DIM)
+    assert p["user"].shape == (USERS, width) and width == 256
+    ids = jax.ShapeDtypeStruct((n_pad,), jnp.int32, sharding=one_chip)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = epoch.lower(p, o, scalar, ids, ids, key).compile()
+
+    memory = compiled.memory_analysis()
+    state_bytes = (USERS + ITEMS) * width * 4
+    assert memory.argument_size_in_bytes >= state_bytes
+    assert memory.alias_size_in_bytes >= state_bytes  # updated in place
+    assert memory.temp_size_in_bytes < 0.5e9  # no second table anywhere
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < HBM
+
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the fused kernel is in the program
+    table = re.compile(rf"f32\[({USERS}|{ITEMS}),\d+\]")
+    ops = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (\S+) ([\w\-]+)\(", line)
+        if m and table.match(m.group(1).split("{")[0]):
+            ops[m.group(2)] = ops.get(m.group(2), 0) + 1
+    # what yields a table-sized buffer: the arguments and the loop's carry,
+    # and per table one in-place scatter (inside its fusion). No copy, no
+    # broadcast or pad (a zero-filled gradient), no transpose.
+    assert set(ops) <= {"parameter", "get-tuple-element", "scatter", "fusion",
+                        "while", "tuple", "bitcast"}, ops
+    assert ops.get("scatter") == 2, ops

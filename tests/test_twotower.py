@@ -103,16 +103,31 @@ class TestTrainTwoTower:
         assert ing > outg + 0.2, (ing, outg)
         assert m.loss_history[-1][1] < m.loss_history[0][1]
 
-    def test_mesh_matches_single_device(self):
+    def test_mesh_matches_single_device(self, monkeypatch):
         # fp32 GEMMs here: the test pins SHARDING equivalence, and bf16
         # rounding (the default) amplifies benign reduction-order noise
         # past any tolerance that would still catch a real sharding bug
+        import predictionio_tpu.ops.twotower as tt
+
         cfg = dataclasses.replace(CFG, gemm_dtype="float32")
         rows, cols = clustered_interactions()
+        info = {}
+        lazy = train_two_tower(rows, cols, 60, 30, cfg, info=info)
+        assert info["optimizer"] == "rows"
+        # one device updates the rows a batch gathered, a mesh still runs
+        # dense Adam: another optimizer, not another sharding. The single
+        # device is steered to the mesh's optimizer here, in the test
+        monkeypatch.setattr(
+            tt, "_optimizer_path", lambda mesh: ("dense", "steered by the test"))
         single = train_two_tower(rows, cols, 60, 30, cfg)
+        assert np.abs(single.user_vecs - lazy.user_vecs).max() > 1e-3
+        monkeypatch.undo()
         for sizes in ((4, 2), (2, 4)):
             ctx = mesh_context(axis_sizes=sizes)
-            sharded = train_two_tower(rows, cols, 60, 30, cfg, mesh=ctx.mesh)
+            info = {}
+            sharded = train_two_tower(
+                rows, cols, 60, 30, cfg, mesh=ctx.mesh, info=info)
+            assert info["optimizer"] == "dense"
             np.testing.assert_allclose(
                 single.user_vecs, sharded.user_vecs, rtol=1e-3, atol=1e-4
             )
@@ -213,8 +228,8 @@ class TestTwoTowerTemplate:
         assert items, "no recommendations"
         # seen items are excluded
         model = qs._algo_model_pairs[0][1]
-        seen = model.seen.get("2", set())
-        assert not (set(items) & seen)
+        seen = model.seen_items("2")
+        assert seen and not (set(items) & seen)
         # user 2 is group 0: every UNSEEN group-0 item must outrank the
         # out-group items (most group-0 items are already seen, so a
         # simple majority check would be vacuous)
@@ -308,3 +323,89 @@ class TestNonToyScale:
         )
         # one upload per side (rows + cols), regardless of epoch count
         assert len(uploads) == 2, uploads
+
+
+class TestSeenItems:
+    """The serving-time filter as two arrays (PR 32): the same answers as
+    the dict of sets it replaced, through the four places that read it."""
+
+    def _model(self, seen):
+        from predictionio_tpu.data.aggregator import BiMap
+        from predictionio_tpu.templates.twotower.engine import TwoTowerServingModel
+
+        rng = np.random.default_rng(5)
+        unit = lambda a: a / np.linalg.norm(a, axis=1, keepdims=True)  # noqa: E731
+        return TwoTowerServingModel(
+            user_vecs=unit(rng.normal(size=(6, 8))).astype(np.float32),
+            item_vecs=unit(rng.normal(size=(12, 8))).astype(np.float32),
+            user_index=BiMap({f"u{k}": k for k in range(6)}),
+            item_index=BiMap({f"i{k}": k for k in range(12)}),
+            seen=seen,
+        )
+
+    @pytest.mark.parametrize("shuffled", [False, True])
+    def test_from_pairs_sorted_or_not(self, shuffled):
+        from predictionio_tpu.templates.twotower.engine import SeenItems
+
+        rows = np.array([0, 0, 2, 2, 2, 5])
+        cols = np.array([3, 7, 0, 4, 11, 9])
+        if shuffled:
+            order = np.random.default_rng(1).permutation(rows.size)
+            rows, cols = rows[order], cols[order]
+        seen = SeenItems.from_pairs(rows, cols, 6)
+        assert seen.offsets.tolist() == [0, 2, 2, 5, 5, 5, 6]
+        assert [seen.codes(u).tolist() for u in range(6)] == [
+            [3, 7], [], [0, 4, 11], [], [], [9]]
+        assert seen.codes(17).size == 0  # a user added after training
+
+    def test_add_replaces_a_users_codes_whole(self):
+        import pickle
+
+        from predictionio_tpu.templates.twotower.engine import SeenItems
+
+        seen = SeenItems.from_pairs(np.array([0, 0]), np.array([3, 7]), 2)
+        before = seen.codes(0)
+        seen.add(0, 5)
+        seen.add(4, 1)  # beyond the trained users
+        assert before.tolist() == [3, 7]  # a reader's array never changes
+        assert seen.codes(0).tolist() == [3, 5, 7] and seen.codes(4).tolist() == [1]
+        back = pickle.loads(pickle.dumps(seen))
+        assert back.codes(0).tolist() == [3, 5, 7] and back.codes(1).size == 0
+
+    def test_dict_and_arrays_answer_alike(self):
+        from predictionio_tpu.templates.twotower.engine import (
+            Query, SeenItems, TwoTowerAlgorithm, TwoTowerParams,
+        )
+
+        rows = np.array([0, 0, 0, 3, 3])
+        cols = np.array([1, 2, 8, 0, 5])
+        as_dict = {"u0": {"i1", "i2", "i8"}, "u3": {"i0", "i5"}}
+        old, new = self._model(as_dict), self._model(SeenItems.from_pairs(rows, cols, 6))
+        assert new.seen.as_dict(new.user_index, new.item_index) == as_dict
+        algo = TwoTowerAlgorithm(TwoTowerParams())
+        for user in ("u0", "u1", "u3", "nobody"):
+            assert set(old.seen_items(user)) == set(new.seen_items(user))
+            q = Query(user=user, num=4)
+            a, b = algo.predict(old, q), algo.predict(new, q)
+            assert [s.item for s in a.item_scores] == [s.item for s in b.item_scores]
+            assert not {s.item for s in b.item_scores} & set(new.seen_items(user))
+        queries = [(k, Query(user=u, num=3)) for k, u in enumerate(("u0", "u3", "u5"))]
+        got = {k: [s.item for s in r.item_scores] for k, r in algo.batch_predict(new, queries)}
+        want = {k: [s.item for s in r.item_scores] for k, r in algo.batch_predict(old, queries)}
+        assert got == want
+
+    def test_an_online_update_grows_either_form(self):
+        from types import SimpleNamespace
+
+        from predictionio_tpu.templates.twotower.engine import (
+            SeenItems, TwoTowerAlgorithm, TwoTowerParams,
+        )
+
+        algo = TwoTowerAlgorithm(TwoTowerParams())
+        upd = SimpleNamespace(user_ids=[], item_ids=[], user_rows=None, item_rows=None,
+                              seen_pairs=[("u1", "i4"), ("u0", "i9"), ("ghost", "i1")])
+        for seen in ({"u0": {"i1"}}, SeenItems.from_pairs(np.array([0]), np.array([1]), 6)):
+            model = self._model(seen)
+            algo.apply_online_update(model, upd)
+            assert set(model.seen_items("u0")) == {"i1", "i9"}
+            assert set(model.seen_items("u1")) == {"i4"}
