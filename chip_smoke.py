@@ -77,7 +77,7 @@ FULL = {
     # a rank that is not a multiple of 8 is padded into the next one, so
     # the templates' default rank 10 runs the kernel at K=16, rank 5 at
     # K=8 and rank 20 at K=24 — each its own Mosaic compile
-    "kernels": {"gj": [[138_000, 64], [8_000, 128]],
+    "kernels": {"chol": [[138_000, 64], [8_000, 128]],
                 "spd": [[138_000, 10], [27_027, 5], [27_027, 20]],
                 "ce": [8192, 64]},
 }
@@ -91,7 +91,7 @@ TOY = {
     "twotower": {"interactions": 4_000, "users": 300, "items": 120,
                  "dim": 16, "batch": 256, "epochs": 2},
     "serve": {"sequential": 8, "concurrent": 24, "clients": 8, "num": 10},
-    "kernels": {"gj": [[64, 16]], "spd": [[64, 10]], "ce": [256, 16]},
+    "kernels": {"chol": [[64, 16]], "spd": [[64, 10]], "ce": [256, 16]},
 }
 
 # Agreement tolerance of leg 4, per item i: |served_i - ref_i| <= AGREE_REL *
@@ -104,14 +104,14 @@ TOY = {
 AGREE_REL = 5e-5
 AGREE_ABS = 1e-6
 
-# Kernel tolerances of leg 6. GJ solver: max relative error against XLA
+# Kernel tolerances of leg 6. SPD solver: max relative error against XLA
 # Cholesky and relative residual ||Ax-b||/||b|| both under 1e-4 (float32
-# elimination of a ridge-regularised SPD system; tests/test_pallas_tpu.py uses
+# factorization of a ridge-regularised SPD system; tests/test_pallas_tpu.py uses
 # the same bound). Fused CE: loss within 5e-3 of the XLA reference, gradients
 # within 2e-2 of the largest reference gradient entry — the kernel
 # exponentiates and forms dL in bf16 (8 mantissa bits, 2^-9 ~ 2e-3 an
 # element), the reference only rounds the GEMM operands.
-GJ_TOL = 1e-4
+SOLVE_TOL = 1e-4
 CE_LOSS_TOL = 5e-3
 CE_GRAD_TOL = 2e-2
 
@@ -154,7 +154,7 @@ def child_kernels(spec: dict, interpret: bool) -> int:
     from predictionio_tpu.ops import fused_ce, solve
 
     hi = jax.lax.Precision.HIGHEST
-    out: dict = {"gj": [], "ce": None}
+    out: dict = {"solve": [], "ce": None}
 
     def first_and_steady(fn):
         t0 = time.perf_counter()
@@ -168,10 +168,10 @@ def child_kernels(spec: dict, interpret: bool) -> int:
         return result, first, sorted(steady)[1]
 
     method = "pallas_interpret" if interpret else "pallas"
-    # "gj": the kernel called directly at a multiple-of-8 K; "spd": a rank
+    # "chol": the kernel called directly at a multiple-of-8 K; "spd": a rank
     # through spd_solve(method), which pads it into the next multiple
     for via, batch, k in (
-        [("gj", *bk) for bk in spec["gj"]] + [("spd", *bk) for bk in spec["spd"]]
+        [("chol", *bk) for bk in spec["chol"]] + [("spd", *bk) for bk in spec["spd"]]
     ):
         @jax.jit
         def make(key, batch=batch, k=k):
@@ -187,7 +187,7 @@ def child_kernels(spec: dict, interpret: bool) -> int:
             x, first, steady = first_and_steady(lambda: solver(a, b))
         else:
             x, first, steady = first_and_steady(
-                lambda: solve.gj_solve_pallas(a, b, interpret=interpret)
+                lambda: solve.chol_solve_pallas(a, b, interpret=interpret)
             )
         x_ref = solve.cholesky_solve(a, b)
 
@@ -204,21 +204,20 @@ def child_kernels(spec: dict, interpret: bool) -> int:
         rel, resid = (float(v) for v in errors(a, b, x, x_ref))
         k_kernel = -(-k // 8) * 8
         rec = {
-            "via": "gj_solve_pallas" if via == "gj" else f"spd_solve({method})",
+            "via": solve.SOLVE_KERNEL if via == "chol" else f"spd_solve({method})",
             "shape": [batch, k, k],
             "kernelK": k_kernel,
-            "blockRows": solve._auto_block_rows(k_kernel),
             "relErrVsCholesky": rel,
             "residual": resid,
             "firstCallSeconds": round(first, 2),
             "steadyMs": round(steady * 1e3, 2),
         }
-        print("kernels: gj", json.dumps(rec), flush=True)
-        _require(np.isfinite(rel) and rel < GJ_TOL,
-                 f"GJ solver off Cholesky by {rel} at {rec['shape']}")
-        _require(np.isfinite(resid) and resid < GJ_TOL,
-                 f"GJ solver residual {resid} at {rec['shape']}")
-        out["gj"].append(rec)
+        print("kernels: solve", json.dumps(rec), flush=True)
+        _require(np.isfinite(rel) and rel < SOLVE_TOL,
+                 f"SPD solver off Cholesky by {rel} at {rec['shape']}")
+        _require(np.isfinite(resid) and resid < SOLVE_TOL,
+                 f"SPD solver residual {resid} at {rec['shape']}")
+        out["solve"].append(rec)
         del a, b, x, x_ref
 
     bsz, dim = spec["ce"]

@@ -150,9 +150,10 @@ class ALSConfig:
     #: while costing ~6% top-10 overlap churn (bench precision_compare).
     precision: str = "highest"
     #: SPD solver for the normal equations: "auto" picks the Pallas
-    #: blocked-Gauss-Jordan kernel on a single-device TPU backend (~3x
-    #: faster than XLA Cholesky at bench shapes) and Cholesky elsewhere;
-    #: explicit "cholesky" / "pallas" / "pallas_interpret" override.
+    #: lane-batched Cholesky kernel on a single-device TPU backend (how
+    #: it reads against XLA's Cholesky on the chip: ops/solve.py) and
+    #: Cholesky elsewhere; explicit "cholesky" / "pallas" /
+    #: "pallas_interpret" override.
     solver: str = "auto"
 
 
@@ -644,6 +645,13 @@ def build_buckets_device(
     return bucketed, counts_all > 0
 
 
+def _solve_systems(b: BucketedRatings) -> int:
+    """Systems one half-sweep hands the solver, from the bucket shapes:
+    every chunk row (padding rows included) and every hot slot (the
+    sentinel's included)."""
+    return sum(ch.row_id.size for ch in b.normal) + sum(len(hr) for hr in b.hot_rows)
+
+
 def rated_row_mask(b: BucketedRatings) -> np.ndarray:
     """Bool [num_rows]: which rows appear in the ratings. Rows outside get
     zero factors (parity: the reference only emits factors for trained
@@ -768,7 +776,8 @@ def _finish_solve(
 ) -> jax.Array:
     """Add ALS-WR regularization (λ·max(n,1)·I — MLlib scales λ by the
     rating count in both objectives) and the implicit YᵀY, then solve
-    (Pallas blocked-GJ on TPU, Cholesky elsewhere — see ops/solve.py)."""
+    (Pallas lane-batched Cholesky on TPU, XLA's elsewhere — see
+    ops/solve.py)."""
     from predictionio_tpu.ops.solve import spd_solve
 
     K = A.shape[-1]
@@ -1248,7 +1257,7 @@ def train_als(
         config.bucketing == "device"
         or (config.bucketing == "auto" and jax.default_backend() != "cpu")
     )
-    from predictionio_tpu.ops.solve import pallas_rank_ok
+    from predictionio_tpu.ops.solve import pallas_rank_ok, solve_kernel_name
 
     decisions = {
         "backend": jax.default_backend(),
@@ -1259,6 +1268,8 @@ def train_als(
             if not solver.startswith("pallas") or pallas_rank_ok(rank)
             else "cholesky"
         ),
+        # the solver as a device trace names it
+        "solveKernel": solve_kernel_name(solver, rank),
         "bucketing": "device" if use_device_bucketing else "host",
         "precision": config.precision,
         "rank": rank,
@@ -1365,6 +1376,9 @@ def train_als(
                 if timed:
                     jax.block_until_ready((user_bucketed, item_bucketed))
 
+    info["solveSystemsPerSweep"] = _solve_systems(user_bucketed) + _solve_systems(
+        item_bucketed
+    )
     if timed:
         info["bucketingSeconds"] = round(
             bucketing.seconds + (transfer.seconds if transfer else 0.0), 3

@@ -490,7 +490,121 @@ class TestInference:
         np.testing.assert_allclose(np.asarray(s), 4.0)
 
 
+def _spd_batch(seed: int, B: int, K: int, ridge: float = 0.1):
+    """Well-conditioned SPD systems in float32 and their float64 solution."""
+    rng = np.random.default_rng(seed)
+    M = rng.normal(size=(B, K, K)).astype(np.float32)
+    A = M @ M.transpose(0, 2, 1) / K + ridge * np.eye(K, dtype=np.float32)
+    b = rng.normal(size=(B, K)).astype(np.float32)
+    want = np.linalg.solve(A.astype(np.float64), b.astype(np.float64)[..., None])[..., 0]
+    return A, b, want
+
+
+def _row_err(got, want) -> float:
+    """Largest relative L2 error of a row."""
+    got = np.asarray(got, np.float64)
+    return float(
+        (np.linalg.norm(got - want, axis=1) / np.linalg.norm(want, axis=1)).max()
+    )
+
+
 class TestPallasSolver:
+    """The lane-batched Cholesky kernel (ops/solve.py) in interpret mode:
+    the same kernel body Mosaic compiles on the chip."""
+
+    @pytest.mark.parametrize("K", [8, 16, 24, 64, 96, 128])
+    def test_kernel_matches_cholesky_and_float64(self, K):
+        # every sublane-tile count the column loop meets: one vreg a
+        # column (8), a ragged last block of columns (24), the bench rank
+        # (64), the ranks whose blocks ask for more scoped VMEM (96, 128)
+        from predictionio_tpu.ops.solve import chol_solve_pallas, cholesky_solve
+
+        A, b, want = _spd_batch(K, 5, K)
+        x = chol_solve_pallas(jnp.asarray(A), jnp.asarray(b), interpret=True)
+        assert x.shape == b.shape
+        assert _row_err(x, want) < 1e-5
+        np.testing.assert_allclose(
+            np.asarray(x), np.asarray(cholesky_solve(jnp.asarray(A), jnp.asarray(b))),
+            rtol=5e-4, atol=5e-5,
+        )
+
+    @pytest.mark.parametrize("B", [1, 33, 129, 2049])
+    def test_batch_is_no_multiple_of_the_lane_block(self, B):
+        # 2049 is the hot group's H_g + 1: 16 groups of 128 lanes and one
+        # system more, in grid steps of 4 groups at this rank; the last
+        # block is ragged, nothing is padded
+        from predictionio_tpu.ops.solve import chol_solve_pallas
+
+        A, b, want = _spd_batch(B, B, 8)
+        x = chol_solve_pallas(jnp.asarray(A), jnp.asarray(b), interpret=True)
+        assert x.shape == (B, 8)
+        assert _row_err(x, want) < 1e-5
+
+    @pytest.mark.parametrize("n", [20, 67_359])
+    def test_als_wr_extremes(self, n):
+        # the least and the most rated row of the ML-20M shape, lambda
+        # 0.05: A = X'X + lambda n I grows with n, its conditioning too
+        from predictionio_tpu.ops.solve import chol_solve_pallas
+
+        rng = np.random.default_rng(n)
+        K = 64
+        X = np.abs(rng.normal(size=(3, n, K))) / 8
+        r = rng.integers(1, 11, size=(3, n)) / 2.0
+        A = np.einsum("bnk,bnj->bkj", X, X) + 0.05 * n * np.eye(K)
+        b = np.einsum("bnk,bn->bk", X, r)
+        A32, b32 = A.astype(np.float32), b.astype(np.float32)
+        want = np.linalg.solve(
+            A32.astype(np.float64), b32.astype(np.float64)[..., None]
+        )[..., 0]
+        x = chol_solve_pallas(jnp.asarray(A32), jnp.asarray(b32), interpret=True)
+        assert _row_err(x, want) < 1e-4
+
+    def test_padding_systems_solve_to_zero(self):
+        # a chunk's padding rows reach the solver as A = lambda I, b = 0
+        # (no ratings: the ridge alone): they solve to exactly 0, beside
+        # real systems and in a ragged last block (130 = 128 + 2 lanes;
+        # the lanes past the batch hold whatever the buffer held)
+        from predictionio_tpu.ops.solve import chol_solve_pallas
+
+        A, b, want = _spd_batch(3, 130, 8)
+        pad = np.arange(130) % 3 == 0
+        A[pad], b[pad] = 0.05 * np.eye(8, dtype=np.float32), 0.0
+        x = np.asarray(chol_solve_pallas(jnp.asarray(A), jnp.asarray(b), interpret=True))
+        assert x.shape == (130, 8) and np.isfinite(x).all()
+        np.testing.assert_array_equal(x[pad], 0.0)
+        assert _row_err(x[~pad], want[~pad]) < 1e-5
+
+    def test_the_kernel_body_is_traced_once_for_every_batch(self, monkeypatch):
+        # a sweep solves once a bucket and hot group (21 times at the
+        # ML-20M shape), each a pallas_call of its own batch: the body is
+        # jitted on its refs, so they share one trace (traced once a
+        # call, a warm job's first sweep took 125 s on the chip's host).
+        # A trace calls rsqrt once a column.
+        from predictionio_tpu.ops import solve
+
+        K = 40  # a rank no other test of this file solves at
+        calls = []
+        real = jax.lax.rsqrt
+        monkeypatch.setattr(jax.lax, "rsqrt", lambda x: calls.append(1) or real(x))
+        for B in (5, 130, 300):
+            A, b, want = _spd_batch(B, B, K)
+            x = solve.chol_solve_pallas(jnp.asarray(A), jnp.asarray(b), interpret=True)
+            assert _row_err(x, want) < 1e-5
+        assert len(calls) == K
+
+    def test_groups_per_step_follow_the_rank(self):
+        # the block follows K: several 128-system groups a grid step at
+        # small ranks (a row of K floats lies padded to 128 lanes), one
+        # from K=24 up; the ranks whose blocks pass the default scoped
+        # VMEM ask for more, up to the ceiling
+        from predictionio_tpu.ops.solve import (
+            _MAX_PALLAS_K, _block_bytes, _groups_per_step,
+        )
+
+        assert [_groups_per_step(k) for k in (8, 16, 24, 64, 128)] == [4, 2, 1, 1, 1]
+        assert all(_groups_per_step(k) >= 1 for k in range(8, _MAX_PALLAS_K + 1, 8))
+        assert _block_bytes(64) == 4 << 20 and _block_bytes(_MAX_PALLAS_K) == 8 << 20
+
     def test_interpret_kernel_matches_cholesky(self):
         from predictionio_tpu.ops.solve import cholesky_solve, spd_solve
 
@@ -513,45 +627,42 @@ class TestPallasSolver:
             np.asarray(got.user), np.asarray(ref.user), rtol=5e-3, atol=5e-4
         )
 
+    @pytest.mark.parametrize(
+        "solver,kernel",
+        [("pallas_interpret", "chol_solve_pallas"), ("cholesky", "cholesky_xla")],
+    )
+    def test_train_records_the_solve_kernel_and_its_systems(self, solver, kernel):
+        # what `pio train` writes under kernels.als: the kernel as a
+        # device trace names it, and the systems a sweep hands the solver
+        # (every chunk row and hot slot of both sides, padding included)
+        from predictionio_tpu.ops.als import build_buckets
+
+        rows, cols, vals, _ = synthetic_ratings()
+        info = {}
+        config = ALSConfig(rank=8, iterations=1, solver=solver, bucketing="host")
+        train_als(rows, cols, vals, 60, 40, config, info=info)
+        assert info["solver"] == solver
+        assert info["solveKernel"] == kernel
+        want = 0
+        for r, c, nr, nc in ((rows, cols, 60, 40), (cols, rows, 40, 60)):
+            bk = build_buckets(
+                r, c, vals, nr, nc, config.bucket_widths, 8,
+                config.chunk_entries, config.hot_group_slots,
+            )
+            want += sum(ch.row_id.size for ch in bk.normal)
+            want += sum(len(hr) for hr in bk.hot_rows)
+        assert info["solveSystemsPerSweep"] == want
+        assert want >= 100  # 60 users and 40 items, padded to whole chunks
+
     def test_invalid_solver_rejected(self):
         rows, cols, vals, _ = synthetic_ratings()
         with pytest.raises(ValueError, match="solver"):
             train_als(rows, cols, vals, 60, 40, ALSConfig(solver="qr"))
 
-    def test_auto_block_rows_shrinks_with_rank(self):
-        # large K must scale the VMEM block down, in multiples of 8 (the
-        # [TB, K] block is tiled (8, 128): Mosaic refused TB=2 and 4 on
-        # the chip), and the interpret path still agrees with cholesky at
-        # a shrunken block
-        from predictionio_tpu.ops.solve import (
-            _MAX_PALLAS_K,
-            _auto_block_rows,
-            cholesky_solve,
-            gj_solve_pallas,
-        )
-
-        # thresholds from MEASURED Mosaic VMEM use on v5e (the kernel's
-        # working set is ~17x the A block: 17.14 MB at TB=64, K=64 against
-        # the 16 MiB scoped limit — PERF.md, PR 21)
-        assert _auto_block_rows(64) == 32
-        assert _auto_block_rows(96) == 16
-        assert _auto_block_rows(128) == 8
-        assert all(
-            _auto_block_rows(k) % 8 == 0 for k in range(8, _MAX_PALLAS_K + 1, 8)
-        )
-        rng = np.random.default_rng(7)
-        B, K = 5, 128
-        M = rng.normal(size=(B, K, K)).astype(np.float32)
-        A = jnp.asarray(M @ M.transpose(0, 2, 1) + 20 * np.eye(K, dtype=np.float32))
-        b = jnp.asarray(rng.normal(size=(B, K)).astype(np.float32))
-        np.testing.assert_allclose(
-            np.asarray(gj_solve_pallas(A, b, interpret=True)),
-            np.asarray(cholesky_solve(A, b)),
-            rtol=5e-3, atol=5e-4,
-        )
-
     def test_rank_above_vmem_ceiling_falls_back_loudly(self, caplog):
-        from predictionio_tpu.ops.solve import spd_solve, cholesky_solve
+        from predictionio_tpu.ops.solve import (
+            cholesky_solve, solve_kernel_name, spd_solve,
+        )
 
         rng = np.random.default_rng(8)
         B, K = 2, 136  # multiple of 8 but above _MAX_PALLAS_K
@@ -566,11 +677,13 @@ class TestPallasSolver:
             "K=136" in r.getMessage() and "Cholesky" in r.getMessage()
             for r in caplog.records
         ), "the downgrade to Cholesky must be logged"
+        assert solve_kernel_name("pallas", K) == "cholesky_xla"
+        assert solve_kernel_name("pallas", 128) == "chol_solve_pallas"
 
-    def test_non_multiple_rank_runs_the_kernel_padded(self, caplog):
-        # rank 10 (the templates' default) is not a multiple of the pivot
-        # block: spd_solve embeds it in a 16x16 system with an identity
-        # block, so the kernel runs — no downgrade, nothing to log
+    def test_non_multiple_rank_runs_the_kernel_padded(self, caplog, monkeypatch):
+        # rank 10 (the templates' default) is not a multiple of the
+        # sublane tile: spd_solve embeds it in a 16x16 system with an
+        # identity block, so the kernel runs — no downgrade, nothing to log
         from predictionio_tpu.ops import solve
 
         rng = np.random.default_rng(1)
@@ -579,19 +692,17 @@ class TestPallasSolver:
         A = M @ M.transpose(0, 2, 1) + 5 * np.eye(K, dtype=np.float32)
         b = rng.normal(size=(B, K)).astype(np.float32)
         seen = []
-        real = solve.gj_solve_pallas
-        try:
-            solve.gj_solve_pallas = lambda A2, b2, **kw: (
-                seen.append(A2.shape) or real(A2, b2, **kw)
-            )
-            with caplog.at_level("WARNING", logger="predictionio_tpu.ops.solve"):
-                x = np.asarray(
-                    solve.spd_solve(
-                        jnp.asarray(A), jnp.asarray(b), method="pallas_interpret"
-                    )
+        real = solve.chol_solve_pallas
+        monkeypatch.setattr(
+            solve, "chol_solve_pallas",
+            lambda A2, b2, **kw: seen.append(A2.shape) or real(A2, b2, **kw),
+        )
+        with caplog.at_level("WARNING", logger="predictionio_tpu.ops.solve"):
+            x = np.asarray(
+                solve.spd_solve(
+                    jnp.asarray(A), jnp.asarray(b), method="pallas_interpret"
                 )
-        finally:
-            solve.gj_solve_pallas = real
+            )
         assert seen == [(B, 16, 16)], seen
         assert not caplog.records
         want = np.linalg.solve(

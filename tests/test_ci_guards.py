@@ -669,7 +669,7 @@ def test_chip_smoke_cpu_rehearsal_runs_every_leg():
         "als numIterations 2 of the engine default 20",
         "twotower epochs 2 of the engine default 5",
     ]
-    spd = [g for g in rec["legs"]["kernels"]["gj"] if g["via"].startswith("spd")]
+    spd = [g for g in rec["legs"]["kernels"]["solve"] if g["via"].startswith("spd")]
     assert [(g["shape"][1], g["kernelK"]) for g in spd] == [(10, 16)]
     assert rec["legsPassed"] is True and rec["claim"] is None
     assert rec["device"]["platform"] == "cpu"

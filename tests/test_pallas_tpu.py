@@ -1,4 +1,4 @@
-"""REAL-TPU correctness for the Pallas blocked-Gauss-Jordan solver.
+"""REAL-TPU correctness for the Pallas lane-batched Cholesky solver.
 
 Tier-1 runs the kernel only through ``interpret=True`` (CPU). These
 tests run the REAL Mosaic-compiled kernel on a TPU backend at the
@@ -49,16 +49,16 @@ def _device_spd_batch(batch: int, k: int, seed: int):
     "batch,k",
     [
         (138_000, 64),  # the flagship bench shape
-        (8_000, 128),  # the larger-K regime (VMEM model at TB=8)
+        (8_000, 128),  # the ceiling: 8 MiB blocks, vmem_limit_bytes raised
     ],
 )
-def test_gj_solve_matches_cholesky_on_tpu(batch, k):
+def test_chol_solve_matches_cholesky_on_tpu(batch, k):
     import jax.numpy as jnp
 
-    from predictionio_tpu.ops.solve import cholesky_solve, gj_solve_pallas
+    from predictionio_tpu.ops.solve import chol_solve_pallas, cholesky_solve
 
     A, b = _device_spd_batch(batch, k, seed=k)
-    x_gj = gj_solve_pallas(A, b)  # REAL Mosaic lowering (no interpret)
+    x_pl = chol_solve_pallas(A, b)  # REAL Mosaic lowering (no interpret)
     x_ch = cholesky_solve(A, b)
 
     @jax.jit
@@ -67,7 +67,7 @@ def test_gj_solve_matches_cholesky_on_tpu(batch, k):
         den = jnp.maximum(jnp.max(jnp.abs(xb), axis=-1), 1e-6)
         return jnp.max(num / den)
 
-    err = float(rel_err(x_gj, x_ch))
+    err = float(rel_err(x_pl, x_ch))
     assert np.isfinite(err)
     assert err < 1e-4, f"pallas vs cholesky rel err {err} at [{batch},{k},{k}]"
 
@@ -76,15 +76,16 @@ def test_gj_solve_matches_cholesky_on_tpu(batch, k):
     "batch,rank",
     [
         (138_000, 10),  # the templates' default rank: padded to K=16
-        (27_027, 5),  # K=8, a single pivot block
+        (27_027, 5),  # K=8, one vreg a column
         (27_027, 20),  # K=24
     ],
 )
 def test_spd_solve_pads_small_ranks_into_the_kernel_on_tpu(batch, rank):
     """`spd_solve(.., "pallas")` — the call the ALS sweep makes — embeds a
     rank that is not a multiple of 8 in the next one. Each padded K is
-    its own Mosaic compile ([32, K, K] blocks, 8-wide lane slices), and
-    Mosaic refuses shapes the interpreter accepts: compile them here."""
+    its own Mosaic compile ([128 * groups, K, K] blocks, K/8 vregs a
+    column), and Mosaic refuses shapes the interpreter accepts: compile
+    them here."""
     import jax.numpy as jnp
 
     from predictionio_tpu.ops.solve import (
@@ -95,9 +96,9 @@ def test_spd_solve_pads_small_ranks_into_the_kernel_on_tpu(batch, rank):
 
     assert pallas_rank_ok(rank)
     A, b = _device_spd_batch(batch, rank, seed=rank)
-    x_gj = jax.jit(lambda A, b: spd_solve(A, b, "pallas"))(A, b)
+    x_pl = jax.jit(lambda A, b: spd_solve(A, b, "pallas"))(A, b)
     x_ch = cholesky_solve(A, b)
-    assert x_gj.shape == (batch, rank)
+    assert x_pl.shape == (batch, rank)
 
     @jax.jit
     def rel_err(xa, xb):
@@ -105,20 +106,20 @@ def test_spd_solve_pads_small_ranks_into_the_kernel_on_tpu(batch, rank):
         den = jnp.maximum(jnp.max(jnp.abs(xb), axis=-1), 1e-6)
         return jnp.max(num / den)
 
-    err = float(rel_err(x_gj, x_ch))
+    err = float(rel_err(x_pl, x_ch))
     assert np.isfinite(err)
     assert err < 1e-4, f"pallas vs cholesky rel err {err} at rank {rank}"
 
 
-def test_gj_solve_residual_on_tpu():
+def test_chol_solve_residual_on_tpu():
     """Independent ground truth: the kernel's solution must satisfy the
     system itself (not just agree with another solver)."""
     import jax.numpy as jnp
 
-    from predictionio_tpu.ops.solve import gj_solve_pallas
+    from predictionio_tpu.ops.solve import chol_solve_pallas
 
     A, b = _device_spd_batch(4_096, 64, seed=7)
-    x = gj_solve_pallas(A, b)
+    x = chol_solve_pallas(A, b)
 
     @jax.jit
     def resid(A, x, b):

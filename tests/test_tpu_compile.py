@@ -7,6 +7,7 @@ The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU's library, and every xdist
 worker imports every test file."""
 
+import math
 import os
 import re
 
@@ -90,3 +91,51 @@ def test_the_two_tower_epoch_touches_rows_only_and_fits(one_chip, no_compile_cac
     assert set(ops) <= {"parameter", "get-tuple-element", "scatter", "fusion",
                         "while", "tuple", "bitcast"}, ops
     assert ops.get("scatter") == 2, ops
+
+
+@pytest.mark.parametrize(
+    "chunk,width,rank",
+    [
+        (32768, 32, 64),  # als_ml20m.retrain: the sweep's full chunk
+        (32768, 32, 16),  # the templates' default rank 10, embedded
+        (4096, 32, 128),  # the largest rank the kernel takes
+    ],
+)
+def test_the_als_solve_lowers_through_mosaic_and_fits(
+    one_chip, no_compile_cache, chunk, width, rank
+):
+    """A chunk's normal equations built and solved as the sweep does it
+    (`_partials` then `_finish_solve(.., "pallas")`): the lane-batched
+    Cholesky kernel lowers through Mosaic at this rank and fits its
+    scoped VMEM (a refusal raises here), and it reads the Gramian as the
+    product's fusion wrote it: no transposed or padded copy of it."""
+    from predictionio_tpu.ops import als, solve
+
+    def solve_chunk(Q, val, mask):
+        A, b, n = als._partials(Q, val, mask, False, 1.0, jax.lax.Precision.HIGHEST)
+        return als._finish_solve(A, b, n, 0.05, None, "pallas")
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(solve_chunk).lower(
+        arg(chunk, width, rank), arg(chunk, width), arg(chunk, width)
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and solve.SOLVE_KERNEL in text
+    # arrays of the Gramian's size: the product's fusions make it, and
+    # nothing copies, pads or transposes it on its way to the kernel
+    made: dict = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = f32\[([\d,]+)\]\S* ([\w\-]+)\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",")) == chunk * rank * rank:
+            made[m.group(2)] = made.get(m.group(2), 0) + 1
+    assert not {"copy", "transpose", "pad", "concatenate"} & set(made), made
+    # temporaries: the Gramian as XLA lays it (rows of K floats padded to
+    # 128 lanes) and the gathered rows relaid for the product, plus small
+    # change
+    lanes = max(rank, 128)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= (
+        chunk * rank * lanes * 4 + chunk * width * lanes * 4 + (64 << 20)
+    ), memory.temp_size_in_bytes
