@@ -97,13 +97,18 @@ def _percentiles(samples, points=(50, 95, 99)) -> dict[str, float]:
 #: is a window of ``latencyMs``
 BATCH_PHASES = (
     "release", "take", "drain", "batchForm",
-    "bind", "lookup", "filterLookup", "filterBuild",
+    "bind", "lookup", "queryVectors", "filterLookup", "filterBuild",
     "dispatch", "deviceWait", "format",
 )
 
 #: what a handler that filters its answers counts on the dispatcher's
-#: collector as ``filter.<name>`` (templates/ecommerce/engine.py)
+#: collector as ``filter.<name>`` (templates/retrieval.py
+#: ``FilteredItemRetrieval`` and the two engines that take it)
 FILTER_COUNTS = ("excludedIds", "categoryRows", "hostPath", "shortAnswers")
+
+#: what the similar-product engine counts as ``similar.<name>``
+#: (templates/similarproduct/engine.py)
+SIMILAR_COUNTS = ("queryItems", "unknownItems")
 
 #: the plans of ``ops.topk.select_plan``: the ``batcher.select`` counts
 SELECT_PLANS = ("blocked", "plain")
@@ -131,7 +136,9 @@ class ServingStats:
       (padding + bookkeeping);
     * ``handle`` — the ``handle_batch`` call itself, and inside it
       ``bind`` (query objects from bodies), ``lookup`` (ids to the
-      padded index vector), ``filterLookup`` (a filtering engine's
+      padded index vector), ``queryVectors`` (the similar-product
+      engine's query vectors, made from the query items' rows),
+      ``filterLookup`` (a filtering engine's
       store reads for the batch), ``filterBuild`` (its rules into
       device inputs), ``dispatch`` (the call into the scoring
       program until it returns), ``deviceWait`` (the readback that
@@ -169,12 +176,15 @@ class ServingStats:
     ``plain`` (``lax.top_k`` over the whole row). A host GEMM and the
     tiers with kernels of their own (IVF, int8, sharded) count neither.
 
-    ``filter`` counts what a filtering engine (the e-commerce template)
-    did over the live batches: ``excludedIds`` (item ids its rows left
-    out: seen, unavailable, black-listed), ``categoryRows`` (rows that
-    named a category), ``hostPath`` (queries answered by the host
-    ``predict``: a white list or an unknown user), ``shortAnswers``
-    (rows the rules left fewer than ``num`` items).
+    ``filter`` counts what a filtering engine (the e-commerce and the
+    similar-product templates) did over the live batches: ``excludedIds``
+    (item ids its rows left out: seen, unavailable, black-listed, a
+    query's own items), ``categoryRows`` (rows that named a category),
+    ``hostPath`` (queries answered by the host ``predict``: a white list
+    or an unknown user), ``shortAnswers`` (rows the rules left fewer than
+    ``num`` items). ``similar`` counts the similar-product engine's query
+    items: ``queryItems`` (all that its queries named) and
+    ``unknownItems`` (those of them the model does not hold, dropped).
 
     Windows keep the most recent :attr:`WINDOW` samples so percentiles
     track current behavior on a long-running server; counters are
@@ -197,8 +207,13 @@ class ServingStats:
         #: the handler's own counts (templates/serving_util.py)
         self.rows_scored = 0
         self.rows_real = 0
-        self.filter_counts = dict.fromkeys(FILTER_COUNTS, 0)
-        self.select_counts = dict.fromkeys(SELECT_PLANS, 0)
+        #: the handlers' named counts, a block of ``to_json`` each
+        self.handler_counts = {
+            block: dict.fromkeys(names, 0)
+            for block, names in (("filter", FILTER_COUNTS),
+                                 ("similar", SIMILAR_COUNTS),
+                                 ("select", SELECT_PLANS))
+        }
         self.queue_depth = 0  # last observed; gauge
         self.inflight_batch = 0  # 0|1|2 — the batcher's two workers
         self.overlap = {"overlapped": 0, "alone": 0}
@@ -260,8 +275,9 @@ class ServingStats:
         :data:`BATCH_PHASES`), the host gap before it, whether it was
         dispatched while the batch before it was on the device, the rows its
         scoring dispatches took and really held, and the handler's other
-        ``counts`` (those named ``filter.<one of FILTER_COUNTS>`` and
-        ``select.<one of SELECT_PLANS>``)."""
+        ``counts`` (those named ``filter.<one of FILTER_COUNTS>``,
+        ``similar.<one of SIMILAR_COUNTS>`` and ``select.<one of
+        SELECT_PLANS>``)."""
         with self._lock:
             self.inflight_batch -= 1
             self.overlap["overlapped" if overlapped else "alone"] += 1
@@ -270,14 +286,9 @@ class ServingStats:
             self.padded_queries += bucket - size
             self.rows_scored += rows_scored
             self.rows_real += rows_real
-            for name in FILTER_COUNTS:
-                self.filter_counts[name] += (counts or {}).get(
-                    "filter." + name, 0
-                )
-            for name in SELECT_PLANS:
-                self.select_counts[name] += (counts or {}).get(
-                    "select." + name, 0
-                )
+            for block, held in self.handler_counts.items():
+                for name in held:
+                    held[name] += (counts or {}).get(f"{block}.{name}", 0)
             self.batch_size_hist[size] += 1
             self.bucket_hist[bucket] += 1
             if bucket not in self.warmed_buckets:
@@ -332,8 +343,7 @@ class ServingStats:
                 "paddingOverhead": round(self.padded_queries / real, 4),
                 "rowsScored": self.rows_scored,
                 "rowsReal": self.rows_real,
-                "filter": dict(self.filter_counts),
-                "select": dict(self.select_counts),
+                **{b: dict(held) for b, held in self.handler_counts.items()},
                 "batchSizeHist": {
                     str(k): v for k, v in sorted(self.batch_size_hist.items())
                 },

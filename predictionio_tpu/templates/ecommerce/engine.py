@@ -16,12 +16,11 @@ Parity map (reference scala-parallel-ecommercerecommendation template):
 
 The rules are one mask however a query is answered: per item its category
 codes and whether it is in stock, per query the categories asked for and
-the item ids left out (seen, black-listed). ``predict`` applies it on the
-host to one score row; ``batch_predict`` hands it to
-``serving_util.chunked_topk(filt=...)``, which under ``pio deploy
---pin-model`` selects inside one tiled device program
-(``ops.als.top_k_items_filtered``) over the item table and the category
-codes that :meth:`ECommAlgorithm.pin_model_for_serving` laid out there.
+the item ids left out (seen, black-listed). What is this engine's own is
+where the rules come from (the event store, read at query time), the user
+rows and the popularity fallback; the mask, the pin and the filtered top-K
+are ``templates/retrieval.py``'s :class:`FilteredItemRetrieval`, which the
+similar-product engine takes too.
 """
 
 from __future__ import annotations
@@ -44,15 +43,13 @@ from predictionio_tpu.controller import (
 from predictionio_tpu.data.aggregator import BiMap, aggregate_properties_single
 from predictionio_tpu.data.store import LEventStore, PEventStore
 from predictionio_tpu.ops.als import ALSConfig, factors_to_host, train_als
-from predictionio_tpu.ops.topk import NO_ITEM, bucket_width, top_k_host
+from predictionio_tpu.ops.topk import top_k_host
 from predictionio_tpu.templates.results import ItemScore, PredictedResult
-from predictionio_tpu.templates.retrieval import ServingState, serving_state
-from predictionio_tpu.templates.serving_util import (
-    TOPK_CHUNK,
-    TopkFilter,
-    allowed_items_host,
-    chunked_topk,
+from predictionio_tpu.templates.retrieval import (
+    FilteredItemRetrieval,
+    category_arrays,
 )
+from predictionio_tpu.templates.serving_util import TopkFilter
 from predictionio_tpu.utils.spans import count, span
 
 __all__ = [
@@ -62,7 +59,6 @@ __all__ = [
     "ECommerceDataSource",
     "ECommAlgorithmParams",
     "ECommModel",
-    "category_arrays",
     "ECommAlgorithm",
     "engine_factory",
 ]
@@ -234,46 +230,10 @@ class ECommModel:
     category_index: BiMap | None = None
 
 
-@dataclasses.dataclass
-class ECommServingState(ServingState):
-    """What this engine keeps beside a deployed model."""
-
-    #: pinned: the item factors and the category codes on the device, as
-    #: ``ops.als.tile_items`` cut them
-    item_tiles: Any = None
-    code_tiles: Any = None
-    #: ``(unavailable ids, mask)`` of the last :meth:`ECommAlgorithm._blocked`
-    blocked: tuple | None = None
-
-
-def category_arrays(categories: dict, item_index: BiMap) -> tuple[np.ndarray, BiMap]:
-    """``{item id: categories}`` as ``(codes int32[I, C], name -> code)``:
-    any number of distinct categories, ``C`` the most one item carries."""
-    index = BiMap.string_index(
-        sorted({c for cats in categories.values() for c in cats})
-    )
-    width = max([1, *(len(cats) for cats in categories.values())])
-    codes = np.full((len(item_index), width), -1, np.int32)
-    for item, cats in categories.items():
-        row = item_index.get(item)
-        if row is not None:
-            codes[row, : len(cats)] = [index[c] for c in cats]
-    return codes, index
-
-
-#: floors of the two per-row list widths of a filtered top-K
-#: (``ops.topk.bucket_width``). The excluded ids scatter into a mask whose
-#: cost hardly moves with their number (measured on a v5e, PERF.md), so one
-#: wide bucket holds every history short of a thousand items and a deploy
-#: compiles a single width; wanted categories are compared item by item,
-#: so their floor is what a category page asks for
-EXCLUDED_FLOOR = 1024
-WANTED_FLOOR = 2
-
-
-class ECommAlgorithm(JaxAlgorithm):
+class ECommAlgorithm(FilteredItemRetrieval, JaxAlgorithm):
     params_class = ECommAlgorithmParams
     query_class = Query
+    ITEM_TABLE = "item_factors"
 
     def __init__(self, params: ECommAlgorithmParams):
         super().__init__(params)
@@ -336,83 +296,22 @@ class ECommAlgorithm(JaxAlgorithm):
         }
         return seen, set() if pm is None else set(pm.opt("items", list, []))
 
-    @staticmethod
-    def _categories(model: ECommModel) -> tuple[np.ndarray, BiMap]:
-        codes = getattr(model, "category_codes", None)
-        if codes is None:
-            # two batches may fill this at once (the batcher has two in
-            # flight): the index first, so whoever sees the codes sees both
-            codes, model.category_index = category_arrays(
-                model.categories, model.item_index
-            )
-            model.category_codes = codes
-        return codes, model.category_index
-
-    @staticmethod
-    def _blocked(model: ECommModel, unavailable: set):
-        """The out-of-stock mask over the item rows — the host's
-        ``bool[items]`` or, pinned, the device's ``bool[tiles, width]`` with
-        the padding past the catalog blocked too — made when the constraint
-        changes, not per batch. Two batches in flight may hold different
-        reads of the constraint: each is served the mask of its own (the
-        cache is one tuple, read once and assigned once)."""
-        state = serving_state(model, ECommServingState)
-        cached = state.blocked
-        if cached is not None and cached[0] == unavailable:
-            return cached[1]
-        n = len(model.item_index)
-        tiles = state.item_tiles
-        mask = np.zeros(n if tiles is None else tiles.shape[0] * tiles.shape[2], bool)
-        mask[n:] = True
-        rows = [model.item_index.get(i) for i in unavailable]
-        mask[[r for r in rows if r is not None]] = True
-        if tiles is not None:
-            import jax
-
-            mask = jax.device_put(mask.reshape(tiles.shape[0], tiles.shape[2]))
-        state.blocked = (set(unavailable), mask)
-        return mask
-
     def _rules(
         self, model: ECommModel, queries: Sequence[Query], seen: dict,
         unavailable: set,
     ) -> TopkFilter:
-        """The rules of ``queries`` as the arrays the top-K takes."""
-        codes, category_index = self._categories(model)
-        item_row = model.item_index.get
-        left_out = []
-        for q in queries:
-            ids = set(seen.get(q.user, ())).union(q.black_list or ())
-            left_out.append([r for r in map(item_row, ids) if r is not None])
-        excluded = np.full(
-            (len(queries), bucket_width(max(map(len, left_out)), EXCLUDED_FLOOR)),
-            NO_ITEM, np.int32,
-        )
-        for row, rows in zip(excluded, left_out):
-            row[: len(rows)] = rows
-        asked = [q.categories or () for q in queries]
-        wanted = np.full(
-            (len(queries), bucket_width(max(map(len, asked)), WANTED_FLOOR)),
-            -2, np.int32,
-        )
-        for row, names in zip(wanted, asked):
-            # a name no item carries is a code no item carries
-            row[: len(names)] = [
-                category_index.get(str(c), len(category_index)) for c in names
-            ]
-        count("filter.excludedIds",
-              sum(map(len, left_out)) + len(unavailable) * len(queries))
-        count("filter.categoryRows", sum(1 for names in asked if names))
-        state = serving_state(model, ECommServingState)
-        return TopkFilter(
-            codes=codes if state.item_tiles is None else state.code_tiles,
-            blocked=self._blocked(model, unavailable),
-            wanted=wanted, excluded=excluded, item_tiles=state.item_tiles,
+        """The rules of ``queries`` as the arrays the top-K takes: a query
+        leaves out what its user has seen and its black list; what is out
+        of stock is blocked for all."""
+        return self.topk_filter(
+            model,
+            [set(seen.get(q.user, ())).union(q.black_list or ()) for q in queries],
+            [q.categories or () for q in queries],
+            unavailable,
         )
 
     def predict(self, model: ECommModel, query: Query) -> PredictedResult:
         """One query on the host: one score row, the rules as one mask."""
-        n = len(model.item_index)
         uidx = model.user_index.get(query.user)
         if uidx is not None:
             scores = np.asarray(model.item_factors) @ np.asarray(
@@ -423,15 +322,7 @@ class ECommAlgorithm(JaxAlgorithm):
             # fallback to recent/popular items)
             scores = np.asarray(model.popularity, dtype=np.float64).copy()
         filt = self._rules(model, [query], *self._store_rules([query.user]))
-        codes, _ = self._categories(model)
-        allowed = allowed_items_host(
-            codes, np.asarray(filt.blocked).reshape(-1)[:n], filt.wanted,
-            filt.excluded,
-        )[0]
-        if query.white_list:
-            allowed &= np.isin(
-                np.arange(n), [model.item_index.get(i, -1) for i in query.white_list]
-            )
+        allowed = self.allowed_on_host(model, filt, query.white_list)[0]
         scores = np.where(allowed, scores, -np.inf)
         k = min(int(query.num), int(allowed.sum()))
         if k <= 0:
@@ -444,10 +335,6 @@ class ECommAlgorithm(JaxAlgorithm):
                 if np.isfinite(scores[i])
             )
         )
-
-    #: the most queries one dispatch scores (serving_util.TOPK_CHUNK; the
-    #: filtered branch lowers it to what its score tile allows)
-    BATCH_PREDICT_CHUNK = TOPK_CHUNK
 
     def batch_predict(
         self, model: ECommModel, queries: Sequence[tuple[int, Query]]
@@ -479,46 +366,7 @@ class ECommAlgorithm(JaxAlgorithm):
             seen, unavailable = self._store_rules(list({q.user for q in rows}))
         with span("filterBuild"):
             filt = self._rules(model, rows, seen, unavailable)
-        inverse = model.item_index.inverse
-        for part, idx_l, score_l in chunked_topk(
-            model.user_factors, model.item_factors, valid,
-            chunk=self.BATCH_PREDICT_CHUNK, filt=filt,
-        ):
-            with span("format"):
-                for (slot, _, k), ids, scs in zip(part, idx_l, score_l):
-                    if len(ids) < k:
-                        count("filter.shortAnswers", 1)
-                    results.append((
-                        slot,
-                        PredictedResult(tuple(
-                            ItemScore(item=inverse(i), score=s)
-                            for i, s in zip(ids[:k], scs[:k])
-                        )),
-                    ))
-        return results
-
-    def pin_model_for_serving(self, model: ECommModel) -> tuple[ECommModel, int]:
-        """``--pin-model`` (workflow/device_state.py): the item table and the
-        category codes go to the device once per model generation, cut into
-        the tiles ``ops.als.top_k_items_filtered`` scans, and
-        ``batch_predict`` selects there. The user table stays on the host:
-        a batch's rows ride with its rules (``serving_util._filtered_topk``
-        says why). Returns the model and the device bytes it holds."""
-        from predictionio_tpu.ops.als import tile_items
-
-        codes, _ = self._categories(model)
-        state = serving_state(model, ECommServingState)
-        state.blocked = None
-        state.item_tiles = tile_items(
-            np.asarray(model.item_factors, np.float32), 0.0
-        )
-        state.code_tiles = tile_items(codes, -1)
-        state.pinned = True
-        state.bytes_by_dtype = {
-            "float32": int(state.item_tiles.nbytes),
-            "int32": int(state.code_tiles.nbytes),
-        }
-        return model, sum(state.bytes_by_dtype.values())
+        return results + self.filtered_top_k(model, model.user_factors, valid, filt)
 
 
 def engine_factory() -> Engine:

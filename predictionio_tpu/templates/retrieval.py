@@ -7,24 +7,38 @@ interface ``workflow/device_state.py`` and ``workflow/aot.py`` duck-type
 on. What the hooks build rides on the model as ONE :class:`ServingState`,
 reached through :func:`serving_state` by engines and workflow alike. jax
 is imported inside the functions that need it.
+
+:class:`FilteredItemRetrieval` is the other retrieval the templates share:
+the top-K of one item table under per-query rules (categories asked for,
+item ids left out, items no query may be given), whatever the query
+vectors are rows of. The e-commerce and the similar-product engines take it.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import logging
-from typing import Any, Iterator
+from typing import Any, Collection, Iterable, Iterator, Sequence
 
 import numpy as np
 
+from predictionio_tpu.data.aggregator import BiMap
+from predictionio_tpu.templates.results import ItemScore, PredictedResult
 from predictionio_tpu.templates.serving_util import (
     TOPK_CHUNK,
+    TopkFilter,
+    allowed_items_host,
     chunked_topk,
     device_latency_probe,
     serving_row_buckets,
 )
+from predictionio_tpu.utils.spans import count, span
 
-__all__ = ["ServingState", "serving_state", "ItemTableAnn", "TwoTableRetrieval"]
+__all__ = [
+    "ServingState", "serving_state", "ItemTableAnn", "TwoTableRetrieval",
+    "FilteredServingState", "FilteredItemRetrieval", "category_arrays",
+    "EXCLUDED_FLOOR", "WANTED_FLOOR",
+]
 
 logger = logging.getLogger(__name__)
 
@@ -480,3 +494,207 @@ class TwoTableRetrieval(ItemTableAnn):
             ann=state.ann, shards=state.shards, quant=state.quant,
             aot=state.aot,
         )
+
+
+# ------------------------------------------------ filtered item retrieval
+@dataclasses.dataclass
+class FilteredServingState(ServingState):
+    """What :class:`FilteredItemRetrieval` keeps beside a deployed model."""
+
+    #: pinned: the item factors and the category codes on the device, as
+    #: ``ops.als.tile_items`` cut them
+    item_tiles: Any = None
+    code_tiles: Any = None
+    #: ``(blocked ids, mask)`` of the last
+    #: :meth:`FilteredItemRetrieval.blocked_mask`
+    blocked: tuple | None = None
+
+
+def category_arrays(categories: dict, item_index: BiMap) -> tuple[np.ndarray, BiMap]:
+    """``{item id: categories}`` as ``(codes int32[I, C], name -> code)``:
+    any number of distinct categories, ``C`` the most one item carries."""
+    index = BiMap.string_index(
+        sorted({c for cats in categories.values() for c in cats})
+    )
+    width = max([1, *(len(cats) for cats in categories.values())])
+    codes = np.full((len(item_index), width), -1, np.int32)
+    for item, cats in categories.items():
+        row = item_index.get(item)
+        if row is not None:
+            codes[row, : len(cats)] = [index[c] for c in cats]
+    return codes, index
+
+
+#: floors of the two per-row list widths of a filtered top-K
+#: (``ops.topk.bucket_width``). The excluded ids scatter into a mask whose
+#: cost hardly moves with their number (measured on a v5e, PERF.md), so one
+#: wide bucket holds every history short of a thousand items and a deploy
+#: compiles a single width; wanted categories are compared item by item,
+#: so their floor is what a category page asks for
+EXCLUDED_FLOOR = 1024
+WANTED_FLOOR = 2
+
+
+class FilteredItemRetrieval:
+    """The filtered top-K of a ``JaxAlgorithm`` whose model holds an item
+    table under ``ITEM_TABLE``, its ``item_index``, and the category rule
+    as ``categories`` (``{item id: names}``, as training read them) and,
+    built from it on first use, ``category_codes`` / ``category_index``
+    (:func:`category_arrays`). The engine brings the query vectors and,
+    per query, the item ids left out and the category names asked for;
+    the rules become one mask however the query is answered: on the host
+    over one score row (:meth:`allowed_on_host`), or inside
+    ``serving_util.chunked_topk(filt=...)``, which under ``pio deploy
+    --pin-model`` selects in one tiled device program
+    (``ops.als.top_k_items_filtered``) over the tiles
+    :meth:`pin_model_for_serving` laid out."""
+
+    ITEM_TABLE: str
+
+    @staticmethod
+    def category_codes(model) -> tuple[np.ndarray, BiMap]:
+        codes = getattr(model, "category_codes", None)
+        if codes is None:
+            # two batches may fill this at once (the batcher has two in
+            # flight): the index first, so whoever sees the codes sees both
+            codes, model.category_index = category_arrays(
+                model.categories, model.item_index
+            )
+            model.category_codes = codes
+        return codes, model.category_index
+
+    def pin_model_for_serving(self, model) -> tuple[Any, int]:
+        """``--pin-model`` (workflow/device_state.py): the item table and the
+        category codes go to the device once per model generation, cut into
+        the tiles ``ops.als.top_k_items_filtered`` scans, and
+        :meth:`filtered_top_k` selects there. Whatever the query vectors
+        are rows of stays on the host: a batch's rows ride with its rules
+        (``serving_util._filtered_topk`` says why). Returns the model and
+        the device bytes it holds."""
+        from predictionio_tpu.ops.als import tile_items
+
+        codes, _ = self.category_codes(model)
+        state = serving_state(model, FilteredServingState)
+        state.blocked = None
+        state.item_tiles = tile_items(
+            np.asarray(getattr(model, self.ITEM_TABLE), np.float32), 0.0
+        )
+        state.code_tiles = tile_items(codes, -1)
+        state.pinned = True
+        state.bytes_by_dtype = {
+            "float32": int(state.item_tiles.nbytes),
+            "int32": int(state.code_tiles.nbytes),
+        }
+        return model, sum(state.bytes_by_dtype.values())
+
+    @staticmethod
+    def blocked_mask(model, blocked_ids: Collection[str]):
+        """The mask of the items no query may be given (``blocked_ids``: an
+        engine's out-of-stock items, say) over the item rows — the host's
+        ``bool[items]`` or, pinned, the device's ``bool[tiles, width]`` with
+        the padding past the catalog blocked too — made when the ids
+        change, not per batch. Two batches in flight may hold different
+        reads of them: each is served the mask of its own (the cache is
+        one tuple, read once and assigned once)."""
+        state = serving_state(model, FilteredServingState)
+        cached = state.blocked
+        if cached is not None and cached[0] == blocked_ids:
+            return cached[1]
+        n = len(model.item_index)
+        tiles = state.item_tiles
+        mask = np.zeros(n if tiles is None else tiles.shape[0] * tiles.shape[2], bool)
+        mask[n:] = True
+        rows = [model.item_index.get(i) for i in blocked_ids]
+        mask[[r for r in rows if r is not None]] = True
+        if tiles is not None:
+            import jax
+
+            mask = jax.device_put(mask.reshape(tiles.shape[0], tiles.shape[2]))
+        state.blocked = (set(blocked_ids), mask)
+        return mask
+
+    def topk_filter(
+        self, model, left_out: Sequence[Iterable[str]],
+        asked: Sequence[Sequence[str]], blocked_ids: Collection[str] = frozenset(),
+    ) -> TopkFilter:
+        """The rules of a batch as the arrays the top-K takes: per query the
+        item ids it leaves out (``left_out``; unknown ids are dropped) and
+        the category names it asks for (``asked``; empty = any), and the
+        ids blocked for all of them."""
+        from predictionio_tpu.ops.topk import NO_ITEM, bucket_width
+
+        codes, category_index = self.category_codes(model)
+        item_row = model.item_index.get
+        out_rows = [
+            [r for r in map(item_row, ids) if r is not None] for ids in left_out
+        ]
+        excluded = np.full(
+            (len(out_rows), bucket_width(max(map(len, out_rows)), EXCLUDED_FLOOR)),
+            NO_ITEM, np.int32,
+        )
+        for row, rows in zip(excluded, out_rows):
+            row[: len(rows)] = rows
+        wanted = np.full(
+            (len(asked), bucket_width(max(map(len, asked)), WANTED_FLOOR)),
+            -2, np.int32,
+        )
+        for row, names in zip(wanted, asked):
+            # a name no item carries is a code no item carries
+            row[: len(names)] = [
+                category_index.get(str(c), len(category_index)) for c in names
+            ]
+        count("filter.excludedIds",
+              sum(map(len, out_rows)) + len(blocked_ids) * len(out_rows))
+        count("filter.categoryRows", sum(1 for names in asked if names))
+        state = serving_state(model, FilteredServingState)
+        return TopkFilter(
+            codes=codes if state.item_tiles is None else state.code_tiles,
+            blocked=self.blocked_mask(model, blocked_ids),
+            wanted=wanted, excluded=excluded, item_tiles=state.item_tiles,
+        )
+
+    def allowed_on_host(
+        self, model, filt: TopkFilter, white_list: Sequence[str] | None = None,
+    ) -> np.ndarray:
+        """``bool[rows, items]``: the items each row of ``filt`` is allowed,
+        on the host, by the rule the device program applies; under a
+        ``white_list`` (a rule the device path does not take) only its
+        items."""
+        n = len(model.item_index)
+        codes, _ = self.category_codes(model)
+        allowed = allowed_items_host(
+            codes, np.asarray(filt.blocked).reshape(-1)[:n], filt.wanted,
+            filt.excluded,
+        )
+        if white_list:
+            listed = np.zeros(n, dtype=bool)
+            rows = [model.item_index.get(i) for i in white_list]
+            listed[[r for r in rows if r is not None]] = True
+            allowed &= listed
+        return allowed
+
+    def filtered_top_k(
+        self, model, vectors, valid: Sequence[tuple[int, int, int]],
+        filt: TopkFilter,
+    ) -> list[tuple[int, PredictedResult]]:
+        """The answers of ``valid = [(slot, row of vectors, k), ...]`` under
+        ``filt`` (its rows in ``valid``'s order): the selection inside
+        ``chunked_topk`` (on the device when the model is pinned), an
+        answer shorter than ``k`` where the rules allow fewer."""
+        inverse = model.item_index.inverse
+        results = []
+        for part, idx_l, score_l in chunked_topk(
+            vectors, getattr(model, self.ITEM_TABLE), valid, filt=filt
+        ):
+            with span("format"):
+                for (slot, _, k), ids, scs in zip(part, idx_l, score_l):
+                    if len(ids) < k:
+                        count("filter.shortAnswers", 1)
+                    results.append((
+                        slot,
+                        PredictedResult(tuple(
+                            ItemScore(item=inverse(i), score=s)
+                            for i, s in zip(ids[:k], scs[:k])
+                        )),
+                    ))
+        return results

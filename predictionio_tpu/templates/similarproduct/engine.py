@@ -11,6 +11,17 @@ Parity map (reference scala-parallel-similarproduct template):
   :func:`predictionio_tpu.ops.als.train_als`.
 * Query ``{"items": ["i1"], "num": 4, "categories"?: [...],
   "whiteList"?: [...], "blackList"?: [...]}`` -> ``{"itemScores": [...]}``.
+
+The score of item ``i`` is ``v_i . u``, ``u`` the normalised sum of the
+query items' unit rows: the cosine against that sum (upstream sums the
+cosines item by item, which is this score times ``|sum|``: the same order).
+An item is allowed when it is not a query item, not on the ``blackList``,
+and carries one of ``categories`` when the query names any. ``predict``
+applies that on the host to one score row; ``batch_predict`` makes the
+batch's query vectors on the host and hands them, with the rules, to
+``templates/retrieval.py``'s :class:`FilteredItemRetrieval` (the object
+the e-commerce engine takes), which under ``pio deploy --pin-model``
+selects on the device. A ``whiteList`` keeps the host ``predict``.
 """
 
 from __future__ import annotations
@@ -34,7 +45,13 @@ from predictionio_tpu.data.aggregator import BiMap
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.ops.als import ALSConfig, factors_to_host, train_als
 from predictionio_tpu.templates.results import ItemScore, PredictedResult
-from predictionio_tpu.templates.retrieval import ItemTableAnn, serving_state
+from predictionio_tpu.templates.retrieval import (
+    FilteredItemRetrieval,
+    ItemTableAnn,
+    category_arrays,
+    serving_state,
+)
+from predictionio_tpu.utils.spans import count, span
 
 __all__ = [
     "Query",
@@ -176,10 +193,16 @@ class ALSAlgorithmParams(Params):
 class SimilarProductModel:
     item_factors: Any  # [I, K], L2-normalized rows for cosine scoring
     item_index: BiMap
-    categories: dict
+    categories: dict  # item id -> tuple of categories, as training read them
+    #: the category rule as arrays (``retrieval.category_arrays``): per item
+    #: row its codes into ``category_index``, ``-1`` = none. ``None`` in a
+    #: blob written before they existed: built from ``categories`` on first
+    #: use
+    category_codes: Any = None  # int32 [I, C]
+    category_index: BiMap | None = None
 
 
-class ALSAlgorithm(ItemTableAnn, JaxAlgorithm):
+class ALSAlgorithm(FilteredItemRetrieval, ItemTableAnn, JaxAlgorithm):
     params_class = ALSAlgorithmParams
     query_class = Query
     #: ``--ann`` clusters the L2-normalized item factors: cosine scoring is
@@ -205,20 +228,40 @@ class ALSAlgorithm(ItemTableAnn, JaxAlgorithm):
         (item,) = factors_to_host(ctx.run_info["als"], factors.item)
         norms = np.linalg.norm(item, axis=1, keepdims=True)
         norms[norms == 0] = 1.0
+        codes, category_index = category_arrays(pd.categories, pd.item_index)
         return SimilarProductModel(
             item_factors=item / norms,
             item_index=pd.item_index,
             categories=pd.categories,
+            category_codes=codes,
+            category_index=category_index,
+        )
+
+    @staticmethod
+    def _query_vector(model: SimilarProductModel, rows: list) -> np.ndarray | None:
+        """``u`` of the query items at ``rows``: their stored unit rows
+        summed and normalised, in float32; ``None`` where they cancel."""
+        target = np.asarray(model.item_factors)[rows].sum(axis=0)
+        norm = np.linalg.norm(target)
+        return None if norm == 0 else target / norm
+
+    def _rules(self, model: SimilarProductModel, queries: Sequence[Query]):
+        """The rules of ``queries`` as the arrays the top-K takes: a query
+        leaves out its own items and its black list."""
+        return self.topk_filter(
+            model,
+            [set(q.items).union(q.black_list or ()) for q in queries],
+            [q.categories or () for q in queries],
         )
 
     def predict(self, model: SimilarProductModel, query: Query) -> PredictedResult:
+        """One query on the host: one score row, the rules as one mask."""
         idxs = [model.item_index.get(i) for i in query.items]
         idxs = [i for i in idxs if i is not None]
         if not idxs:
             return PredictedResult(())
-        target = model.item_factors[idxs].sum(axis=0)
-        norm = np.linalg.norm(target)
-        if norm == 0:
+        unit = self._query_vector(model, idxs)
+        if unit is None:
             return PredictedResult(())
         ann = serving_state(model).ann
         if ann is not None and not query.white_list and not query.categories:
@@ -241,9 +284,7 @@ class ALSAlgorithm(ItemTableAnn, JaxAlgorithm):
                 bidx = model.item_index.get(item)
                 if bidx is not None:
                     exclude.add(bidx)
-            ids, scores = ivf.query_topk(
-                ann, target / norm, num + len(exclude)
-            )
+            ids, scores = ivf.query_topk(ann, unit, num + len(exclude))
             return PredictedResult(
                 tuple(
                     ItemScore(item=model.item_index.inverse(int(i)), score=float(s))
@@ -251,8 +292,10 @@ class ALSAlgorithm(ItemTableAnn, JaxAlgorithm):
                     if i not in exclude
                 )[:num]
             )
-        scores = model.item_factors @ (target / norm)  # cosine vs all items
-        allowed = self._allowed_mask(model, query, exclude=set(idxs))
+        scores = np.asarray(model.item_factors) @ unit  # cosine vs all items
+        allowed = self.allowed_on_host(
+            model, self._rules(model, [query]), query.white_list
+        )[0]
         scores = np.where(allowed, scores, -np.inf)
         k = min(int(query.num), int(allowed.sum()))
         if k <= 0:
@@ -268,29 +311,55 @@ class ALSAlgorithm(ItemTableAnn, JaxAlgorithm):
             )
         )
 
-    @staticmethod
-    def _allowed_mask(model: SimilarProductModel, query: Query, exclude: set) -> np.ndarray:
-        n = model.item_factors.shape[0]
-        allowed = np.ones(n, dtype=bool)
-        for i in exclude:
-            allowed[i] = False
-        if query.white_list:
-            allowed &= np.zeros(n, dtype=bool) | np.isin(
-                np.arange(n),
-                [model.item_index.get(i, -1) for i in query.white_list],
-            )
-        if query.black_list:
-            for item in query.black_list:
-                idx = model.item_index.get(item)
-                if idx is not None:
-                    allowed[idx] = False
-        if query.categories:
-            wanted = set(query.categories)
-            for idx in np.nonzero(allowed)[0]:
-                cats = model.categories.get(model.item_index.inverse(int(idx)), ())
-                if not wanted.intersection(cats):
-                    allowed[idx] = False
-        return allowed
+    def batch_predict(
+        self, model: SimilarProductModel, queries: Sequence[tuple[int, Query]]
+    ) -> list[tuple[int, PredictedResult]]:
+        """A batch through one filtered top-K: the batch's query vectors
+        from the model's own table (a host gather of the query items'
+        rows), the rules compiled into arrays, the selection inside
+        ``chunked_topk`` (on the device when the model is pinned). A white
+        list keeps :meth:`predict` (counted as ``filter.hostPath``), as
+        does every query of an ``--ann`` deploy (its index is
+        :meth:`predict`'s branch)."""
+        if serving_state(model).ann is not None:
+            return [(slot, self.predict(model, q)) for slot, q in queries]
+        n_items = len(model.item_index)
+        results: list[tuple[int, PredictedResult]] = []
+        known: list[tuple[int, Query, list, int]] = []  # (slot, query, its items' rows, k)
+        n_query_items = n_unknown = 0
+        with span("lookup"):
+            for slot, q in queries:
+                idxs = [model.item_index.get(i) for i in q.items]
+                n_query_items += len(idxs)
+                n_unknown += idxs.count(None)
+                idxs = [i for i in idxs if i is not None]
+                k = min(int(q.num), n_items)
+                if k <= 0 or not idxs:
+                    results.append((slot, PredictedResult(())))
+                elif q.white_list:
+                    count("filter.hostPath", 1)
+                    results.append((slot, self.predict(model, q)))
+                else:
+                    known.append((slot, q, idxs, k))
+        count("similar.queryItems", n_query_items)
+        count("similar.unknownItems", n_unknown)
+        valid: list[tuple[int, int, int]] = []  # (slot, row of vectors, k)
+        with span("queryVectors"):
+            vectors = np.zeros((len(known), model.item_factors.shape[1]), np.float32)
+            kept: list[Query] = []
+            for slot, q, idxs, k in known:
+                unit = self._query_vector(model, idxs)
+                if unit is None:
+                    results.append((slot, PredictedResult(())))
+                    continue
+                vectors[len(valid)] = unit
+                valid.append((slot, len(valid), k))
+                kept.append(q)
+        if not valid:
+            return results
+        with span("filterBuild"):
+            filt = self._rules(model, kept)
+        return results + self.filtered_top_k(model, vectors, valid, filt)
 
 
 def engine_factory() -> Engine:

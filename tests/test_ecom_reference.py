@@ -21,11 +21,13 @@ from predictionio_tpu.templates.ecommerce.engine import (  # noqa: E402
     ECommAlgorithm,
     ECommAlgorithmParams,
     ECommModel,
-    ECommServingState,
     Query,
-    category_arrays,
 )
-from predictionio_tpu.templates.retrieval import serving_state  # noqa: E402
+from predictionio_tpu.templates.retrieval import (  # noqa: E402
+    FilteredServingState,
+    category_arrays,
+    serving_state,
+)
 from predictionio_tpu.utils import spans  # noqa: E402
 
 APP, N_ITEMS, N_USERS, RANK, NUM = "shop", 700, 40, 8, 10
@@ -226,11 +228,11 @@ def test_the_blocked_mask_is_read_from_its_cache_once(shop):
     reach this one: the cache is one tuple, read once."""
     algo, model, *_ = shop
     mine, theirs = {"1", "2"}, {"3"}
-    mask = algo._blocked(model, mine)
-    other = algo._blocked(model, theirs)
+    mask = algo.blocked_mask(model, mine)
+    other = algo.blocked_mask(model, theirs)
     assert not np.array_equal(mask, other)
 
-    class Swapped(ECommServingState):
+    class Swapped(FilteredServingState):
         """A state whose cache another batch re-assigns after every read."""
         reads = 0
 
@@ -244,4 +246,4 @@ def test_the_blocked_mask_is_read_from_its_cache_once(shop):
             pass
 
     model._pio_serving = Swapped()
-    assert algo._blocked(model, mine) is mask and Swapped.reads == 1
+    assert algo.blocked_mask(model, mine) is mask and Swapped.reads == 1

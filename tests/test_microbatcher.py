@@ -54,13 +54,13 @@ def _echo_batch(bodies):
     return [(200, {"echo": b}) for b in bodies]
 
 
-_INNER_PHASES = ("bind", "lookup", "filterLookup", "filterBuild", "dispatch",
-                 "deviceWait", "format")
+_INNER_PHASES = ("bind", "lookup", "queryVectors", "filterLookup", "filterBuild",
+                 "dispatch", "deviceWait", "format")
 
 
 def _phased_batch(bodies):
     """Stand-in handler cut like a device-backed ``handle_batch`` of an
-    engine that filters: the seven inner phases, each a few hundred
+    engine that filters: the eight inner phases, each a few hundred
     microseconds."""
     for name in _INNER_PHASES:
         with span(name):

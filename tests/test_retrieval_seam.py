@@ -26,6 +26,10 @@ from predictionio_tpu.templates.recommendation.engine import (
     ALSModel,
 )
 from predictionio_tpu.templates.retrieval import (
+    EXCLUDED_FLOOR,
+    WANTED_FLOOR,
+    FilteredItemRetrieval,
+    FilteredServingState,
     ServingState,
     TwoTableRetrieval,
     serving_state,
@@ -234,9 +238,7 @@ def test_workflow_reads_no_private_name_of_an_engine():
         assert "model._pio_" not in src, rel
 
 
-def test_served_from_reads_device_arrays_held_by_the_state_alone():
-    """The e-commerce engine pins tiles onto its state and leaves the
-    model's own tables on the host: ``GET /`` still says ``device``."""
+def _ecommerce() -> tuple:
     from predictionio_tpu.templates.ecommerce.engine import (
         ECommAlgorithm,
         ECommAlgorithmParams,
@@ -244,17 +246,100 @@ def test_served_from_reads_device_arrays_held_by_the_state_alone():
     )
 
     user, item = _tables(3)
-    model = ECommModel(
+    return ECommAlgorithm(ECommAlgorithmParams()), ECommModel(
         user_factors=user, item_factors=item,
         user_index=BiMap.string_index(str(i) for i in range(N_USERS)),
         item_index=BiMap.string_index(str(i) for i in range(N_ITEMS)),
         categories={}, popularity=np.zeros(N_ITEMS),
     )
-    algo = ECommAlgorithm(ECommAlgorithmParams())
+
+
+def _similarproduct() -> tuple:
+    from predictionio_tpu.templates.similarproduct.engine import (
+        ALSAlgorithm as SimilarAlgorithm,
+        ALSAlgorithmParams as SimilarParams,
+        SimilarProductModel,
+    )
+
+    _, item = _tables(4)
+    return SimilarAlgorithm(SimilarParams()), SimilarProductModel(
+        item_factors=item / np.linalg.norm(item, axis=1, keepdims=True),
+        item_index=BiMap.string_index(str(i) for i in range(N_ITEMS)),
+        categories={},
+    )
+
+
+FILTERING = pytest.mark.parametrize(
+    "engine", [_ecommerce, _similarproduct], ids=["ecommerce", "similarproduct"]
+)
+
+
+@FILTERING
+def test_served_from_reads_device_arrays_held_by_the_state_alone(engine):
+    """The filtering engines pin tiles onto their state and leave the
+    model's own tables on the host: ``GET /`` still says ``device``."""
+    algo, model = engine()
     assert device_state.serving_device([(algo, model)])["servedFrom"] == "host"
     pairs, nbytes = device_state.pin_pairs([(algo, model)])
     assert nbytes > 0 and isinstance(model.item_factors, np.ndarray)
     assert device_state.serving_device(pairs)["servedFrom"] == "device"
+    state = serving_state(model)
+    assert type(state) is FilteredServingState and state.pinned
+    assert state.bytes_by_dtype == {
+        "float32": int(state.item_tiles.nbytes), "int32": int(state.code_tiles.nbytes)}
+
+
+# ------------------ (c2) one filtered retrieval under both filtering engines
+#: what ``FilteredItemRetrieval`` owns: an engine that defined one of these
+#: itself would have a filter of its own again
+FILTER_SEAM = ("category_codes", "pin_model_for_serving", "blocked_mask",
+               "topk_filter", "allowed_on_host", "filtered_top_k")
+
+
+@FILTERING
+def test_a_filtering_engine_reaches_the_filter_through_the_shared_object(engine):
+    algo, _ = engine()
+    assert isinstance(algo, FilteredItemRetrieval)
+    for name in FILTER_SEAM:
+        owner = next(c for c in type(algo).__mro__ if name in vars(c))
+        assert owner is FilteredItemRetrieval, (name, owner)
+
+
+@pytest.mark.parametrize("pattern, what", [
+    (r"\btile_items\(", "a call of ops.als.tile_items"),
+    (r"^(EXCLUDED|WANTED)_FLOOR\s*=", "a floor of the filter's list widths"),
+    (r"\bTopkFilter\(", "a TopkFilter made"),
+    (r"\bfilt=filt\b", "a filtered chunked_topk call"),
+    (r"^def category_arrays\b", "category_arrays"),
+])
+def test_the_filter_is_built_in_one_module(pattern, what):
+    """No second ``tile_items`` call site, no second ``EXCLUDED_FLOOR``: what
+    the two engines share lives once, in ``templates/retrieval.py``."""
+    found = {
+        rel for rel, src in _sources("predictionio_tpu/templates")
+        if re.search(pattern, src, re.MULTILINE)
+    }
+    assert found == {"predictionio_tpu/templates/retrieval.py"}, what
+
+
+@FILTERING
+def test_both_engines_hand_the_same_rules_to_the_same_arrays(engine):
+    """Lists of item ids and category names become the one ``TopkFilter``
+    layout whichever engine asks: ids left out padded to the shared floor,
+    unknown ids dropped, a name no item carries a code no item carries."""
+    from predictionio_tpu.ops.topk import NO_ITEM
+
+    algo, model = engine()
+    model.categories = {"3": ("a",), "5": ("a", "b")}
+    filt = algo.topk_filter(model, [["1", "2", "nobody"], []], [["b", "zzz"], []], {"7"})
+    assert filt.excluded.shape == (2, EXCLUDED_FLOOR) and filt.wanted.shape == (2, WANTED_FLOOR)
+    assert filt.excluded[0, :3].tolist() == [1, 2, NO_ITEM] and filt.excluded[1, 0] == NO_ITEM
+    assert filt.wanted.tolist() == [[1, 2], [-2, -2]]
+    assert np.flatnonzero(filt.blocked).tolist() == [7] and filt.item_tiles is None
+    allowed = algo.allowed_on_host(model, filt, white_list=None)
+    assert np.flatnonzero(allowed[0]).tolist() == [5]
+    assert (~allowed[1]).sum() == 1 and not allowed[1, 7]
+    assert np.flatnonzero(algo.allowed_on_host(model, filt, ["5", "7", "9"])[1]).tolist() == [5, 9]
 
 
 # --------------------------------- (d) the state never enters a blob
