@@ -104,7 +104,8 @@ BATCH_PHASES = (
 #: what a handler that filters its answers counts on the dispatcher's
 #: collector as ``filter.<name>`` (templates/retrieval.py
 #: ``FilteredItemRetrieval`` and the two engines that take it)
-FILTER_COUNTS = ("excludedIds", "categoryRows", "hostPath", "shortAnswers")
+FILTER_COUNTS = ("excludedIds", "excludedPairs", "categoryRows", "hostPath",
+                 "shortAnswers")
 
 #: what the similar-product engine counts as ``similar.<name>``
 #: (templates/similarproduct/engine.py)
@@ -122,7 +123,8 @@ class ServingStats:
     * ``queueWait`` — enqueue until the dispatcher formed its batch;
     * ``total`` — enqueue until the caller gets its result back;
     * ``wake`` — the worker's ``done.set()`` until the caller's
-      thread runs again.
+      thread runs again, past a worker that has the right of way
+      (``serving/batcher.py``).
 
     Per batch, on the worker thread that carried it (the batcher has
     two), flat and in this order (one cycle runs from one of the
@@ -179,7 +181,11 @@ class ServingStats:
     ``filter`` counts what a filtering engine (the e-commerce and the
     similar-product templates) did over the live batches: ``excludedIds``
     (item ids its rows left out: seen, unavailable, black-listed, a
-    query's own items), ``categoryRows`` (rows that named a category),
+    query's own items; the unavailable ones once a row, though they
+    travel as one mask), ``excludedPairs`` (the (row, item id) pairs the
+    device dispatches were handed: the rows' own lists, without repeats),
+    ``pairBucket.<P>`` (those dispatches by the pair bucket of their
+    program, ``ops.als.tile_pairs``), ``categoryRows`` (rows that named a category),
     ``hostPath`` (queries answered by the host ``predict``: a white list
     or an unknown user), ``shortAnswers`` (rows the rules left fewer than
     ``num`` items). ``similar`` counts the similar-product engine's query
@@ -275,9 +281,11 @@ class ServingStats:
         :data:`BATCH_PHASES`), the host gap before it, whether it was
         dispatched while the batch before it was on the device, the rows its
         scoring dispatches took and really held, and the handler's other
-        ``counts`` (those named ``filter.<one of FILTER_COUNTS>``,
-        ``similar.<one of SIMILAR_COUNTS>`` and ``select.<one of
-        SELECT_PLANS>``)."""
+        ``counts``: those named ``<block>.<name>`` for a block of
+        ``handler_counts`` (``filter``, ``similar``, ``select``). The names
+        of ``FILTER_COUNTS``, ``SIMILAR_COUNTS`` and ``SELECT_PLANS`` are
+        there from the start at 0; any other (``filter.pairBucket.<P>``)
+        makes its key when first counted."""
         with self._lock:
             self.inflight_batch -= 1
             self.overlap["overlapped" if overlapped else "alone"] += 1
@@ -286,9 +294,11 @@ class ServingStats:
             self.padded_queries += bucket - size
             self.rows_scored += rows_scored
             self.rows_real += rows_real
-            for block, held in self.handler_counts.items():
-                for name in held:
-                    held[name] += (counts or {}).get(f"{block}.{name}", 0)
+            for key, n in (counts or {}).items():
+                block, _, name = key.partition(".")
+                held = self.handler_counts.get(block)
+                if held is not None:  # a name first seen makes its key
+                    held[name] = held.get(name, 0) + n
             self.batch_size_hist[size] += 1
             self.bucket_hist[bucket] += 1
             if bucket not in self.warmed_buckets:

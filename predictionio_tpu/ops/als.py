@@ -63,6 +63,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec, reshard
 from predictionio_tpu.ops.topk import (
     NO_ITEM,
     SCORE_PRECISION,
+    bucket_width,
     select_top_k,
     sort_merge_topk,
     top_k_scores,
@@ -1663,9 +1664,15 @@ def top_k_items_batch(
 FILTER_TILE = 1 << 19
 
 #: the most bytes those scores may take: what bounds the rows of one
-#: dispatch (64 at a full tile; the ``[tiles, rows, tile]`` mask of the
-#: excluded ids is a quarter of the catalog-wide scores)
+#: dispatch (64 at a full tile)
 FILTER_SCORE_BYTES = 1 << 27
+
+#: floor of the pair bucket of :func:`tile_pairs`: the (row, excluded id)
+#: pairs of a batch that fall into its fullest tile. 32 rows that leave out
+#: 60 ids each put 64 into each of 30 tiles and some 90 into the fullest,
+#: so ordinary traffic (histories and black lists of tens of ids) stays in
+#: ONE bucket and a deploy compiles one; what a slot costs on a v5e: PERF.md
+FILTER_PAIR_FLOOR = 128
 
 
 @functools.partial(jax.jit, donate_argnums=0)
@@ -1699,6 +1706,36 @@ def tile_items(table: np.ndarray, fill, tile: int | None = None) -> jax.Array:
     return tiles
 
 
+def tile_pairs(
+    excluded: np.ndarray, n_tiles: int, width: int
+) -> tuple[np.ndarray, np.ndarray, int]:
+    """The item ids a batch's rows leave out (``excluded`` ``i32[rows,
+    E]``, padded with ``NO_ITEM``), grouped by the tile of
+    :func:`tile_items` each falls into: ``(row, column)``, two ``i32[tiles,
+    P]`` arrays that hold, tile by tile, the row and the position within
+    the tile of every real pair, and the number of pairs. ``NO_ITEM``,
+    ids past the tiles and a row's repeats are dropped; the unused slots of
+    a tile hold column ``width``, past the tile, which
+    :func:`top_k_items_filtered` discards. ``P`` is the
+    ``ops.topk.bucket_width`` bucket of the fullest tile's count (floor
+    ``FILTER_PAIR_FLOOR``): an array extent of the program, it follows the
+    batch's own lists. Numpy over a few hundred pairs."""
+    n_rows = excluded.shape[0]
+    real = (excluded >= 0) & (excluded < n_tiles * width)
+    # one sort by (id, row) drops a row's repeats and groups by tile
+    key = np.unique(excluded[real].astype(np.int64) * n_rows + np.nonzero(real)[0])
+    ids, row = np.divmod(key, n_rows)
+    tile, col = np.divmod(ids, width)
+    per_tile = np.bincount(tile, minlength=n_tiles)
+    slot = np.arange(key.size) - np.repeat(np.cumsum(per_tile) - per_tile, per_tile)
+    p = bucket_width(int(per_tile.max()), FILTER_PAIR_FLOOR)
+    drop_row = np.zeros((n_tiles, p), np.int32)
+    drop_col = np.full((n_tiles, p), width, np.int32)
+    drop_row[tile, slot] = row
+    drop_col[tile, slot] = col
+    return drop_row, drop_col, int(key.size)
+
+
 @functools.partial(jax.jit, static_argnames=("k",))
 def top_k_items_filtered(
     user_vecs: jax.Array,
@@ -1706,7 +1743,8 @@ def top_k_items_filtered(
     code_tiles: jax.Array,
     blocked: jax.Array,
     wanted: jax.Array,
-    excluded: jax.Array,
+    drop_row: jax.Array,
+    drop_col: jax.Array,
     k: int,
 ) -> tuple[jax.Array, jax.Array]:
     """:func:`top_k_items_batch` under per-row business rules: the exact
@@ -1725,34 +1763,30 @@ def top_k_items_filtered(
     given: padding past the catalog, and items out of stock. Per row:
     ``wanted`` ``i32[rows, W]`` the category codes asked for (padded
     with ``-2``; a row whose first entry is negative names no category
-    and allows all), ``excluded`` ``i32[rows, E]`` item ids to leave out
-    (padded with ``NO_ITEM``, which points past the tiles and is
-    dropped).
+    and allows all). Per tile, :func:`tile_pairs` of the item ids the
+    rows leave out: ``drop_row`` / ``drop_col`` ``i32[tiles, P]``, the
+    row and the position within the tile of each (row, id) pair (a
+    position of ``width`` pads and is discarded).
 
-    The excluded ids are scattered once into a ``[tiles, rows, width]``
-    mask; then each tile is scored (``U_b @ V_tile`` at
-    ``SCORE_PRECISION``), masked to ``-inf`` where not allowed, cut to
-    its own top ``k`` by :func:`~predictionio_tpu.ops.topk.select_top_k`
-    (``lax.top_k``'s result; at a full tile's width it reads the scores
-    once for the maxima of 128-column blocks and selects among the ``k``
-    leading blocks' columns) and merged into the carried ``[rows, k]`` by
+    Each tile is scored (``U_b @ V_tile`` at ``SCORE_PRECISION``),
+    masked to ``-inf`` where category or ``blocked`` do not allow, cut to
+    its own top ``k`` without its own pairs by
+    :func:`~predictionio_tpu.ops.topk.select_top_k` (``lax.top_k``'s
+    result; at a full tile's width it reads the scores once for the
+    maxima of 128-column blocks, mends the ``P`` blocks that hold a pair
+    and selects among the ``k`` leading blocks' columns) and merged into
+    the carried ``[rows, k]`` by
     :func:`~predictionio_tpu.ops.topk.sort_merge_topk`'s two keys — the
-    ``lax.top_k`` of the masked full row. A slot no allowed item fills
-    comes back as ``(NO_ITEM, -inf)``."""
+    ``lax.top_k`` of the masked full row. What the rows leave out costs
+    by the pairs there are, never by the catalog. A slot no allowed item
+    fills comes back as ``(NO_ITEM, -inf)``."""
     n_tiles, _, width = item_tiles.shape
     rows = user_vecs.shape[0]
-    with jax.named_scope("pio_topk_mask"):
-        row_of = jnp.broadcast_to(
-            jnp.arange(rows, dtype=jnp.int32)[:, None], excluded.shape
-        )
-        left_out = jnp.zeros((n_tiles, rows, width), jnp.bool_).at[
-            excluded // width, row_of, excluded % width
-        ].set(True, mode="drop")
-        names_none = wanted[:, :1] < 0
+    names_none = wanted[:, :1] < 0
     kt = min(k, width)
 
     def one_tile(best, tile):
-        t, v_t, codes_t, blocked_t, left_out_t = tile
+        t, v_t, codes_t, blocked_t, drop_row_t, drop_col_t = tile
         with jax.named_scope("pio_topk_score"):
             scores = jnp.matmul(user_vecs, v_t, precision=SCORE_PRECISION)
         with jax.named_scope("pio_topk_mask"):
@@ -1760,10 +1794,10 @@ def top_k_items_filtered(
                 codes_t[None, :, None, :] == wanted[:, None, :, None],
                 axis=(1, 2),
             )
-            allowed = (in_category | names_none) & ~(blocked_t[None] | left_out_t)
+            allowed = (in_category | names_none) & ~blocked_t[None]
             scores = jnp.where(allowed, scores, -jnp.inf)
         with jax.named_scope("pio_topk_select"):
-            vals, pos = select_top_k(scores, kt)
+            vals, pos = select_top_k(scores, kt, drop=(drop_row_t, drop_col_t))
         with jax.named_scope("pio_topk_merge"):
             ids = jnp.where(vals > -jnp.inf, pos + t * width, NO_ITEM)
             ids, vals = sort_merge_topk(
@@ -1780,6 +1814,6 @@ def top_k_items_filtered(
     (ids, vals), _ = jax.lax.scan(
         one_tile, best,
         (jnp.arange(n_tiles, dtype=jnp.int32), item_tiles, code_tiles,
-         blocked, left_out),
+         blocked, drop_row, drop_col),
     )
     return ids, vals

@@ -94,9 +94,9 @@ def bucket_rows(n: int, cap: int, floor: int = 8) -> int:
 
 
 def bucket_width(n: int, floor: int) -> int:
-    """The pow2 bucket of a per-row list's padded WIDTH (the excluded ids
-    and the wanted categories of a filtered top-K): ``n`` rounds up to a
-    power of two, not under ``floor``. The width is an array extent of
+    """The pow2 bucket of a list's padded WIDTH (a tile's excluded pairs
+    and a row's wanted categories of a filtered top-K): ``n`` rounds up to
+    a power of two, not under ``floor``. The width is an array extent of
     the program, so it keys the jit cache as ``bucket_rows`` does (piolint
     PIO306 knows a bucket step by the "bucket" in its name)."""
     return max(int(floor), 1 << (max(1, int(n)) - 1).bit_length())
@@ -104,7 +104,7 @@ def bucket_width(n: int, floor: int) -> int:
 
 #: the id of a result slot that holds no item (its score is ``-inf``): past
 #: any catalog, so ``serving_util._drain_staged`` trims it, and the padding
-#: of an excluded-id list, so the scatter that reads it drops it
+#: of an excluded-id list, which ``ops.als.tile_pairs`` drops
 NO_ITEM = np.iinfo(np.int32).max
 
 
@@ -145,6 +145,13 @@ _SELECT_MIN_RATIO = 16
 _SELECT_MAX_K = 128
 _SELECT_MAX_PADDED = 1 << 28
 
+#: the most positions :func:`select_top_k` strikes from the scores in one
+#: scatter; a longer ``drop`` list is a whole number of such chunks. From
+#: 1,024 updates on the chip's compiler flattens the scatter's operand,
+#: which relays a 64 MB tile of scores twice: 23.4 ms a batch of 30 tiles
+#: at 1,024 in one piece against 16.8 in chunks (v5e, PERF.md, PR 36)
+_DROP_CHUNK = 512
+
 
 def select_plan(rows: int, width: int, k: int) -> str:
     """Which selection :func:`select_top_k` builds for ``rows`` score
@@ -161,7 +168,9 @@ def select_plan(rows: int, width: int, k: int) -> str:
     return "blocked" if blocked else "plain"
 
 
-def select_top_k(scores: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
+def select_top_k(
+    scores: jax.Array, k: int, drop: tuple[jax.Array, jax.Array] | None = None
+) -> tuple[jax.Array, jax.Array]:
     """``jax.lax.top_k(scores, k)``, values and positions bit for bit,
     ties included — by :func:`select_plan`'s ``"blocked"`` plan where
     the row is wide:
@@ -191,12 +200,27 @@ def select_top_k(scores: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
     ``lax.reduce_window`` reads it several times slower (compiled for
     and timed on a v5e: PERF.md section 6, PR 29). Not a jitted program
     of its own: it only ever runs inside a caller's trace, as
-    :func:`sort_merge_topk`."""
+    :func:`sort_merge_topk`.
+
+    ``drop`` ``(i32[P] row, i32[P] column)`` names positions of the
+    (float) scores to leave out: the result is ``lax.top_k``'s of the
+    scores with those at ``-inf``, at a cost that follows ``P`` and not
+    the width. A column past the row pads the list and is discarded; a
+    position named twice is harmless; ``P`` over ``_DROP_CHUNK`` is a
+    multiple of it (a ``bucket_width`` bucket is). Under the ``"blocked"`` plan the
+    maxima are still taken from the scores as their producer wrote them
+    (so they fuse into it), then mended: the ``P`` positions go to
+    ``-inf`` where the scores lie, the ``P`` blocks that hold them are
+    gathered, and their maxima, taken anew, replace the stale ones
+    (``_DROP_CHUNK`` positions at a time)."""
     *lead, width = scores.shape
     rows = math.prod(lead)
     if select_plan(rows, width, k) == "plain" or not jnp.issubdtype(
         scores.dtype, jnp.floating
     ):
+        if drop is not None:
+            scores = scores.reshape(rows, width).at[drop].set(
+                -jnp.inf, mode="drop").reshape(scores.shape)
         return jax.lax.top_k(scores, k)
     b = SELECT_BLOCK
     nb = -(-width // b)
@@ -206,10 +230,41 @@ def select_top_k(scores: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
         s = jax.lax.pad(s, low, ((0, 0, 0), (0, nb * b - width, 0)))
     tiles = s.reshape(rows // 8, 8, nb, b)
     maxima = tiles.max(axis=-1).reshape(rows, nb)
+
+    def block_table(tiles, row, block):
+        """The scores as a table of blocks, and where ``block`` of ``row``
+        lies in it."""
+        at = ((row // 8) * nb + block) * 8 + row % 8
+        return tiles.transpose(0, 2, 1, 3).reshape(rows * nb, b), at
+
+    if drop is not None:
+
+        def strike(held, pairs):
+            s, maxima = held
+            drop_row, drop_col = pairs
+            s = s.at[drop_row, drop_col].set(-jnp.inf, mode="drop")
+            table, at = block_table(
+                s.reshape(rows // 8, 8, nb, b), drop_row, drop_col // b)
+            mended = table.at[at].get(mode="clip").max(axis=-1)
+            return s, maxima.at[drop_row, drop_col // b].set(mended, mode="drop")
+
+        # one form whatever the list's length: a scan over its chunks (a
+        # list of one chunk compiles to the chunk's body alone)
+        chunk = min(drop[0].shape[0], _DROP_CHUNK)
+        if drop[0].shape[0] % chunk:
+            raise ValueError(
+                f"a drop list of {drop[0].shape[0]} positions is no whole "
+                f"number of chunks of {_DROP_CHUNK}"
+            )
+        (s, maxima), _ = jax.lax.scan(
+            lambda held, pairs: (strike(held, pairs), None),
+            (s, maxima),
+            tuple(a.reshape(-1, chunk) for a in drop),
+        )
+        tiles = s.reshape(rows // 8, 8, nb, b)
     blocks = jnp.sort(jax.lax.top_k(maxima, k)[1], axis=-1)
     row = jnp.arange(rows, dtype=jnp.int32)[:, None]
-    at = ((row // 8) * nb + blocks) * 8 + row % 8
-    table = tiles.transpose(0, 2, 1, 3).reshape(rows * nb, b)
+    table, at = block_table(tiles, row, blocks)
     candidates = table.at[at.reshape(-1)].get(mode="promise_in_bounds")
     values, place = jax.lax.top_k(candidates.reshape(rows, k * b), k)
     positions = jnp.take_along_axis(blocks, place // b, axis=-1) * b + place % b

@@ -37,7 +37,7 @@ from predictionio_tpu.utils.spans import count, span
 __all__ = [
     "ServingState", "serving_state", "ItemTableAnn", "TwoTableRetrieval",
     "FilteredServingState", "FilteredItemRetrieval", "category_arrays",
-    "EXCLUDED_FLOOR", "WANTED_FLOOR",
+    "WANTED_FLOOR",
 ]
 
 logger = logging.getLogger(__name__)
@@ -525,13 +525,11 @@ def category_arrays(categories: dict, item_index: BiMap) -> tuple[np.ndarray, Bi
     return codes, index
 
 
-#: floors of the two per-row list widths of a filtered top-K
-#: (``ops.topk.bucket_width``). The excluded ids scatter into a mask whose
-#: cost hardly moves with their number (measured on a v5e, PERF.md), so one
-#: wide bucket holds every history short of a thousand items and a deploy
-#: compiles a single width; wanted categories are compared item by item,
-#: so their floor is what a category page asks for
-EXCLUDED_FLOOR = 1024
+#: floor of the wanted-category width of a filtered top-K
+#: (``ops.topk.bucket_width``): categories are compared item by item, so
+#: the floor is what a category page asks for. (The excluded ids are no
+#: extent of the program: they reach it as pairs grouped by tile,
+#: ``ops.als.tile_pairs``.)
 WANTED_FLOOR = 2
 
 
@@ -629,8 +627,7 @@ class FilteredItemRetrieval:
             [r for r in map(item_row, ids) if r is not None] for ids in left_out
         ]
         excluded = np.full(
-            (len(out_rows), bucket_width(max(map(len, out_rows)), EXCLUDED_FLOOR)),
-            NO_ITEM, np.int32,
+            (len(out_rows), max(1, *map(len, out_rows))), NO_ITEM, np.int32
         )
         for row, rows in zip(excluded, out_rows):
             row[: len(rows)] = rows

@@ -49,9 +49,10 @@ class TopkFilter:
     ``blocked`` (no row may be given it). Per entry of ``valid``, in its
     order: ``wanted`` ``i32[n, W]`` (category codes asked for, padded
     with ``-2``; a row whose first entry is negative names none and
-    allows all) and ``excluded`` ``i32[n, E]`` (item ids left out,
-    padded with ``ops.topk.NO_ITEM``); ``W`` and ``E`` are
-    ``ops.topk.bucket_width`` buckets.
+    allows all; ``W`` an ``ops.topk.bucket_width`` bucket) and
+    ``excluded`` ``i32[n, E]`` (item ids left out, padded with
+    ``ops.topk.NO_ITEM``; ``E`` the longest list, no extent of any
+    program).
 
     Unpinned, ``codes`` is ``i32[items, C]`` and ``blocked``
     ``bool[items]`` on the host. Pinned, ``item_tiles`` holds the item
@@ -101,7 +102,11 @@ def _filtered_topk(
     buckets, leaf spans and sentinel trim as the others. The chunk's user
     rows are gathered where the user table lies and ride with the rules:
     a row gather from a pinned ``[users, rank]`` table makes XLA copy the
-    whole table into a row-major layout on every dispatch."""
+    whole table into a row-major layout on every dispatch. On the device
+    the chunk's excluded ids go as (row, position) pairs grouped by tile
+    (``ops.als.tile_pairs``), counted as ``filter.excludedPairs`` and, a
+    dispatch, under the pair bucket of its program
+    (``filter.pairBucket.<P>``)."""
     from predictionio_tpu.ops.topk import NO_ITEM, select_plan, top_k_host
 
     n_items = int(item_mat.shape[0])
@@ -109,11 +114,12 @@ def _filtered_topk(
     if on_device:
         from predictionio_tpu.ops.als import (
             FILTER_SCORE_BYTES,
+            tile_pairs,
             top_k_items_filtered,
         )
 
         # the [rows, width] float32 scores of one tile bound the rows
-        width = int(filt.item_tiles.shape[2])
+        n_tiles, _, width = map(int, filt.item_tiles.shape)
         most = FILTER_SCORE_BYTES // (4 * width)
         chunk = min(chunk, max(8, 1 << (most.bit_length() - 1)))
     staged: list = []
@@ -125,18 +131,20 @@ def _filtered_topk(
         with span("dispatch"):
             user_vecs = user_mat[padded]
             wanted = _pad_rows(filt.wanted[lo : lo + chunk], padded.size, -2)
-            excluded = _pad_rows(
-                filt.excluded[lo : lo + chunk], padded.size, NO_ITEM
-            )
+            excluded = filt.excluded[lo : lo + chunk]
             if on_device:
+                drop_row, drop_col, pairs = tile_pairs(excluded, n_tiles, width)
+                count("filter.excludedPairs", pairs)
+                count(f"filter.pairBucket.{drop_row.shape[1]}", 1)
                 idx_b, score_b = top_k_items_filtered(
                     user_vecs, filt.item_tiles, filt.codes, filt.blocked,
-                    wanted, excluded, k_max,
+                    wanted, drop_row, drop_col, k_max,
                 )
             else:
                 scores = np.where(
                     allowed_items_host(
-                        filt.codes, filt.blocked, wanted, excluded
+                        filt.codes, filt.blocked, wanted,
+                        _pad_rows(excluded, padded.size, NO_ITEM),
                     ),
                     np.asarray(user_vecs) @ np.asarray(item_mat).T,
                     -np.inf,
@@ -170,11 +178,14 @@ def _drain_staged(
     off = 0
     for part, idx_b, _ in staged:
         with span("format"):
-            ids_l, scores_l = [], []
-            for r in range(len(part)):
-                keep = idx_all[off + r] < n_items
-                ids_l.append(idx_all[off + r][keep].tolist())
-                scores_l.append(score_all[off + r][keep].tolist())
+            # the chunk's rows as lists at once; a row that holds a
+            # sentinel (the rules left it fewer than k items) is trimmed
+            rows = slice(off, off + len(part))
+            keep = idx_all[rows] < n_items
+            ids_l, scores_l = idx_all[rows].tolist(), score_all[rows].tolist()
+            for r in np.flatnonzero(~keep.all(axis=1)).tolist():
+                ids_l[r] = idx_all[off + r][keep[r]].tolist()
+                scores_l[r] = score_all[off + r][keep[r]].tolist()
         yield part, ids_l, scores_l
         # chunks hold unequal rows (the tail's bucket is its own)
         off += int(idx_b.shape[0])
@@ -263,7 +274,8 @@ def chunked_topk(
     blocked mask, per-row wanted categories and excluded ids) returns the
     exact top ``k`` of the items each row is ALLOWED: on the device
     through ``ops.als.top_k_items_filtered`` (tiled over the items; the
-    exclusion and category widths are further buckets of its program),
+    excluded pairs of its fullest tile and the category width are further
+    buckets of its program),
     on the host through the same rule in numpy. A row with fewer than
     ``k`` allowed items comes back shorter. It composes with none of the
     tiers above."""

@@ -34,7 +34,7 @@ import sys
 import threading
 import time
 from collections import deque
-from typing import Iterable, NamedTuple
+from typing import Callable, Iterable, NamedTuple
 
 __all__ = [
     "Collector",
@@ -69,12 +69,16 @@ class Collector:
     #: a thread whose owner never takes keeps the newest spans only
     MAX_SPANS = 1024
 
-    __slots__ = ("annotate", "seq", "_closed", "_open", "_counts")
+    __slots__ = ("annotate", "seq", "on_close", "_closed", "_open", "_counts")
 
     def __init__(self, annotate: bool = False):
         #: leaf spans of this thread also go into the profiler's trace
         self.annotate = annotate
         self.seq = 0
+        #: called on the owning thread with the name of each span as it
+        #: closes, for an owner that acts on a phase's end while the
+        #: phases after it still run (the batcher: ``dispatch``)
+        self.on_close: Callable[[str], None] | None = None
         self._closed: deque = deque(maxlen=self.MAX_SPANS)
         self._open: list[str] = []
         #: :func:`count` totals since the last :meth:`take_counts`; the
@@ -154,6 +158,8 @@ class span:
             collector._open[-1] if collector._open else None,
             collector.seq, self.start_ns, self.end_ns,
         ))
+        if collector.on_close is not None:
+            collector.on_close(self.name)
 
     __enter__ = start
 

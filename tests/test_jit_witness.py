@@ -573,7 +573,7 @@ class TestBucketCompileCounts:
                                 for u in range(n)
                             ],
                             collector,
-                            qs.batcher._number(collector),
+                            *qs.batcher._number(collector, n),
                         )
 
                 _, serve_rep = jw.run_with_jit_witness(serve)

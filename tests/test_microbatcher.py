@@ -1208,3 +1208,173 @@ def test_serving_stats_percentiles_empty_and_filled():
     assert j["latencyMs"]["wake"]["p50"] is None  # none was passed
     s.record_request(5.0, wake_ms=0.25)
     assert s.to_json()["latencyMs"]["wake"]["p50"] == 0.25
+
+
+class _HeldHost:
+    """Stand-in handler whose host half can be held: a call sits in
+    ``bind`` until ``host`` is set (call 0 never does), passes
+    ``dispatch``, then sits in ``deviceWait`` until its own gate opens."""
+
+    def __init__(self, calls=2, fail_in_bind=False):
+        self.host = threading.Event()
+        self.gates = [threading.Event() for _ in range(calls)]
+        self.entered = 0
+        self.dispatched = 0
+        self.fail_in_bind = fail_in_bind
+        self._lock = threading.Lock()
+
+    def __call__(self, bodies):
+        with self._lock:
+            n, self.entered = self.entered, self.entered + 1
+        with span("bind"):
+            if n:
+                self.host.wait(timeout=10)
+            if self.fail_in_bind:
+                raise RuntimeError("the host half broke")
+        with span("dispatch"):
+            pass
+        with self._lock:
+            self.dispatched += 1
+        with span("deviceWait"):
+            self.gates[n].wait(timeout=10)
+        return _echo_batch(bodies)
+
+    def open(self):
+        self.host.set()
+        for gate in self.gates:
+            gate.set()
+
+
+class TestALoadedBatchsWorkerGoesFirst:
+    """ISSUE 36: a worker between a batch at least half full gathered and
+    its program enqueued has the right of way over the riders that wake
+    meanwhile."""
+
+    @pytest.fixture()
+    def patient(self, monkeypatch):
+        from predictionio_tpu.serving import batcher
+
+        monkeypatch.setattr(batcher, "_GIVE_WAY_S", 10.0)
+
+    def test_riders_that_wake_wait_until_the_other_batchs_program_is_enqueued(
+            self, patient):
+        device = _HeldHost()
+        # the delay: the first batch waits for its second rider
+        b = MicroBatcher(device, BatcherConfig(max_batch_size=2, max_batch_delay_ms=500.0))
+        try:
+            first = _riders(b, ["a", "b"])
+            _wait_until(lambda: device.dispatched == 1)
+            assert b._floor.is_set()  # enqueued: the claim is over
+            second = _riders(b, ["c", "d"])  # full: goes beside the first
+            _wait_until(lambda: device.entered == 2)
+            assert not b._floor.is_set()  # held in its host half
+            device.gates[0].set()  # the first batch comes back
+            _wait_until(lambda: b.stats.batches == 1)  # released, accounted
+            time.sleep(0.1)
+            assert all(t.is_alive() for t in first)  # answered, and waiting
+            device.host.set()
+            _join(first)  # the second's program is enqueued: they go on
+            assert device.dispatched == 2 and all(t.is_alive() for t in second)
+            assert b._floor.is_set()
+        finally:
+            device.open()
+            b.close()
+        _join(second)
+
+    def test_a_batch_under_half_full_claims_nothing(self, patient):
+        device = _HeldHost()
+        b = MicroBatcher(device, BatcherConfig(max_batch_size=4, max_batch_delay_ms=0.0))
+        try:
+            first = _riders(b, ["a"])
+            _wait_until(lambda: device.dispatched == 1)
+            device.gates[0].set()
+            _join(first)
+            second = _riders(b, ["b"])
+            _wait_until(lambda: device.entered == 2)  # held in bind, 1 of 4
+            assert b._floor.is_set() and b._preparing == 0
+        finally:
+            device.open()
+            b.close()
+        _join(second)
+
+    def test_a_handler_that_breaks_before_its_dispatch_gives_the_floor_back(
+            self, patient):
+        device = _HeldHost(fail_in_bind=True)
+        device.host.set()  # no call is held
+        b = MicroBatcher(device, BatcherConfig(max_batch_size=1, max_batch_delay_ms=0.0))
+        try:
+            assert b.submit("a")[0] == 500
+            assert b._floor.is_set() and b._preparing == 0
+            assert b.submit("b")[0] == 500  # the worker's next claim is its own
+            assert b._floor.is_set() and b._preparing == 0
+        finally:
+            device.open()
+            b.close()
+
+    def test_a_rider_waits_no_longer_than_the_limit_for_a_host_half_that_hangs(self):
+        from predictionio_tpu.serving import batcher
+
+        device = _HeldHost()
+        b = MicroBatcher(device, BatcherConfig(max_batch_size=1, max_batch_delay_ms=0.0))
+        try:
+            first = _riders(b, ["a"])
+            _wait_until(lambda: device.dispatched == 1)
+            second = _riders(b, ["b"])
+            _wait_until(lambda: device.entered == 2)  # hangs in its host half
+            device.gates[0].set()
+            t0 = time.monotonic()
+            _join(first)
+            assert time.monotonic() - t0 < 40 * batcher._GIVE_WAY_S
+            assert not b._floor.is_set()
+        finally:
+            device.open()
+            b.close()
+        _join(second)
+
+    def test_wake_counts_the_wait(self, patient):
+        device = _HeldHost()
+        b = MicroBatcher(device, BatcherConfig(max_batch_size=1, max_batch_delay_ms=0.0))
+        try:
+            first = _riders(b, ["a"])
+            _wait_until(lambda: device.dispatched == 1)
+            second = _riders(b, ["b"])
+            _wait_until(lambda: device.entered == 2)
+            device.gates[0].set()
+            _wait_until(lambda: b.stats.batches == 1)
+            time.sleep(0.05)
+            device.host.set()
+            _join(first)
+            assert b.stats.to_json()["latencyMs"]["wake"]["p50"] >= 50.0
+        finally:
+            device.open()
+            b.close()
+        _join(second)
+
+    def test_claims_rest_once_the_batches_after_them_come_out_part_full(
+            self, monkeypatch):
+        """The watch on what a claim leaves the next batch: batches of 1
+        of 2 are loaded (they claim) and part full; after a window of such
+        claims none claims for the rest, and a full batch after every claim
+        keeps them."""
+        from predictionio_tpu.serving import batcher
+
+        monkeypatch.setattr(batcher, "_CLAIM_WINDOW", 4)
+        b = MicroBatcher(_phased_batch, BatcherConfig(max_batch_size=2, max_batch_delay_ms=0.0))
+        try:
+            for q in range(4):
+                assert b.submit(q)[0] == 200 and b._no_claim_until == 0.0
+            assert b._claimed_last and b._claims == 3  # the first followed none
+            assert b.submit(4)[0] == 200  # the fourth claim followed by a part-full one
+            assert b._no_claim_until > time.monotonic() + 0.5 * batcher._REST_S
+            assert (b._claims, b._short_after) == (0, 0) and not b._claimed_last
+            assert b.submit(5)[0] == 200 and not b._claimed_last  # at rest
+        finally:
+            b.close()
+        full = MicroBatcher(_phased_batch, BatcherConfig(max_batch_size=1, max_batch_delay_ms=0.0))
+        try:
+            for q in range(12):
+                assert full.submit(q)[0] == 200
+            assert full._no_claim_until == 0.0 and full._claimed_last
+            assert full._short_after == 0
+        finally:
+            full.close()

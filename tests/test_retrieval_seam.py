@@ -26,7 +26,6 @@ from predictionio_tpu.templates.recommendation.engine import (
     ALSModel,
 )
 from predictionio_tpu.templates.retrieval import (
-    EXCLUDED_FLOOR,
     WANTED_FLOOR,
     FilteredItemRetrieval,
     FilteredServingState,
@@ -307,13 +306,13 @@ def test_a_filtering_engine_reaches_the_filter_through_the_shared_object(engine)
 
 @pytest.mark.parametrize("pattern, what", [
     (r"\btile_items\(", "a call of ops.als.tile_items"),
-    (r"^(EXCLUDED|WANTED)_FLOOR\s*=", "a floor of the filter's list widths"),
+    (r"^WANTED_FLOOR\s*=", "the floor of the filter's category width"),
     (r"\bTopkFilter\(", "a TopkFilter made"),
     (r"\bfilt=filt\b", "a filtered chunked_topk call"),
     (r"^def category_arrays\b", "category_arrays"),
 ])
 def test_the_filter_is_built_in_one_module(pattern, what):
-    """No second ``tile_items`` call site, no second ``EXCLUDED_FLOOR``: what
+    """No second ``tile_items`` call site, no second ``WANTED_FLOOR``: what
     the two engines share lives once, in ``templates/retrieval.py``."""
     found = {
         rel for rel, src in _sources("predictionio_tpu/templates")
@@ -325,15 +324,17 @@ def test_the_filter_is_built_in_one_module(pattern, what):
 @FILTERING
 def test_both_engines_hand_the_same_rules_to_the_same_arrays(engine):
     """Lists of item ids and category names become the one ``TopkFilter``
-    layout whichever engine asks: ids left out padded to the shared floor,
-    unknown ids dropped, a name no item carries a code no item carries."""
+    layout whichever engine asks: ids left out padded to the longest list
+    (no extent of any program: they reach the device as pairs grouped by
+    tile), unknown ids dropped, a name no item carries a code no item
+    carries."""
     from predictionio_tpu.ops.topk import NO_ITEM
 
     algo, model = engine()
     model.categories = {"3": ("a",), "5": ("a", "b")}
     filt = algo.topk_filter(model, [["1", "2", "nobody"], []], [["b", "zzz"], []], {"7"})
-    assert filt.excluded.shape == (2, EXCLUDED_FLOOR) and filt.wanted.shape == (2, WANTED_FLOOR)
-    assert filt.excluded[0, :3].tolist() == [1, 2, NO_ITEM] and filt.excluded[1, 0] == NO_ITEM
+    assert filt.excluded.shape == (2, 2) and filt.wanted.shape == (2, WANTED_FLOOR)
+    assert filt.excluded.tolist() == [[1, 2], [NO_ITEM, NO_ITEM]]
     assert filt.wanted.tolist() == [[1, 2], [-2, -2]]
     assert np.flatnonzero(filt.blocked).tolist() == [7] and filt.item_tiles is None
     allowed = algo.allowed_on_host(model, filt, white_list=None)

@@ -109,6 +109,114 @@ def test_select_top_k_is_lax_top_k_bit_for_bit(name):
         assert np.asarray(got_p)[0].tolist() == [7 * B + 5, *range(0, 15 * B, B)]
 
 
+#: ``drop=`` on both sides of the rule: 8 rows, k 16, a width under and
+#: one at ``select_plan``'s least blocked shape
+DROP_SHAPES = {"plain": 1024, "blocked": 32_768}
+
+
+def _drop(name: str, scores: np.ndarray, k: int, rng) -> tuple[list, list]:
+    """The (row, column) pairs of one ``drop=`` case over ``scores``."""
+    rows, width = scores.shape
+    lead = np.argsort(-scores, axis=1, kind="stable")  # each row's ranking
+    if name == "a_blocks_maximum":  # the block's next best must stand in
+        return [0, 3], [int(lead[0, 0]), int(lead[3, 2])]
+    if name == "two_of_one_row_in_one_block":
+        c = int(lead[1, 0])
+        return [1, 1, 1], [c, c ^ 1, c ^ 2]  # the leader and two beside it
+    if name == "a_whole_block":
+        first = int(lead[2, 0]) // B * B
+        return [2] * B, list(range(first, first + B))
+    if name == "a_rows_whole_top_k":
+        return [5] * k + [6] * (2 * k), lead[5, :k].tolist() + lead[6, :2 * k].tolist()
+    if name == "the_same_position_twice":
+        return [4, 4, 7, 4], [int(lead[4, 0])] * 2 + [int(lead[7, 1]), int(lead[4, 0])]
+    if name == "padding_past_the_row":  # a column of `width` pads the list
+        return [0, 0, 2, 0], [width, int(lead[0, 1]), width, width]
+    if name == "no_pair_at_all":
+        return [0] * 8, [width] * 8
+    if name == "every_row_a_pair_in_every_leading_block":
+        return (np.repeat(np.arange(rows), k).tolist(), lead[:, :k].reshape(-1).tolist())
+    if name == "a_list_of_more_than_one_chunk":  # 1,100 pairs in three chunks
+        assert topk._DROP_CHUNK == 512
+        n = 1100 // rows + 1
+        return (np.repeat(np.arange(rows), n)[:1100].tolist() + [0] * 436,
+                lead[:, :n].reshape(-1)[:1100].tolist() + [width] * 436)
+    raise AssertionError(name)
+
+
+DROP_CASES = [
+    "a_blocks_maximum", "two_of_one_row_in_one_block", "a_whole_block",
+    "a_rows_whole_top_k", "the_same_position_twice", "padding_past_the_row",
+    "no_pair_at_all", "every_row_a_pair_in_every_leading_block",
+    "a_list_of_more_than_one_chunk",
+]
+
+
+@pytest.mark.parametrize("plan", list(DROP_SHAPES))
+@pytest.mark.parametrize("name", DROP_CASES)
+@pytest.mark.parametrize("ties", [False, True])
+def test_drop_is_lax_top_k_of_the_row_without_those_positions(name, plan, ties):
+    """``select_top_k(drop=)`` against ``lax.top_k`` of the scores with the
+    dropped positions at ``-inf``, bit for bit, ties included."""
+    rng = np.random.default_rng(len(name))
+    rows, width, k = 8, DROP_SHAPES[plan], 16
+    assert select_plan(rows, width, k) == plan
+    scores = (rng.integers(0, 5, (rows, width)) if ties
+              else rng.standard_normal((rows, width))).astype(np.float32)
+    row, col = (np.asarray(a, np.int32) for a in _drop(name, scores, k, rng))
+    got_v, got_p = jax.jit(lambda s, r, c: select_top_k(s, k, drop=(r, c)))(
+        scores, row, col)
+    masked = scores.copy()
+    masked[row[col < width], col[col < width]] = -np.inf
+    want_v, want_p = jax.lax.top_k(masked, k)
+    assert np.array_equal(np.asarray(got_p), np.asarray(want_p))
+    assert np.array_equal(np.asarray(got_v).view(np.uint32),
+                          np.asarray(want_v).view(np.uint32))
+    if name == "a_rows_whole_top_k" and not ties:
+        assert not set(np.asarray(got_p)[5].tolist()) & set(col[:k].tolist())
+
+
+def test_a_long_drop_list_is_a_whole_number_of_chunks():
+    import jax.numpy as jnp
+
+    spec = jax.ShapeDtypeStruct((8, WIDE), jnp.float32)
+    for n, fine in ((3, True), (512, True), (2048, True), (1100, False)):
+        pairs = jax.ShapeDtypeStruct((n,), jnp.int32)
+        trace = lambda: jax.make_jaxpr(  # noqa: E731
+            lambda s, r, c: select_top_k(s, 16, drop=(r, c)))(spec, pairs, pairs)
+        if fine:
+            trace()
+        else:
+            with pytest.raises(ValueError, match="whole number of chunks"):
+                trace()
+
+
+#: the primitives ``select_top_k`` WITHOUT ``drop`` traced to before it took
+#: one (ISSUE 36; the parent commit's jaxpr): every other scoring program
+#: shares the function
+_BLOCKED_BEFORE = [
+    "reshape", "reduce_max", "reshape", "top_k", "jit", "iota",
+    "broadcast_in_dim", "jit", "mul", "add", "mul", "jit", "add", "transpose",
+    "reshape", "reshape", "lt", "add", "select_n", "broadcast_in_dim", "gather",
+    "reshape", "top_k", "jit", "jit", "mul", "jit", "add",
+]
+
+
+@pytest.mark.parametrize("shape,before", [
+    ((8, WIDE), _BLOCKED_BEFORE),
+    ((8, 700_001), ["pad", *_BLOCKED_BEFORE]),
+    ((32, 26_744), ["top_k"]),
+])
+def test_without_drop_the_trace_is_what_it_was(shape, before):
+    import jax.numpy as jnp
+
+    spec = jax.ShapeDtypeStruct(shape, jnp.float32)
+    traced = jax.make_jaxpr(lambda s: select_top_k(s, 16))(spec)
+    assert [e.primitive.name for e in traced.eqns] == before
+    assert str(traced) == str(
+        jax.make_jaxpr(lambda s: select_top_k(s, 16, drop=None))(spec))
+
+
 @pytest.mark.parametrize("rows,width,k,plan", [
     (32, 1 << 19, 16, "blocked"),    # an e-commerce tile at a full batch
     (8, 1 << 19, 16, "blocked"),     # ... and at the floor of 8 rows

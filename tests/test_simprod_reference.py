@@ -4,6 +4,7 @@
 unpinned (host) and pinned (the tiled device program), and the
 comparison's own teeth."""
 
+import dataclasses
 import os
 import pickle
 import sys
@@ -165,6 +166,10 @@ def test_white_list_keeps_the_host_path_and_the_counters_count(shop):
     assert counts["filter.excludedIds"] == 1 + sum(
         len(set(q.items) | set(q.black_list or ())) - ("no-such-item" in q.items)
         for q in (queries[1], queries[-2], queries[-3]))
+    # what the device dispatch was handed: the three rows' own known ids as
+    # (row, id) pairs, at the floor of its program's pair bucket
+    assert counts["filter.excludedPairs"] == counts["filter.excludedIds"] - 1
+    assert counts["filter.pairBucket.128"] == 1
     n_items = 1 + 1 + 2 + sum(len(q.items) for q in (queries[1], queries[-2], queries[-3]))
     assert counts["similar.queryItems"] == n_items and counts["similar.unknownItems"] == 2
     assert {s.item for s in got[0].item_scores} == {"5", "6", "9"}
@@ -230,3 +235,30 @@ def test_a_blob_pickled_without_category_codes_loads_and_serves(shop):
     # and a blob of today's model carries the arrays
     again = loads_model(dumps_model(model))
     np.testing.assert_array_equal(again.category_codes, model.category_codes)
+
+
+def test_the_batchs_query_vectors_are_each_querys_own(shop):
+    """One gather for the batch gives what a query alone gives: the unit
+    sum of its items' rows in float32; rows that cancel are no direction."""
+    algo, model, *_ = shop
+    table = model.item_factors.copy()
+    table[21] = -table[20]
+    model = dataclasses.replace(model, item_factors=table)
+    rows = [[5], [20, 21], [7, 8, 9, 1999], [20, 21, 3]]
+    vectors, ok = algo._query_vectors(model, rows)
+    assert vectors.dtype == np.float32 and ok.tolist() == [True, False, True, True]
+    assert not vectors[1].any()
+    for got, idxs in zip(vectors[ok], [rows[0], rows[2], rows[3]]):
+        want = table[idxs].astype(np.float64).sum(axis=0)
+        np.testing.assert_allclose(got, want / np.linalg.norm(want), rtol=0, atol=2e-7)
+    alone, fine = algo._query_vectors(model, [rows[2]])
+    assert fine.tolist() == [True] and np.array_equal(alone[0], vectors[2])
+    # a batch holding a query whose items cancel answers it empty, the others as alone
+    queries = [Query(items=tuple(str(i) for i in r), num=NUM) for r in rows]
+    batch = _served(algo, model, queries, "batch_unpinned")
+    assert batch[1].item_scores == ()
+    for got, want in zip(batch, [algo.predict(model, q) for q in queries]):
+        assert [s.item for s in got.item_scores] == [s.item for s in want.item_scores]
+        np.testing.assert_allclose([s.score for s in got.item_scores],
+                                   [s.score for s in want.item_scores],
+                                   rtol=2e-6, atol=1e-7)

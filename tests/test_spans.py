@@ -56,6 +56,20 @@ class TestSpan:
         s.stop()
         assert [r.name for r in collector.take()] == ["httpRead"]
 
+    def test_on_close_hears_each_span_as_it_closes_the_record_already_kept(
+            self, collector):
+        heard = []
+        collector.on_close = lambda name: heard.append((name, len(collector._closed)))
+        with span("handle", enclosing=True):
+            with span("dispatch"):
+                pass
+            assert heard == [("dispatch", 1)]  # while handle is still open
+        assert heard == [("dispatch", 1), ("handle", 2)]
+        collector.on_close = None
+        with span("format"):
+            pass
+        assert len(heard) == 2
+
     def test_a_collector_belongs_to_its_thread(self, collector):
         seen = []
 

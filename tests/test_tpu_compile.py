@@ -139,3 +139,37 @@ def test_the_als_solve_lowers_through_mosaic_and_fits(
     assert memory.temp_size_in_bytes <= (
         chunk * rank * lanes * 4 + chunk * width * lanes * 4 + (64 << 20)
     ), memory.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("pairs", [128, 1024])
+def test_the_filtered_top_k_holds_no_array_of_the_excluded_ids(
+    one_chip, no_compile_cache, pairs
+):
+    """`top_k_items_filtered` at the shape of `ecom_amazon2018` and
+    `simprod_amazon2018` (32 rows, 30 tiles of 2^19 items, rank 64, k 16, two
+    wanted categories) at its pair bucket's floor and at 1,024 (two chunks
+    of `ops.topk._DROP_CHUNK`): no `[tiles, rows, width]` array exists for
+    the ids left out, one tile's scores are never copied or relaid flat to
+    strike a pair from them, and what the program holds beside its
+    arguments is little more than one tile's scores."""
+    from predictionio_tpu.ops.als import FILTER_PAIR_FLOOR, FILTER_TILE, top_k_items_filtered
+
+    assert FILTER_PAIR_FLOOR == 128
+    rows, tiles, width, rank = 32, 30, FILTER_TILE, 64
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = top_k_items_filtered.lower(
+        arg((rows, rank), jnp.float32), arg((tiles, rank, width), jnp.float32),
+        arg((tiles, 1, width), jnp.int32), arg((tiles, width), jnp.bool_),
+        arg((rows, 2), jnp.int32), arg((tiles, pairs), jnp.int32),
+        arg((tiles, pairs), jnp.int32), k=16).compile()
+    text = compiled.as_text()
+    assert not re.search(rf"\[{tiles},{rows},{width}\]", text)
+    score_tile = rf"f32\[{rows},{width}\]"
+    assert re.search(score_tile, text)  # the tile's scores are there ...
+    assert not re.search(rf"{score_tile}\S* copy\(", text)  # ... never copied
+    assert not re.search(rf"f32\[{rows * width}\]", text)  # ... nor relaid flat
+    assert re.search(rf"{score_tile}\S* scatter\(", text)  # struck in place
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * rows * width * 4
