@@ -14,6 +14,7 @@ import logging
 import os
 import ssl
 import threading
+import time
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import TYPE_CHECKING, Callable, Mapping
@@ -63,6 +64,15 @@ def ssl_context_from_env() -> ssl.SSLContext | None:
 
 #: signature shared with EventService.dispatch / QueryService.dispatch
 Dispatcher = Callable[..., "object"]
+
+#: requests that rode in a batch between two reads of an HTTP thread's CPU
+#: clock: the read is a system call made under the interpreter lock, 0.3 us
+#: on a plain Linux host and 6 us idle, more under load, on the chip's
+#: (PERF.md, PR 37). A thread serves its connection's requests one after
+#: another and burns next to no CPU between them, so one read a group still
+#: counts every request's CPU time; the group's sums go to the service
+#: together
+RIDERS_A_CPU_READ = 64
 
 
 class _LengthReader:
@@ -170,8 +180,15 @@ def _resolve_readiness(
 def _resolve_span_sink(dispatch: Dispatcher) -> Callable | None:
     """A service object's ``record_http`` method, discovered like
     ``readiness``: it is handed the spans each request closed on its
-    HTTP thread (``httpRead``, ``httpWrite``). Servers without one bind
-    no collector and record nothing."""
+    HTTP thread (``httpRead``, ``httpWrite``) and, once a group of
+    ``RIDERS_A_CPU_READ`` requests that rode in a batch is full (or the
+    connection ends), what was counted on the thread's collector over
+    the group: ``rider.requestNs`` (each rider's stretch on this thread,
+    ``Handler.parse_request`` to the flush, summed), ``rider.cpuNs`` (the
+    thread's CPU time since the group before) and what the service
+    counted (the batcher: ``rider.requests``, ``rider.queuedNs``,
+    ``rider.giveWayNs``). Servers without one bind no collector and
+    record nothing."""
     hook = getattr(getattr(dispatch, "__self__", None), "record_http", None)
     return hook if callable(hook) else None
 
@@ -207,6 +224,33 @@ def _make_handler(
             # profiler: utils/spans.py) collect here, taken per request
             self._collector = spans.Collector() if span_sink else None
             spans.bind(self._collector)
+            self._request_ns = 0
+            self._cpu_ns = time.thread_time_ns() if span_sink else 0
+
+        def parse_request(self):
+            # the handler's first instruction on a request:
+            # handle_one_request has just read the request line (a
+            # keep-alive thread waits for its caller in that read) and
+            # parses the headers next
+            if self._collector is not None:
+                self._request_ns = time.perf_counter_ns()
+            return super().parse_request()
+
+        def finish(self):
+            # the connection ends: what is left of a group of riders
+            collector = getattr(self, "_collector", None)
+            if collector is not None and collector.counted("rider.requests"):
+                span_sink((), self._take_riders())
+            super().finish()
+
+        def _take_riders(self) -> dict:
+            """Close the group of riders this thread has served since the
+            last one: one read of the thread's CPU clock, and the
+            collector's counts."""
+            cpu_ns, before = time.thread_time_ns(), self._cpu_ns
+            self._cpu_ns = cpu_ns
+            spans.count("rider.cpuNs", cpu_ns - before)
+            return self._collector.take_counts()
 
         def _respond(self):
             parsed = urllib.parse.urlparse(self.path)
@@ -259,8 +303,10 @@ def _make_handler(
             if stream_routes and (self.command, parsed.path) in stream_routes:
                 self._dispatch_stream(parsed, params)
                 return
+            riders = 0
             if self._collector is not None:
                 self._collector.take()  # what an unanswered request left
+                riders = self._collector.counted("rider.requests")
             body = None
             form: Mapping[str, str] | None = None
             with spans.span("httpRead"):
@@ -312,7 +358,16 @@ def _make_handler(
                 )
                 self.wfile.flush()  # the reply is on the wire
             if span_sink is not None:
-                span_sink(self._collector.take())
+                counts = None
+                group = self._collector.counted("rider.requests")
+                if group > riders:  # this request rode in a batch
+                    spans.count(
+                        "rider.requestNs",
+                        time.perf_counter_ns() - self._request_ns,
+                    )
+                    if group >= RIDERS_A_CPU_READ:
+                        counts = self._take_riders()
+                span_sink(self._collector.take(), counts)
             after_send = getattr(resp, "after_send", None)
             if after_send is not None:
                 # the reply is flushed before the hook runs: /stop shuts

@@ -1537,7 +1537,7 @@ def train_als(
                     "Resumed ALS from checkpoint step %d", latest
                 )
 
-    sweep_seconds = []
+    sweep_seconds, sweep_cpu_seconds = [], []
     for step in range(start_step, config.iterations):
         with span("train.sweep") as sweep:
             uf, vf = als_sweep(
@@ -1555,6 +1555,7 @@ def train_als(
                 jax.block_until_ready(vf)
         if timed:
             sweep_seconds.append(round(sweep.seconds, 3))
+            sweep_cpu_seconds.append(round(sweep.cpu_seconds, 3))
         if manager is not None and (
             (step + 1) % config.checkpoint_interval == 0
             or step + 1 == config.iterations
@@ -1564,6 +1565,10 @@ def train_als(
             manager.save(step + 1, _to_canonical(uf, vf))
     if timed:
         info["sweepSeconds"] = sweep_seconds
+        # the calling thread's CPU time in each (all 0 where its collector
+        # takes none): near the wall where the host computes (a trace, a
+        # lowering), far under it where it waits for the device
+        info["sweepCpuSeconds"] = sweep_cpu_seconds
     if manager is not None:
         manager.wait()
         manager.close()
@@ -1588,16 +1593,27 @@ def train_als(
                 user=np.asarray(uf)[:num_users],
                 item=np.asarray(vf)[:num_items],
             )
-    return ALSFactors(user=uf[:num_users], item=vf[:num_items])
+    # the sentinel rows off: two eager slices, each traced and loaded on
+    # its first call (0.18 s each on the chip's host): the first step of
+    # bringing the tables out, so the readback's span holds it
+    with span("train.readback") as strip:
+        factors = ALSFactors(user=uf[:num_users], item=vf[:num_items])
+        if timed:
+            jax.block_until_ready(factors)
+    if timed:
+        info["readbackSeconds"] = round(strip.seconds, 3)
+    return factors
 
 
 def factors_to_host(info: dict, *tables: jax.Array) -> tuple[np.ndarray, ...]:
     """The trained tables as host arrays (what a model blob holds), and
-    the seconds that took as ``info["readbackSeconds"]`` beside the other
-    timings of :func:`train_als`."""
+    the seconds that took, added to what :func:`train_als` spent stripping
+    them, as ``info["readbackSeconds"]`` beside its other timings."""
     with span("train.readback") as readback:
         host = tuple(np.asarray(t) for t in tables)
-    info["readbackSeconds"] = round(readback.seconds, 3)
+    info["readbackSeconds"] = round(
+        info.get("readbackSeconds", 0.0) + readback.seconds, 3
+    )
     return host
 
 

@@ -467,7 +467,9 @@ def train_two_tower(
 
     ``info``, when given, receives the kernel decisions this train took
     (backend, cross-entropy path and why, optimizer and why, GEMM dtype,
-    batch, dim, mesh) and what it measured: ``epochSeconds`` (one dispatch
+    batch, dim, mesh) and what it measured: ``initSeconds`` (span
+    ``train.init``: seeding the tables and padding the pairs before the
+    upload, packing the tables after it), ``epochSeconds`` (one dispatch
     and one sync an epoch; the first carries the compile), ``stepMs`` (the
     median epoch but the first over its steps), ``firstLosses`` and
     ``firstGradNorms`` (of the first steps: each table's gradient norm,
@@ -514,6 +516,11 @@ def train_two_tower(
         d_size = int(mesh.shape.get(data_axis, 1))
         B = _pad_rows(B, d_size)
 
+    # seeding the two tables and padding the pairs: a handful of eager
+    # programs, each traced and loaded on its first call, and host numpy.
+    # Closed by a sync, like every phase here, so the next is not charged
+    # for it
+    seeding = span("train.init").start()
     key = jax.random.PRNGKey(config.seed)
     k_u, k_i, k_perm = jax.random.split(key, 3)
     scale = 1.0 / np.sqrt(D)
@@ -567,6 +574,8 @@ def train_two_tower(
     r_host = rows[reps].astype(np.int32)
     c_host = cols[reps].astype(np.int32)
     pairs_checksum = zlib.crc32(c_host, zlib.crc32(r_host))
+    jax.block_until_ready(params)
+    seeding.stop()
     with span("train.ingest") as ingest:
         r_base = jnp.asarray(r_host)
         c_base = jnp.asarray(c_host)
@@ -578,29 +587,37 @@ def train_two_tower(
 
     steps_per_epoch = n_pad // B
     inv_temp = 1.0 / config.temperature
-    ce_path, ce_why = _ce_path(
-        mesh, config.gemm_dtype, config.fused_ce, B, D, inv_temp
-    )
-    optimizer, optimizer_why = _optimizer_path(mesh)
-    decisions = {
-        "backend": jax.default_backend(),
-        "fusedCe": ce_path,
-        "fusedCeWhy": ce_why,
-        "optimizer": optimizer,
-        "optimizerWhy": optimizer_why,
-        "gemmDtype": config.gemm_dtype,
-        "batch": B,
-        "dim": D,
-        "mesh": None if mesh is None else dict(mesh.shape),
-    }
-    logger.info("two-tower kernel decisions: %s", decisions)
-    info = {} if info is None else info
-    info.update(decisions)
-    train_epoch, init_state, tables = _epoch_program(
-        mesh, data_axis, model_axis, B, D, n_pad, steps_per_epoch,
-        config.learning_rate, inv_temp, config.gemm_dtype, ce_path, optimizer,
-    )
-    params, opt_state = init_state(params)
+    # the step's two decisions (the first imports the kernel's module: a
+    # second on the chip's host), then packing: the tables into the layout
+    # the epoch program updates in place, the optimizer's state beside
+    # them; the same phase as the seeding, on the other side of the upload
+    with span("train.init") as packing:
+        ce_path, ce_why = _ce_path(
+            mesh, config.gemm_dtype, config.fused_ce, B, D, inv_temp
+        )
+        optimizer, optimizer_why = _optimizer_path(mesh)
+        decisions = {
+            "backend": jax.default_backend(),
+            "fusedCe": ce_path,
+            "fusedCeWhy": ce_why,
+            "optimizer": optimizer,
+            "optimizerWhy": optimizer_why,
+            "gemmDtype": config.gemm_dtype,
+            "batch": B,
+            "dim": D,
+            "mesh": None if mesh is None else dict(mesh.shape),
+        }
+        logger.info("two-tower kernel decisions: %s", decisions)
+        info = {} if info is None else info
+        info.update(decisions)
+        train_epoch, init_state, tables = _epoch_program(
+            mesh, data_axis, model_axis, B, D, n_pad, steps_per_epoch,
+            config.learning_rate, inv_temp, config.gemm_dtype, ce_path,
+            optimizer,
+        )
+        params, opt_state = init_state(params)
+        jax.block_until_ready((params, opt_state))
+    info["initSeconds"] = round(seeding.seconds + packing.seconds, 3)
     ledger = CompileLedger.install()
     compiled_before = ledger.snapshot()
 

@@ -48,8 +48,11 @@ part of its host half runs under the first one's device time. Where a
 cycle is the interpreter lock's and not the device's (a small model:
 the lock is shared with the HTTP threads), the second batch's program
 meets an idle device every time (measured: 0.5% of the batches were
-enqueued behind a running program there, 45% where it pays; PERF.md,
-PR 31) and all it does is empty the queue, so that now and then both
+enqueued behind a running program there; where it pays, 45% of them
+with the device the longer half, PERF.md, PR 31, and still 4-15% with
+the host the longer half, where a second batch hides only part of its
+host half and is worth 7% of the rate all the same, PERF.md, PR 37) and
+all it does is empty the queue, so that now and then both
 workers return to a short one and a part-full batch goes. So after
 ``_ALONE_RUN`` second batches in a row whose program met an idle device
 no second batch goes for ``_REST_S`` seconds (the single dispatcher's
@@ -87,9 +90,11 @@ Admission control is explicit: when the queue is full the configured
 policy either rejects immediately (HTTP 429 + ``Retry-After``) or
 blocks the caller up to ``block_timeout_ms`` (503 on timeout). Queue
 depth, in-flight batch state, bucket hit/miss counts and the latency
-decomposition (per request: queue wait, total, wake; per batch: its
-worker's phases as spans of ``utils/spans.py``, the host gap before it
-and whether it overlapped the batch before it) are recorded in
+decomposition (per request: queue wait, total, wake and what of it gave
+way to a worker; per batch: its worker's phases as spans of
+``utils/spans.py``, each with the worker thread's CPU time beside its
+wall, the host gap before it and whether it overlapped the batch before
+it), and how often the batcher sent itself to either rest, are recorded in
 :class:`predictionio_tpu.api.stats.ServingStats` and served from the
 query server's ``GET /stats.json``. Each worker's leaf spans are also
 ``pio.*`` events in a running ``jax.profiler`` trace, one flat line a
@@ -129,13 +134,18 @@ _RESULT_TIMEOUT_S = 300.0
 #: cover a cycle of two halves (module text)
 _WORKERS = 2
 #: second batches in a row whose program met an idle device, after which
-#: none goes for _REST_S seconds. Where a second batch pays about one in
-#: two is enqueued behind a running program, where it does not one in two
-#: hundred (PERF.md, PR 31): 64 in a row is 2**-55 by the first share and
-#: a second's worth of batches by the other (at a cost of some 3% of the
-#: rate while they last). A rest in seconds, not in batches, so that being
+#: none goes for _REST_S seconds. Where a second batch pays, between one in
+#: two (the device the longer half: PERF.md, PR 31) and one in twenty (the
+#: host the longer half: PR 37) is enqueued behind a running program; where
+#: it does not, one in two hundred. 128 in a row is 2**-110 by the first
+#: share, 0.1% by the second and two seconds' worth of batches by the last
+#: (at a cost of some 3% of the rate while they last). At 64 the second
+#: share made it 4%: such a server rested half its time, and more with
+#: every microsecond added to its host half (a host half 1.6% longer took
+#: the share from 12% to 7%: the rest, not the work, cost 5% of the rate;
+#: PERF.md, PR 37). A rest in seconds, not in batches, so that being
 #: wrong costs a slow model no more than a fast one
-_ALONE_RUN = 64
+_ALONE_RUN = 128
 _REST_S = 10.0
 #: the longest a rider that has its answer waits for a worker's host half
 #: (module text): ten of those halves; one that takes longer (a compile, a
@@ -147,6 +157,20 @@ _GIVE_WAY_S = 0.05
 #: does not
 _CLAIM_WINDOW = 32
 _SHORT_SHARE = 0.25
+#: one of a worker's cycles in this many takes CPU time: its spans'
+#: (``hostCpu``, ``hostWait``, ``cpuMs``; some 28 reads of the thread's CPU
+#: clock) and, one read more, the thread's total since it last did
+#: (``cpuNs.workers``). That clock is a system call made under the
+#: interpreter lock: 0.3 us on a plain Linux host; on the chip's 6 us idle
+#: and, by what the spans grew, 10-40 us under load (PERF.md, PR 37). Read
+#: around every span and every request it cost ``serve_saturated`` 3-15%
+#: there; at one cycle in 16 with the total read every cycle, this and the
+#: counts a request together still cost the similar-product cell 1.6-2.5%
+#: with two batches in flight, and twice that through the second batches'
+#: rest (module text: a longer host half meets a busy device less often).
+#: The share is fixed, not steered by a measured cost, so that a host's
+#: numbers mean the same everywhere
+_CPU_EVERY = 32
 
 
 class AdmissionPolicy(str, enum.Enum):
@@ -226,12 +250,14 @@ class BatcherConfig:
 
 
 class _Pending:
-    __slots__ = ("body", "enqueued_at", "done", "result", "drained", "seq",
-                 "released_ns", "worker")
+    __slots__ = ("body", "enqueued_at", "enqueued_ns", "done", "result",
+                 "drained", "seq", "released_ns", "worker")
 
     def __init__(self, body: Any):
         self.body = body
         self.enqueued_at = time.monotonic()
+        #: the same instant on the clock of ``released_ns``
+        self.enqueued_ns = time.perf_counter_ns()
         self.done = threading.Event()
         self.result: tuple[int, Any] | None = None
         #: sequence number of the batch this request rode in
@@ -434,6 +460,7 @@ class MicroBatcher:
             if time.monotonic() >= give_up_at:
                 return 500, {"message": "Batch dispatcher did not respond."}
         # a worker preparing a loaded batch goes first (module text)
+        gave_way_ns = time.perf_counter_ns()
         self._floor.wait(timeout=_GIVE_WAY_S)
         woke_ns = time.perf_counter_ns()
         assert pending.result is not None
@@ -446,11 +473,20 @@ class MicroBatcher:
             self.stats.record_request(
                 total_ms=(time.monotonic() - pending.enqueued_at) * 1e3,
                 wake_ms=(woke_ns - pending.released_ns) / 1e6,
+                give_way_ms=(woke_ns - gave_way_ns) / 1e6,
             )
             collector = spans.current()
             if collector is not None:
                 # this request's spans share its batch's identifier
                 collector.seq = pending.seq
+                # for the HTTP thread's account of its riders
+                # (api/http.py): how long this one was meant to wait, and
+                # what of the rest it gave way
+                spans.count("rider.requests", 1)
+                spans.count(
+                    "rider.queuedNs", pending.released_ns - pending.enqueued_ns
+                )
+                spans.count("rider.giveWayNs", woke_ns - gave_way_ns)
         return pending.result
 
     def retry_after_seconds(self) -> int:
@@ -593,9 +629,13 @@ class MicroBatcher:
 
     def _loop(self) -> None:
         # a worker feeds the device: its leaf spans also go into a
-        # running profiler trace (utils/spans.py); taken once a batch
-        collector = spans.Collector(annotate=True)
+        # running profiler trace (utils/spans.py), and on one of this
+        # worker's cycles in _CPU_EVERY (its first is one) each records the
+        # thread's CPU time beside its wall; taken once a batch
+        collector = spans.Collector(annotate=True, cpu=True)
         spans.bind(collector)
+        cpu_ns = time.thread_time_ns()
+        cycles = 0
         while True:
             while True:
                 # cleared before the look, so that what is set after it
@@ -615,7 +655,14 @@ class MicroBatcher:
                     batch = self._drain(first)
                 # before the forming lock goes: the next to form sees it
                 beside, claims = self._number(collector, len(batch))
-            self._dispatch(batch, collector, beside, claims)
+            cycles += 1
+            sampled = cycles % _CPU_EVERY == 0
+            self._dispatch(batch, collector, beside, claims, cpu_next=sampled)
+            if sampled:
+                # the whole thread's CPU time since it was last read, the
+                # forming's polls and the accounting included
+                cpu_ns, before = time.thread_time_ns(), cpu_ns
+                self.stats.record_worker_cpu(cpu_ns - before)
         # drain leftovers so no client hangs on shutdown
         self._drain_dead_queue()
 
@@ -624,6 +671,7 @@ class MicroBatcher:
         in flight. Was another in flight already, and does this one claim
         the right of way (module text)?"""
         full = size >= self.config.max_batch_size
+        rests = False
         with self._lock:
             self._seq += 1
             collector.seq = self._seq
@@ -634,12 +682,16 @@ class MicroBatcher:
                 if self._claims >= _CLAIM_WINDOW:
                     if self._short_after > _SHORT_SHARE * _CLAIM_WINDOW:
                         self._no_claim_until = time.monotonic() + _REST_S
+                        rests = True
                     self._claims = self._short_after = 0
             self._claimed_last = (
                 2 * size >= self.config.max_batch_size
                 and time.monotonic() >= self._no_claim_until
             )
-            return self._in_flight > 1, self._claimed_last
+            numbered = self._in_flight > 1, self._claimed_last
+        if rests:
+            self.stats.record_rest("claims")
+        return numbered
 
     def _claim_floor(self, collector: spans.Collector) -> Callable[[], None]:
         """The calling worker has gathered a loaded batch: riders that wake
@@ -663,12 +715,13 @@ class MicroBatcher:
 
     def _dispatch(
         self, batch: list[_Pending], collector: spans.Collector, beside: bool,
-        claims: bool,
+        claims: bool, cpu_next: bool = False,
     ) -> None:
         """Pad, run and answer one batch on the calling thread, a worker's:
         ``collector`` is the one bound to it; ``beside``: formed while
         another batch was in flight; ``claims``: its host half has the
-        right of way. What lies between two of a worker's
+        right of way; ``cpu_next``: the spans of the cycle that begins with
+        this batch's release take CPU time. What lies between two of a worker's
         batches (release, take, drain) carries the earlier one's sequence
         number."""
         with span("batchForm"):
@@ -704,6 +757,10 @@ class MicroBatcher:
             # this batch's take ... handle
             cycle = collector.take()
             counts = collector.take_counts()
+            # the cycle that starts here (with this batch's release) takes
+            # CPU time or not as a whole
+            took_cpu = collector.cpu
+            collector.cpu = cpu_next
             flight = _Flight(
                 cycle,
                 dict(
@@ -712,6 +769,7 @@ class MicroBatcher:
                     handle_ms=handle.ms,
                     queue_wait_ms=waits,
                     phases=spans.durations_ms(cycle),
+                    phases_cpu=spans.cpu_ms(cycle) if took_cpu else None,
                     rows_scored=counts.get("rowsScored", 0),
                     rows_real=counts.get("rowsReal", 0),
                     counts=counts,
@@ -741,6 +799,7 @@ class MicroBatcher:
         accounted with no gap whenever it does return. ``flight`` None:
         the batch measured nothing."""
         ready = []
+        rests = False
         with self._lock:
             self._in_flight -= 1
             if seq <= self._accounted:  # passed over: nothing to measure from
@@ -778,6 +837,9 @@ class MicroBatcher:
                     if self._alone_run >= _ALONE_RUN:
                         self._alone_run = 0
                         self._shut_until = time.monotonic() + _REST_S
+                        rests = True
         self._go.set()  # a worker that waits to form may now (_may_form)
+        if rests:
+            self.stats.record_rest("secondBatch")
         for record in ready:
             self.stats.record_batch(**record)

@@ -253,6 +253,14 @@ class TwoTowerDataSource(DataSource):
             "idMaps": round(id_maps.seconds, 3),
             "seen": round(seen_span.seconds, 3),
         }
+        # the calling thread's CPU seconds in each (0 where its collector
+        # takes none)
+        ctx.run_info["twotower"]["readCpuSeconds"] = {
+            "scan": round(scan.cpu_seconds, 3),
+            "pairs": round(pairs.cpu_seconds, 3),
+            "idMaps": round(id_maps.cpu_seconds, 3),
+            "seen": round(seen_span.cpu_seconds, 3),
+        }
         return TrainingData(rows, cols_idx, user_index, item_index, seen)
 
     def read_training(self, ctx: WorkflowContext) -> TrainingData:

@@ -18,6 +18,7 @@ import argparse
 import json
 import os
 import sys
+import time
 
 from predictionio_tpu.tools import commands
 from predictionio_tpu.utils import compile_cache, spans
@@ -1427,6 +1428,7 @@ def main(argv: list[str] | None = None) -> int:
     # process start (the kernel's record) to here: interpreter, imports
     # and, under a wrapper that opens the device first, the device
     startup_s = spans.process_age_s()
+    startup_cpu_s = time.process_time()
     # PIO_JAX_PLATFORMS=cpu forces the JAX platform even when the
     # interpreter preloaded jax with a different one (CPU CI runs,
     # multi-host rehearsals on hosts whose default platform is a single
@@ -1488,8 +1490,9 @@ def main(argv: list[str] | None = None) -> int:
 
             initialize_from_env()  # multi-host when PIO_COORDINATOR_* set
             # the main thread feeds the device: its leaf spans also go
-            # into a profiler trace, if someone wraps this job in one
-            unbound = spans.bind(spans.Collector(annotate=True))
+            # into a profiler trace, if someone wraps this job in one,
+            # and each records the thread's CPU time beside its wall
+            unbound = spans.bind(spans.Collector(annotate=True, cpu=True))
             try:
                 with spans.span("train.backend_init") as backend_init:
                     import jax
@@ -1500,6 +1503,9 @@ def main(argv: list[str] | None = None) -> int:
                 }
                 if startup_s is not None:
                     phase_timings["startup"] = round(startup_s, 3)
+                    # the process's CPU seconds over the same stretch
+                    # (every thread's; a span's are its own thread's)
+                    phase_timings["cpu"] = {"startup": round(startup_cpu_s, 3)}
                 variant = load_engine_variant(args.engine_json)
                 ctx = _parse_mesh(args.mesh)
                 instance = run_train(

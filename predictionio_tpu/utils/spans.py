@@ -14,6 +14,13 @@ Enclosing spans and the spans of other threads are never annotated: a
 reduction that names an idle gap by the host event covering most of it
 would otherwise name every gap after the longest parent.
 
+On a collector made with ``cpu`` (the batcher's two workers, the main
+thread of ``pio train``) a span also reads its thread's CPU clock at both
+ends (``time.thread_time_ns``, a system call): ``wall - cpu`` of a span is
+the time its thread was off the CPU, waiting for the interpreter lock,
+blocked in a call that let the lock go, or waiting for a core. A thread
+that waits for the lock accrues no CPU time.
+
 The profiler (``POST /profiler/start``, or whoever wraps ``pio train``) is
 the only store of raw spans; there is no exporter and no switch.
 
@@ -42,6 +49,7 @@ __all__ = [
     "SpanRecord",
     "bind",
     "count",
+    "cpu_ms",
     "current",
     "durations_ms",
     "process_age_s",
@@ -60,6 +68,9 @@ class SpanRecord(NamedTuple):
     seq: int
     start_ns: int
     end_ns: int
+    #: the thread's CPU time between the two ends; 0 where the collector
+    #: takes none
+    cpu_ns: int = 0
 
 
 class Collector:
@@ -69,11 +80,15 @@ class Collector:
     #: a thread whose owner never takes keeps the newest spans only
     MAX_SPANS = 1024
 
-    __slots__ = ("annotate", "seq", "on_close", "_closed", "_open", "_counts")
+    __slots__ = ("annotate", "cpu", "seq", "on_close", "_closed", "_open",
+                 "_counts")
 
-    def __init__(self, annotate: bool = False):
+    def __init__(self, annotate: bool = False, cpu: bool = False):
         #: leaf spans of this thread also go into the profiler's trace
         self.annotate = annotate
+        #: spans of this thread also read the thread's CPU clock; the
+        #: owner may change it between spans
+        self.cpu = cpu
         self.seq = 0
         #: called on the owning thread with the name of each span as it
         #: closes, for an owner that acts on a phase's end while the
@@ -93,6 +108,10 @@ class Collector:
     def take_counts(self) -> dict[str, int]:
         out, self._counts = self._counts, {}
         return out
+
+    def counted(self, name: str) -> int:
+        """The count ``name`` since the last :meth:`take_counts`."""
+        return self._counts.get(name, 0)
 
 
 def bind(collector: Collector | None) -> Collector | None:
@@ -120,16 +139,20 @@ class span:
     """``with span("bind") as s: ...`` then ``s.ms`` / ``s.seconds``.
     ``enclosing=True`` marks a span that has spans inside it: recorded,
     never annotated. ``start()``/``stop()`` are the two ends for a span
-    whose ends do not share a block."""
+    whose ends do not share a block. On a collector that takes CPU time
+    also ``s.cpu_ns`` / ``s.cpu_ms`` / ``s.cpu_seconds``: the CPU clock is
+    read inside the wall clock's two reads, so it never exceeds the wall
+    by more than the two clocks differ."""
 
-    __slots__ = ("name", "enclosing", "start_ns", "end_ns", "_collector",
-                 "_annotation")
+    __slots__ = ("name", "enclosing", "start_ns", "end_ns", "cpu_ns",
+                 "_collector", "_annotation", "_takes_cpu")
 
     def __init__(self, name: str, enclosing: bool = False):
         self.name = name
         self.enclosing = enclosing
-        self.start_ns = self.end_ns = 0
+        self.start_ns = self.end_ns = self.cpu_ns = 0
         self._collector = self._annotation = None
+        self._takes_cpu = False
 
     def start(self) -> "span":
         collector = self._collector = getattr(_bound, "collector", None)
@@ -143,9 +166,16 @@ class span:
                     self._annotation.__enter__()
             collector._open.append(self.name)
         self.start_ns = time.perf_counter_ns()
+        # as the collector says when the span opens: its owner may turn
+        # ``cpu`` on and off between spans (the batcher: one cycle in 32)
+        self._takes_cpu = collector is not None and collector.cpu
+        if self._takes_cpu:
+            self.cpu_ns = -time.thread_time_ns()
         return self
 
     def stop(self) -> None:
+        if self._takes_cpu:
+            self.cpu_ns += time.thread_time_ns()
         self.end_ns = time.perf_counter_ns()
         collector = self._collector
         if collector is None:
@@ -156,7 +186,7 @@ class span:
         collector._closed.append(SpanRecord(
             self.name,
             collector._open[-1] if collector._open else None,
-            collector.seq, self.start_ns, self.end_ns,
+            collector.seq, self.start_ns, self.end_ns, self.cpu_ns,
         ))
         if collector.on_close is not None:
             collector.on_close(self.name)
@@ -179,12 +209,29 @@ class span:
     def seconds(self) -> float:
         return self.ns / 1e9
 
+    @property
+    def cpu_ms(self) -> float:
+        return self.cpu_ns / 1e6
+
+    @property
+    def cpu_seconds(self) -> float:
+        return self.cpu_ns / 1e9
+
 
 def durations_ms(records: Iterable[SpanRecord]) -> dict[str, float]:
     """Milliseconds by span name, the spans of one name summed."""
     out: dict[str, float] = {}
     for r in records:
         out[r.name] = out.get(r.name, 0.0) + (r.end_ns - r.start_ns) / 1e6
+    return out
+
+
+def cpu_ms(records: Iterable[SpanRecord]) -> dict[str, float]:
+    """Milliseconds of the thread's CPU time by span name, the spans of
+    one name summed; all 0 for a collector that takes none."""
+    out: dict[str, float] = {}
+    for r in records:
+        out[r.name] = out.get(r.name, 0.0) + r.cpu_ns / 1e6
     return out
 
 

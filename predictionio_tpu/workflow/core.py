@@ -28,6 +28,7 @@ from predictionio_tpu.controller.evaluation import (
 from predictionio_tpu.controller.params import params_to_json
 from predictionio_tpu.data.storage import Storage
 from predictionio_tpu.data.storage.base import EngineInstance, EvaluationInstance, Model
+from predictionio_tpu.utils import spans
 from predictionio_tpu.utils.spans import CompileLedger, span
 from predictionio_tpu.workflow.engine_json import EngineVariant
 
@@ -66,6 +67,33 @@ def _params_json(ep: EngineParams) -> dict[str, str]:
     }
 
 
+def _phase_cpu(timings: dict) -> dict:
+    """``{phase: CPU seconds}`` for the phases of ``timings``, from the
+    spans closed on this thread's collector (taken here): the one place
+    where a phase's span becomes its CPU time. Empty where the thread has
+    no collector that takes CPU time. What the caller measured itself
+    (``timings["cpu"]``: `pio train`'s ``startup``) is kept."""
+    collector = spans.current()
+    if collector is None or not collector.cpu:
+        return {}
+    records = collector.take()
+    by_span = spans.cpu_ms(records)
+    # Engine.train's algorithms share one span name, in the order of
+    # their keys
+    algorithms = iter(r.cpu_ns / 1e6 for r in records if r.name == "train.algorithm")
+    cpu = dict(timings.get("cpu") or {})
+    for phase in timings:
+        ms = (
+            next(algorithms, None) if phase.startswith("train:")
+            else by_span.get("train." + phase)
+        )
+        if ms is not None:
+            cpu[phase] = round(ms / 1e3, 3)
+    if "serialize" in cpu and "blob_write" in cpu:
+        cpu["publish"] = round(cpu["serialize"] + cpu["blob_write"], 3)
+    return cpu
+
+
 def run_train(
     variant: EngineVariant,
     ctx: WorkflowContext,
@@ -86,7 +114,10 @@ def run_train(
     caller measured before this call (``phase_timings``: `pio train`
     passes ``startup`` and ``backend_init``), ``read``, ``prepare`` and
     ``train:<algorithm>`` from ``Engine.train``, and ``publish`` with its
-    parts ``serialize`` and ``blob_write``; ``env["kernels"]["compile"]``
+    parts ``serialize`` and ``blob_write``; under ``cpu`` the calling
+    thread's CPU seconds in each of them, where the thread's collector
+    takes CPU time (`pio train`'s does: a phase whose CPU is far under
+    its wall waited, one near it computed); ``env["kernels"]["compile"]``
     is the compile ledger's table for this job (``utils/spans.py``).
     """
     ledger = CompileLedger.install()
@@ -221,6 +252,9 @@ def run_train(
                 serialize.seconds + blob_write.seconds, 3
             )
             logger.info("Saved model blob for instance %s (%d bytes)", instance.id, len(blob))
+        cpu = _phase_cpu(timings)
+        if cpu:
+            timings["cpu"] = cpu
         # what this job traced, lowered and compiled (or loaded from the
         # persistent cache), per jitted function
         ctx.run_info["compile"] = ledger.table(since=compiled_before)

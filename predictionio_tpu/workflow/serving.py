@@ -40,7 +40,7 @@ from predictionio_tpu.serving.cache import (
     canonical_key,
     extract_scope,
 )
-from predictionio_tpu.api.stats import HttpStats
+from predictionio_tpu.api.stats import HttpStats, LockStats
 from predictionio_tpu.templates.retrieval import serving_state
 from predictionio_tpu.utils.spans import CompileLedger, durations_ms, span
 from predictionio_tpu.workflow.engine_json import EngineVariant
@@ -213,6 +213,9 @@ class QueryService:
         self._compiles = CompileLedger.install()
         #: what the HTTP threads spend around dispatch() (`http` block)
         self._http_stats = HttpStats()
+        #: the interpreter lock as the beat thread sees it (`lock` block);
+        #: the thread starts at the boot mark
+        self._lock_stats = LockStats()
         self._cache_stats: CacheStats | None = None
         self._result_cache: ResultCache | None = None
         self._singleflight: Singleflight | None = None
@@ -303,8 +306,33 @@ class QueryService:
         # loaded) its programs as BOOT work, so the mark reload() left
         # moves here; every later reload() marks at its own end
         self._compiles.mark_boot_complete()
+        self._beat = self._start_beat()
         for p in self.plugins:
             p.start(self)
+
+    def _start_beat(self):
+        """The deploy's one beat thread (``serving/lockbeat.py``): it
+        times the interpreter lock and catches the host standing still.
+        It holds the stats, not the service, and ends with it (or once
+        nobody holds the service any more)."""
+        import os
+
+        from predictionio_tpu.serving.lockbeat import LockBeat
+
+        http_stats = self._http_stats
+        batcher_stats = self.batcher.stats if self.batcher is not None else None
+
+        def cpu_total_ns() -> int:
+            workers = batcher_stats.cpu_ns_workers if batcher_stats else 0
+            return workers + http_stats.cpu_ns_riders
+
+        return LockBeat(
+            self._lock_stats, cpu_total_ns,
+            os.path.join(
+                Storage.base_dir(), "deployments", f"stalls-{os.getpid()}.txt"
+            ),
+            owner=self,
+        ).start()
 
     def _feedback_worker(self) -> None:
         assert self._feedback_queue is not None
@@ -1048,6 +1076,20 @@ class QueryService:
             # what the HTTP threads spend reading a request and writing
             # its answer, around dispatch()
             "http": self._http_stats.to_json(),
+            # the interpreter lock from outside the request path: how
+            # late a sleeping thread runs, the share of wall the request
+            # path's threads were on a CPU (their CPU time since boot
+            # beside it), and the times the host stood still
+            "lock": {
+                **self._lock_stats.to_json(),
+                "cpuNs": {
+                    "workers": (
+                        self.batcher.stats.cpu_ns_workers
+                        if self.batcher is not None else 0
+                    ),
+                    "riders": self._http_stats.cpu_ns_riders,
+                },
+            },
         }
         if self.feedback is not None:
             out["feedback"] = feedback_counts
@@ -1121,12 +1163,22 @@ class QueryService:
             }
         return out
 
-    def record_http(self, records: Sequence) -> None:
+    def record_http(
+        self, records: Sequence, counts: Mapping[str, int] | None = None
+    ) -> None:
         """The HTTP wrapper's hook (``api/http.py`` finds it by name):
-        the spans one request closed on its HTTP thread."""
+        the spans one request closed on its HTTP thread and, where a
+        group of that thread's riders is full, the group's counts: the
+        wrapper's and the batcher's ``rider.*``."""
         ms = durations_ms(records)
         if "httpRead" in ms and "httpWrite" in ms:
             self._http_stats.record(ms["httpRead"], ms["httpWrite"])
+        if counts and counts.get("rider.requests"):
+            self._http_stats.record_riders(
+                counts["rider.requests"], counts.get("rider.requestNs", 0),
+                counts["rider.cpuNs"], counts["rider.queuedNs"],
+                counts["rider.giveWayNs"],
+            )
 
     def readiness(self) -> dict:
         """``GET /readyz`` (served by the HTTP wrapper): storage
@@ -1155,8 +1207,8 @@ class QueryService:
         return report
 
     def close(self) -> None:
-        """Release background resources (the batcher's dispatcher thread
-        and the online follower/trainer threads) and run the ``on_close``
+        """Release background resources (the beat thread, the batcher's
+        dispatcher thread and the online follower/trainer threads) and run the ``on_close``
         callbacks (e.g. the endpoint-registry withdraw the console wires
         under ``--announce-dir``, so a draining replica leaves the ring
         cleanly instead of waiting out its lease). Safe to call more
@@ -1172,6 +1224,7 @@ class QueryService:
             self.online = None
         if self.batcher is not None:
             self.batcher.close()
+        self._beat.stop()
 
     def drain(self) -> None:
         """Graceful-shutdown hook, auto-discovered by the HTTP wrapper
@@ -1367,6 +1420,8 @@ class QueryService:
                 return Response(
                     501, {"message": "This deployment has no stop hook."}
                 )
+            # the process goes: no traceback dump of its way out
+            self._beat.stop()
             return Response(
                 200, {"message": "Shutting down."},
                 after_send=self.stop_server,

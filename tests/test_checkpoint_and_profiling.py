@@ -83,6 +83,41 @@ class TestALSCheckpointing:
 
 
 class TestPhaseTimings:
+    def test_every_leaf_of_an_als_train_lies_in_a_named_phase(self):
+        """The main thread's leaves from the upload to the host copy: no
+        eager program of the job (the two slices that strip the sentinel
+        rows were the last without) runs outside a span, and the readback's
+        seconds hold both of its spans."""
+        from predictionio_tpu.ops.als import factors_to_host
+        from predictionio_tpu.utils import spans
+
+        rows, cols, vals = synthetic()
+        info = {}
+        collector = spans.Collector(cpu=True)
+        unbound = spans.bind(collector)
+        try:
+            factors = train_als(rows, cols, vals, 40, 30,
+                                ALSConfig(rank=4, iterations=2, seed=1), info=info)
+            stripped = info["readbackSeconds"]
+            user, item = factors_to_host(info, factors.user, factors.item)
+        finally:
+            spans.bind(unbound)
+        assert user.shape == (40, 4) and item.shape == (30, 4)
+        records = collector.take()
+        names = [r.name for r in records]
+        assert names[-4:] == ["train.sweep", "train.sweep", "train.readback",
+                              "train.readback"]
+        assert set(names[:-4]) == {"train.transfer", "train.bucketing", "train.init"}
+        # each phase begins where the one before it ended: a few lines of
+        # Python between them, never a program
+        for before, after in zip(records, records[1:]):
+            assert 0 <= after.start_ns - before.end_ns < 500_000_000, (
+                before.name, after.name)
+        assert info["readbackSeconds"] >= stripped >= 0
+        assert info["readbackSeconds"] == pytest.approx(
+            spans.durations_ms(records)["train.readback"] / 1e3, abs=0.002)
+        assert len(info["sweepCpuSeconds"]) == len(info["sweepSeconds"]) == 2
+
     def test_engine_instance_records_phase_timings(self, memory_storage_env):
         variant = load_engine_variant({
             "id": "fake-engine", "version": "0.1",
@@ -101,6 +136,46 @@ class TestPhaseTimings:
         instance = run_train(
             variant, local_context(), phase_timings={"startup": 1.5})
         assert json.loads(instance.env["phase_timings"])["startup"] == 1.5
+
+    def test_a_thread_whose_collector_takes_cpu_gets_every_phases_cpu(
+            self, memory_storage_env):
+        """``phase_timings.cpu``: made in one place from the collector's
+        records, for every phase the instance holds; what the caller
+        measured (`pio train`: ``startup``) rides along; no collector that
+        takes CPU time, no ``cpu``."""
+        from predictionio_tpu.utils import spans
+
+        variant = load_engine_variant({
+            "id": "fake-engine", "version": "0.1",
+            "engineFactory": "fake_dase:engine0",
+            "datasource": {"params": {"base": 10}},
+            "algorithms": [{"name": "a0", "params": {"mult": 2}},
+                           {"name": "a1", "params": {"mult": 3}}],
+        })
+        unbound = spans.bind(spans.Collector(cpu=True))
+        try:
+            instance = run_train(
+                variant, local_context(),
+                phase_timings={"startup": 1.5, "cpu": {"startup": 0.5}})
+        finally:
+            spans.bind(unbound)
+        timings = json.loads(instance.env["phase_timings"])
+        cpu = timings.pop("cpu")
+        assert set(cpu) == set(timings) == {
+            "startup", "read", "prepare", "train:a0", "train:a1",
+            "serialize", "blob_write", "publish"}
+        assert cpu["startup"] == 0.5
+        # a thread's CPU time in a span lies inside the span's wall
+        assert all(0 <= cpu[k] <= timings[k] + 0.002 for k in cpu), (cpu, timings)
+        assert cpu["publish"] == pytest.approx(
+            cpu["serialize"] + cpu["blob_write"], abs=0.002)
+        for collector in (None, spans.Collector()):
+            unbound = spans.bind(collector)
+            try:
+                instance = run_train(variant, local_context())
+            finally:
+                spans.bind(unbound)
+            assert "cpu" not in json.loads(instance.env["phase_timings"])
 
     def test_a_toy_pio_train_instance_holds_the_job_by_phase(
         self, memory_storage_env, tmp_path
@@ -138,13 +213,22 @@ class TestPhaseTimings:
         inst = memory_storage_env.get_meta_data_engine_instances(
         ).get_latest_completed("span-engine", "1", "span-engine")
         timings = json.loads(inst.env["phase_timings"])
-        assert set(timings) == {
+        cpu = timings.pop("cpu")
+        assert set(cpu) == set(timings) == {
             "startup", "backend_init", "read", "prepare", "train:als",
             "serialize", "blob_write", "publish"}
         assert timings["startup"] > 0  # this process is older than that
+        assert cpu["startup"] > 0  # the process's CPU seconds by then
+        assert all(0 <= cpu[k] <= timings[k] + 0.002 for k in cpu
+                   if k != "startup"), (cpu, timings)
         kernels = json.loads(inst.env["kernels"])
         als = kernels["als"]
         assert len(als["sweepSeconds"]) == 3  # one span a sweep, as before
+        assert len(als["sweepCpuSeconds"]) == 3
+        assert all(0 <= c <= s + 0.002 for c, s in zip(
+            als["sweepCpuSeconds"], als["sweepSeconds"]))
+        # the first sweep traces and lowers on this thread: it computes
+        assert als["sweepCpuSeconds"][0] > 0
         # bucketingSeconds keeps its meaning: transfer, sort and fill
         assert als["bucketingSeconds"] >= als["transferSeconds"] >= 0
         assert als["readbackSeconds"] >= 0 and als["initSeconds"] >= 0

@@ -68,6 +68,18 @@ def _phased_batch(bodies):
     return _echo_batch(bodies)
 
 
+class _KeptStats(ServingStats):
+    """Keeps the keywords of every ``record_batch`` in ``records``."""
+
+    def __init__(self, records: list):
+        super().__init__()
+        self._records = records
+
+    def record_batch(self, **record):
+        self._records.append(record)
+        super().record_batch(**record)
+
+
 def _wait_until(condition, timeout=10.0):
     give_up = time.monotonic() + timeout
     while not condition():
@@ -664,6 +676,7 @@ class TestTwoBatchesInFlight:
             _join(threads)
             _wait_until(lambda: b.stats.batches == 4)
             assert b._shut_until > time.monotonic()
+            assert b.stats.to_json()["rest"] == {"secondBatch": 1, "claims": 0}
             threads = _riders(b, [4, 5])
             time.sleep(0.15)
             assert len(entered) == 5 and b._queue.qsize() == 1  # one at a time
@@ -673,6 +686,8 @@ class TestTwoBatchesInFlight:
                 gate.set()
             _join(threads)
             assert b.stats.to_json()["overlap"]["overlapped"] == 0
+            # counted once a rest, not once a batch that found it on
+            assert b.stats.to_json()["rest"]["secondBatch"] == 1
         finally:
             for gate in gates:
                 gate.set()
@@ -695,6 +710,7 @@ class TestTwoBatchesInFlight:
             _wait_until(lambda: b.stats.batches == 6)
             assert b.stats.to_json()["overlap"]["overlapped"] == 5
             assert b._alone_run == 0 and b._shut_until == 0.0
+            assert b.stats.to_json()["rest"] == {"secondBatch": 0, "claims": 0}
         finally:
             device.open()
             b.close()
@@ -850,7 +866,8 @@ class TestDispatcherSpans:
         finally:
             b.close()
         assert set(ms) == set(BATCH_PHASES) | {
-            "queueWait", "handle", "total", "wake", "hostGap"}
+            "queueWait", "handle", "total", "wake", "giveWay", "hostGap",
+            "hostCpu", "hostWait"}
         inner = _INNER_PHASES
         for name in ("take", "drain", "batchForm", "wake", *inner):
             assert ms[name]["p50"] is not None, name
@@ -859,6 +876,108 @@ class TestDispatcherSpans:
         assert sum(ms[n]["p50"] for n in inner) <= ms["handle"]["p50"] + 0.005
         # release is the PREVIOUS batch's, recorded with the next cycle
         assert ms["release"]["p50"] is None
+
+    def test_host_cpu_and_wait_add_up_to_the_host_half_of_the_same_batch(
+            self, monkeypatch):
+        """Per batch ``hostCpu + hostWait`` is the wall of the cycle's
+        phases that do not wait by design; a phase that sleeps is wait, a
+        phase that spins is CPU, and ``cpuMs`` says which was which."""
+        from predictionio_tpu.api.stats import HOST_HALF_PHASES
+        from predictionio_tpu.serving import batcher
+
+        monkeypatch.setattr(batcher, "_CPU_EVERY", 1)  # every cycle takes it
+
+        def handler(bodies):
+            with span("bind"):  # on the CPU: the thread's clock moves
+                t0 = time.thread_time_ns()
+                while time.thread_time_ns() - t0 < 5_000_000:
+                    pass
+            with span("filterLookup"):  # off it: a store read in the kernel
+                time.sleep(0.03)
+            with span("dispatch"):
+                pass
+            with span("deviceWait"):  # waits by design: not the host half
+                time.sleep(0.02)
+            return _echo_batch(bodies)
+
+        records = []
+        b = MicroBatcher(handler, BatcherConfig(max_batch_delay_ms=0.0),
+                         stats=_KeptStats(records))
+        try:
+            for q in range(3):
+                assert b.submit(q)[0] == 200
+            _wait_until(lambda: len(records) == 3)
+            out = b.stats.to_json()
+        finally:
+            b.close()
+        assert "deviceWait" not in HOST_HALF_PHASES
+        assert {"take", "drain"}.isdisjoint(HOST_HALF_PHASES)
+        cpus, waits = b.stats._host_cpu_ms, b.stats._host_wait_ms
+        assert len(cpus) == len(waits) == 3
+        for record, cpu, wait in zip(records, cpus, waits):
+            half = sum(record["phases"].get(n, 0.0) for n in HOST_HALF_PHASES)
+            assert cpu + wait == pytest.approx(half, abs=1e-9)
+            assert set(record["phases_cpu"]) == set(record["phases"])
+            # each phase's CPU lies inside its wall (two clocks: a hair)
+            for name, ms in record["phases_cpu"].items():
+                assert 0.0 <= ms <= record["phases"][name] + 0.5, name
+            assert record["phases_cpu"]["bind"] >= 5.0
+            assert cpu >= 5.0 and wait >= 25.0  # the sleep is all wait
+            # the wait is the store read's, and deviceWait's is nobody's
+            assert record["phases_cpu"]["filterLookup"] < 0.1 * record["phases"]["filterLookup"]
+            assert wait < record["phases"]["filterLookup"] + record["phases"]["bind"]
+        assert out["latencyMs"]["hostCpu"]["p50"] >= 5.0
+        assert out["latencyMs"]["hostWait"]["p50"] >= 25.0
+        # the mean beside the percentiles: what to read where the CPU
+        # clock ticks coarsely
+        assert out["latencyMs"]["hostCpu"]["mean"] == pytest.approx(
+            sum(cpus) / 3, abs=1e-3)
+        assert out["latencyMs"]["hostWait"]["mean"] >= 25.0
+        assert set(out["cpuMs"]) == set(BATCH_PHASES)
+        assert out["cpuMs"]["bind"]["p50"] >= 5.0 <= out["cpuMs"]["bind"]["mean"]
+        assert out["cpuMs"]["filterLookup"]["p50"] < 3.0
+        # the workers' whole CPU time since boot holds the spinning at least
+        assert b.stats.cpu_ns_workers >= 3 * 5_000_000
+
+    def test_one_cycle_in_many_takes_cpu_time(self):
+        """The CPU clock is a system call: a worker reads it around its
+        spans on its first cycle and then on one of its cycles in
+        ``_CPU_EVERY``, and adds its thread's whole CPU time since the last
+        such cycle with it."""
+        from predictionio_tpu.serving import batcher
+
+        records = []
+        b = MicroBatcher(_phased_batch, BatcherConfig(max_batch_delay_ms=0.0),
+                         stats=_KeptStats(records))
+        n = 2 * batcher._CPU_EVERY + 4
+        try:
+            for q in range(n):
+                assert b.submit(q)[0] == 200
+            _wait_until(lambda: len(records) == n)
+        finally:
+            b.close()
+        took = [r["phases_cpu"] is not None for r in records]
+        # each worker's first cycle, then the cycle after each of its
+        # batches whose count divides by _CPU_EVERY (two workers share the n)
+        assert 2 <= sum(took) <= 4, took
+        assert len(b.stats._host_cpu_ms) == len(b.stats._host_wait_ms) == sum(took)
+        for r in records:
+            if r["phases_cpu"] is not None:  # a cycle takes it whole, or not at all
+                assert set(r["phases_cpu"]) == set(r["phases"])
+        assert b.stats.cpu_ns_workers > 0
+
+    def test_give_way_is_the_part_of_wake_spent_behind_a_claiming_worker(self):
+        b = MicroBatcher(_phased_batch, BatcherConfig(max_batch_delay_ms=0.0))
+        try:
+            for q in range(4):
+                assert b.submit(q)[0] == 200
+            ms = b.stats.to_json()["latencyMs"]
+        finally:
+            b.close()
+        # nobody claimed (one of 32 is no loaded batch): nothing to give way to
+        assert ms["giveWay"]["p50"] is not None
+        assert 0.0 <= ms["giveWay"]["p99"] <= ms["wake"]["p99"]
+        assert ms["giveWay"]["p99"] < 5.0
 
     def test_host_gap_is_absent_for_the_first_batch_and_excludes_take(self):
         b = MicroBatcher(_phased_batch, BatcherConfig(max_batch_delay_ms=0.0))
@@ -1074,11 +1193,28 @@ class TestQueryServiceIntegration:
         finally:
             qs.close()
 
-    def test_http_threads_record_read_and_write_per_request(self, trained):
+    def test_http_threads_record_read_and_write_per_request(
+            self, trained, monkeypatch):
+        from predictionio_tpu.api import http
+
+        from predictionio_tpu.serving import batcher
+
+        # a group of one: the thread's CPU clock is read once a rider, and
+        # a worker's on every cycle
+        monkeypatch.setattr(http, "RIDERS_A_CPU_READ", 1)
+        monkeypatch.setattr(batcher, "_CPU_EVERY", 1)
         qs = QueryService(
             trained,
             batching=BatcherConfig(max_batch_size=4, max_batch_delay_ms=0.0),
         )
+        riders, record_http = [], qs.record_http
+
+        def keep(records, counts=None):
+            if counts:
+                riders.append(dict(counts))
+            record_http(records, counts)
+
+        qs.record_http = keep  # the wrapper finds the hook by name
         server, _ = start_background(qs.dispatch)
         port = server.server_address[1]
         try:
@@ -1099,14 +1235,107 @@ class TestQueryServiceIntegration:
                 time.sleep(0.01)
             assert http["requests"] == 6
             ms = http["latencyMs"]
-            assert set(ms) == {"httpRead", "httpWrite", "inServer"}
-            assert all(ms[k]["p50"] is not None and ms[k]["p50"] >= 0 for k in ms)
+            assert set(ms) == {"httpRead", "httpWrite", "inServer",
+                               "request", "riderCpu", "riderWait"}
+            assert all(ms[k]["p50"] is not None for k in ms)
+            # riderWait is a remainder: on an idle server it can read a
+            # hair under 0 (the CPU a thread spends going to sleep lies
+            # inside the time it was meant to wait)
+            assert all(ms[k]["p50"] >= 0 for k in ms if k != "riderWait")
+            assert ms["riderWait"]["p50"] > -0.5
             assert ms["inServer"]["p99"] >= max(
                 ms["httpRead"]["p50"], ms["httpWrite"]["p50"])
+            # a rider's request, measured: per request
+            # riderCpu + riderWait + (enqueue to released) + giveWay = request
+            stats = qs._http_stats
+            assert len(riders) == len(stats._request_ms) == 6
+            for counts, request, cpu, wait in zip(
+                    riders, stats._request_ms, stats._rider_cpu_ms,
+                    stats._rider_wait_ms):
+                assert counts["rider.requests"] == 1
+                assert request == counts["rider.requestNs"] / 1e6
+                queued = counts["rider.queuedNs"] / 1e6
+                gave_way = counts["rider.giveWayNs"] / 1e6
+                assert cpu + wait + queued + gave_way == pytest.approx(
+                    request, abs=1e-9)
+                # the whole stretch holds its parts, in their order of size
+                assert 0 < cpu <= request and 0 < queued < request
+                assert 0 <= gave_way < request
+                assert wait > -0.5  # two clocks: a hair under 0 at most
+            assert stats.cpu_ns_riders == sum(c["rider.cpuNs"] for c in riders)
+            assert ms["riderCpu"]["mean"] == pytest.approx(
+                stats.cpu_ns_riders / 6e6, abs=1e-3)
+            lock = qs.stats_json()["lock"]
+            assert lock["cpuNs"]["riders"] == stats.cpu_ns_riders
+            assert lock["cpuNs"]["workers"] == qs.batcher.stats.cpu_ns_workers > 0
+            # the request's stretch holds the two spans inside it
+            assert ms["request"]["p50"] >= ms["inServer"]["p50"]
+            assert qs.batcher.stats.to_json()["latencyMs"]["giveWay"]["p50"] is not None
+            # a request that rode in no batch records no rider
+            with urllib.request.urlopen(
+                    f"http://127.0.0.1:{port}/stats.json", timeout=10) as r:
+                assert "lock" in json.loads(r.read())
+            time.sleep(0.05)
+            assert len(stats._request_ms) == 6
         finally:
             server.shutdown()
             server.server_close()
             qs.close()
+
+    def test_riders_are_accounted_a_group_of_one_threads_requests_at_a_time(
+            self, trained):
+        """One read of an HTTP thread's CPU clock a group of its riders:
+        a keep-alive connection's 32nd rider closes a group, the
+        connection's end closes what is left, and the identity holds of
+        each group's sums. Every rider is in exactly one group."""
+        import http.client
+
+        from predictionio_tpu.api.http import RIDERS_A_CPU_READ
+
+        qs = QueryService(
+            trained,
+            batching=BatcherConfig(max_batch_size=4, max_batch_delay_ms=0.0),
+        )
+        groups, record_http = [], qs.record_http
+
+        def keep(records, counts=None):
+            if counts:
+                groups.append(dict(counts))
+            record_http(records, counts)
+
+        qs.record_http = keep
+        server, _ = start_background(qs.dispatch)
+        n = RIDERS_A_CPU_READ + 5
+        try:
+            conn = http.client.HTTPConnection(
+                "127.0.0.1", server.server_address[1], timeout=10)
+            for q in range(n):
+                conn.request("POST", "/queries.json", body=json.dumps(q),
+                             headers={"Content-Type": "application/json"})
+                assert json.loads(conn.getresponse().read()) == 2 * q + 55
+                if q == RIDERS_A_CPU_READ - 2:
+                    # a request that rides in no batch joins no group
+                    conn.request("GET", "/stats.json")
+                    assert "lock" in json.loads(conn.getresponse().read())
+            _wait_until(lambda: len(groups) == 1)
+            conn.close()  # the thread's finish() hands over the rest
+            _wait_until(lambda: len(groups) == 2)
+        finally:
+            server.shutdown()
+            server.server_close()
+            qs.close()
+        assert [g["rider.requests"] for g in groups] == [RIDERS_A_CPU_READ, 5]
+        stats = qs._http_stats
+        for g, request, cpu, wait in zip(
+                groups, stats._request_ms, stats._rider_cpu_ms, stats._rider_wait_ms):
+            k = g["rider.requests"]
+            assert request == pytest.approx(g["rider.requestNs"] / 1e6 / k)
+            assert cpu == pytest.approx(g["rider.cpuNs"] / 1e6 / k)
+            assert (cpu + wait + (g["rider.queuedNs"] + g["rider.giveWayNs"]) / 1e6 / k
+                    == pytest.approx(request, abs=1e-9))
+            assert 0 < g["rider.cpuNs"] and 0 < g["rider.queuedNs"] < g["rider.requestNs"]
+        assert stats.cpu_ns_riders == sum(g["rider.cpuNs"] for g in groups)
+        assert qs.batcher.stats.completed == n
 
     def test_http_429_carries_retry_after_header(self, trained):
         qs = QueryService(
@@ -1344,7 +1573,10 @@ class TestALoadedBatchsWorkerGoesFirst:
             time.sleep(0.05)
             device.host.set()
             _join(first)
-            assert b.stats.to_json()["latencyMs"]["wake"]["p50"] >= 50.0
+            ms = b.stats.to_json()["latencyMs"]
+            assert ms["wake"]["p50"] >= 50.0
+            # the wait was behind the other batch's worker, and is in both
+            assert 45.0 <= ms["giveWay"]["p50"] <= ms["wake"]["p50"]
         finally:
             device.open()
             b.close()
@@ -1368,6 +1600,7 @@ class TestALoadedBatchsWorkerGoesFirst:
             assert b._no_claim_until > time.monotonic() + 0.5 * batcher._REST_S
             assert (b._claims, b._short_after) == (0, 0) and not b._claimed_last
             assert b.submit(5)[0] == 200 and not b._claimed_last  # at rest
+            assert b.stats.to_json()["rest"] == {"secondBatch": 0, "claims": 1}
         finally:
             b.close()
         full = MicroBatcher(_phased_batch, BatcherConfig(max_batch_size=1, max_batch_delay_ms=0.0))
@@ -1376,5 +1609,6 @@ class TestALoadedBatchsWorkerGoesFirst:
                 assert full.submit(q)[0] == 200
             assert full._no_claim_until == 0.0 and full._claimed_last
             assert full._short_after == 0
+            assert full.stats.to_json()["rest"]["claims"] == 0
         finally:
             full.close()

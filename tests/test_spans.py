@@ -35,7 +35,7 @@ class TestSpan:
         with span("bind") as s:
             pass
         (record,) = collector.take()
-        assert record == ("bind", None, 7, s.start_ns, s.end_ns)
+        assert record == ("bind", None, 7, s.start_ns, s.end_ns, 0)
         assert collector.take() == []  # take empties it
 
     def test_nested_spans_know_their_parent(self, collector):
@@ -97,11 +97,105 @@ class TestSpan:
         ]
         assert spans.durations_ms(records) == {"format": 3.0, "bind": 0.5}
 
+    def test_cpu_sums_the_spans_of_one_name(self):
+        records = [
+            spans.SpanRecord("format", None, 1, 0, 2_000_000, 1_500_000),
+            spans.SpanRecord("format", None, 1, 5_000_000, 6_000_000, 500_000),
+            spans.SpanRecord("bind", None, 1, 2_000_000, 2_500_000),  # none taken
+        ]
+        assert spans.cpu_ms(records) == {"format": 2.0, "bind": 0.0}
+
     def test_process_age_is_the_kernels_record(self):
         age = spans.process_age_s()
         assert age is not None and 0 < age < 24 * 3600
         time.sleep(0.05)
         assert spans.process_age_s() > age
+
+
+class TestSpanCpu:
+    """A span on a collector made with ``cpu`` reads its thread's CPU
+    clock beside the wall (ISSUE 37). Orderings, not times: the suite
+    shares its machine."""
+
+    @pytest.fixture()
+    def cpu_collector(self):
+        c = Collector(cpu=True)
+        previous = spans.bind(c)
+        yield c
+        spans.bind(previous)
+
+    def test_a_span_that_spins_reads_its_cpu_and_no_more_than_its_wall(
+            self, cpu_collector):
+        with span("spin") as s:
+            t0 = time.thread_time_ns()
+            while time.thread_time_ns() - t0 < 20_000_000:
+                pass
+        (record,) = cpu_collector.take()
+        assert record.cpu_ns == s.cpu_ns >= 20_000_000
+        # the CPU clock is read inside the wall clock's two reads
+        assert s.cpu_ns <= s.ns
+        assert s.cpu_ms == s.cpu_ns / 1e6 and s.cpu_seconds == s.cpu_ns / 1e9
+        assert spans.cpu_ms([record]) == {"spin": s.cpu_ms}
+
+    def test_a_span_that_sleeps_reads_next_to_none(self, cpu_collector):
+        with span("sleep") as s:
+            time.sleep(0.05)
+        (record,) = cpu_collector.take()
+        assert s.ns >= 50_000_000
+        assert 0 <= record.cpu_ns < 0.1 * s.ns
+
+    def test_a_thread_that_waits_for_the_interpreter_lock_accrues_none(self):
+        """Two threads spinning in Python share one lock: each is on the
+        CPU for part of its wall only, and wall less CPU is the wait."""
+        out = {}
+
+        def spin(name):
+            spans.bind(Collector(cpu=True))
+            with span(name) as s:
+                end = time.perf_counter_ns() + 200_000_000
+                while time.perf_counter_ns() < end:
+                    pass
+            out[name] = s
+
+        threads = [threading.Thread(target=spin, args=(n,)) for n in "ab"]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        a, b = out["a"], out["b"]
+        assert a.cpu_ns <= a.ns and b.cpu_ns <= b.ns
+        # while both ran, one of them at a time held the lock: together
+        # they cannot have been on the CPU for more than the wall of the
+        # two, end to end, plus what either ran alone
+        overlap = min(a.end_ns, b.end_ns) - max(a.start_ns, b.start_ns)
+        assert overlap > 100_000_000
+        alone = (a.ns - overlap) + (b.ns - overlap)
+        assert a.cpu_ns + b.cpu_ns <= overlap + alone + 20_000_000
+
+    def test_enclosing_spans_hold_their_inner_spans_cpu(self, cpu_collector):
+        with span("handle", enclosing=True) as outer:
+            with span("bind") as inner:
+                t0 = time.thread_time_ns()
+                while time.thread_time_ns() - t0 < 5_000_000:
+                    pass
+        assert 5_000_000 <= inner.cpu_ns <= outer.cpu_ns <= outer.ns
+
+    def test_without_cpu_a_span_reads_zero_and_never_calls_the_thread_clock(
+            self, collector, monkeypatch):
+        def no_call():
+            raise AssertionError("a span read the thread's CPU clock")
+
+        monkeypatch.setattr(time, "thread_time_ns", no_call)
+        with span("bind") as s:
+            pass
+        t = span("two-ends").start()
+        t.stop()
+        assert s.cpu_ns == 0 and s.cpu_ms == 0.0
+        assert [r.cpu_ns for r in collector.take()] == [0, 0]
+        spans.bind(None)
+        with span("nobody") as s:  # no collector at all
+            pass
+        assert s.cpu_ns == 0
 
 
 class TestCompileLedger:

@@ -168,3 +168,35 @@ def test_a_train_records_what_the_reference_replays(fused_ce):
     assert info["stepMs"] > 0 and isinstance(info["epochProgram"], dict)
     norms = np.linalg.norm(model.user_vecs, axis=1)
     assert np.abs(norms - 1).max() < 1e-5
+
+
+def test_seeding_and_packing_are_one_named_phase_around_the_upload():
+    """Span ``train.init`` twice (seeding the tables and padding the pairs,
+    then packing the tables), ``train.ingest`` between them: every leaf of
+    the job from the first key to the first epoch has a name, and
+    ``initSeconds`` is the two together."""
+    from predictionio_tpu.utils import spans
+
+    rows, cols = _pairs()
+    info = {}
+    collector = spans.Collector(cpu=True)
+    unbound = spans.bind(collector)
+    try:
+        train_two_tower(
+            rows, cols, USERS, ITEMS,
+            TwoTowerConfig(dim=DIM, batch_size=BATCH, epochs=1, learning_rate=LR,
+                           temperature=TEMP, seed=SEED, fused_ce="off"), info=info)
+    finally:
+        spans.bind(unbound)
+    records = collector.take()
+    assert [r.name for r in records] == [
+        "train.init", "train.ingest", "train.init", "train.epoch", "train.finalize"]
+    assert all(r.parent is None for r in records)  # leaves: each a pio.* event
+    assert info["initSeconds"] == pytest.approx(
+        spans.durations_ms(records)["train.init"] / 1e3, abs=0.002)
+    assert info["initSeconds"] > 0
+    # one phase ends where the next begins: nothing of the host's between
+    # seeding and the upload, little between the upload and the packing
+    seeding, ingest = records[0], records[1]
+    assert 0 <= ingest.start_ns - seeding.end_ns < 500_000_000
+    assert all(0 <= r.cpu_ns <= r.end_ns - r.start_ns for r in records)
