@@ -122,9 +122,11 @@ HOST_HALF_PHASES = tuple(
 
 #: what a handler that filters its answers counts on the dispatcher's
 #: collector as ``filter.<name>`` (templates/retrieval.py
-#: ``FilteredItemRetrieval`` and the two engines that take it)
+#: ``FilteredItemRetrieval`` and the two engines that take it), and what
+#: the event store counts there of a batch's read of its users' seen items
+#: (``LEvents.targets_by_entities``: from its columns, or through events)
 FILTER_COUNTS = ("excludedIds", "excludedPairs", "categoryRows", "hostPath",
-                 "shortAnswers")
+                 "shortAnswers", "columnReads", "eventReads")
 
 #: what the similar-product engine counts as ``similar.<name>``
 #: (templates/similarproduct/engine.py)
@@ -228,7 +230,9 @@ class ServingStats:
     program, ``ops.als.tile_pairs``), ``categoryRows`` (rows that named a category),
     ``hostPath`` (queries answered by the host ``predict``: a white list
     or an unknown user), ``shortAnswers`` (rows the rules left fewer than
-    ``num`` items). ``similar`` counts the similar-product engine's query
+    ``num`` items), ``columnReads`` and ``eventReads`` (batches whose read
+    of their users' seen items the event store answered from its columns,
+    and through ``Event`` objects). ``similar`` counts the similar-product engine's query
     items: ``queryItems`` (all that its queries named) and
     ``unknownItems`` (those of them the model does not hold, dropped).
 
@@ -557,11 +561,12 @@ class LockStats:
       100); the time arrives a group of requests or of cycles at a time,
       so read its ``mean``;
     * ``stalls`` — beats late by over half a second: their ``count``, the
-      ``longestMs``, and of the ``last`` one ``at`` (UTC), ``lateMs``,
-      ``cpuMs`` (the process's CPU time over it) and ``dump`` (the file
-      that holds every thread's traceback, written by ``faulthandler``'s
-      own thread while the interpreter stood; None where none was
-      written).
+      ``longestMs``, the running sums ``lateMsTotal`` and ``cpuMsTotal``
+      (the process's CPU time over the stalls), and of the ``last`` one
+      ``at`` (UTC), ``lateMs`` and ``cpuMs``. CPU a small share of the
+      lateness: the machine stood still; CPU near the lateness: a thread
+      held the lock and worked. No traceback is taken (``lockbeat.py``
+      says why).
 
     Windows keep the last :attr:`ServingStats.WINDOW` samples."""
 
@@ -572,6 +577,8 @@ class LockStats:
         self._busy_pct: deque = deque(maxlen=n)
         self.stalls = 0
         self.longest_ms = 0.0
+        self.late_ms_total = 0.0
+        self.cpu_ms_total = 0.0
         self.last_stall: dict | None = None
 
     def record_beat(self, late_ms: float, busy_pct: float | None) -> None:
@@ -580,16 +587,16 @@ class LockStats:
             if busy_pct is not None:
                 self._busy_pct.append(busy_pct)
 
-    def record_stall(self, late_ms: float, cpu_ms: float,
-                     dump: str | None) -> None:
+    def record_stall(self, late_ms: float, cpu_ms: float) -> None:
         with self._lock:
             self.stalls += 1
             self.longest_ms = max(self.longest_ms, late_ms)
+            self.late_ms_total += late_ms
+            self.cpu_ms_total += cpu_ms
             self.last_stall = {
                 "at": _dt.datetime.now(_dt.timezone.utc).isoformat(),
                 "lateMs": round(late_ms, 3),
                 "cpuMs": round(cpu_ms, 3),
-                "dump": dump,
             }
 
     def to_json(self) -> dict:
@@ -600,6 +607,8 @@ class LockStats:
                 "stalls": {
                     "count": self.stalls,
                     "longestMs": round(self.longest_ms, 3),
+                    "lateMsTotal": round(self.late_ms_total, 3),
+                    "cpuMsTotal": round(self.cpu_ms_total, 3),
                     "last": dict(self.last_stall) if self.last_stall else None,
                 },
             }

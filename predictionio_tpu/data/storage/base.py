@@ -24,6 +24,7 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Iterable, Iterator, Sequence
 
 from predictionio_tpu.data.event import Event
+from predictionio_tpu.utils.spans import count
 
 __all__ = [
     "StorageError",
@@ -409,6 +410,33 @@ class LEvents(abc.ABC):
                 event_names=event_names,
             ))
             for etype, eid in dict.fromkeys(entities)
+        }
+
+    def targets_by_entities(
+        self, app_id: int, entity_type: str, entity_ids: Sequence[str],
+        channel_id: int | None = None,
+        event_names: Sequence[str] | None = None,
+    ) -> dict[str, list[str]]:
+        """For each of ``entity_ids`` (of one ``entity_type``; every id a
+        key) the target entity ids of its events named in ``event_names``
+        (``None``: any), as the store holds them at the call, in no order:
+        what a served batch that filters by "already seen" asks. An event
+        with no target gives none. The base reduces
+        :meth:`find_by_entities`' events; a driver that keeps columns
+        answers from them (``columnar``). Whichever answered says so on the
+        caller's span collector, if it has one: ``filter.eventReads`` here,
+        ``filter.columnReads`` there."""
+        found = self.find_by_entities(
+            app_id, [(entity_type, eid) for eid in entity_ids], channel_id,
+            event_names=event_names,
+        )
+        count("filter.eventReads", 1)
+        return {
+            eid: [
+                e.target_entity_id for e in events
+                if e.target_entity_id is not None
+            ]
+            for (_, eid), events in found.items()
         }
 
     def close(self) -> None:  # optional resource hook

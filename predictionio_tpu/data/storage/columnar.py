@@ -68,6 +68,7 @@ from predictionio_tpu.data.storage.base import (
     StorageClientConfig,
     StorageError,
 )
+from predictionio_tpu.utils.spans import count
 
 __all__ = ["StorageClient"]
 
@@ -143,6 +144,14 @@ def _codes_of(vocab: np.ndarray, values: Iterable[str]) -> np.ndarray:
     return at[vocab[at] == values]
 
 
+def _among(vocab: np.ndarray, values: Iterable[str]) -> np.ndarray:
+    """A table over ``vocab``: which codes are one of ``values`` (one
+    gather through it, however many are asked for)."""
+    table = np.zeros(vocab.size, dtype=bool)
+    table[_codes_of(vocab, values)] = True
+    return table
+
+
 @dataclasses.dataclass
 class _Segment:
     """Loaded segment columns (decoded lazily from one ``seg-*.npz``)."""
@@ -189,7 +198,9 @@ class _Segment:
             order = np.argsort(self.eid_code, kind="stable")
             self._by_entity = (order, self.eid_code[order])
         order, codes = self._by_entity
-        want = _codes_of(self.eid_vocab, entity_ids)
+        # the needle in the haystack's own dtype: a wider one makes numpy
+        # widen the whole column first, on every search
+        want = _codes_of(self.eid_vocab, entity_ids).astype(codes.dtype)
         lo = np.searchsorted(codes, want, side="left")
         hi = np.searchsorted(codes, want, side="right")
         return np.sort(np.concatenate(
@@ -288,6 +299,10 @@ class _ColumnarEvents(LEvents):
     #: latency. DEDUP_WARM_BYTES in the source config overrides.
     _DEDUP_WARM_BYTES = 64 * 1024 * 1024
 
+    #: what was read of a tail or tombstone file up to this size is kept
+    #: between two snapshots while the file stands (:meth:`_file_as`)
+    _KEPT_FILE_BYTES = 4 * 1024 * 1024
+
     def __init__(self, base: str, segment_rows: int, fsync: bool,
                  cache_segments: int | None = None,
                  dedup_window: int | None = None,
@@ -330,9 +345,12 @@ class _ColumnarEvents(LEvents):
             self._CACHE_SEGMENTS if cache_segments is None else cache_segments
         )
         self._seg_seq = 0
-        #: (raw tail lines, the events they decode to) of the last
-        #: :meth:`find`; events are immutable, so readers share them
+        #: (raw tail lines, the events they decode to) of the last read
+        #: by :meth:`_parsed_tail`
         self._tail_parsed: tuple[list, list] | None = None
+        #: tail / tombstone path -> (what the file was, what was read of
+        #: it) of the last :meth:`_snapshot` that read it: :meth:`_file_as`
+        self._kept_files: dict[str, tuple[tuple, Any]] = {}
 
     # ---------------------------------------------------------- paths
     def _stream_dir(self, app_id: int, channel_id: int | None) -> str:
@@ -611,21 +629,73 @@ class _ColumnarEvents(LEvents):
         lock-free reader interleaving the two reads would either lose
         the moved events or count them twice. ``count_tail_only``
         returns an int line count instead of the lines — scan_state on a
-        large uncompacted tail must not materialize it."""
+        large uncompacted tail must not materialize it.
+
+        A serving-time reader comes back every few milliseconds to files
+        that have not moved, and on a loaded host every file call costs:
+        one directory listing says which segments there are and whether a
+        compaction waits to be finished, and the tail's and the
+        tombstones' contents are read again only when the file is another
+        (:meth:`_file_as`). Nothing is kept by the clock."""
         with self._lock:
-            self._recover(d)
-            seg_paths = self._segment_paths(d)
+            names = self._listdir(d)
+            if "compact.commit" in names:
+                self._recover(d)
+                names = self._listdir(d)
+            seg_paths = sorted(
+                os.path.join(d, f) for f in names
+                if f.startswith("seg-") and f.endswith(".npz")
+            )
             lines: Any = 0 if count_tail_only else []
-            try:
-                with open(os.path.join(d, "tail.jsonl")) as f:
-                    if count_tail_only:
+            if "tail.jsonl" in names:
+                if count_tail_only:
+                    with open(os.path.join(d, "tail.jsonl")) as f:
                         lines = sum(1 for ln in f if ln.strip())
-                    else:
-                        lines = [ln for ln in f if ln.strip()]
-            except FileNotFoundError:
-                pass
-            tomb = self._tombstones(d)
+                else:
+                    lines = self._file_as(
+                        os.path.join(d, "tail.jsonl"), seg_paths,
+                        lambda f: [ln for ln in f if ln.strip()], [],
+                    )
+            tomb: set = set()
+            if "tombstones.txt" in names:
+                tomb = self._file_as(
+                    os.path.join(d, "tombstones.txt"), seg_paths,
+                    lambda f: {ln.strip() for ln in f if ln.strip()}, set(),
+                )
         return seg_paths, lines, tomb
+
+    @staticmethod
+    def _listdir(d: str) -> list[str]:
+        try:
+            return os.listdir(d)
+        except (FileNotFoundError, NotADirectoryError):
+            return []
+
+    def _file_as(self, path: str, seg_paths: list[str], read, missing):
+        """``read(file)`` of the text file at ``path`` (``missing`` where
+        there is none), read again only when the file is not the one last
+        read: keyed on what the files are (the stream's segment names;
+        the file's inode, size and ``st_mtime_ns``), never on when they
+        were read. An append grows the size, a compaction names a new
+        segment, a rewrite is another inode or time. A file over
+        ``_KEPT_FILE_BYTES`` is not kept (a training read of a huge
+        uncompacted tail must not stay resident); what is handed out is
+        shared, and not to be changed. Called under the store lock."""
+        try:
+            st = os.stat(path)
+            key = (tuple(seg_paths), st.st_ino, st.st_size, st.st_mtime_ns)
+            kept = self._kept_files.get(path)
+            if kept is not None and kept[0] == key:
+                return kept[1]
+            with open(path) as f:
+                value = read(f)
+        except FileNotFoundError:
+            return missing
+        if st.st_size <= self._KEPT_FILE_BYTES:
+            self._kept_files[path] = (key, value)
+        else:
+            self._kept_files.pop(path, None)
+        return value
 
     @staticmethod
     def _decode_tail_lines(lines: Sequence[str]) -> Iterator[Event]:
@@ -679,6 +749,8 @@ class _ColumnarEvents(LEvents):
             self._recent_ids.pop(d, None)
             self._recent_complete.pop(d, None)
             self._warm_ms.pop(d, None)
+            for p in [p for p in self._kept_files if p.startswith(d)]:
+                del self._kept_files[p]
         return True
 
     def insert(self, event: Event, app_id: int, channel_id: int | None = None) -> str:
@@ -1628,24 +1700,11 @@ class _ColumnarEvents(LEvents):
                 event_names, target_entity_type, target_entity_id,
                 entities,
             )
-            if seg.ids is not None:
-                # explicit-id (compacted) segment: tombstones match by id
-                for row in rows:
-                    if str(seg.ids[int(row)]) not in tail_tomb:
-                        out.append(seg.row_event(int(row)))
-                continue
-            dead = seg_tomb.get(seg.name, ())
-            for row in rows:
-                if int(row) not in dead:
-                    out.append(seg.row_event(int(row)))
-        # a serving-time reader comes back every few milliseconds to a
-        # tail that has not moved: its lines are parsed once
-        parsed = self._tail_parsed
-        if parsed is None or parsed[0] != tail_lines:
-            parsed = (tail_lines, list(self._decode_tail_lines(tail_lines)))
-            with self._lock:
-                self._tail_parsed = parsed
-        for e in parsed[1]:
+            out.extend(
+                seg.row_event(int(row))
+                for row in self._live_rows(seg, rows, tail_tomb, seg_tomb)
+            )
+        for e in self._parsed_tail(tail_lines):
             if e.event_id not in tail_tomb and keep(e):
                 out.append(e)
         out.sort(key=BaseStorageClient.sorted_events_key, reverse=reversed)
@@ -1669,6 +1728,78 @@ class _ColumnarEvents(LEvents):
         ):
             out[e.entity_type, e.entity_id].append(e)
         return out
+
+    def targets_by_entities(
+        self, app_id: int, entity_type: str, entity_ids: Sequence[str],
+        channel_id: int | None = None,
+        event_names: Sequence[str] | None = None,
+    ) -> dict[str, list[str]]:
+        """The target ids straight from the columns, from one snapshot:
+        per segment a binary search in the entity order, the other filters
+        and the tombstones on the rows found, then two gathers (whose
+        entity, which target). No ``Event`` is made of a segment's row,
+        and nothing is sorted by time."""
+        d = self._stream_dir(app_id, channel_id)
+        seg_paths, tail_lines, tomb = self._snapshot(d)
+        tail_tomb, seg_tomb = self._split_tombstones(tomb)
+        out: dict[str, list[str]] = {eid: [] for eid in entity_ids}
+        for path in seg_paths:
+            seg = self._segment(path)
+            rows = seg.entity_rows(list(out))
+            rows = rows[self._matching_mask(
+                seg, None, None, entity_type, None, event_names, None, None,
+                rows=rows,
+            ) & (seg.tid_code[rows] >= 0)]
+            rows = self._live_rows(seg, rows, tail_tomb, seg_tomb)
+            for eid, target in zip(
+                seg.eid_vocab[seg.eid_code[rows]].tolist(),
+                seg.tid_vocab[seg.tid_code[rows]].tolist(),
+            ):
+                out[eid].append(target)
+        names = None if event_names is None else set(event_names)
+        for e in self._parsed_tail(tail_lines):
+            if (
+                e.entity_type == entity_type and e.entity_id in out
+                and e.target_entity_id is not None
+                and (names is None or e.event in names)
+                and e.event_id not in tail_tomb
+            ):
+                out[e.entity_id].append(e.target_entity_id)
+        count("filter.columnReads", 1)
+        return out
+
+    @staticmethod
+    def _live_rows(
+        seg: _Segment, rows: np.ndarray, tail_tomb: set[str],
+        seg_tomb: dict[str, set[int]],
+    ) -> np.ndarray:
+        """``rows`` of ``seg`` less the deleted ones. An explicit-id
+        (compacted) segment's tombstones match by id, a positional one's
+        by row."""
+        if seg.ids is not None:
+            if not tail_tomb:
+                return rows
+            dead = [i in tail_tomb for i in seg.ids[rows].tolist()]
+        else:
+            dead_rows = seg_tomb.get(seg.name)
+            if not dead_rows:
+                return rows
+            dead = [r in dead_rows for r in rows.tolist()]
+        return rows[~np.asarray(dead, dtype=bool)]
+
+    def _parsed_tail(self, tail_lines: list[str]) -> list[Event]:
+        """The events of a snapshot's tail lines. A serving-time reader
+        comes back every few milliseconds to a tail that has not moved:
+        its lines are parsed once (events are immutable, so readers share
+        them)."""
+        parsed = self._tail_parsed
+        if parsed is None or (
+            parsed[0] is not tail_lines and parsed[0] != tail_lines
+        ):
+            parsed = (tail_lines, list(self._decode_tail_lines(tail_lines)))
+            with self._lock:
+                self._tail_parsed = parsed
+        return parsed[1]
 
     @staticmethod
     def _matching_rows(
@@ -1733,13 +1864,6 @@ class _ColumnarEvents(LEvents):
                 return int(i)
             return -2  # matches nothing (tid/ttype use -1 for "none")
 
-        def among(vocab: np.ndarray, values) -> np.ndarray:
-            """A table over ``vocab``: which codes are one of ``values``
-            (one gather through it, however many are asked for)."""
-            table = np.zeros(vocab.size, dtype=bool)
-            table[_codes_of(vocab, values)] = True
-            return table
-
         if start_time is not None:
             mask &= col(seg.t_us) >= _to_us(start_time)
         if until_time is not None:
@@ -1756,7 +1880,7 @@ class _ColumnarEvents(LEvents):
                     return np.zeros(mask.size, dtype=bool)
                 mask &= col(codes) == code
         if event_names is not None:
-            mask &= among(seg.ev_vocab, event_names)[col(seg.ev_code)]
+            mask &= _among(seg.ev_vocab, event_names)[col(seg.ev_code)]
         return mask
 
     # ------------------------------------------------- bulk (PEvents side)

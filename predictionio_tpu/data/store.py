@@ -192,6 +192,29 @@ class _LEventStore:
             ).items(),
         ))
 
+    def targets_by_entities(
+        self,
+        app_name: str,
+        entity_type: str,
+        entity_ids: Sequence[str],
+        channel_name: str | None = None,
+        event_names: Sequence[str] | None = None,
+        timeout: float | None = None,
+    ) -> dict[str, list[str]]:
+        """For each of ``entity_ids`` (every id a key) the target entity
+        ids of its ``event_names`` events as the store holds them at the
+        call, in no order: a batch's "what has each user seen" without an
+        :class:`Event` a row where the driver keeps columns
+        (``LEvents.targets_by_entities``)."""
+        app_id, channel_id = resolve_app(app_name, channel_name)
+        return dict(self._scan(
+            timeout,
+            lambda: Storage.get_l_events().targets_by_entities(
+                app_id, entity_type, entity_ids, channel_id,
+                event_names=event_names,
+            ).items(),
+        ))
+
     def find(
         self,
         app_name: str,

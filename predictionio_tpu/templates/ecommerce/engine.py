@@ -41,6 +41,7 @@ from predictionio_tpu.controller import (
     WorkflowContext,
 )
 from predictionio_tpu.data.aggregator import BiMap, aggregate_properties_single
+from predictionio_tpu.data.event import Event
 from predictionio_tpu.data.store import LEventStore, PEventStore
 from predictionio_tpu.ops.als import ALSConfig, factors_to_host, train_als
 from predictionio_tpu.ops.topk import top_k_host
@@ -234,6 +235,9 @@ class ECommAlgorithm(FilteredItemRetrieval, JaxAlgorithm):
     params_class = ECommAlgorithmParams
     query_class = Query
     ITEM_TABLE = "item_factors"
+    #: (the constraint entity's events, the ids they fold to) of this
+    #: algorithm's last read that folded: :meth:`_unavailable_items`
+    _constraint_fold: tuple[list, frozenset] | None = None
 
     def __init__(self, params: ECommAlgorithmParams):
         super().__init__(params)
@@ -266,35 +270,49 @@ class ECommAlgorithm(FilteredItemRetrieval, JaxAlgorithm):
         )
 
     # ------------------------------------------------------------- serving
-    def _store_rules(self, users: Sequence[str]) -> tuple[dict[str, set], set]:
-        """What the event store says at query time, in ONE read where the
-        store can (``LEventStore.find_by_entities``): per user the items of
-        their view/buy events (parity: ECommAlgorithm's seen-events lookup
-        through ``LEventStore``), and the current ``items`` of the
-        ``constraint`` entity ``unavailableItems`` (parity: the template's
-        availability constraint). A store error means no filter."""
+    def _store_rules(self, users: Sequence[str]) -> tuple[dict[str, list], frozenset]:
+        """What the event store says at query time, both reads for the
+        whole batch: per user the items of their view/buy events as the
+        store's columns give them (``LEventStore.targets_by_entities``;
+        parity: ECommAlgorithm's seen-events lookup through
+        ``LEventStore``), and the current ``items`` of the ``constraint``
+        entity ``unavailableItems``, its ``$set`` / ``$unset`` / ``$delete``
+        events folded (parity: the template's availability constraint). A
+        store error in either means no filter."""
         p = self.params
         if not p.app_name:
-            return {}, set()
+            return {}, frozenset()
         constraint = ("constraint", "unavailableItems")
-        people = [("user", u) for u in users] if p.unseen_only else []
         try:
-            events = LEventStore.find_by_entities(
-                app_name=p.app_name,
-                entities=[*people, constraint],
-                event_names=[*p.seen_events, "$set", "$unset", "$delete"],
-            )
+            seen = LEventStore.targets_by_entities(
+                app_name=p.app_name, entity_type="user", entity_ids=users,
+                event_names=p.seen_events,
+            ) if p.unseen_only else {}
+            changes = LEventStore.find_by_entities(
+                app_name=p.app_name, entities=[constraint],
+                event_names=["$set", "$unset", "$delete"],
+            )[constraint]
         except Exception:
-            return {}, set()
-        pm = aggregate_properties_single(events.pop(constraint, ()))
-        seen = {
-            user: {
-                e.target_entity_id for e in found
-                if e.target_entity_id and e.event in p.seen_events
-            }
-            for (_, user), found in events.items()
-        }
-        return seen, set() if pm is None else set(pm.opt("items", list, []))
+            return {}, frozenset()
+        return seen, self._unavailable_items(changes)
+
+    def _unavailable_items(self, changes: list[Event]) -> frozenset:
+        """The ``items`` the constraint entity's events fold to: the very
+        set of the last fold while the events are the last fold's (equal
+        events fold the same, and a list of the very objects compares by
+        identity: the columnar store hands those out while its tail's
+        lines stand), so ``blocked_mask`` knows the set it was last given
+        without comparing its ids. Two batches in flight may both fold:
+        the holder is one tuple, read once and assigned once."""
+        held = self._constraint_fold
+        if held is None or held[0] != changes:
+            pm = aggregate_properties_single(changes)
+            held = (
+                changes,
+                frozenset(() if pm is None else pm.opt("items", list, [])),
+            )
+            self._constraint_fold = held
+        return held[1]
 
     def _rules(
         self, model: ECommModel, queries: Sequence[Query], seen: dict,

@@ -596,7 +596,9 @@ class FilteredItemRetrieval:
         one tuple, read once and assigned once)."""
         state = serving_state(model, FilteredServingState)
         cached = state.blocked
-        if cached is not None and cached[0] == blocked_ids:
+        if cached is not None and (
+            cached[0] is blocked_ids or cached[0] == blocked_ids
+        ):
             return cached[1]
         n = len(model.item_index)
         tiles = state.item_tiles
@@ -608,7 +610,7 @@ class FilteredItemRetrieval:
             import jax
 
             mask = jax.device_put(mask.reshape(tiles.shape[0], tiles.shape[2]))
-        state.blocked = (set(blocked_ids), mask)
+        state.blocked = (frozenset(blocked_ids), mask)  # itself, if it is one
         return mask
 
     def topk_filter(

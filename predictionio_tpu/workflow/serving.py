@@ -315,8 +315,6 @@ class QueryService:
         times the interpreter lock and catches the host standing still.
         It holds the stats, not the service, and ends with it (or once
         nobody holds the service any more)."""
-        import os
-
         from predictionio_tpu.serving.lockbeat import LockBeat
 
         http_stats = self._http_stats
@@ -326,13 +324,7 @@ class QueryService:
             workers = batcher_stats.cpu_ns_workers if batcher_stats else 0
             return workers + http_stats.cpu_ns_riders
 
-        return LockBeat(
-            self._lock_stats, cpu_total_ns,
-            os.path.join(
-                Storage.base_dir(), "deployments", f"stalls-{os.getpid()}.txt"
-            ),
-            owner=self,
-        ).start()
+        return LockBeat(self._lock_stats, cpu_total_ns, owner=self).start()
 
     def _feedback_worker(self) -> None:
         assert self._feedback_queue is not None

@@ -1767,3 +1767,102 @@ def test_piolint_baseline_only_ratchets_down():
     # the other half of the ratchet — zero NON-baselined findings on the
     # real tree — is test_full_tree_lints_clean_and_fast's assertion;
     # duplicating the ~6 s whole-program lint here would buy nothing
+
+
+# ---------------------------------------------------------------------------
+# No traceback taken by a thread that does not hold the interpreter lock
+# ---------------------------------------------------------------------------
+
+#: position of ``all_threads`` among the positional arguments
+_ALL_THREADS_AT = {"enable": 1, "register": 2}
+
+
+def _lock_free_tracebacks(tree):
+    """(line, what) for every mention of ``dump_traceback_later`` in a
+    module's syntax tree, and every ``faulthandler.enable`` / ``.register``
+    that leaves ``all_threads`` on."""
+    modules, names = set(), {}  # aliases of faulthandler, and of its functions
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            modules |= {a.asname or a.name for a in node.names
+                        if a.name == "faulthandler"}
+        elif isinstance(node, ast.ImportFrom) and node.module == "faulthandler":
+            names.update({a.asname or a.name: a.name for a in node.names})
+    for node in ast.walk(tree):
+        if isinstance(node, ast.alias):
+            mention = node.name
+        elif isinstance(node, ast.Attribute):
+            mention = node.attr
+        elif isinstance(node, ast.Name):
+            mention = names.get(node.id, node.id)
+        else:
+            mention = None
+        if mention == "dump_traceback_later":
+            yield node.lineno, "dump_traceback_later"
+        if not isinstance(node, ast.Call):
+            continue
+        f = node.func
+        if (isinstance(f, ast.Attribute) and isinstance(f.value, ast.Name)
+                and f.value.id in modules):
+            called = f.attr
+        else:
+            called = names.get(f.id) if isinstance(f, ast.Name) else None
+        if called not in _ALL_THREADS_AT:
+            continue
+        at = _ALL_THREADS_AT[called]
+        given = [kw.value for kw in node.keywords if kw.arg == "all_threads"]
+        given += node.args[at:at + 1]
+        if not (len(given) == 1 and isinstance(given[0], ast.Constant)
+                and given[0].value is False):
+            yield node.lineno, f"faulthandler.{called} with all_threads left on"
+
+
+def _lock_free_traceback_hazards(root):
+    """Every module under ``root/predictionio_tpu``: CPython's watchdog
+    thread (``dump_traceback_later``) walks the other threads' frames
+    without the interpreter lock, and among running threads that is signal
+    11 (ISSUE 42); ``enable`` / ``register`` with ``all_threads`` make the
+    same walk from a signal handler. ``faulthandler.dump_traceback`` stays
+    allowed: it runs in the calling thread, which holds the lock."""
+    found = []
+    for here, dirs, files in os.walk(os.path.join(root, "predictionio_tpu")):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        for name in sorted(f for f in files if f.endswith(".py")):
+            rel = os.path.relpath(os.path.join(here, name), root)
+            with open(os.path.join(root, rel), "rb") as fh:
+                tree = ast.parse(fh.read(), filename=rel)
+            found += [f"{rel}:{line}: {what}" for line, what in _lock_free_tracebacks(tree)]
+    return found
+
+
+def test_no_module_takes_tracebacks_without_the_interpreter_lock():
+    assert _lock_free_traceback_hazards(REPO) == []
+
+
+@pytest.mark.parametrize("source, hazards", [
+    ("import faulthandler\nfaulthandler.dump_traceback_later(0.6)\n", 1),
+    ("import faulthandler as fh\nfh.dump_traceback_later(0.6, repeat=True)\n", 1),
+    ("from faulthandler import dump_traceback_later as later\nlater(1)\n", 2),
+    ("import faulthandler\narm = faulthandler.dump_traceback_later\n", 1),
+    ("import faulthandler\nfaulthandler.enable()\n", 1),
+    ("import faulthandler, signal\nfaulthandler.register(signal.SIGUSR1)\n", 1),
+    ("from faulthandler import enable\nenable(all_threads=True)\n", 1),
+    ("import faulthandler, sys\nfaulthandler.enable(sys.stderr, flag)\n", 1),
+    ("import faulthandler\nfaulthandler.enable(all_threads=False)\n", 0),
+    ("import faulthandler, signal, sys\n"
+     "faulthandler.register(signal.SIGUSR1, sys.stderr, False)\n", 0),
+    # taken by the calling thread, under the lock it holds
+    ("import faulthandler\nfaulthandler.dump_traceback(all_threads=True)\n", 0),
+    ("import sys\nframes = sys._current_frames()\n", 0),
+])
+def test_the_guard_sees_a_lock_free_traceback_in_a_copy_of_the_beat(
+        tmp_path, source, hazards):
+    """The beat's own module with the hazard written back in."""
+    serving = tmp_path / "predictionio_tpu" / "serving"
+    serving.mkdir(parents=True)
+    with open(os.path.join(REPO, "predictionio_tpu", "serving", "lockbeat.py")) as fh:
+        beat = fh.read()
+    (serving / "lockbeat.py").write_text(beat + "\n" + source)
+    found = _lock_free_traceback_hazards(str(tmp_path))
+    assert len(found) == hazards, found
+    assert all(f.startswith("predictionio_tpu/serving/lockbeat.py:") for f in found)

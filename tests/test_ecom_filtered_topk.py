@@ -251,7 +251,8 @@ def test_a_filtered_batch_counts_its_pairs_and_its_pair_bucket():
                        counts={**counts, "filter.pairBucket.256": 2})
     assert stats.to_json()["filter"] == {
         "excludedIds": 0, "excludedPairs": 8, "categoryRows": 0, "hostPath": 0,
-        "shortAnswers": 0, f"pairBucket.{FILTER_PAIR_FLOOR}": 2, "pairBucket.256": 2}
+        "shortAnswers": 0, "columnReads": 0, "eventReads": 0,
+        f"pairBucket.{FILTER_PAIR_FLOOR}": 2, "pairBucket.256": 2}
 
 
 def test_bucket_width_is_a_pow2_with_a_floor():

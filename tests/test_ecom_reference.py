@@ -36,6 +36,10 @@ LIMITS = {"serve_tol_rel": 5e-5, "serve_tol_abs": 1e-6, "serve_rms_rel_err": 2e-
 
 @pytest.fixture()
 def shop(memory_storage_env, monkeypatch):
+    return _shop_in(memory_storage_env, monkeypatch)
+
+
+def _shop_in(storage, monkeypatch):
     """Seeded tables as a model, histories and the constraint in the store,
     and the same rules as the reference takes them."""
     monkeypatch.setattr(als, "FILTER_TILE", 256)  # three tiles, the last ragged
@@ -52,8 +56,8 @@ def shop(memory_storage_env, monkeypatch):
         user_index=BiMap({str(u): u for u in range(N_USERS)}), item_index=item_index,
         categories=cats, popularity=np.zeros(N_ITEMS, np.float32),
         category_codes=codes, category_index=category_index)
-    app_id = memory_storage_env.get_meta_data_apps().insert(App(id=0, name=APP))
-    le = memory_storage_env.get_l_events()
+    app_id = storage.get_meta_data_apps().insert(App(id=0, name=APP))
+    le = storage.get_l_events()
     le.init(app_id)
     seen = {u: rng.choice(N_ITEMS, int(rng.integers(1, 30)), replace=False)
             for u in range(N_USERS)}
@@ -178,6 +182,121 @@ def test_a_store_error_means_no_filter(shop, monkeypatch):
     monkeypatch.setattr(store.LEventStore, "find_by_entities", boom)
     assert algo._store_rules(["1", "2"]) == ({}, set())
     assert len(algo.predict(model, Query(user="1", num=5)).item_scores) == 5
+
+
+@pytest.fixture(params=["columnar", "sqlite"])
+def shop_on_disk(request, tmp_path, monkeypatch):
+    """The same shop with its events in a driver that keeps columns (the
+    histories sealed into a segment, the constraint in the tail behind it,
+    as the benchmark's cell has them) and in one that does not."""
+    from predictionio_tpu.data.storage import Storage
+
+    source = {
+        "columnar": {"PIO_STORAGE_SOURCES_EV_TYPE": "columnar",
+                     "PIO_STORAGE_SOURCES_EV_PATH": str(tmp_path / "events")},
+        "sqlite": {"PIO_STORAGE_SOURCES_EV_TYPE": "sqlite",
+                   "PIO_STORAGE_SOURCES_EV_PATH": str(tmp_path / "pio.db")},
+    }[request.param]
+    Storage.configure({
+        "PIO_STORAGE_REPOSITORIES_METADATA_SOURCE": "MEM",
+        "PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE": "EV",
+        "PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE": "MEM",
+        "PIO_STORAGE_SOURCES_MEM_TYPE": "memory", **source})
+    try:
+        made = _shop_in(Storage, monkeypatch)
+        if request.param == "columnar":
+            le = Storage.get_l_events()
+            app_id = Storage.get_meta_data_apps().get_by_name(APP).id
+            constraint, = le.find(app_id, entity_type="constraint")
+            assert le.delete(constraint.event_id, app_id)
+            assert Storage.get_p_events().compact(app_id) > 0
+            le.insert(constraint, app_id)
+        yield request.param, made
+    finally:
+        Storage.configure(None)
+
+
+@pytest.mark.parametrize("how", ["predict", "batch_unpinned", "batch_pinned"])
+def test_engine_agrees_with_the_plain_reference_whatever_driver_holds_the_events(
+        shop_on_disk, how):
+    _, made = shop_on_disk
+    test_engine_agrees_with_the_plain_reference(made, how)
+
+
+def _batch_counts(algo, model, queries):
+    collector = spans.Collector()
+    previous = spans.bind(collector)
+    try:
+        got = dict(algo.batch_predict(model, list(enumerate(queries))))
+    finally:
+        spans.bind(previous)
+    return got, collector.take_counts()
+
+
+def test_a_batch_counts_which_way_the_store_answered_it(shop_on_disk):
+    """``filter.columnReads`` a batch whose users' seen items came out of
+    the store's columns, ``filter.eventReads`` one that went through
+    ``Event`` objects (a driver without columns)."""
+    driver, (algo, model, queries, *_) = shop_on_disk
+    _, counts = _batch_counts(algo, model, queries[:8])
+    mine, other = (("filter.columnReads", "filter.eventReads") if driver == "columnar"
+                   else ("filter.eventReads", "filter.columnReads"))
+    assert counts[mine] == 1 and other not in counts
+    _, counts = _batch_counts(algo, model, queries[8:12])
+    assert counts[mine] == 1
+
+
+def test_a_batch_on_the_memory_driver_counts_an_event_read(shop):
+    algo, model, queries, *_ = shop
+    _, counts = _batch_counts(algo, model, queries[:8])
+    assert counts["filter.eventReads"] == 1 and "filter.columnReads" not in counts
+
+
+@pytest.mark.parametrize("read", ["targets_by_entities", "find_by_entities"])
+def test_an_error_in_either_store_read_means_no_filter(shop, monkeypatch, read):
+    algo, model, queries, _, unavailable, *_ = shop
+    from predictionio_tpu.data import store
+
+    seen, blocked = algo._store_rules(["1", "2"])
+    assert seen["1"] and seen["2"] and blocked == {str(int(i)) for i in unavailable}
+
+    def boom(*a, **kw):
+        raise RuntimeError("store down")
+
+    monkeypatch.setattr(store.LEventStore, read, boom)
+    assert algo._store_rules(["1", "2"]) == ({}, set())
+    got, counts = _batch_counts(algo, model, queries[:8])
+    assert all(len(got[i].item_scores) == NUM for i in range(1, 8))
+    assert "filter.columnReads" not in counts
+
+
+def test_the_constraint_is_folded_once_while_its_events_stand_and_again_when_they_change(
+        shop_on_disk):
+    """While the constraint's events are the last read's (the columnar
+    driver hands out the very objects of its parsed tail; another driver
+    equal ones), the fold and ``blocked_mask``'s key are the same object
+    batch after batch; a ``$set`` or a ``$delete`` posted between two
+    reads shows in the second."""
+    from predictionio_tpu.data.storage import Storage
+
+    _, (algo, model, _, _, unavailable, *_) = shop_on_disk
+    app_id = Storage.get_meta_data_apps().get_by_name(APP).id
+    le = Storage.get_l_events()
+    _, first = algo._store_rules(["1"])
+    _, again = algo._store_rules(["2"])
+    assert first == again == {str(int(i)) for i in unavailable}
+    assert again is first
+    mask = algo.blocked_mask(model, first)
+    assert algo.blocked_mask(model, again) is mask
+    le.insert(Event(event="$set", entity_type="constraint", entity_id="unavailableItems",
+                    properties=DataMap({"items": ["3", "4"]})), app_id)
+    _, changed = algo._store_rules(["1"])
+    assert changed == {"3", "4"}
+    assert algo.blocked_mask(model, changed) is not mask
+    assert np.flatnonzero(algo.blocked_mask(model, changed)).tolist() == [3, 4]
+    le.insert(Event(event="$delete", entity_type="constraint",
+                    entity_id="unavailableItems"), app_id)
+    assert algo._store_rules(["1"])[1] == set()
 
 
 def test_two_batches_at_once_are_each_served_the_mask_of_their_own_read(shop, monkeypatch):
