@@ -767,6 +767,48 @@ def _gram_chunk(
     return _partials(Q, chunk_val, chunk_mask, implicit, alpha, hi)
 
 
+#: rows a block of :func:`_gram_all_rows`
+_YTY_BLOCK_ROWS = 1024
+
+
+def _gram_all_rows(
+    table: jax.Array,  # [N, K] — model-sharded on a 2-axis mesh
+    hi: jax.lax.Precision,
+    mesh: Mesh | None,
+    model_axis: str | None,
+) -> jax.Array:
+    """``table.T @ table`` of a whole factor table in float32: a Gramian a
+    block of 1,024 rows, the blocks summed pairwise. One matmul over a
+    million rows adds thousands of partial sums into one float32
+    accumulator, each add rounded relative to the running sum; that error
+    sits in every system of the half-sweep and reaches its solution
+    amplified by the system's condition. Pairwise it is a few ulps.
+
+    With a model axis each device sums its own shard's blocks so, and the
+    shards' Gramians are psum'd over ``model`` (ICI): [K, K] a device
+    crosses, the table never moves. The result is replicated."""
+
+    def local(t):
+        n, k = t.shape
+        blocks = bucket_width(-(-n // _YTY_BLOCK_ROWS), 1)  # a power of two, to halve
+        rows = blocks * _YTY_BLOCK_ROWS
+        t = jnp.pad(t, ((0, rows - n), (0, 0))).reshape(blocks, _YTY_BLOCK_ROWS, k)
+        g = jnp.einsum("bnk,bnj->bkj", t, t, precision=hi)
+        while g.shape[0] > 1:
+            g = g.reshape(g.shape[0] // 2, 2, k, k)
+            g = g[:, 0] + g[:, 1]
+        return g[0]
+
+    if mesh is None or model_axis is None:
+        return local(table)  # one device, or a table every device holds whole
+    return jax.shard_map(
+        lambda t: jax.lax.psum(local(t), model_axis),
+        mesh=mesh,
+        in_specs=PartitionSpec(model_axis, None),
+        out_specs=PartitionSpec(None, None),
+    )(table)
+
+
 def _finish_solve(
     A: jax.Array,  # [.., K, K] accumulated Gramian (no reg / yty yet)
     b: jax.Array,  # [.., K]
@@ -816,15 +858,9 @@ def _half_sweep(
     yty = None
     if implicit:
         # Gramian over the other side; sentinel row is zero so it is a
-        # no-op term. From the model-sharded table this is a sharded
-        # matmul whose contraction psums over the model axis (ICI).
-        if mesh is not None:
-            yty = jnp.matmul(
-                other_factors.T, other_factors, precision=hi,
-                out_sharding=NamedSharding(mesh, PartitionSpec(None, None)),
-            )
-        else:
-            yty = jnp.matmul(other_factors.T, other_factors, precision=hi)
+        # no-op term
+        with jax.named_scope("pio_als_yty"):
+            yty = _gram_all_rows(other_factors, hi, mesh, model_axis)
 
     # --- normal rows: solve in-chunk, scatter into the factor table ------
     for ch in bucketed.normal:
@@ -1199,7 +1235,9 @@ def train_als(
     """Train factor matrices from COO ratings.
 
     ``info``, when given, receives the kernel decisions this train took
-    (backend, solver, bucketing, precision, rank, mesh) and its timing
+    (backend, solver, bucketing, ``objective`` -- and under the implicit
+    one ``alpha`` and ``positiveEntries`` --, precision, rank, mesh), the
+    skew of the data a side (``hotRows``, ``hotGroups``) and its timing
     (``bucketingSeconds``: transfer, sort and fill, of which
     ``transferSeconds`` is the host-to-device copy; ``initSeconds``: the
     two tables seeded; ``sweepSeconds`` — the first sweep carries the
@@ -1272,10 +1310,16 @@ def train_als(
         # the solver as a device trace names it
         "solveKernel": solve_kernel_name(solver, rank),
         "bucketing": "device" if use_device_bucketing else "host",
+        "objective": "implicit" if config.implicit else "explicit",
         "precision": config.precision,
         "rank": rank,
         "mesh": None if mesh is None else dict(mesh.shape),
     }
+    if config.implicit:
+        decisions["alpha"] = float(config.alpha)
+        # entries with preference 1 (this process's, in a multi-process
+        # job): the terms of the right-hand sides, either side's
+        decisions["positiveEntries"] = int(np.count_nonzero(np.asarray(vals) > 0))
     logger.info("ALS kernel decisions: %s", decisions)
     # the timing fields cost a host sync after bucketing and after every
     # sweep: taken only for a caller that asked for them
@@ -1380,6 +1424,13 @@ def train_als(
     info["solveSystemsPerSweep"] = _solve_systems(user_bucketed) + _solve_systems(
         item_bucketed
     )
+    # the skew: rows wider than the widest bucket, and the groups of
+    # hot_group_slots their Gramians are accumulated in, a side
+    sides = {"user": user_bucketed, "item": item_bucketed}
+    info["hotRows"] = {
+        k: sum(len(hr) - 1 for hr in b.hot_rows) for k, b in sides.items()
+    }
+    info["hotGroups"] = {k: len(b.hot) for k, b in sides.items()}
     if timed:
         info["bucketingSeconds"] = round(
             bucketing.seconds + (transfer.seconds if transfer else 0.0), 3

@@ -22,7 +22,7 @@ HBM = 15.75e9  # what the compiler lets a v5e program use of the chip's 16 GiB
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def four_chips():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
@@ -30,7 +30,12 @@ def one_chip():
         topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    return SingleDeviceSharding(four_chips[0])
 
 
 @pytest.fixture()
@@ -139,6 +144,64 @@ def test_the_als_solve_lowers_through_mosaic_and_fits(
     assert memory.temp_size_in_bytes <= (
         chunk * rank * lanes * 4 + chunk * width * lanes * 4 + (64 << 20)
     ), memory.temp_size_in_bytes
+
+
+def test_the_implicit_sweeps_shared_gramian_is_blocks_summed_pairwise_and_fits(
+    one_chip, no_compile_cache
+):
+    """The implicit objective's ``X^T X`` over a whole table, at the Taste
+    Profile's user side (benchmark/configs/als_tasteprofile.json's source:
+    1,019,318 rows and the sentinel): a Gramian a block of 1,024 rows, then
+    ten halvings, never one accumulator over a million rows; its
+    temporaries are one padded copy of the table and the blocks' Gramians."""
+    from predictionio_tpu.ops import als
+
+    rows, rank = 1_019_318 + 1, 64
+    compiled = jax.jit(als._gram_all_rows, static_argnums=(1, 2, 3)).lower(
+        jax.ShapeDtypeStruct((rows, rank), jnp.float32, sharding=one_chip),
+        jax.lax.Precision.HIGHEST, None, None,
+    ).compile()
+    text = compiled.as_text()
+    blocks = 1024
+    assert f"f32[{blocks},{als._YTY_BLOCK_ROWS},{rank}]" in text  # the table in blocks
+    assert f"f32[{blocks},{rank},{rank}]" in text  # a Gramian a block
+    for half in (512, 64, 8, 1):  # the halvings, down to one
+        assert f"f32[{half},2,{rank},{rank}]" in text, half
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == rank * 128 * 4  # [64, 64], rows padded to the lanes
+    padded = blocks * als._YTY_BLOCK_ROWS * rank * 4
+    assert memory.temp_size_in_bytes <= padded + blocks * rank * 128 * 4 + (16 << 20)
+
+
+def test_the_shared_gramian_over_a_model_axis_sums_each_shard_pairwise(
+    four_chips, no_compile_cache
+):
+    """The same Gramian with the table's rows sharded over the model axis of
+    a 2 x 2 mesh of v5e chips (Explicit axes, as ``mesh_context`` makes
+    them): under ``shard_map`` each chip sums the blocks of its own half
+    pairwise, and one all-reduce of a [64, 64] array joins the halves; no
+    chip holds the other's rows."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
+
+    from predictionio_tpu.ops import als
+
+    mesh = Mesh(np.array(four_chips).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Explicit,) * 2)
+    rows, rank = 1_019_320, 64  # train_als pads the table to the model axis
+    compiled = jax.jit(als._gram_all_rows, static_argnums=(1, 2, 3)).lower(
+        jax.ShapeDtypeStruct((rows, rank), jnp.float32,
+                             sharding=NamedSharding(mesh, PartitionSpec("model", None))),
+        jax.lax.Precision.HIGHEST, mesh, "model",
+    ).compile()
+    text = compiled.as_text()
+    blocks = 512  # 509,660 rows a chip
+    assert f"f32[{blocks},{als._YTY_BLOCK_ROWS},{rank}]" in text
+    assert f"f32[{blocks // 2},2,{rank},{rank}]" in text
+    assert f"f32[{2 * blocks},{als._YTY_BLOCK_ROWS},{rank}]" not in text
+    reduces = [line for line in text.splitlines() if re.search(r"= \S+ all-reduce", line)]
+    assert len(reduces) == 1 and f"f32[{rank},{rank}]" in reduces[0], reduces
+    assert "all-gather" not in text and "collective-permute" not in text
 
 
 @pytest.mark.parametrize("pairs", [128, 1024])
