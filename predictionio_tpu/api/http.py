@@ -1,10 +1,17 @@
-"""Stdlib HTTP wrapper around the handler cores.
+"""The HTTP/1.1 transport in front of the handler cores.
 
 Parity: the spray-can ``Http.Bind`` layer of ``data/api/EventServer.scala``
-and ``core/workflow/CreateServer.scala``. A small threading HTTP server is
-all the transport the framework needs — handler logic lives in the
-transport-agnostic service objects, matching the reference's actor/route
-split and keeping tests in-process.
+and ``core/workflow/CreateServer.scala``. A threading server, one thread a
+connection, is all the transport the framework needs — handler logic lives
+in the transport-agnostic service objects, matching the reference's
+actor/route split and keeping tests in-process.
+
+Of the standard library it keeps the sockets (``ThreadingHTTPServer``, the
+per-connection ``setup`` / ``handle`` / ``finish``); the head of a request
+is read and the head of its answer written here, in a few ``bytes``
+operations a request, because every request pays for them on its thread
+under the interpreter lock (PERF.md, the HTTP layer). What the transport
+accepts and refuses is in docs/serving.md ("The transport").
 """
 
 from __future__ import annotations
@@ -12,10 +19,13 @@ from __future__ import annotations
 import json
 import logging
 import os
+import re
 import ssl
 import threading
 import time
 import urllib.parse
+from email.utils import formatdate
+from http import HTTPStatus
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import TYPE_CHECKING, Callable, Mapping
 
@@ -73,6 +83,73 @@ Dispatcher = Callable[..., "object"]
 #: counts every request's CPU time; the group's sums go to the service
 #: together
 RIDERS_A_CPU_READ = 64
+
+#: the longest request line or header line read, and the most lines a head
+#: may hold after the request line, the blank one included: the limits
+#: ``http.server`` and ``http.client`` enforced before this module read
+#: heads itself
+_MAX_LINE = 65536
+_MAX_HEADER_LINES = 100
+
+_METHODS = frozenset({"GET", "POST", "DELETE", "PUT"})
+_VERSION = re.compile(rb"HTTP/(\d{1,10})\.(\d{1,10})")
+
+#: every answer's first line, with the standard library's phrases
+_STATUS_LINES = {
+    status.value: f"HTTP/1.1 {status.value} {status.phrase}\r\n".encode("latin-1")
+    for status in HTTPStatus
+}
+
+_SERVER_LINE = (
+    f"Server: {BaseHTTPRequestHandler.server_version} "
+    f"{BaseHTTPRequestHandler.sys_version}\r\nDate: "
+)
+
+#: (second, the ``Server`` and ``Date`` lines made in it), shared by every
+#: thread of every server in the process. The tuple is built, then
+#: published in one store: a thread that reads the old one meanwhile
+#: writes a date a second old, and two that build at once build the same
+_dated: tuple[int, bytes] = (0, b"")
+
+
+def _server_and_date() -> bytes:
+    """An answer's ``Server`` and ``Date`` lines; the date is formatted at
+    most once a second."""
+    global _dated
+    second = int(time.time())
+    dated = _dated
+    if dated[0] != second:
+        line = _SERVER_LINE + formatdate(second, usegmt=True) + "\r\n"
+        dated = _dated = (second, line.encode("latin-1"))
+    return dated[1]
+
+
+class _Refused(Exception):
+    """A head this server does not serve: ``(status, message)``. The
+    answer closes the connection — what follows a head that could not be
+    read is not known to be a request."""
+
+
+def _keeps_alive(version: bytes) -> bool:
+    """Whether a request of this HTTP version keeps its connection open
+    by default, by ``http.server``'s reading of the version token
+    (``HTTP/<digits>.<digits>``, leading zeros ignored): 1.1 and later
+    minors do, 1.0 does not; 2.0 and later is a 505, anything else,
+    HTTP/0.9 included (an answer with no head cannot say how it ends),
+    a 400."""
+    if version == b"HTTP/1.1":
+        return True
+    if version == b"HTTP/1.0":
+        return False
+    match = _VERSION.fullmatch(version)
+    number = (int(match[1]), int(match[2])) if match else (0, 0)
+    if number < (1, 0):
+        raise _Refused(
+            400, f"Bad request version ({version[:32].decode('latin-1')!r})."
+        )
+    if number >= (2, 0):
+        raise _Refused(505, "Invalid HTTP version.")
+    return number >= (1, 1)
 
 
 class _LengthReader:
@@ -184,7 +261,7 @@ def _resolve_span_sink(dispatch: Dispatcher) -> Callable | None:
     ``RIDERS_A_CPU_READ`` requests that rode in a batch is full (or the
     connection ends), what was counted on the thread's collector over
     the group: ``rider.requestNs`` (each rider's stretch on this thread,
-    ``Handler.parse_request`` to the flush, summed), ``rider.cpuNs`` (the
+    the request line read to the flush, summed), ``rider.cpuNs`` (the
     thread's CPU time since the group before) and what the service
     counted (the batcher: ``rider.requests``, ``rider.queuedNs``,
     ``rider.giveWayNs``). Servers without one bind no collector and
@@ -200,22 +277,23 @@ def _make_handler(
 ):
     span_sink = _resolve_span_sink(dispatch)
 
+    # resolved once: a service names its streaming routes on its class
+    stream_routes = getattr(
+        getattr(dispatch, "__self__", None), "stream_routes", None
+    )
+
     class Handler(BaseHTTPRequestHandler):
-        protocol_version = "HTTP/1.1"
         #: per-connection socket timeout — bounds stalled clients (incl.
         #: the lazy TLS handshake, which runs on first I/O in this
         #: worker thread; see _make_server)
         timeout = 60
         #: keep-alive clients otherwise stall ~40 ms per request on the
-        #: Nagle/delayed-ACK interaction: headers and body would go out as
+        #: Nagle/delayed-ACK interaction should an answer ever leave as
         #: two segments, the second waiting on the client's delayed ACK
         disable_nagle_algorithm = True
-        #: buffer the response so status+headers+body leave in one send
+        #: buffer the response so head and body leave in one send
         #: (handle_one_request flushes wfile after each request)
         wbufsize = 64 * 1024
-
-        def log_message(self, fmt, *args):  # route through logging, not stderr
-            logger.debug("%s - %s", self.address_string(), fmt % args)
 
         def setup(self):
             super().setup()
@@ -227,14 +305,116 @@ def _make_handler(
             self._request_ns = 0
             self._cpu_ns = time.thread_time_ns() if span_sink else 0
 
-        def parse_request(self):
-            # the handler's first instruction on a request:
-            # handle_one_request has just read the request line (a
-            # keep-alive thread waits for its caller in that read) and
-            # parses the headers next
-            if self._collector is not None:
-                self._request_ns = time.perf_counter_ns()
-            return super().parse_request()
+        def handle_one_request(self):
+            """One request of the connection: its head read here (never by
+            ``http.server.parse_request`` and its ``email`` parser), then
+            ``_respond``."""
+            try:
+                # a keep-alive thread waits for its caller in this read
+                line = self.rfile.readline(_MAX_LINE + 1)
+                if not line:
+                    self.close_connection = True
+                    return
+                # the handler's first instruction on a request: the request
+                # line is in hand, the header lines are parsed next
+                if self._collector is not None:
+                    self._request_ns = time.perf_counter_ns()
+                try:
+                    self._read_head(line)
+                except _Refused as refused:
+                    self._refuse(*refused.args)
+                    return
+                self._respond()
+                self.wfile.flush()  # send the response if not already done
+            except TimeoutError as e:
+                # a read or a write timed out: discard this connection
+                logger.debug("Request timed out: %r", e)
+                self.close_connection = True
+
+        def _read_head(self, line: bytes) -> None:
+            """Parse the request line and read the header lines: sets
+            ``command``, ``path`` (the target as sent), ``headers`` (the
+            received spelling of each name to its value, the first of a
+            repeated name; handed to ``dispatch`` as it is), ``_length``,
+            ``_content_type``, ``_chunked`` and ``close_connection``, and
+            answers ``Expect: 100-continue``. Raises :class:`_Refused`."""
+            self._line = line
+            if len(line) > _MAX_LINE:
+                raise _Refused(414, "Request line too long.")
+            words = line.split()
+            if len(words) != 3:
+                raise _Refused(400, "Malformed request line.")
+            method, target, version = words
+            keep_alive = http11 = _keeps_alive(version)
+            command = method.decode("latin-1")
+            if command not in _METHODS:
+                raise _Refused(501, f"Unsupported method ({command[:32]!r}).")
+            if target[:2] == b"//":
+                # gh-87389: clients read //path as a URI without a scheme
+                target = b"/" + target.lstrip(b"/")
+            self.command = command
+            self.path = target.decode("latin-1")
+
+            headers: dict[str, str] = {}
+            low: dict[str, str] = {}  # the same values under lowered names
+            readline = self.rfile.readline
+            lines = 0
+            while True:
+                line = readline(_MAX_LINE + 1)
+                if len(line) > _MAX_LINE:
+                    raise _Refused(431, "Header line too long.")
+                lines += 1
+                if lines > _MAX_HEADER_LINES:
+                    raise _Refused(431, "Too many headers.")
+                if line in (b"\r\n", b"\n", b""):
+                    break
+                name, colon, value = line.decode("latin-1").partition(":")
+                if not colon or not name or name[0] in " \t" or name[-1] in " \t":
+                    # no name, a folded line (RFC 7230 3.2.4 lets a server
+                    # refuse one) or space before the colon: read leniently,
+                    # each hides a header from this parser or from the next
+                    raise _Refused(400, "Malformed header line.")
+                value = value.strip()
+                key = name.lower()
+                if key in low:
+                    # a repeated name: the first value stands, but a body's
+                    # length is never guessed
+                    if key == "content-length" and low[key] != value:
+                        raise _Refused(400, "Conflicting Content-Length.")
+                    headers.setdefault(name, low[key])
+                else:
+                    low[key] = headers[name] = value
+            self.headers = headers
+
+            connection = low.get("connection")
+            if connection is not None:
+                connection = connection.lower()
+                if connection == "close":
+                    keep_alive = False
+                elif connection == "keep-alive":
+                    keep_alive = True
+            self.close_connection = not keep_alive
+            length = low.get("content-length")
+            if length is None:
+                self._length = 0
+            elif length.isdecimal():
+                self._length = int(length)
+            else:
+                raise _Refused(400, "Content-Length is no number.")
+            self._content_type = low.get("content-type", "")
+            self._chunked = "chunked" in low.get("transfer-encoding", "").lower()
+            if http11 and low.get("expect", "").lower() == "100-continue":
+                # the caller holds its body back until it reads this
+                self.wfile.write(b"HTTP/1.1 100 Continue\r\n\r\n")
+                self.wfile.flush()
+
+        def _refuse(self, status: int, message: str) -> None:
+            logger.debug("refused with %d: %s", status, message)
+            self._send(
+                status,
+                json.dumps({"message": message}).encode(),
+                extra_headers={"Connection": "close"},
+            )
 
         def finish(self):
             # the connection ends: what is left of a group of riders
@@ -253,15 +433,28 @@ def _make_handler(
             return self._collector.take_counts()
 
         def _respond(self):
-            parsed = urllib.parse.urlparse(self.path)
+            target = self.path
+            if (
+                target[0] == "/"
+                and "?" not in target
+                and "#" not in target
+                and ";" not in target
+            ):
+                path, params = target, {}
+            else:
+                parsed = urllib.parse.urlparse(target)
+                path = parsed.path
+                params = {
+                    k: v[0] for k, v in urllib.parse.parse_qs(parsed.query).items()
+                }
             # health probes are transport-level (docs/operations.md):
             # answered before service dispatch so every server exposes
             # them uniformly and a wedged service layer cannot take the
             # liveness probe down with it
-            if self.command == "GET" and parsed.path == "/healthz":
+            if self.command == "GET" and path == "/healthz":
                 self._send(200, b'{"status": "ok"}')
                 return
-            if self.command == "GET" and parsed.path == "/readyz":
+            if self.command == "GET" and path == "/readyz":
                 self._ready_probe()
                 return
             if lifecycle is not None:
@@ -271,7 +464,7 @@ def _make_handler(
                 # and the in-flight count are one atomic step, so the
                 # drain's idle-wait can never miss a racing request.
                 if not lifecycle.try_begin_request():
-                    # Connection: close (send_header flips close_connection
+                    # Connection: close (_send flips close_connection
                     # too): the rejection never reads the request body, so
                     # a kept-alive connection would desync on the unread
                     # bytes — and a draining listener is going away anyway
@@ -285,23 +478,18 @@ def _make_handler(
                     )
                     return
                 try:
-                    self._dispatch_and_send(parsed)
+                    self._dispatch_and_send(path, params)
                 finally:
                     lifecycle.end_request()
                 return
-            self._dispatch_and_send(parsed)
+            self._dispatch_and_send(path, params)
 
-        def _dispatch_and_send(self, parsed):
-            params = {
-                k: v[0] for k, v in urllib.parse.parse_qs(parsed.query).items()
-            }
+        def _dispatch_and_send(self, path, params):
             # streaming routes (the bulk-ingest endpoint): the service
             # gets the raw body reader instead of a parsed JSON body, so
             # the payload is consumed incrementally — never materialized
-            owner = getattr(dispatch, "__self__", None)
-            stream_routes = getattr(owner, "stream_routes", None)
-            if stream_routes and (self.command, parsed.path) in stream_routes:
-                self._dispatch_stream(parsed, params)
+            if stream_routes and (self.command, path) in stream_routes:
+                self._dispatch_stream(path, params)
                 return
             riders = 0
             if self._collector is not None:
@@ -310,11 +498,7 @@ def _make_handler(
             body = None
             form: Mapping[str, str] | None = None
             with spans.span("httpRead"):
-                length = int(self.headers.get("Content-Length") or 0)
-                raw = self.rfile.read(length) if length else b""
-                ctype = (
-                    (self.headers.get("Content-Type") or "").split(";")[0].strip()
-                )
+                raw = self.rfile.read(self._length) if self._length else b""
                 if raw:
                     # Tolerant parse: clients (e.g. bare `curl -d`) often
                     # send JSON under a form-encoded default content type.
@@ -324,6 +508,7 @@ def _make_handler(
                     try:
                         body = json.loads(raw)
                     except json.JSONDecodeError:
+                        ctype = self._content_type.split(";")[0].strip()
                         if ctype == "application/x-www-form-urlencoded":
                             form = {
                                 k: v[0]
@@ -337,14 +522,14 @@ def _make_handler(
             try:
                 resp = dispatch(
                     method=self.command,
-                    path=parsed.path,
+                    path=path,
                     params=params,
                     body=body,
-                    headers=dict(self.headers),
+                    headers=self.headers,
                     form=form,
                 )
             except Exception:
-                logger.exception("Unhandled error for %s %s", self.command, parsed.path)
+                logger.exception("Unhandled error for %s %s", self.command, path)
                 self._send(500, b'{"message": "Internal Server Error"}')
                 return
             with spans.span("httpWrite"):
@@ -374,28 +559,23 @@ def _make_handler(
                 # the listener (and the process) down from here
                 after_send()
 
-        def _dispatch_stream(self, parsed, params):
-            te = (self.headers.get("Transfer-Encoding") or "").lower()
-            if "chunked" in te:
+        def _dispatch_stream(self, path, params):
+            if self._chunked:
                 reader = _ChunkedReader(self.rfile)
             else:
-                reader = _LengthReader(
-                    self.rfile, int(self.headers.get("Content-Length") or 0)
-                )
+                reader = _LengthReader(self.rfile, self._length)
             try:
                 resp = dispatch(
                     method=self.command,
-                    path=parsed.path,
+                    path=path,
                     params=params,
                     body=None,
-                    headers=dict(self.headers),
+                    headers=self.headers,
                     form=None,
                     stream=reader,
                 )
             except Exception:
-                logger.exception(
-                    "Unhandled error for %s %s", self.command, parsed.path
-                )
+                logger.exception("Unhandled error for %s %s", self.command, path)
                 self._send(500, b'{"message": "Internal Server Error"}')
                 self.close_connection = True
                 return
@@ -419,15 +599,14 @@ def _make_handler(
         def _send_stream(self, resp, chunks):
             """Chunked-transfer response: each piece goes out (and is
             flushed) the moment the service yields it."""
-            self.send_response(resp.status)
-            self.send_header(
-                "Content-Type",
-                getattr(resp, "content_type", "application/x-ndjson"),
+            self.wfile.write(
+                self._head(
+                    resp.status,
+                    getattr(resp, "content_type", "application/x-ndjson"),
+                    "Transfer-Encoding: chunked\r\n",
+                    getattr(resp, "headers", None),
+                )
             )
-            self.send_header("Transfer-Encoding", "chunked")
-            for k, v in (getattr(resp, "headers", None) or {}).items():
-                self.send_header(k, v)
-            self.end_headers()
             try:
                 for piece in chunks:
                     if not piece:
@@ -463,6 +642,41 @@ def _make_handler(
             status = 200 if report.get("ready") else 503
             self._send(status, json.dumps(report, default=str).encode())
 
+        def _head(
+            self,
+            status: int,
+            content_type: str,
+            framing: str,
+            extra_headers: Mapping[str, str] | None,
+        ) -> bytes:
+            """An answer's head, the blank line included: the status line,
+            ``Server``, ``Date``, ``Content-Type``, the ``framing`` line
+            (how the body ends), the extra headers in their order. An
+            extra ``Connection`` header decides, as a request's does,
+            whether the connection outlives the answer."""
+            lines = f"Content-Type: {content_type}\r\n{framing}"
+            if extra_headers:
+                for name, value in extra_headers.items():
+                    lines += f"{name}: {value}\r\n"
+                    if name.lower() == "connection":
+                        value = value.lower()
+                        if value == "close":
+                            self.close_connection = True
+                        elif value == "keep-alive":
+                            self.close_connection = False
+            if logger.isEnabledFor(logging.DEBUG):
+                logger.debug(
+                    '%s - "%s" %d',
+                    self.client_address[0],
+                    self._line.decode("latin-1").rstrip("\r\n"),
+                    status,
+                )
+            lines += "\r\n"
+            status_line = _STATUS_LINES.get(status)
+            if status_line is None:  # a status the standard library has no phrase for
+                status_line = f"HTTP/1.1 {status} \r\n".encode("latin-1")
+            return status_line + _server_and_date() + lines.encode("latin-1")
+
         def _send(
             self,
             status: int,
@@ -470,15 +684,16 @@ def _make_handler(
             content_type: str = "application/json; charset=UTF-8",
             extra_headers: Mapping[str, str] | None = None,
         ):
-            self.send_response(status)
-            self.send_header("Content-Type", content_type)
-            self.send_header("Content-Length", str(len(payload)))
-            for k, v in (extra_headers or {}).items():
-                self.send_header(k, v)
-            self.end_headers()
-            self.wfile.write(payload)
-
-        do_GET = do_POST = do_DELETE = do_PUT = _respond
+            # head and body in one write
+            self.wfile.write(
+                self._head(
+                    status,
+                    content_type,
+                    f"Content-Length: {len(payload)}\r\n",
+                    extra_headers,
+                )
+                + payload
+            )
 
     return Handler
 

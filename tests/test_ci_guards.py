@@ -1866,3 +1866,68 @@ def test_the_guard_sees_a_lock_free_traceback_in_a_copy_of_the_beat(
     found = _lock_free_traceback_hazards(str(tmp_path))
     assert len(found) == hazards, found
     assert all(f.startswith("predictionio_tpu/serving/lockbeat.py:") for f in found)
+
+
+def test_a_requests_head_stays_a_few_calls_on_its_thread():
+    """ISSUE 46 guard: every request pays for its head on its HTTP thread
+    under the interpreter lock, and the chip decides what that is worth
+    only when someone measures there. In between, this holds the cost by
+    count: a connection of loadgen-shaped requests through the real
+    ``Handler`` (a socket in memory, a trivial ``dispatch``), counted as
+    ``cProfile`` counts calls, built-ins included. ``http.server``'s
+    ``parse_request`` / ``send_response`` made 386 a request; a third of
+    that is the ceiling, and no frame of the ``email`` parser may be
+    among them."""
+    import cProfile
+    import io
+    import pstats
+
+    from predictionio_tpu.api.http import _make_handler
+
+    class Answer:
+        status = 200
+
+        def json_bytes(self):
+            return b'{"itemScores": []}'
+
+    def dispatch(method, path, params, body, headers, form):
+        assert (method, path, body) == ("POST", "/queries.json", {"user": "7", "num": 10})
+        assert headers["Content-Type"] == "application/json"
+        return Answer()
+
+    class Written(io.BytesIO):
+        def close(self):  # the handler closes its files; the bytes are read after
+            pass
+
+    class Socket:
+        def __init__(self, data):
+            self.read, self.written = io.BufferedReader(io.BytesIO(data)), Written()
+
+        def makefile(self, mode, bufsize=-1):
+            if "r" in mode:
+                return self.read
+            return io.BufferedWriter(self.written, bufsize)
+
+        def settimeout(self, seconds):
+            pass
+
+        def setsockopt(self, *args):
+            pass
+
+    body = b'{"user": "7", "num": 10}'
+    request = (
+        b"POST /queries.json HTTP/1.1\r\nHost: 127.0.0.1:8000\r\n"
+        b"Content-Type: application/json\r\nContent-Length: %d\r\n\r\n" % len(body)
+    ) + body
+    requests = 200
+    sock = Socket(request * requests)
+    profile = cProfile.Profile()
+    profile.enable()
+    _make_handler(dispatch)(sock, ("127.0.0.1", 1), object())
+    profile.disable()
+    assert sock.written.getvalue().count(b"HTTP/1.1 200 OK\r\n") == requests
+    stats = pstats.Stats(profile)
+    assert stats.total_calls / requests < 386 / 3, stats.total_calls / requests
+    parsers = ("email/parser.py", "email/feedparser.py", "email/message.py")
+    frames = sorted({code[0] for code in stats.stats if code[0].endswith(parsers)})
+    assert frames == []
